@@ -8,7 +8,8 @@ sequence inside each data shard; attention runs as ring attention over the
 ICI ``seq`` ring (``ops.ring``); the classification task stays byte-
 compatible with every other strategy.  On the short-sequence corpus it is a
 correctness/scale demonstration — its natural use is sequences that do not
-fit one device (``results/longcontext.json`` for the measured rows).
+fit one device (measured before PR 1 on v5e, record removed, not
+re-measured on this code).
 
 Multi-process: the spawn launcher runs this same path with the seq axis
 spanning OS processes (``multi-tpu-spawn-cls.py --mode sp``), pinned by

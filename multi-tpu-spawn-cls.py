@@ -10,7 +10,12 @@ with a localhost coordinator) and run the same mesh-DP training as
 On a TPU pod each host instead runs one process (use multi-tpu-jax-cls.py
 with ``--coordinator_address``); this single-command spawn flavor is for
 multi-process runs on one machine and is exercised in CI on the CPU backend,
-where each worker owns a slice of virtual devices.
+where each worker owns a slice of virtual devices.  A chip belongs to one
+process, and this launcher does not partition a host's chips among workers:
+``--num_processes > 1`` on a TPU backend is refused with one line rather than
+left to hang in the second worker's start-up.  The parent itself never
+initialises a backend — it reads ``jax.config`` (through ``parse_cli``) and
+nothing else — so the workers find the devices free.
 
     python multi-tpu-spawn-cls.py --num_processes 2
 """
@@ -27,6 +32,23 @@ from pdnlp_tpu.utils.config import Args, parse_cli
 # override lets concurrent/back-to-back gangs avoid a lingering listener
 # from a previously killed gang
 _PORT = int(os.environ.get("PDNLP_SPAWN_PORT", "12355"))
+
+
+def _worker_platform() -> str:
+    """The platform a worker would get, found WITHOUT initialising a backend
+    in this parent (which would hold the chip against its own workers):
+    ``JAX_PLATFORMS`` where it names one, else a short-lived probe process
+    that exits — and lets go of the device — before any worker starts."""
+    named = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if named:
+        return named.lower()
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        sys.exit("multi-tpu-spawn-cls.py: the backend probe failed:\n"
+                 + probe.stderr[-2000:])
+    return probe.stdout.split()[-1].lower()
 
 
 def _launch_gang(args, extra_argv, num_processes=None) -> list:
@@ -120,6 +142,15 @@ def main() -> int:
     # resume target a shrunken gang degrades to)
     if (multi or args.elastic) and not already_child \
             and args.process_id is None:
+        if multi and _worker_platform() == "tpu":
+            sys.exit(
+                f"multi-tpu-spawn-cls.py: --num_processes "
+                f"{args.num_processes} on a TPU backend: a chip belongs to "
+                "one process and this launcher gives no worker its own. One "
+                "process already drives every local chip "
+                "(multi-tpu-jax-cls.py); across hosts run one process per "
+                "host with --coordinator_address; for a multi-process "
+                "rehearsal on one machine set JAX_PLATFORMS=cpu.")
         return spawn(args)
     # --mode picks the sharding the gang executes: dp (default, the
     # mp.spawn analog), zero (fully-sharded state spanning the process

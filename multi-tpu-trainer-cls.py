@@ -18,16 +18,15 @@ def parse_trainer_args(argv=None) -> TrainerArgs:
     the whole framework)."""
     import argparse
 
-    from pdnlp_tpu.utils.config import add_dataclass_args
+    from pdnlp_tpu.utils.config import (
+        add_dataclass_args, enable_compilation_cache,
+    )
 
     p = argparse.ArgumentParser()
     add_dataclass_args(p, TrainerArgs)
     ns, _ = p.parse_known_args(argv)
-    targs = TrainerArgs(**vars(ns))
-    from pdnlp_tpu.utils.config import enable_compilation_cache
-
-    enable_compilation_cache(targs.to_args())
-    return targs
+    enable_compilation_cache()
+    return TrainerArgs(**vars(ns))
 
 
 if __name__ == "__main__":

@@ -25,7 +25,19 @@ id2label = {i: name for i, name in enumerate(LABELS)}
 
 def load_data(path: str) -> List[Example]:
     """Read the corpus and strip pre-tokenization spaces."""
-    with open(path, encoding="utf-8") as f:
+    try:
+        f = open(path, encoding="utf-8")
+    except FileNotFoundError as e:
+        raise FileNotFoundError(
+            f"no corpus at {path!r}: --data_path must name a JSON file "
+            "holding one array of [text, label] pairs (the reference's "
+            "data/train.json format: text optionally pre-tokenized with "
+            f"spaces, label an int in 0..{len(LABELS) - 1}).  The default "
+            "is the reference checkout's corpus, which is not part of this "
+            "repository — pass your own file, or a generated one "
+            "(chip_smoke.py and tests/conftest.py write seeded corpora in "
+            "this format)") from e
+    with f:
         raw = json.load(f)
     out: List[Example] = []
     for text, label in raw:

@@ -234,10 +234,9 @@ def encode(
     B, S = input_ids.shape
     shard_offset = 0
     if seq_axis is not None:
-        from pdnlp_tpu.parallel.compat import axis_size
-
         shard_offset = jax.lax.axis_index(seq_axis) * S
-        if position_ids is None and S * axis_size(seq_axis) > cfg.max_position:
+        if (position_ids is None
+                and S * jax.lax.axis_size(seq_axis) > cfg.max_position):
             raise ValueError("global sequence exceeds max_position")
     elif position_ids is None and S > cfg.max_position:
         # explicit position_ids (packed rows restart per segment) carry

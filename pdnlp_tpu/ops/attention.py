@@ -11,11 +11,13 @@ bias instead of boolean select so the whole score pipeline stays fused.
 - ``"pallas"`` — the hand-written flash-attention kernel in
   ``pdnlp_tpu.ops.flash`` (segment-native: packed rows mask in-kernel from
   ``segment_ids`` instead of a [B, 1, S, S] HBM bias);
-- ``"auto"`` — the measured default: pallas for SEGMENTED (packed) batches
-  on a real TPU backend, where skipping the quadratic segment-bias
-  materialization wins; XLA otherwise (``scripts/bench_attention.py``
-  measured XLA's fused attention ahead of the dense-path kernel at every
-  tested shape on v5e — README "Pallas flash attention vs XLA").
+- ``"auto"`` — the default: pallas for SEGMENTED (packed) batches on a
+  real TPU backend, where the quadratic segment-bias materialization is
+  skipped; XLA otherwise (XLA's fused attention measured ahead of the
+  dense-path kernel at every tested shape before PR 1 on v5e; record
+  removed, not re-measured on this code).  A program that GSPMD partitions
+  over several devices cannot hold a Mosaic kernel: there ``auto`` is
+  pinned to XLA (:func:`pin_auto_for_mesh`).
 
 Routing is resolved statically at trace time (:func:`routed_impl`); a
 *requested* pallas that cannot run (sequence not tiling the 128-wide
@@ -35,18 +37,18 @@ import jax.numpy as jnp
 
 NEG_INF = -1e9  # additive mask bias; well inside bf16/f32 range
 
-#: Measured per-(width, segmented) routing crossovers consulted by
-#: ``"auto"`` — the full-step numbers in ``results/longcontext.json``
-#: (v5e, bert-base-long, fwd+bwd+AdamW), re-measured by ``bench.py
-#: --longcontext`` on the chip after kernel changes.  Dense (unsegmented)
-#: long widths measured XLA ahead of the streamed kernel at every width on
-#: v5e, so auto keeps them on XLA even where the static rule would allow
-#: pallas; segmented widths carry no entries — the static
-#: packed-on-TPU-at-tiling-widths rule stands (the block-sparse tile skip
-#: is width-independent upside).  An entry here OVERRIDES the static rule
-#: for auto only; explicit ``--attn_impl pallas``/``xla`` never consults it.
+#: Per-(width, segmented) routing crossovers consulted by ``"auto"`` —
+#: full-step numbers (bert-base-long, fwd+bwd+AdamW) measured before PR 1 on
+#: v5e; record removed, not re-measured on this code (the kernel was
+#: rewritten since, and compiled for a chip for the first time in PR 21).
+#: Dense (unsegmented) long widths then measured XLA ahead of the kernel at
+#: every width, so auto keeps them on XLA even where the static rule would
+#: allow pallas; segmented widths carry no entries — the static
+#: packed-on-TPU-at-tiling-widths rule stands.  An entry here OVERRIDES the
+#: static rule for auto only; explicit ``--attn_impl pallas``/``xla`` never
+#: consults it.  Changing an entry is a measured change: it needs a chip cell.
 ROUTING_TABLE = {
-    (512, False): "xla",    # flash 0.66x full-step vs XLA (longcontext.json)
+    (512, False): "xla",    # flash 0.66x full-step vs XLA (before PR 1)
     (1024, False): "xla",   # 0.73x
     (2048, False): "xla",   # 0.67x
 }
@@ -96,6 +98,28 @@ def resolve_impl(requested: str, *, segmented: bool = False,
     return requested
 
 
+def pin_auto_for_mesh(requested: str, mesh, what: str = "attn_impl") -> str:
+    """``requested`` as a program that GSPMD partitions over ``mesh`` may
+    take it — for the attention kernel and the fused-CE kernel alike.
+
+    Mosaic kernels cannot be partitioned automatically (the compiler's own
+    refusal: "wrap the call in a shard_map"), so inside a ``jit`` whose
+    arguments are sharded over more than one device ``auto`` means the XLA
+    path, said once on stderr.  An explicit ``pallas`` passes through and
+    fails at lowering with that message; the ``shard_map`` strategies
+    (shardmap / sp / pp) run per-device bodies and never come here."""
+    if requested != "auto" or mesh is None or mesh.size == 1:
+        return requested
+    key = ("mesh", what)
+    if key not in _FALLBACK_WARNED:
+        _FALLBACK_WARNED.add(key)
+        print(f"[ops] {what}='auto' in a jit over a {mesh.size}-device mesh: "
+              "Mosaic kernels cannot be partitioned automatically — taking "
+              "the XLA path (a shard_map strategy keeps the kernels)",
+              file=sys.stderr)
+    return "xla"
+
+
 def routed_impl(requested: str, seq_len: int, *, segmented: bool = False,
                 dropout: bool = False, causal: bool = False,
                 backend: Optional[str] = None) -> str:
@@ -118,7 +142,7 @@ def routed_impl(requested: str, seq_len: int, *, segmented: bool = False,
             if impl == "pallas":  # the table OVERRODE the static rule
                 _warn_fallback(requested, seq_len,
                                "measured slower than XLA at this width "
-                               "(ROUTING_TABLE / results/longcontext.json)")
+                               "(ROUTING_TABLE)")
             return "xla"
         if measured == "pallas":
             # a measured win routes pallas even where the static rule is

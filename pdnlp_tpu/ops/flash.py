@@ -97,11 +97,18 @@ def _compiler_params():
     """Grid dimension semantics: (batch*head, q-tile) iterate freely; the
     innermost streamed tile axis is sequential (it owns the scratch
     accumulators).  Interpret mode ignores the hint."""
-    try:
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # pragma: no cover — very old pallas without params
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _tile_live(act_ref, n_heads, n_q, n_k, qi, ki):
+    """This grid step's entry of the tile-activity map.  The map rides the
+    grid as a scalar-prefetch operand: the whole ``[B * n_q * n_k]`` int32
+    vector sits in SMEM before the body runs (Mosaic refuses a ``(1, 1, 1)``
+    VMEM block over it at any width above one tile), flattened to 1-D
+    because SMEM pads every minor dim of a multi-dim array to a full tile."""
+    b = pl.program_id(0) // n_heads
+    return act_ref[(b * n_q + qi) * n_k + ki] != 0
 
 
 def supported_seq(seq_len: int) -> bool:
@@ -188,14 +195,14 @@ def _seg_bias_block(qs, ks):
 # ---------------------------------------------------------------- forward
 
 
-def _fwd_kernel(*refs, scale, n_k, segmented):
+def _fwd_kernel(act_ref, *refs, scale, n_heads, n_q, n_k, segmented):
     if segmented:
-        (q_ref, k_ref, v_ref, sq_ref, skv_ref, act_ref,
+        (q_ref, k_ref, v_ref, sq_ref, skv_ref,
          o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, bias_ref, act_ref,
+        (q_ref, k_ref, v_ref, bias_ref,
          o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr) = refs
-    ki = pl.program_id(2)
+    qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -203,7 +210,7 @@ def _fwd_kernel(*refs, scale, n_k, segmented):
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    @pl.when(act_ref[0, 0, 0] != 0)
+    @pl.when(_tile_live(act_ref, n_heads, n_q, n_k, qi, ki))
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale           # [Bq, D]
         k = k_ref[0].astype(jnp.float32)                   # [Bk, D]
@@ -235,78 +242,90 @@ def _fwd_kernel(*refs, scale, n_k, segmented):
         l_ref[0, 0] = l[:, 0]
 
 
-def _fwd(q3, k3, v3, mask, active, scale, n_heads, segmented):
-    """q3/k3/v3: [BN, S, D]; mask: [B,1,S] bias or (seg_kv, seg_q);
-    active: [B, nq, nk] tile map.  -> (o3, m[BN, 1, S], l[BN, 1, S]).
-    Mask/activity operands live at batch granularity and are broadcast
-    over heads via the ``bh // n_heads`` index maps — no N-fold HBM copy."""
-    BN, S, D = q3.shape
+def _block_specs(D, n_heads, segmented, at=lambda i, j: (i, j)):
+    """The operands' BlockSpecs for one grid order: ``at`` maps the two
+    inner grid indices to (q tile, k tile) — identity for the forward and
+    dQ grids, swapped for dK/dV.  Mask operands live at batch granularity
+    and are broadcast over heads via ``bh // n_heads`` — no N-fold HBM copy.
+    The tile map is scalar-prefetched (:func:`_tile_live`), so every index
+    map takes its ref as a trailing argument.  -> (q, kv, row, [mask...])."""
     n = n_heads
-    nq, nk = S // BLOCK_Q, S // BLOCK_K
-    grid = (BN, nq, nk)
-    kernel = functools.partial(_fwd_kernel, scale=scale, n_k=nk,
-                               segmented=segmented)
+
+    def spec(block, index):
+        def index_map(bh, i, j, act_ref):
+            return index(bh, *at(i, j))
+        return pl.BlockSpec(block, index_map)
+
+    q_spec = spec((1, BLOCK_Q, D), lambda bh, qi, ki: (bh, qi, 0))
+    kv_spec = spec((1, BLOCK_K, D), lambda bh, qi, ki: (bh, ki, 0))
+    row_spec = spec((1, 1, BLOCK_Q), lambda bh, qi, ki: (bh, 0, qi))
+    key_spec = spec((1, 1, BLOCK_K), lambda bh, qi, ki: (bh // n, 0, ki))
+    if segmented:   # (seg_q, seg_kv) operand order
+        mask_specs = [spec((1, BLOCK_Q, LANES),
+                           lambda bh, qi, ki: (bh // n, qi, 0)), key_spec]
+    else:
+        mask_specs = [key_spec]
+    return q_spec, kv_spec, row_spec, mask_specs
+
+
+def _mask_operands(mask, segmented):
+    """mask: [B,1,S] bias, or (seg_kv, seg_q) -> operands in spec order."""
     if segmented:
         seg_kv, seg_q = mask
-        mask_ops = [seg_q, seg_kv]
-        mask_specs = [
-            pl.BlockSpec((1, BLOCK_Q, LANES),
-                         lambda bh, qi, ki: (bh // n, qi, 0)),
-            pl.BlockSpec((1, 1, BLOCK_K),
-                         lambda bh, qi, ki: (bh // n, 0, ki)),
-        ]
-    else:
-        mask_ops = [mask]
-        mask_specs = [pl.BlockSpec((1, 1, BLOCK_K),
-                                   lambda bh, qi, ki: (bh // n, 0, ki))]
+        return [seg_q, seg_kv]
+    return [mask]
+
+
+def _fwd(q3, k3, v3, mask, active, scale, n_heads, segmented):
+    """q3/k3/v3: [BN, S, D]; mask: [B,1,S] bias or (seg_kv, seg_q);
+    active: [B, nq, nk] tile map.  -> (o3, m[BN, 1, S], l[BN, 1, S])."""
+    BN, S, D = q3.shape
+    nq, nk = S // BLOCK_Q, S // BLOCK_K
+    kernel = functools.partial(_fwd_kernel, scale=scale, n_heads=n_heads,
+                               n_q=nq, n_k=nk, segmented=segmented)
+    q_spec, kv_spec, row_spec, mask_specs = _block_specs(D, n_heads,
+                                                         segmented)
     o3, m, l = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, qi, ki: (bh, ki, 0)),
-            *mask_specs,
-            pl.BlockSpec((1, 1, 1), lambda bh, qi, ki: (bh // n, qi, ki)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_Q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, qi, ki: (bh, 0, qi)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BN, nq, nk),
+            in_specs=[q_spec, kv_spec, kv_spec, *mask_specs],
+            out_specs=[q_spec, row_spec, row_spec],
+            scratch_shapes=[
+                pltpu.VMEM((BLOCK_Q, D), jnp.float32),
+                pltpu.VMEM((BLOCK_Q, LANES), jnp.float32),
+                pltpu.VMEM((BLOCK_Q, LANES), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((BN, S, D), q3.dtype),
             jax.ShapeDtypeStruct((BN, 1, S), jnp.float32),
             jax.ShapeDtypeStruct((BN, 1, S), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((BLOCK_Q, D), jnp.float32),
-            pltpu.VMEM((BLOCK_Q, LANES), jnp.float32),
-            pltpu.VMEM((BLOCK_Q, LANES), jnp.float32),
-        ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(q3, k3, v3, *mask_ops, active)
+    )(active.reshape(-1), q3, k3, v3, *_mask_operands(mask, segmented))
     return o3, m, l
 
 
 # --------------------------------------------------------------- backward
 
 
-def _dq_kernel(*refs, scale, n_k, segmented):
+def _dq_kernel(act_ref, *refs, scale, n_heads, n_q, n_k, segmented):
     if segmented:
-        (q_ref, k_ref, v_ref, sq_ref, skv_ref, act_ref, do_ref,
+        (q_ref, k_ref, v_ref, sq_ref, skv_ref, do_ref,
          m_ref, l_ref, Di_ref, dq_ref, dq_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, bias_ref, act_ref, do_ref,
+        (q_ref, k_ref, v_ref, bias_ref, do_ref,
          m_ref, l_ref, Di_ref, dq_ref, dq_scr) = refs
-    ki = pl.program_id(2)
+    qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(act_ref[0, 0, 0] != 0)
+    @pl.when(_tile_live(act_ref, n_heads, n_q, n_k, qi, ki))
     def _compute():
         q = q_ref[0].astype(jnp.float32)                   # [Bq, D]
         k = k_ref[0].astype(jnp.float32)                   # [Bk, D]
@@ -332,21 +351,21 @@ def _dq_kernel(*refs, scale, n_k, segmented):
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, n_q, segmented):
+def _dkv_kernel(act_ref, *refs, scale, n_heads, n_q, n_k, segmented):
     if segmented:
-        (q_ref, k_ref, v_ref, sq_ref, skv_ref, act_ref, do_ref,
+        (q_ref, k_ref, v_ref, sq_ref, skv_ref, do_ref,
          m_ref, l_ref, Di_ref, dk_ref, dv_ref, dk_scr, dv_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, bias_ref, act_ref, do_ref,
+        (q_ref, k_ref, v_ref, bias_ref, do_ref,
          m_ref, l_ref, Di_ref, dk_ref, dv_ref, dk_scr, dv_scr) = refs
-    qi = pl.program_id(2)
+    ki, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(act_ref[0, 0, 0] != 0)
+    @pl.when(_tile_live(act_ref, n_heads, n_q, n_k, qi, ki))
     def _compute():
         q = q_ref[0].astype(jnp.float32)                   # [Bq, D]
         k = k_ref[0].astype(jnp.float32)                   # [Bk, D]
@@ -381,85 +400,54 @@ def _dkv_kernel(*refs, scale, n_q, segmented):
 def _bwd_impl(scale, n_heads, segmented, res, do3):
     q3, k3, v3, mask, active, o3, m, l = res
     BN, S, D = q3.shape
-    n = n_heads
     nq, nk = S // BLOCK_Q, S // BLOCK_K
     Di = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                  axis=-1)[:, None, :]
-    if segmented:
-        seg_kv, seg_q = mask
-        mask_ops = [seg_q, seg_kv]
-        dq_mask_specs = [
-            pl.BlockSpec((1, BLOCK_Q, LANES),
-                         lambda bh, qi, ki: (bh // n, qi, 0)),
-            pl.BlockSpec((1, 1, BLOCK_K),
-                         lambda bh, qi, ki: (bh // n, 0, ki)),
-        ]
-        dkv_mask_specs = [
-            pl.BlockSpec((1, BLOCK_Q, LANES),
-                         lambda bh, ki, qi: (bh // n, qi, 0)),
-            pl.BlockSpec((1, 1, BLOCK_K),
-                         lambda bh, ki, qi: (bh // n, 0, ki)),
-        ]
-    else:
-        mask_ops = [mask]
-        dq_mask_specs = [pl.BlockSpec((1, 1, BLOCK_K),
-                                      lambda bh, qi, ki: (bh // n, 0, ki))]
-        dkv_mask_specs = [pl.BlockSpec((1, 1, BLOCK_K),
-                                       lambda bh, ki, qi: (bh // n, 0, ki))]
+    operands = (active.reshape(-1), q3, k3, v3,
+                *_mask_operands(mask, segmented), do3, m, l, Di)
+    static = dict(scale=scale, n_heads=n_heads, n_q=nq, n_k=nk,
+                  segmented=segmented)
 
+    def in_specs(q_spec, kv_spec, row_spec, mask_specs):
+        return [q_spec, kv_spec, kv_spec, *mask_specs,
+                q_spec, row_spec, row_spec, row_spec]
+
+    specs = _block_specs(D, n_heads, segmented)
     dq3 = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, n_k=nk,
-                          segmented=segmented),
-        grid=(BN, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, qi, ki: (bh, ki, 0)),
-            *dq_mask_specs,
-            pl.BlockSpec((1, 1, 1), lambda bh, qi, ki: (bh // n, qi, ki)),
-            pl.BlockSpec((1, BLOCK_Q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, qi, ki: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK_Q, D),
-                               lambda bh, qi, ki: (bh, qi, 0)),
+        functools.partial(_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BN, nq, nk),
+            in_specs=in_specs(*specs),
+            out_specs=specs[0],
+            scratch_shapes=[pltpu.VMEM((BLOCK_Q, D), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((BN, S, D), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((BLOCK_Q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(q3, k3, v3, *mask_ops, active, do3, m, l, Di)
+    )(*operands)
 
+    # dK/dV: k tiles outer, q tiles innermost
+    specs = _block_specs(D, n_heads, segmented, at=lambda ki, qi: (qi, ki))
     dk3, dv3 = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, n_q=nq,
-                          segmented=segmented),
-        grid=(BN, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, ki, qi: (bh, ki, 0)),
-            *dkv_mask_specs,
-            pl.BlockSpec((1, 1, 1), lambda bh, ki, qi: (bh // n, qi, ki)),
-            pl.BlockSpec((1, BLOCK_Q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, ki, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, ki, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, BLOCK_Q), lambda bh, ki, qi: (bh, 0, qi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, BLOCK_K, D), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
+        functools.partial(_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BN, nk, nq),
+            in_specs=in_specs(*specs),
+            out_specs=[specs[1], specs[1]],
+            scratch_shapes=[
+                pltpu.VMEM((BLOCK_K, D), jnp.float32),
+                pltpu.VMEM((BLOCK_K, D), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((BN, S, D), k3.dtype),
             jax.ShapeDtypeStruct((BN, S, D), v3.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((BLOCK_K, D), jnp.float32),
-            pltpu.VMEM((BLOCK_K, D), jnp.float32),
-        ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-    )(q3, k3, v3, *mask_ops, active, do3, m, l, Di)
+    )(*operands)
     return dq3, dk3, dv3
 
 

@@ -95,9 +95,7 @@ def ring_attention(
     Masks depend on the shard layout, so dropped outputs don't match the
     single-device XLA path draw-for-draw (same as any two attention
     backends); the *distribution* is identical (``tests/test_sp.py``)."""
-    from pdnlp_tpu.parallel.compat import axis_size
-
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     segmented = segment_ids is not None
     if segmented:
         if bias_local is not None:

@@ -22,12 +22,12 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pdnlp_tpu.models import BertConfig, bert, get_config
 from pdnlp_tpu.models.config import args_overrides
 from pdnlp_tpu.parallel import collectives
-from pdnlp_tpu.parallel.compat import shard_map
 from pdnlp_tpu.parallel.mesh import DATA_AXIS
 from pdnlp_tpu.parallel.sharding import batch_sharding, replicated, state_shardings
 from pdnlp_tpu.train.optim import build_optimizer

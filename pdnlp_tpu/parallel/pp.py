@@ -41,9 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pdnlp_tpu.parallel.compat import shard_map
 from pdnlp_tpu.models import bert
 from pdnlp_tpu.models.config import BertConfig
 from pdnlp_tpu.parallel.mesh import DATA_AXIS

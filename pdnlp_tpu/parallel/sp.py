@@ -13,9 +13,9 @@ This capability has no reference twin (``SURVEY.md`` §5: long-context
 "absent"); it exists so the framework scales past single-device sequence
 lengths.  The full dropout recipe applies — hidden-state dropout per
 shard and attention-probability dropout per ring block (``ops.ring``) —
-so sp trains the same model as every other strategy.  Measured on the chip at the lengths it exists for: 7.0 steps/s
-training ``bert-base-long`` at seq 1024 (57k tokens/s,
-``results/longcontext.json``); multi-shard parity is pinned by
+so sp trains the same model as every other strategy.  Its speed at the
+lengths it exists for was measured before PR 1 on v5e (record removed, not
+re-measured on this code); multi-shard parity is pinned by
 ``tests/test_sp.py``, the multichip dryrun, and a seq axis spanning two
 real OS processes in ``tests/test_spawn.py``.
 """
@@ -26,10 +26,10 @@ from typing import Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pdnlp_tpu.models import BertConfig, bert
-from pdnlp_tpu.parallel.compat import shard_map
 from pdnlp_tpu.train.precision import resolve_dtype
 from pdnlp_tpu.train.steps import State, weighted_ce
 

@@ -101,14 +101,17 @@ def pick_bucket(n_tokens: int, buckets: Sequence[int]) -> int:
     return max(buckets)
 
 
-def resolve_serve_pack(mode: str, pack_width: int) -> bool:
+def resolve_serve_pack(mode: str, pack_width: int,
+                       attn_requested: str = "auto") -> bool:
     """ONE resolution of ``--serve_pack auto|on|off`` -> packed or padded,
     shared by the batcher, the router and the CLI/bench so a request can
     never be packed by one layer and padded by another.
 
     ``auto`` packs exactly where the segment-native pallas flash kernel
-    routes for the pack width (TPU, 128-tiling widths): there the packed
-    batch pays block-diagonal attention in-kernel and the win is pure.
+    routes for the pack width (TPU, 128-tiling widths, and an engine whose
+    ``attn_requested`` lets it — ``InferenceEngine`` pins a multi-device
+    mesh to XLA): there the packed batch pays block-diagonal attention
+    in-kernel and the win is pure.
     Elsewhere (CPU tests, non-tiling widths) the XLA fallback materializes
     the ``[B,1,S,S]`` segment bias per batch — packing still usually wins
     on padding waste (``on`` forces it; the bench gates it), but it is an
@@ -120,7 +123,7 @@ def resolve_serve_pack(mode: str, pack_width: int) -> bool:
         return mode == "on"
     from pdnlp_tpu.ops.attention import routed_impl_cached
 
-    return routed_impl_cached("auto", int(pack_width),
+    return routed_impl_cached(attn_requested, int(pack_width),
                               segmented=True) == "pallas"
 
 
@@ -424,7 +427,9 @@ class DynamicBatcher:
         # the queue bound is max_queue rows' worth of token slots, so a
         # storm of short requests is admitted by the work it actually
         # brings, not by how many envelopes it arrives in
-        self.packed = resolve_serve_pack(serve_pack, self.buckets[-1])
+        self.packed = resolve_serve_pack(
+            serve_pack, self.buckets[-1],
+            getattr(engine, "attn_requested", "auto"))
         self.pack_width = self.buckets[-1]
         self.pack_rows = self.max_batch_size
         self.pack_segments = int(pack_max_segments)

@@ -92,13 +92,17 @@ class InferenceEngine:
         # report.  Routing is PER BUCKET WIDTH (sub-128 buckets fall back
         # to XLA), so spans stamp :meth:`routed_attn` of their actual seq,
         # never this attribute.
-        from pdnlp_tpu.ops.attention import routed_impl_cached
+        from pdnlp_tpu.ops.attention import (
+            pin_auto_for_mesh, routed_impl_cached,
+        )
 
-        self._attn_requested = args.attention_impl
+        # a forward jitted over a multi-device mesh is partitioned by GSPMD,
+        # which the kernel cannot follow: ``auto`` is pinned to XLA there
+        self.attn_requested = pin_auto_for_mesh(args.attention_impl, mesh)
         self._impl_by_seq: Dict[int, str] = {}
         # routed directly (not via routed_attn) so _impl_by_seq records
         # only widths actually served, never the construction-time headline
-        self.attn_impl = routed_impl_cached(self._attn_requested,
+        self.attn_impl = routed_impl_cached(self.attn_requested,
                                             args.max_seq_len)
         self.mesh = mesh
         self.metrics = metrics or ServeMetrics()
@@ -130,7 +134,7 @@ class InferenceEngine:
             devices=list(mesh.devices.flat) if mesh is not None else None)
 
         metrics_ref = self.metrics
-        attn_impl = args.attention_impl
+        attn_impl = self.attn_requested
 
         def _forward(params, batch):
             # Python body only executes while tracing: this IS the retrace
@@ -370,7 +374,7 @@ class InferenceEngine:
         routing point."""
         from pdnlp_tpu.ops.attention import routed_impl_cached
 
-        impl = routed_impl_cached(self._attn_requested, seq,
+        impl = routed_impl_cached(self.attn_requested, seq,
                                   segmented=segmented)
         self._impl_by_seq.setdefault(seq, impl)
         return impl

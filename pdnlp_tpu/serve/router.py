@@ -265,7 +265,9 @@ class ReplicaRouter:
         # pending-token depth.  Hedged duplicates always ride the padded
         # per-bucket path (a hedge exists to dodge a slow replica, not to
         # wait for a pack to fill).
-        self.packed = resolve_serve_pack(serve_pack, self.buckets[-1])
+        self.packed = resolve_serve_pack(
+            serve_pack, self.buckets[-1],
+            getattr(engines[0], "attn_requested", "auto"))
         self.pack_width = self.buckets[-1]
         self.pack_segments = int(pack_max_segments)
         unit = self.pack_width if self.packed else 1

@@ -57,6 +57,15 @@ def build_parallel_trainer(
                          "strategies, not shard_map, fused multi-steps, or "
                          "tp — the staged host<->device transfers are only "
                          "wired into the plain data-axis train step")
+    if not explicit_collectives:
+        # the jit strategies let GSPMD partition the step, which Mosaic
+        # kernels cannot follow; pinned HERE so the steps, the Trainer's
+        # surfaced impl and the bench JSON all read the same args
+        from pdnlp_tpu.ops.attention import pin_auto_for_mesh
+
+        args = args.replace(
+            attention_impl=pin_auto_for_mesh(args.attention_impl, mesh),
+            fused_ce=pin_auto_for_mesh(args.fused_ce, mesh, "fused_ce"))
     from pdnlp_tpu.data.sampler import resolve_length_mode
 
     if explicit_collectives and resolve_length_mode(args) != "full":

@@ -383,18 +383,31 @@ def add_dataclass_args(parser, cls, defaults=None) -> None:
             parser.add_argument(f"--{f.name}", type=json.loads, default=default)
 
 
-def enable_compilation_cache(args: "Args") -> None:
-    """Point XLA's persistent compilation cache at ``<output_dir>/xla_cache``
-    so repeat runs of any entrypoint skip the 30-60s first compile (the
-    reference's warm-CUDA-context analog).  Safe to call before or after
-    backend init; harmless on CPU."""
-    try:
-        import jax
+#: the one in-checkout compile cache, anchored on this file (NOT the working
+#: directory, NOT ``--output_dir``): the directory is part of XLA's cache key,
+#: so a cache that moves with either never hits
+COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".xla_cache")
 
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(args.output_dir, "xla_cache"))
-    except Exception:
-        pass  # never let cache plumbing break a training run
+
+def enable_compilation_cache() -> str:
+    """Turn on XLA's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins and nothing is set
+    in code (JAX read it at import) — that is how a deployment, or the chip
+    tool, places the cache.  Otherwise every entrypoint shares
+    :data:`COMPILATION_CACHE_DIR`, so repeat runs of any of them skip the
+    20-40 s first compile of a bert-base step.  Callable before or after
+    backend init; ``JAX_ENABLE_COMPILATION_CACHE=false`` (the test suite)
+    leaves the directory set but unused."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILATION_CACHE_DIR)
+    return COMPILATION_CACHE_DIR
 
 
 def pop_cli_flag(argv, name: str, default=None, cast=str):
@@ -425,6 +438,5 @@ def parse_cli(argv=None, base: Optional[Args] = None) -> Args:
                    default=argparse.SUPPRESS,
                    help="alias for --attention_impl (auto|xla|pallas)")
     ns = p.parse_args(argv)
-    args = Args(**vars(ns))
-    enable_compilation_cache(args)
-    return args
+    enable_compilation_cache()
+    return Args(**vars(ns))

@@ -7,7 +7,9 @@ and records steps/s, tokens/s, and peak HBM.  This is the full-step number
 the op-level flash table (README) could not give: the crossover claim for
 training comes from here.
 
-Writes/merges ``results/longcontext.json``.
+Writes/merges ``results/longcontext.json``.  The rows this script measured
+before PR 1 on v5e were removed with their record and have not been
+re-measured on this code; a chip run recreates the file.
 
     python scripts/bench_longcontext.py [name-substring ...]
 """
@@ -25,7 +27,8 @@ CODE = r"""
 import json, sys, time
 spec = json.loads(sys.argv[1])
 import jax, jax.numpy as jnp
-jax.config.update('jax_compilation_cache_dir', 'output/xla_cache')
+from pdnlp_tpu.utils.config import enable_compilation_cache
+enable_compilation_cache()
 from pdnlp_tpu.train.run import build_parallel_trainer
 from pdnlp_tpu.utils.config import Args
 args = Args(**spec['args'])
@@ -88,8 +91,8 @@ def _dump(res, path=PATH):
 def merge_rows(new_rows, path=PATH, device=None):
     """Merge freshly measured rows into ``results/longcontext.json``
     WITHOUT clobbering history: an existing row without an ``"error"``
-    key is never overwritten (the committed v5e rows are minutes of chip
-    time; a CPU smoke re-run must not eat them) — only error rows and
+    key is never overwritten (measured rows are minutes of chip time; a
+    CPU smoke re-run must not eat them) — only error rows and
     new names take the incoming value.  ``meta.device`` is only stamped
     when absent, for the same reason.  Returns the merged dict (also
     written to ``path``) and the list of row names actually merged —

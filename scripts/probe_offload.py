@@ -11,7 +11,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-jax.config.update("jax_compilation_cache_dir", "output/xla_cache")
+from pdnlp_tpu.utils.config import enable_compilation_cache
+
+enable_compilation_cache()
 
 from pdnlp_tpu.models import bert, get_config
 from pdnlp_tpu.parallel import make_mesh

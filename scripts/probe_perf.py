@@ -15,7 +15,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "output/xla_cache")
+from pdnlp_tpu.utils.config import enable_compilation_cache
+
+enable_compilation_cache()
 
 from pdnlp_tpu.train.run import build_parallel_trainer
 from pdnlp_tpu.utils.config import Args

@@ -9,7 +9,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "output/xla_cache")
+from pdnlp_tpu.utils.config import enable_compilation_cache
+
+enable_compilation_cache()
 
 from pdnlp_tpu.models import bert, get_config
 from pdnlp_tpu.train.optim import build_optimizer

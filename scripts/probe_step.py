@@ -10,7 +10,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
-jax.config.update("jax_compilation_cache_dir", "output/xla_cache")
+from pdnlp_tpu.utils.config import enable_compilation_cache
+
+enable_compilation_cache()
 
 from pdnlp_tpu.models import bert, get_config
 from pdnlp_tpu.train.optim import build_optimizer

@@ -30,9 +30,9 @@ def probe(args_kw, env=None, steps=30, trace_dir=None):
         "import json,sys,time\n"
         "spec=json.loads(sys.argv[1])\n"
         "import jax, jax.numpy as jnp\n"
-        "jax.config.update('jax_compilation_cache_dir','output/xla_cache')\n"
         "from pdnlp_tpu.train.run import build_parallel_trainer\n"
-        "from pdnlp_tpu.utils.config import Args\n"
+        "from pdnlp_tpu.utils.config import Args, enable_compilation_cache\n"
+        "enable_compilation_cache()\n"
         "args=Args(**spec['args'])\n"
         "tr,tl,_=build_parallel_trainer(args,mode='dp')\n"
         "batch=tr.put(next(iter(tl)))\n"
@@ -179,7 +179,7 @@ def main():
     if "base_split_qkv" in variants:  # trace only re-captured on a full run
         out["trace"] = parse_trace(trace_dir)
     try:
-        import jax
+        import jax  # only now: every probe child has exited with the chip
 
         out["device"] = jax.devices()[0].device_kind
     except Exception:

@@ -26,7 +26,8 @@ CODE = r"""
 import json, sys, time
 spec = json.loads(sys.argv[1])
 import jax
-jax.config.update('jax_compilation_cache_dir', 'output/xla_cache')
+from pdnlp_tpu.utils.config import enable_compilation_cache
+enable_compilation_cache()
 from pdnlp_tpu.train.run import build_parallel_trainer
 from pdnlp_tpu.utils.config import Args
 args = Args(**spec)

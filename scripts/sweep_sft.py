@@ -23,7 +23,7 @@ from pdnlp_tpu.utils.config import Args, enable_compilation_cache, \
     pop_cli_flag
 from pdnlp_tpu.utils.sweeps import make_selected, parse_only
 
-enable_compilation_cache(Args())
+enable_compilation_cache()
 
 
 def finetune(tag, ckpt, **kw):

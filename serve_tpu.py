@@ -153,6 +153,37 @@ def _latest_checkpoint(args: Args) -> Optional[str]:
     return ckpt.latest(args.output_dir)
 
 
+def replica_meshes(args: Args, replicas: int, use_mesh: bool) -> list:
+    """One private mesh slice per replica: the devices split into
+    ``replicas`` contiguous groups, so each engine owns its own device
+    stream and one wedged replica cannot stall the others.
+
+    When that cannot be done (``--no_mesh``, or fewer devices than
+    replicas — the CPU tests) every entry is ``None`` and each engine is a
+    plain-jit engine on the DEFAULT device: N replicas then share one chip,
+    which is said on stderr rather than left to be found in a profile."""
+    if use_mesh:
+        import jax
+
+        from pdnlp_tpu.parallel import make_mesh
+
+        devices = list(jax.devices())
+        if args.num_devices:
+            devices = devices[: args.num_devices]
+        per = len(devices) // replicas
+        if per >= 1:
+            return [make_mesh(devices=devices[i * per:(i + 1) * per])
+                    for i in range(replicas)]
+        why = f"{len(devices)} device(s) for {replicas} replicas"
+    else:
+        why = "--no_mesh"
+    if replicas > 1:
+        rank0_print(f"serve_tpu: {why} — all {replicas} engines are "
+                    "plain-jit engines on the default device "
+                    "(no per-replica placement)", file=sys.stderr)
+    return [None] * replicas
+
+
 def build_router(args: Args, replicas: int, *,
                  checkpoint: Optional[str] = None, use_mesh: bool = True,
                  buckets=DEFAULT_BUCKETS, max_batch_size: int = 8,
@@ -163,27 +194,11 @@ def build_router(args: Args, replicas: int, *,
                  serve_pack: str = "auto") -> ReplicaRouter:
     """N replica engines behind the fault-tolerant router.
 
-    Placement: when the host exposes at least ``replicas`` devices (and
-    meshes are allowed), devices split into ``replicas`` contiguous groups
-    and each engine gets a private data-parallel mesh slice — independent
-    device streams, so one wedged replica cannot stall the others.  With
-    fewer devices (CPU tests), each replica is an independent plain-jit
-    engine.  The same factory rebuilds an ejected replica's engine on
-    :meth:`ReplicaRouter.relaunch`.
+    Placement is :func:`replica_meshes` (a private mesh slice each where
+    the devices allow).  The same factory rebuilds an ejected replica's
+    engine on :meth:`ReplicaRouter.relaunch`.
     """
-    import jax
-
-    groups: list = [None] * replicas
-    if use_mesh:
-        from pdnlp_tpu.parallel import make_mesh
-
-        devices = list(jax.devices())
-        if args.num_devices:
-            devices = devices[: args.num_devices]
-        per = len(devices) // replicas
-        if per >= 1:
-            groups = [make_mesh(devices=devices[i * per:(i + 1) * per])
-                      for i in range(replicas)]
+    groups = replica_meshes(args, replicas, use_mesh)
 
     # ONE tokenizer for the whole pool: each engine would otherwise
     # re-read the vocab at construction — and again on every relaunch,
@@ -227,32 +242,17 @@ def build_fleet(args: Args, specs, *, use_mesh: bool = True,
     :class:`ReplicaRouter` per model id (each spec's checkpoint/dtype/
     replica count), composed by a :class:`FleetRouter` front door.
 
-    Placement mirrors :func:`build_router`, over the fleet's TOTAL
-    replica count: with enough devices every replica of every model gets
-    a private mesh slice; otherwise each is an independent plain-jit
-    engine.  The primary pool gets the degrade band (``degrade_at``,
+    Placement is :func:`replica_meshes` over the fleet's TOTAL replica
+    count.  The primary pool gets the degrade band (``degrade_at``,
     defaulting to 5/8 of ``max_queue`` — between the backpressure and
     shed defaults) only when a cheap model exists to absorb it."""
     import dataclasses
-
-    import jax
 
     from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, get_or_build_vocab
     from pdnlp_tpu.serve import FleetRouter, ReplicaRouter
 
     tok = WordPieceTokenizer(get_or_build_vocab(args))
-    total = sum(s.replicas for s in specs)
-    slices: list = [None] * total
-    if use_mesh:
-        from pdnlp_tpu.parallel import make_mesh
-
-        devices = list(jax.devices())
-        if args.num_devices:
-            devices = devices[: args.num_devices]
-        per = len(devices) // total
-        if per >= 1:
-            slices = [make_mesh(devices=devices[i * per:(i + 1) * per])
-                      for i in range(total)]
+    slices = replica_meshes(args, sum(s.replicas for s in specs), use_mesh)
 
     roles = {s.role: s.model_id for s in specs}
     if degrade_at is None and "cheap" in roles:
@@ -301,9 +301,9 @@ def build_decode_pool(args: Args, replicas: int, *,
                       speculate: Optional[str] = None, draft_k: int = 4,
                       disagg: str = "off", prefill_engines: int = 1):
     """Generative serving pool: ``replicas`` :class:`DecodeEngine`\\ s —
-    device-group meshes when the host has them, plain jit otherwise —
-    behind a :class:`DecodeRouter` (1 replica included: the router is the
-    one submit/kill/snapshot surface either way).  ``--kv_layout paged``
+    placed by :func:`replica_meshes` — behind a :class:`DecodeRouter`
+    (1 replica included: the router is the one submit/kill/snapshot
+    surface either way).  ``--kv_layout paged``
     (the default) gives each engine a refcounted page pool with
     cross-request prefix sharing; ``--kv_layout slots`` keeps the classic
     preallocated slot cache (``--decode_slots`` × ``--decode_max_len``
@@ -326,23 +326,11 @@ def build_decode_pool(args: Args, replicas: int, *,
     length-prefixed loopback RPC framing); ``prefill_engines`` sets the
     initial split (the controller's ``prefill_share`` knob re-balances
     it live)."""
-    import jax
-
     from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, get_or_build_vocab
     from pdnlp_tpu.serve import DecodeEngine, DecodeRouter, PagedDecodeEngine
     from pdnlp_tpu.serve.decode import DisaggDecodeRouter
 
-    groups: list = [None] * replicas
-    if use_mesh:
-        from pdnlp_tpu.parallel import make_mesh
-
-        devices = list(jax.devices())
-        if args.num_devices:
-            devices = devices[: args.num_devices]
-        per = len(devices) // replicas
-        if per >= 1:
-            groups = [make_mesh(devices=devices[i * per:(i + 1) * per])
-                      for i in range(replicas)]
+    groups = replica_meshes(args, replicas, use_mesh)
     tok = WordPieceTokenizer(get_or_build_vocab(args))
     paged = getattr(args, "kv_layout", "paged") != "slots"
     if disagg != "off":

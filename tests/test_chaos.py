@@ -406,13 +406,12 @@ def chaos_shrink_run(tmp_path_factory, corpus_path):
 
 
 def _skip_if_multiproc_unsupported(proc):
-    """This image's jax 0.4.37 cannot run ANY cross-process CPU gang
-    ('Multiprocess computations aren't implemented on the CPU backend') —
-    the same incompatibility that fails the whole pre-existing spawn
-    suite here.  Skip rather than mis-assert: the single-process-gang
-    chaos variant below and the in-process elastic-width test carry the
-    coverage on such images; this test runs fully where multi-process
-    collectives exist (real pods, newer jax).
+    """A CPU backend without cross-process collectives cannot run ANY
+    multi-process gang ('Multiprocess computations aren't implemented on
+    the CPU backend').  Skip rather than mis-assert: the
+    single-process-gang chaos variant below and the in-process
+    elastic-width test carry the coverage there; this test runs fully
+    where multi-process collectives exist.
 
     The message is checked REGARDLESS of exit code (the PR-10 skip->fail
     flake): under host load the two init-crashed ranks can be detected on

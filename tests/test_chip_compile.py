@@ -1,0 +1,147 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed next to JAX and compiles for a topology that
+is described, not attached (``on-chip-measurement`` guide, section 2).  The
+interpret-mode kernel tests cannot see what Mosaic refuses — a block shape
+that breaks the (8, 128) tiling rule passed every one of them and was
+refused at every width above 128 — so the kernels of the main path are
+compiled here for ``v5e`` at bert-base's real widths, and the whole train
+step once.  Nothing runs: this says a program compiles, never that it is
+right or fast.
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the test
+steers ``_interpret`` itself (monkeypatch), not through an option.
+"""
+import base64
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from pdnlp_tpu.models import bert, get_config
+from pdnlp_tpu.ops import flash, fused_ce
+from pdnlp_tpu.train.optim import build_optimizer
+from pdnlp_tpu.train.steps import init_state, make_train_step
+from pdnlp_tpu.utils.config import Args
+from pdnlp_tpu.utils.seeding import train_key
+
+B, N, D, H, C = 8, 12, 64, 768, 6     # bert-base heads; the issue's B*N = 96
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compiled_kernels(monkeypatch):
+    """Mosaic, not the interpreter (both modules bind ``_interpret``)."""
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    monkeypatch.setattr(fused_ce, "_interpret", lambda: False)
+
+
+def _attn_loss(mask: str):
+    """Scalar of the attention output; ``mask`` names the mask argument."""
+    return lambda q, k, v, m: flash.flash_attention(
+        q, k, v, **{mask: m}).astype(jnp.float32).sum()
+
+
+def _flash_case(kind, S):
+    q = jax.ShapeDtypeStruct((B, S, N, D), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((B, 1, 1, S), jnp.float32)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    if kind == "biased-fwd":
+        return _attn_loss("bias"), (q, q, q, bias)
+    if kind == "segmented-fwd":
+        return _attn_loss("segment_ids"), (q, q, q, seg)
+    return (jax.grad(_attn_loss("segment_ids"), argnums=(0, 1, 2)),
+            (q, q, q, seg))
+
+
+def _fused_ce_case():
+    def loss(f, w, b, y, ew):
+        return fused_ce.fused_weighted_ce(f, w, b, y, ew)[2]
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2)), (
+        jax.ShapeDtypeStruct((64, H), jnp.bfloat16),
+        jax.ShapeDtypeStruct((H, C), jnp.bfloat16),
+        jax.ShapeDtypeStruct((C,), jnp.bfloat16),
+        jax.ShapeDtypeStruct((64,), jnp.int32),
+        jax.ShapeDtypeStruct((64,), jnp.float32))
+
+
+def _train_step_case():
+    """The whole jitted bert-base step (batch 64, seq 128, bf16, fused-CE
+    kernel, all twelve layers) from ``eval_shape`` shapes.  The layer scan
+    is kept rolled: the same layers compile in ~11 s instead of the ~50 s
+    of the default full unroll, which ``chip_smoke.py`` compiles anyway."""
+    args = Args(model="bert-base", dtype="bfloat16", train_batch_size=64,
+                fused_ce="pallas", scan_unroll=1)
+    cfg = get_config(args.model, num_labels=C, dropout=args.dropout,
+                     attn_dropout=args.attn_dropout)
+    params = jax.eval_shape(lambda: bert.init_params(jax.random.key(0), cfg))
+    tx = build_optimizer(params, args)
+    state = jax.eval_shape(lambda: init_state(
+        jax.random.key(0), cfg, tx, rng=train_key(args.seed, args.rng_impl)))
+    ids = jax.ShapeDtypeStruct((64, 128), jnp.int32)
+    batch = {"input_ids": ids, "token_type_ids": ids, "attention_mask": ids,
+             "label": jax.ShapeDtypeStruct((64,), jnp.int32),
+             "example_weight": jax.ShapeDtypeStruct((64,), jnp.float32)}
+    return make_train_step(cfg, tx, args), (state, batch)
+
+
+# (builder, does the kernel carry grid dimension semantics): the flash grid
+# hints must reach Mosaic — they were silently dropped when the params class
+# was renamed; fused CE runs a 1-D sequential grid and sets none
+CASES = [pytest.param(lambda k=k, S=S: _flash_case(k, S), True,
+                      id=f"flash-{k}-{S}")
+         for k in ("biased-fwd", "segmented-fwd", "segmented-bwd")
+         for S in (128, 512)]
+CASES.append(pytest.param(_fused_ce_case, False,
+                          id="fused_ce-fwd+grad-64x768"))
+CASES.append(pytest.param(_train_step_case, False, id="bert-base-train-step"))
+
+
+def _mosaic_bodies(lowered_text: str):
+    """The serialized Mosaic modules of every ``tpu_custom_call``."""
+    return [base64.b64decode(m) for m in re.findall(
+        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)]
+
+
+@pytest.mark.parametrize("case,grid_hints", CASES)
+def test_compiles_for_v5e(case, grid_hints, one_chip):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    fn, shapes = case()
+    shapes = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)
+    # a described-topology compile is written to the persistent cache but
+    # cannot be read back without a chip: keep it off around these
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        fn = fn if hasattr(fn, "lower") else jax.jit(fn)
+        lowered = fn.lower(*shapes)
+        compiled = lowered.compile()   # raises what the chip's compiler would
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    bodies = _mosaic_bodies(lowered.as_text())
+    assert bodies
+    hinted = [b for b in bodies
+              if b"dimension_semantics" in b and b"parallel" in b]
+    assert len(hinted) == (len(bodies) if grid_hints else 0)

@@ -350,7 +350,7 @@ def test_routing_table_consults_measured_crossover(capsys):
 def test_ring_attention_packed_matches_segment_route(ndev):
     from pdnlp_tpu.ops.ring import ring_attention
     from pdnlp_tpu.parallel import make_mesh
-    from pdnlp_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if ndev < 2:

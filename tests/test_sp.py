@@ -8,10 +8,10 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from pdnlp_tpu.parallel import make_mesh
-from pdnlp_tpu.parallel.compat import shard_map
 from pdnlp_tpu.parallel.sp import make_sp_batch, make_sp_eval_step, make_sp_train_step
 from pdnlp_tpu.train.setup import setup_model
 from pdnlp_tpu.train.steps import make_eval_step, make_train_step
