@@ -54,6 +54,7 @@ rebuild-the-cache anti-pattern).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -62,7 +63,8 @@ import numpy as np
 
 from pdnlp_tpu.models import bert
 from pdnlp_tpu.models.config import BertConfig
-from pdnlp_tpu.ops.attention import dot_product_attention, mask_bias
+from pdnlp_tpu.ops.attention import (NEG_INF, dot_product_attention,
+                                     mask_bias)
 
 Params = Dict[str, Any]
 
@@ -261,39 +263,92 @@ def decode_step(params: Params, head: Params, cfg: BertConfig,
 # ------------------------------------------------------------- paged cache
 #
 # The paged layout stores K/V as fixed-size pages ``[L, n_pages, page_sz,
-# N, D]`` and a per-stream PAGE TABLE maps logical page -> physical page.
-# Every program below works on the FLAT view ``[L, n_pages * page_sz, N,
-# D]`` with host-computed (or in-program) flat indices ``physical_page *
-# page_sz + offset``; dead rows and filler carry the OOB sentinel index
-# ``n_pages * page_sz``, which ``mode="drop"`` scatters ignore and
-# ``mode="fill"`` gathers read as 0.0 — a masked position's exact-zero
-# contribution either way, so the slot-cache bitwise decode contract
-# carries over unchanged (the gather reconstructs the same ``[B, max_len,
-# N, D]`` extent the slot step attends over, with identical values at
-# every visible position).
+# H]`` (H = heads x head width, one position's K or V as ONE row) and a
+# per-stream PAGE TABLE maps logical page -> physical page.  The heads are
+# NOT an axis of the pool: the chip lays an array out by its two minor
+# dimensions, and with ``[.., N, D]`` = ``[.., 12, 64]`` there the TPU's
+# default layout makes the PAGE axis the minor one — every program that
+# indexes pages then converts the whole pool to a padded row-major copy and
+# back (read on the chip, PR 26: 80 of a decode step's 126 ms).  ``[page_sz,
+# H]`` = ``[16, 768]`` tiles exactly, so a page is 24 KB contiguous.  The
+# same compiler moves the whole pool again for a scatter whose window spans
+# the LAYER axis, so every index below addresses major axes only.  The
+# contract of every program below (the engine donates both pools to each):
+#
+# - **the pool is never rebuilt**: it is not sliced per layer, not scanned
+#   over and not re-stacked.  Writes are ONE scatter per layer on the flat
+#   view ``[L * P * page_sz, H]`` (a reshape of major dimensions: no data
+#   moves) at ``layer * P * page_sz + physical_page * page_sz + offset``,
+#   so the donated buffer IS the output buffer
+#   (``tests/test_paged_core.py`` asserts the aliasing and that no
+#   temporary is as large as a pool);
+# - **reads are by page**: whole ``[page_sz, H]`` pages gathered through
+#   the table from the view ``[L * P, page_sz, H]``, over the extent the
+#   TABLE HANDED IN covers — the engine hands the decode step a table cut to
+#   a warmed rung of page counts that reaches the longest live row, so
+#   attention runs over what is live and not over ``max_len``;
+# - **dead rows, filler and padding write nothing**: their index is the
+#   out-of-bounds sentinel, which ``mode="drop"`` scatters ignore.  Sentinel
+#   TABLE entries (>= P) read the pool's last page instead (the gather
+#   clips): always a position the linear visibility mask hides (its
+#   probability is exactly 0.0 after the float32 softmax, and pool contents
+#   are finite), or a dead row whose logits the caller discards.
+#
+# The mathematics is the slot step's: same visibility (``j <= pos``), the
+# current token attends to its own cache entry, float32 softmax and logits.
+# A shorter extent changes which exact zeros are summed, so a paged stream is
+# TOKEN-identical to the slot engine's, not bitwise-equal in its logits.
 
 
-def paged_insert(pages_k: jax.Array,   # [L, P, page_sz, N, D]
+def _layer_rows(flat: jax.Array, n_layers: int, per_layer: int) -> jax.Array:
+    """Per-layer scatter indices ``[L, ...]`` into a flat ``[L * per_layer,
+    ...]`` view from per-layer indices ``flat`` (anything ``>= per_layer``
+    is the sentinel and stays out of bounds in EVERY layer — it must not
+    alias into the next layer's rows)."""
+    base = jnp.arange(n_layers, dtype=jnp.int32) * per_layer
+    base = base.reshape((n_layers,) + (1,) * flat.ndim)
+    return jnp.where(flat[None] < per_layer, base + flat[None],
+                     n_layers * per_layer)
+
+
+def paged_insert(pages_k: jax.Array,   # [L, P, page_sz, H]
                  pages_v: jax.Array,
                  ks: jax.Array,        # [L, B, S, N, D] (prefill output)
                  vs: jax.Array,
-                 flat_pos: jax.Array,  # [B, S] int32 flat indices (OOB drop)
+                 flat_pos: jax.Array,  # [B, S // unit] int32 (OOB drop)
                  *, kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """Scatter a prefill's K/V into pages: the paged analogue of the slot
-    engine's cache insert.  ``flat_pos[b, s]`` is the flat page index for
-    prompt b's position s (padding and filler rows carry the OOB
-    sentinel, so they can never touch a live page)."""
-    L, P, ps = pages_k.shape[0], pages_k.shape[1], pages_k.shape[2]
-    tail = pages_k.shape[3:]
+    engine's cache insert.  ``flat_pos``'s WIDTH says what one index moves:
+    ``[B, S]`` — ``flat_pos[b, s]`` is the flat position ``page * page_sz +
+    offset`` of prompt b's position s — or ``[B, S // page_sz]`` — entry j
+    is the PHYSICAL PAGE of positions ``j * page_sz ..``, written whole (the
+    chip's scatters cost by the index, not by the byte: 16x fewer).  A whole
+    page takes the padded tail's K/V with it, at positions no query can see
+    until a later write replaces them; a prefilled prompt's pages are its
+    stream's own.  Padding and filler carry the OOB sentinel (``P *
+    page_sz``, or ``P`` for pages), so they can never touch a live page."""
+    L, P, ps, H = pages_k.shape
+    B, S = ks.shape[1], ks.shape[2]
+    unit = S // flat_pos.shape[1]
+    if unit not in (1, ps) or unit * flat_pos.shape[1] != S:
+        raise ValueError(f"flat_pos {flat_pos.shape} addresses neither the "
+                         f"{S} positions nor whole pages of {ps}")
     if kv_scales is not None:
         ks = quantize_kv(ks, kv_scales[0][:, None, None])
         vs = quantize_kv(vs, kv_scales[1][:, None, None])
-    pk = pages_k.reshape(L, P * ps, *tail)
-    pv = pages_v.reshape(L, P * ps, *tail)
-    pk = pk.at[:, flat_pos].set(ks.astype(pk.dtype), mode="drop")
-    pv = pv.at[:, flat_pos].set(vs.astype(pv.dtype), mode="drop")
-    return pk.reshape(pages_k.shape), pv.reshape(pages_v.shape)
+    per_layer = P * ps // unit
+    idx = _layer_rows(flat_pos, L, per_layer).reshape(-1)
+    view = (L * per_layer, H) if unit == 1 else (L * per_layer, unit, H)
+
+    def put(pages, new):
+        flat = pages.reshape(view)
+        flat = flat.at[idx].set(
+            new.reshape(idx.shape[0], *view[1:]).astype(pages.dtype),
+            mode="drop")
+        return flat.reshape(pages.shape)
+
+    return put(pages_k, ks), put(pages_v, vs)
 
 
 def copy_pages(pages_k: jax.Array, pages_v: jax.Array,
@@ -314,7 +369,7 @@ def copy_pages(pages_k: jax.Array, pages_v: jax.Array,
 def gather_pages(pages_k: jax.Array, pages_v: jax.Array,
                  src: jax.Array       # [rows] physical page ids (OOB = 0s)
                  ) -> Tuple[jax.Array, jax.Array]:
-    """Export one stream's pages into a dense ``[L, rows, page_sz, N, D]``
+    """Export one stream's pages into a dense ``[L, rows, page_sz, H]``
     payload for a KV handoff.  ``src`` is ALWAYS the fixed
     ``pages_per_stream`` extent, padded with the OOB sentinel ``P``
     (``mode="fill"`` reads zeros there), so one compiled program serves
@@ -326,7 +381,7 @@ def gather_pages(pages_k: jax.Array, pages_v: jax.Array,
 
 
 def scatter_pages(pages_k: jax.Array, pages_v: jax.Array,
-                  payload_k: jax.Array,  # [L, rows, page_sz, N, D]
+                  payload_k: jax.Array,  # [L, rows, page_sz, H]
                   payload_v: jax.Array,
                   dst: jax.Array         # [rows] physical page ids (OOB drop)
                   ) -> Tuple[jax.Array, jax.Array]:
@@ -342,255 +397,193 @@ def scatter_pages(pages_k: jax.Array, pages_v: jax.Array,
     return pages_k, pages_v
 
 
-def _flat_gather_idx(page_table: jax.Array, page_sz: int) -> jax.Array:
-    """[B, MP] page table -> [B, MP * page_sz] flat gather indices.
-    Sentinel table entries (>= P) map past the flat extent and read 0."""
-    B, MP = page_table.shape
-    offs = jnp.arange(page_sz, dtype=jnp.int32)
-    return (page_table[:, :, None] * page_sz
-            + offs[None, None, :]).reshape(B, MP * page_sz)
+#: query rows (window positions x heads) up to which attention runs with the
+#: heads FOLDED (:func:`_attend_folded`): one MXU tile of rows, so the
+#: zero blocks of the expanded query cost nothing the chip would not spend
+FOLD_ROWS = 128
+
+
+def _attend_folded(q: jax.Array,     # [B, T, N, D]
+                   k: jax.Array,     # [B, S, H]: pages as they lie, H = N * D
+                   v: jax.Array,
+                   bias: jax.Array   # [B, 1, T, S] additive, float32
+                   ) -> jax.Array:
+    """``dot_product_attention``'s mathematics on K/V whose heads are NOT
+    split out: each query head is laid into its own ``D``-wide block of an
+    ``H``-wide zero row, so ``[T * N, H] @ [H, S]`` scores every head
+    against its own block (the zeros add exact 0.0) and ``probs @ V``'s
+    block ``n`` of row ``(t, n)`` is head n's output.  Splitting ``[.., H]``
+    into ``[.., N, D]`` is a relayout on the chip (``D`` = 64 is half a lane
+    tile), and done to the gathered pages it cost more than the attention
+    (PERF.md, PR 26); this form reads them once, as they lie, at ``N`` times
+    the multiply-adds of rows that fill one MXU tile either way.  Scores in
+    the compute dtype, softmax in float32."""
+    B, T, N, D = q.shape
+    S = k.shape[1]
+    eye = jnp.eye(N, dtype=q.dtype)
+    qe = (q[:, :, :, None, :] * eye[:, :, None]).reshape(B, T * N, N * D)
+    scores = jnp.einsum("bqh,bkh->bqk", qe, k) * (D ** -0.5)
+    scores = scores.reshape(B, T, N, S) + jnp.swapaxes(bias, 1, 2).astype(
+        scores.dtype)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bqk,bkh->bqh", probs.reshape(B, T * N, S), v)
+    return jnp.einsum("btnnd->btnd", out.reshape(B, T, N, N, D))
+
+
+def paged_attend_layers(params: Params, head: Params, cfg: BertConfig,
+                        tokens: jax.Array,      # [B, T] int32
+                        pages_k: jax.Array,     # [L, P, page_sz, H]
+                        pages_v: jax.Array,
+                        page_table: jax.Array,  # [B, <= MP] int32 (sentinel P)
+                        start: jax.Array,       # [B] abs pos of tokens[:, 0]
+                        nreal: Optional[jax.Array] = None,  # [B] real lengths
+                        *, logits_at: str = "last",
+                        kv_scales: Optional[Tuple[jax.Array,
+                                                  jax.Array]] = None,
+                        dtype=jnp.float32, unroll=True
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The ONE body of every paged program: ``tokens[b, t]`` sits at
+    absolute position ``start[b] + t``, writes its K/V through the page
+    table (in place: module note above), and attends to key positions
+    ``<= start[b] + t`` of the pages ``page_table[b]`` names — the extent
+    attended over is the table's width times the page size, so the caller
+    chooses it by the table it hands in.
+
+    ``nreal`` (``None``: every token is real) marks padded window slots
+    ``t >= nreal[b]``, whose writes land out of bounds; rows with
+    ``nreal == 0`` or a sentinel table are filler that writes nothing and
+    whose logits are garbage the caller discards.  ``logits_at`` says where
+    the LM head is read: ``"last"`` — each row's last real token,
+    ``[B, vocab]`` (the decode step, T = 1, and the suffix chunk) — or
+    ``"all"`` — every position, ``[B, T, vocab]`` (speculative verify).
+    ``kv_scales`` = (k_scale, v_scale) ``[L, N, D]`` switches the pool to
+    int8: new rows quantize before the write and the gathered pages
+    dequantize at read, so the current token's K/V round-trips through the
+    cache like everyone else's."""
+    _check_dense_trunk(params["layers"])
+    if logits_at not in ("last", "all"):
+        raise ValueError(f"logits_at must be 'last' or 'all', "
+                         f"got {logits_at!r}")
+    L, P, ps, H = pages_k.shape
+    N, D = cfg.num_heads, cfg.head_dim
+    B, T = tokens.shape
+    MP = page_table.shape[1]
+    extent = MP * ps
+    start = start.astype(jnp.int32)
+    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)   # [B, T]
+    x, _ = bert.embed(params, cfg, tokens, jnp.zeros_like(tokens),
+                      dtype=dtype, deterministic=True,
+                      position_ids=positions)
+    # linear visibility, never a [S, S] term: query t sees key j iff
+    # j <= start + t (shared prefix + the window's own causal triangle)
+    vis = (jnp.arange(extent, dtype=jnp.int32)[None, None, :]
+           <= positions[:, :, None])                        # [B, T, extent]
+    bias = jnp.where(vis, 0.0, NEG_INF).astype(jnp.float32)[:, None]
+    # write rows: padded window slots, dead rows (sentinel tables) and
+    # positions past the table land on the sentinel in every layer
+    real = positions < extent
+    if nreal is not None:
+        nreal = nreal.astype(jnp.int32)
+        real &= jnp.arange(T, dtype=jnp.int32)[None, :] < nreal[:, None]
+    phys = jnp.take_along_axis(
+        page_table, jnp.clip(positions // ps, 0, MP - 1), axis=1)  # [B, T]
+    wrows = _layer_rows(
+        jnp.where(real & (phys < P), phys * ps + positions % ps, P * ps),
+        L, P * ps).reshape(L, B * T)
+    # read pages: a sentinel entry clips to the pool's last page (module
+    # note)
+    rpages = _layer_rows(page_table, L, P)                   # [L, B, MP]
+    # few query rows (the decode step, a verify window): heads stay folded
+    # and the pages are read as they lie; a prefill-sized window splits the
+    # heads out (a relayout of its few rows' pages) rather than pay N times
+    # the multiply-adds
+    fold = T * N <= FOLD_ROWS
+    attend = (_attend_folded if fold else
+              functools.partial(dot_product_attention, impl="auto"))
+
+    def put(pages, rows, new, scale):
+        if scale is not None:
+            new = quantize_kv(new, scale)
+        flat = pages.reshape(L * P * ps, H)
+        flat = flat.at[rows].set(
+            new.reshape(B * T, H).astype(pages.dtype), mode="drop")
+        return flat.reshape(pages.shape)
+
+    def get(pages, idx, scale):
+        got = jnp.take(pages.reshape(L * P, ps, H), idx, axis=0,
+                       mode="clip").reshape(B, extent, H)
+        if scale is not None:
+            got = dequantize_kv(got, scale.reshape(H), dtype)
+        return got if fold else got.reshape(B, extent, N, D)
+
+    def layer(carry, scanned):
+        x, pk, pv = carry
+        lp, rows, idx, ks_l, vs_l = scanned
+        q, k_new, v_new = _qkv(x, lp, cfg, dtype)           # [B, T, N, D]
+        pk = put(pk, rows, k_new, ks_l)
+        pv = put(pv, rows, v_new, vs_l)
+        attn = attend(q, get(pk, idx, ks_l), get(pv, idx, vs_l), bias)
+        return (_finish_layer(x, lp, cfg, attn, dtype), pk, pv), None
+
+    xs = (params["layers"], wrows, rpages) + (kv_scales or (None, None))
+    (x, pages_k, pages_v), _ = jax.lax.scan(
+        layer, (x, pages_k, pages_v), xs, unroll=unroll)
+    if logits_at == "last":
+        last = (jnp.zeros((B,), jnp.int32) if nreal is None
+                else jnp.clip(nreal - 1, 0, T - 1))
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)   # [B, 1, H]
+    logits = lm_logits(params, head, cfg, x, dtype=dtype)
+    return (logits[:, 0] if logits_at == "last" else logits,
+            pages_k, pages_v)
 
 
 def paged_decode_step(params: Params, head: Params, cfg: BertConfig,
                       tokens: jax.Array,      # [B, 1] int32
-                      pages_k: jax.Array,     # [L, P, page_sz, N, D]
+                      pages_k: jax.Array,     # [L, P, page_sz, H]
                       pages_v: jax.Array,
-                      page_table: jax.Array,  # [B, MP] int32 (sentinel P)
+                      page_table: jax.Array,  # [B, <= MP] int32 (sentinel P)
                       pos: jax.Array,         # [B] int32 write positions
-                      *, kv_scales: Optional[Tuple[jax.Array,
-                                                   jax.Array]] = None,
-                      dtype=jnp.float32, unroll=True
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """:func:`decode_step` over a paged cache: write the current token's
-    K/V at ``page_table[b, pos // page_sz] * page_sz + pos % page_sz``,
-    gather each row's logical ``[max_len]`` view through its table, and
-    attend with the SAME linear visibility mask and extent as the slot
-    step — bitwise-equal logits on bitwise-equal cache contents (module
-    note above).  Shapes are all static ([B, 1] tokens, [B, MP] table,
-    preallocated pages), so the jitted form holds ONE compiled program."""
-    _check_dense_trunk(params["layers"])
-    L, P, ps = pages_k.shape[0], pages_k.shape[1], pages_k.shape[2]
-    tail = pages_k.shape[3:]
-    B, MP = page_table.shape
-    max_len = MP * ps
-    pos = pos.astype(jnp.int32)
-    x, _ = bert.embed(params, cfg, tokens, jnp.zeros_like(tokens),
-                      dtype=dtype, deterministic=True,
-                      position_ids=pos[:, None])
-    visible = (jnp.arange(max_len)[None, :] <= pos[:, None])
-    bias = mask_bias(visible.astype(jnp.float32), jnp.float32)
-    gidx = _flat_gather_idx(page_table, ps)                    # [B, max_len]
-    lp = pos // ps
-    phys = jnp.take_along_axis(page_table, lp[:, None], axis=1)[:, 0]
-    # dead rows ride with sentinel tables: their write lands OOB (dropped)
-    wflat = jnp.where(phys < P, phys * ps + pos % ps, P * ps)  # [B]
-    pk = pages_k.reshape(L, P * ps, *tail)
-    pv = pages_v.reshape(L, P * ps, *tail)
-
-    def layer(carry, scanned):
-        x = carry
-        if kv_scales is None:
-            lp_, _, pk_l, pv_l = scanned
-        else:
-            lp_, _, pk_l, pv_l, ks_l, vs_l = scanned
-        q, k_new, v_new = _qkv(x, lp_, cfg, dtype)             # [B, 1, N, D]
-        if kv_scales is None:
-            pk_l = pk_l.at[wflat].set(k_new[:, 0].astype(pk_l.dtype),
-                                      mode="drop")
-            pv_l = pv_l.at[wflat].set(v_new[:, 0].astype(pv_l.dtype),
-                                      mode="drop")
-            kf = jnp.take(pk_l, gidx, axis=0, mode="fill", fill_value=0)
-            vf = jnp.take(pv_l, gidx, axis=0, mode="fill", fill_value=0)
-        else:
-            pk_l = pk_l.at[wflat].set(quantize_kv(k_new[:, 0], ks_l),
-                                      mode="drop")
-            pv_l = pv_l.at[wflat].set(quantize_kv(v_new[:, 0], vs_l),
-                                      mode="drop")
-            kf = dequantize_kv(
-                jnp.take(pk_l, gidx, axis=0, mode="fill", fill_value=0),
-                ks_l, dtype)
-            vf = dequantize_kv(
-                jnp.take(pv_l, gidx, axis=0, mode="fill", fill_value=0),
-                vs_l, dtype)
-        attn = dot_product_attention(q, kf, vf, bias, impl="auto")
-        return _finish_layer(x, lp_, cfg, attn, dtype), (pk_l, pv_l)
-
-    li = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], li, pk, pv)
-    if kv_scales is not None:
-        xs = xs + (kv_scales[0], kv_scales[1])
-    x, (pk, pv) = jax.lax.scan(layer, x, xs, unroll=unroll)
-    logits = lm_logits(params, head, cfg, x, dtype=dtype)[:, 0]
-    return (logits, pk.reshape(pages_k.shape), pv.reshape(pages_v.shape))
+                      **kw) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`decode_step` over a paged cache — the core at T = 1: write the
+    current token's K/V through the table, attend over the table's extent,
+    next-token logits ``[B, vocab]``.  The table is data, so one compiled
+    program serves every step at a given table width."""
+    return paged_attend_layers(params, head, cfg, tokens, pages_k, pages_v,
+                               page_table, pos, **kw)
 
 
 def paged_chunk_step(params: Params, head: Params, cfg: BertConfig,
                      tokens: jax.Array,      # [B, T] int32 (suffix chunk)
-                     pages_k: jax.Array,     # [L, P, page_sz, N, D]
-                     pages_v: jax.Array,
+                     pages_k: jax.Array, pages_v: jax.Array,
                      page_table: jax.Array,  # [B, MP] int32 (sentinel P)
                      start: jax.Array,       # [B] absolute pos of tokens[:,0]
                      nreal: jax.Array,       # [B] real chunk lengths (0 ok)
-                     *, kv_scales: Optional[Tuple[jax.Array,
-                                                  jax.Array]] = None,
-                     dtype=jnp.float32, unroll=True
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                     **kw) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Suffix prefill against a paged cache: the prompt's SHARED prefix
     pages already hold K/V (a prefix-index hit), so only the divergent
-    suffix runs — ``tokens[b, t]`` sits at absolute position ``start[b] +
-    t``, writes through the page table, and attends to key positions
-    ``<= start + t`` (shared prefix + the chunk's own causal triangle).
-    Returns each row's LAST real token's next-token logits [B, vocab]
-    (fp32), like :func:`prefill`.  Rows with ``nreal == 0`` are filler:
-    their writes land OOB and their logits are garbage the caller
-    discards."""
-    _check_dense_trunk(params["layers"])
-    L, P, ps = pages_k.shape[0], pages_k.shape[1], pages_k.shape[2]
-    tail = pages_k.shape[3:]
-    B, MP = page_table.shape
-    T = tokens.shape[1]
-    max_len = MP * ps
-    start = start.astype(jnp.int32)
-    nreal = nreal.astype(jnp.int32)
-    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)  # [B, T]
-    x, _ = bert.embed(params, cfg, tokens, jnp.zeros_like(tokens),
-                      dtype=dtype, deterministic=True,
-                      position_ids=positions)
-    # per-query linear visibility: query t sees key j iff j <= start + t
-    vis = (jnp.arange(max_len, dtype=jnp.int32)[None, None, :]
-           <= positions[:, :, None])                     # [B, T, max_len]
-    bias = jnp.where(vis, 0.0, -1e9).astype(jnp.float32)[:, None]
-    gidx = _flat_gather_idx(page_table, ps)
-    # write positions: padded chunk slots (t >= nreal) land OOB
-    in_chunk = jnp.arange(T, dtype=jnp.int32)[None, :] < nreal[:, None]
-    lp = jnp.clip(positions // ps, 0, MP - 1)
-    phys = jnp.take_along_axis(page_table, lp, axis=1)   # [B, T]
-    wflat = jnp.where(in_chunk & (phys < P) & (positions < max_len),
-                      phys * ps + positions % ps, P * ps)
-    pk = pages_k.reshape(L, P * ps, *tail)
-    pv = pages_v.reshape(L, P * ps, *tail)
-
-    def layer(carry, scanned):
-        x = carry
-        if kv_scales is None:
-            lp_, _, pk_l, pv_l = scanned
-        else:
-            lp_, _, pk_l, pv_l, ks_l, vs_l = scanned
-        q, k_new, v_new = _qkv(x, lp_, cfg, dtype)       # [B, T, N, D]
-        if kv_scales is None:
-            pk_l = pk_l.at[wflat].set(k_new.astype(pk_l.dtype),
-                                      mode="drop")
-            pv_l = pv_l.at[wflat].set(v_new.astype(pv_l.dtype),
-                                      mode="drop")
-            kf = jnp.take(pk_l, gidx, axis=0, mode="fill", fill_value=0)
-            vf = jnp.take(pv_l, gidx, axis=0, mode="fill", fill_value=0)
-        else:
-            pk_l = pk_l.at[wflat].set(quantize_kv(k_new, ks_l),
-                                      mode="drop")
-            pv_l = pv_l.at[wflat].set(quantize_kv(v_new, vs_l),
-                                      mode="drop")
-            kf = dequantize_kv(
-                jnp.take(pk_l, gidx, axis=0, mode="fill", fill_value=0),
-                ks_l, dtype)
-            vf = dequantize_kv(
-                jnp.take(pv_l, gidx, axis=0, mode="fill", fill_value=0),
-                vs_l, dtype)
-        attn = dot_product_attention(q, kf, vf, bias, impl="auto")
-        return _finish_layer(x, lp_, cfg, attn, dtype), (pk_l, pv_l)
-
-    li = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], li, pk, pv)
-    if kv_scales is not None:
-        xs = xs + (kv_scales[0], kv_scales[1])
-    x, (pk, pv) = jax.lax.scan(layer, x, xs, unroll=unroll)
-    last = jnp.clip(nreal - 1, 0, T - 1)
-    h_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,H]
-    logits = lm_logits(params, head, cfg, h_last, dtype=dtype)[:, 0]
-    return (logits, pk.reshape(pages_k.shape), pv.reshape(pages_v.shape))
+    suffix runs — the core at T = the bucket, returning each row's LAST
+    real token's next-token logits ``[B, vocab]`` like :func:`prefill`."""
+    return paged_attend_layers(params, head, cfg, tokens, pages_k, pages_v,
+                               page_table, start, nreal, **kw)
 
 
 def paged_verify_step(params: Params, head: Params, cfg: BertConfig,
                       tokens: jax.Array,      # [B, K1] int32 (spec window)
-                      pages_k: jax.Array,     # [L, P, page_sz, N, D]
-                      pages_v: jax.Array,
+                      pages_k: jax.Array, pages_v: jax.Array,
                       page_table: jax.Array,  # [B, MP] int32 (sentinel P)
                       start: jax.Array,       # [B] abs pos of tokens[:,0]
                       nreal: jax.Array,       # [B] real window lengths
-                      *, kv_scales: Optional[Tuple[jax.Array,
-                                                   jax.Array]] = None,
-                      dtype=jnp.float32, unroll=True
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                      **kw) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Speculative verify: score the pending token plus k drafted tokens
-    in ONE prefill-shaped call against the primary's paged cache.  The
-    body is :func:`paged_chunk_step` verbatim — same per-query linear
-    visibility, same write-through-the-table K/V commit — but the LM
-    head runs over EVERY window position, returning ``[B, K1, vocab]``
-    fp32 so the caller can take the greedy target at each draft offset.
-    K/V for the whole window is written eagerly; rejected positions stay
-    in the cache as stale entries that no later query can see (the
-    visibility mask is position-based) and the next round overwrites
-    them in place.  Rows with ``nreal == 0`` are filler whose writes
-    land OOB (sentinel table rows) and whose logits the caller
-    discards."""
-    _check_dense_trunk(params["layers"])
-    L, P, ps = pages_k.shape[0], pages_k.shape[1], pages_k.shape[2]
-    tail = pages_k.shape[3:]
-    B, MP = page_table.shape
-    T = tokens.shape[1]
-    max_len = MP * ps
-    start = start.astype(jnp.int32)
-    nreal = nreal.astype(jnp.int32)
-    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)  # [B, K1]
-    x, _ = bert.embed(params, cfg, tokens, jnp.zeros_like(tokens),
-                      dtype=dtype, deterministic=True,
-                      position_ids=positions)
-    vis = (jnp.arange(max_len, dtype=jnp.int32)[None, None, :]
-           <= positions[:, :, None])                    # [B, K1, max_len]
-    bias = jnp.where(vis, 0.0, -1e9).astype(jnp.float32)[:, None]
-    gidx = _flat_gather_idx(page_table, ps)
-    in_chunk = jnp.arange(T, dtype=jnp.int32)[None, :] < nreal[:, None]
-    lp = jnp.clip(positions // ps, 0, MP - 1)
-    phys = jnp.take_along_axis(page_table, lp, axis=1)   # [B, K1]
-    wflat = jnp.where(in_chunk & (phys < P) & (positions < max_len),
-                      phys * ps + positions % ps, P * ps)
-    pk = pages_k.reshape(L, P * ps, *tail)
-    pv = pages_v.reshape(L, P * ps, *tail)
-
-    def layer(carry, scanned):
-        x = carry
-        if kv_scales is None:
-            lp_, _, pk_l, pv_l = scanned
-        else:
-            lp_, _, pk_l, pv_l, ks_l, vs_l = scanned
-        q, k_new, v_new = _qkv(x, lp_, cfg, dtype)       # [B, K1, N, D]
-        if kv_scales is None:
-            pk_l = pk_l.at[wflat].set(k_new.astype(pk_l.dtype),
-                                      mode="drop")
-            pv_l = pv_l.at[wflat].set(v_new.astype(pv_l.dtype),
-                                      mode="drop")
-            kf = jnp.take(pk_l, gidx, axis=0, mode="fill", fill_value=0)
-            vf = jnp.take(pv_l, gidx, axis=0, mode="fill", fill_value=0)
-        else:
-            pk_l = pk_l.at[wflat].set(quantize_kv(k_new, ks_l),
-                                      mode="drop")
-            pv_l = pv_l.at[wflat].set(quantize_kv(v_new, vs_l),
-                                      mode="drop")
-            kf = dequantize_kv(
-                jnp.take(pk_l, gidx, axis=0, mode="fill", fill_value=0),
-                ks_l, dtype)
-            vf = dequantize_kv(
-                jnp.take(pv_l, gidx, axis=0, mode="fill", fill_value=0),
-                vs_l, dtype)
-        attn = dot_product_attention(q, kf, vf, bias, impl="auto")
-        return _finish_layer(x, lp_, cfg, attn, dtype), (pk_l, pv_l)
-
-    li = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], li, pk, pv)
-    if kv_scales is not None:
-        xs = xs + (kv_scales[0], kv_scales[1])
-    x, (pk, pv) = jax.lax.scan(layer, x, xs, unroll=unroll)
-    logits = lm_logits(params, head, cfg, x, dtype=dtype)   # [B, K1, V]
-    return (logits, pk.reshape(pages_k.shape), pv.reshape(pages_v.shape))
+    in ONE prefill-shaped call — the core with the LM head over EVERY
+    window position, ``[B, K1, vocab]`` fp32, so the caller can take the
+    greedy target at each draft offset.  K/V for the whole window is
+    written eagerly; rejected positions stay in the cache as stale entries
+    that no later query can see (the visibility mask is position-based) and
+    the next round overwrites them in place."""
+    return paged_attend_layers(params, head, cfg, tokens, pages_k, pages_v,
+                               page_table, start, nreal, logits_at="all",
+                               **kw)
 
 
 # ------------------------------------------------------- infilling scoring

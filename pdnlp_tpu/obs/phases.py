@@ -555,9 +555,15 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     time from its first leaf to its last, less every ``*.device_wait``,
     over that wall time: the share of a round in which the device can
     only wait for the host.  Calls that compiled (``phase="compile"``:
-    warm-up) and a drafter engine's calls are left out.  Empty when the
-    stream holds no leaf."""
+    warm-up) and a drafter engine's calls are left out.
+    ``kv_read_amplification`` = cached positions the decode steps'
+    attention covered (``kv_positions_read`` of ``decode.dispatch``: rows x
+    extent) over the positions that were live (``kv_positions_live``); per
+    decode step it is the same ratio, both sums having ``steps`` terms.
+    ``chunk_kv_read_amplification`` the same over ``chunk.dispatch``.
+    Empty when the stream holds no leaf."""
     per: Dict[object, Dict[str, List[float]]] = {}
+    kv: Dict[object, Dict[str, List[int]]] = {}    # rep -> call -> [read, live]
     edges: Dict[object, List[float]] = {}
     compiling: Dict[object, bool] = {}   # tid -> inside a compiling call
     for r in records:
@@ -575,6 +581,10 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
         rep = attrs.get("replica", 0)
         t0, dur = float(r.get("t0", 0.0)), float(r.get("dur", 0.0))
         per.setdefault(rep, {}).setdefault(name, []).append(dur)
+        if "kv_positions_read" in attrs:
+            acc = kv.setdefault(rep, {}).setdefault(name, [0, 0])
+            acc[0] += int(attrs["kv_positions_read"])
+            acc[1] += int(attrs.get("kv_positions_live", 0))
         e = edges.setdefault(rep, [t0, t0 + dur])
         e[0], e[1] = min(e[0], t0), max(e[1], t0 + dur)
     out: Dict[str, Dict] = {}
@@ -583,12 +593,19 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
         wall = edges[rep][1] - edges[rep][0]
         waited = sum(sum(v) for k, v in leaves.items()
                      if k.endswith(".device_wait"))
+        amplification = {}
+        for key, call in (("kv_read_amplification", "decode.dispatch"),
+                          ("chunk_kv_read_amplification", "chunk.dispatch")):
+            read, live = kv.get(rep, {}).get(call, (0, 0))
+            if live:
+                amplification[key] = round(read / live, 4)
         out[str(rep)] = {
             "steps": steps, "wall_sec": round(wall, 6),
             "host_exposed_share": round((wall - waited) / wall, 4)
             if wall > 0 else None,
             "host_exposed_ms_per_step": round(
                 1e3 * (wall - waited) / steps, 4) if steps else None,
+            **amplification,
             "leaves": {
                 name: {
                     "count": len(vals),
@@ -615,6 +632,14 @@ def format_decode_table(by_replica: Dict) -> str:
             + (f"{share:.1%} of the round" if share is not None else "n/a")
             + (f" = {b['host_exposed_ms_per_step']:.3f} ms/step"
                if b["host_exposed_ms_per_step"] is not None else ""))
+        if "kv_read_amplification" in b:
+            lines.append(
+                f"  KV read amplification per decode step: "
+                f"{b['kv_read_amplification']:.3f} (positions the step's "
+                "attention covers / positions live)"
+                + (f"; per chunk launch "
+                   f"{b['chunk_kv_read_amplification']:.3f}"
+                   if "chunk_kv_read_amplification" in b else ""))
         header = (f"  {'leaf':<22} {'count':>7} {'ms/step':>10} "
                   f"{'mean_ms':>10} {'p95_ms':>10}")
         lines += [header, "  " + "-" * (len(header) - 2)]

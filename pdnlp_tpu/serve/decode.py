@@ -670,13 +670,22 @@ class _PageClaim:
 
 class PagedDecodeEngine(DecodeEngine):
     """:class:`DecodeEngine` rebased onto the paged KV subsystem
-    (``serve.kvpage``): storage is ``[L, n_pages, page_sz, N, D]`` pages,
-    a per-stream page table drives the decode-step gather
-    (``models.decoder.paged_decode_step`` — still ONE fixed-shape jitted
-    program, pages donated across steps), and capacity is PAGES, not
-    slots: slots become pure decode-batch rows while ``--kv_hbm_mb`` caps
-    the page pool, so short streams stop paying for ``max_len`` stripes
-    and admitted concurrency scales with what streams actually use.
+    (``serve.kvpage``): storage is ``[L, n_pages, page_sz, hidden]`` pages
+    (the layout the chip tiles exactly — ``models.decoder``'s paged-cache
+    note), a per-stream page table drives every program's page reads, and
+    capacity is PAGES, not slots: slots become pure decode-batch rows while
+    ``--kv_hbm_mb`` caps the page pool, so short streams stop paying for
+    ``max_len`` stripes and admitted concurrency scales with what streams
+    actually use.
+
+    The pool stays where it lies: every paged program
+    (``models.decoder.paged_attend_layers`` behind ``paged_decode_step`` /
+    ``paged_chunk_step`` / ``paged_verify_step``, and ``paged_insert``)
+    takes both pools donated, writes rows or whole pages in place and
+    reads whole pages through the table.  The decode step attends over a
+    RUNG of page counts (:attr:`decode_rungs`, quarters of a stream's
+    pages) chosen per step by the longest row: fixed-shape programs, one
+    per rung, all traced in :meth:`warmup_decode`.
 
     Prefix sharing rides the :class:`~pdnlp_tpu.serve.kvpage.PrefixIndex`:
     a repeated prompt maps the indexed pages at refcount+1 and skips its
@@ -689,11 +698,11 @@ class PagedDecodeEngine(DecodeEngine):
     immutable once written, which is what makes sharing safe without
     copies.
 
-    Bitwise contract: a COLD paged stream runs the exact slot-engine
-    prefill program and a decode step that gathers to the same
-    ``[B, max_len]`` attention extent with identical values at every
-    visible position — token-identical continuations (the bench storm
-    gates paged-vs-slot equality stream by stream).  Shared-prefix
+    Parity contract: a COLD paged stream runs the exact slot-engine
+    prefill program and a decode step of the same mathematics over the
+    same values at every visible position, summed over a shorter extent —
+    TOKEN-identical continuations, not bitwise-equal logits (the bench
+    storm gates paged-vs-slot equality stream by stream).  Shared-prefix
     streams reuse K/V that is bitwise what their own prefill would have
     produced (same program, same inputs), so greedy continuations match
     the cold baseline the same way re-prefilled kill survivors always
@@ -708,6 +717,9 @@ class PagedDecodeEngine(DecodeEngine):
     #: fixed copy-on-write batch rows — one compiled ``copy_pages``
     #: program per engine; unused rows ride the OOB sentinel
     COW_ROWS = 4
+    #: rungs of the decode step's attention extent (quarters of a stream's
+    #: pages): each is one compiled program, all traced in warmup
+    DECODE_RUNGS = 4
 
     def __init__(self, args, tokenizer=None, *, mesh=None, metrics=None,
                  tracer=None, slots: Optional[int] = None,
@@ -814,6 +826,12 @@ class PagedDecodeEngine(DecodeEngine):
         ps = max(1, min(self._req_page_sz, self.max_len))
         self.page_sz = ps
         self.pages_per_stream = pages_needed(self.max_len, ps)
+        # the decode step's attention extents, in pages: one warmed
+        # program per rung, chosen per step by the longest live row
+        mp = self.pages_per_stream
+        self.decode_rungs = sorted(
+            {-(-mp * i // self.DECODE_RUNGS)
+             for i in range(1, self.DECODE_RUNGS + 1)})
         self.page_bytes = self.token_bytes * ps
         req_pages = int(requested) * self.pages_per_stream
         self.n_pages = self.budget.cap_pages(
@@ -831,8 +849,10 @@ class PagedDecodeEngine(DecodeEngine):
         """(Re)allocate the page pool + a fresh allocator/index/table —
         construction and post-chaos :meth:`reset_cache`, never hot."""
         cfg = self.cfg
+        # heads folded into ONE minor axis: [page_sz, hidden] tiles the
+        # chip's layout exactly (models.decoder, paged-cache note)
         shape = (cfg.num_layers, self.n_pages, self.page_sz,
-                 cfg.num_heads, cfg.head_dim)
+                 cfg.hidden_size)
 
         def alloc():
             # two SEPARATE buffers (donation aliasing — base note)
@@ -1044,7 +1064,7 @@ class PagedDecodeEngine(DecodeEngine):
     # serve every stream.
     def export_pages(self, slot: int, request_ids=None):
         """Export ``slot``'s pages as a host ``[L, pages_per_stream,
-        page_sz, N, D]`` payload pair (K, V) — raw cache bytes (int8
+        page_sz, H]`` payload pair (K, V) — raw cache bytes (int8
         cache exports int8; both pools calibrate identical scale tables
         from the same params, so no rescaling crosses the wire).  An
         out-of-range ``slot`` exports the sentinel row (zero payload) —
@@ -1082,7 +1102,7 @@ class PagedDecodeEngine(DecodeEngine):
         path.  Compile key ``("import", pages_per_stream)``."""
         cfg = self.cfg
         want = (cfg.num_layers, self.pages_per_stream, self.page_sz,
-                cfg.num_heads, cfg.head_dim)
+                cfg.hidden_size)
         got = tuple(int(s) for s in np.shape(payload_k))
         if got != want or tuple(int(s)
                                 for s in np.shape(payload_v)) != want:
@@ -1207,7 +1227,7 @@ class PagedDecodeEngine(DecodeEngine):
         slot engine (bitwise-identical K/V for identical prompts — the
         sharing contract rests on this), scattered into pages through
         each claimed slot's table.  Filler rows and padding carry the
-        OOB flat sentinel, so they can never touch a live page."""
+        OOB sentinel, so they can never touch a live page."""
         self._flush_cow()
         n = len(id_lists)
         assert n and n <= self.prefill_rows
@@ -1216,19 +1236,26 @@ class PagedDecodeEngine(DecodeEngine):
                                  self.prefill_buckets)
             rows = self.prefill_rows
             ps = self.page_sz
-            oob = self.n_pages * ps
+            # whole pages where the bucket is made of them (one index a
+            # page), else position by position (decoder.paged_insert)
+            unit = ps if bucket % ps == 0 else 1
             ids = np.zeros((rows, bucket), np.int32)
             mask = np.zeros((rows, bucket), np.int32)
             last = np.zeros((rows,), np.int32)
-            flat = np.full((rows, bucket), oob, np.int32)
+            flat = np.full((rows, bucket // unit),
+                           self.n_pages * ps // unit, np.int32)
             for i, (x, s) in enumerate(zip(id_lists, slot_ids)):
                 ids[i, :len(x)] = x
                 mask[i, :len(x)] = 1
                 last[i] = len(x) - 1
                 if 0 <= s < self.slots and self._slot_state[s] is not None:
-                    p = np.arange(len(x))
                     row = self._table[s]
-                    flat[i, :len(x)] = row[p // ps] * ps + p % ps
+                    if unit == ps:
+                        n_pg = pages_needed(len(x), ps)
+                        flat[i, :n_pg] = row[:n_pg]
+                    else:
+                        p = np.arange(len(x))
+                        flat[i, :len(x)] = row[p // ps] * ps + p % ps
             phase = self._seen((int(bucket), int(rows), "prefill"),
                                "prefill")
             sharded = self._shard_batch({"ids": ids, "mask": mask})
@@ -1280,6 +1307,8 @@ class PagedDecodeEngine(DecodeEngine):
                        streams=int(n), prefill=True, paged=True,
                        chunk=True, tokens=int(nreal.sum()),
                        cached=int(sum(int(s) for s in starts)),
+                       kv_positions_read=rows * self.max_len,
+                       kv_positions_live=int((start + nreal)[:n].sum()),
                        dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
             logits, self._cache_k, self._cache_v = self._jit_pchunk(
@@ -1290,24 +1319,31 @@ class PagedDecodeEngine(DecodeEngine):
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
                      live: int, request_ids=None) -> np.ndarray:
-        """One fixed-shape decode step over the slot block, gathering
-        through the per-slot page tables.  Same ONE compile key
-        ``("decode", slots)`` as the slot layout — the table is data,
-        not shape, so paging cannot retrace."""
+        """One fixed-shape decode step over the slot block, reading whole
+        pages through the per-slot page tables.  The table is data, not
+        shape; its WIDTH is the attention extent, cut to the smallest
+        rung of :attr:`decode_rungs` that reaches the longest row — compile
+        key ``("decode", slots, rung)``, every rung traced in warmup, so
+        paging cannot retrace."""
         self._flush_cow()
         with self.tracer.leaf("decode.dispatch", self.span_attrs) as sp:
-            phase = self._seen(("decode", int(self.slots)), "decode")
             tok = np.asarray(tokens, np.int32).reshape(self.slots, 1)
             p = np.clip(np.asarray(pos, np.int32), 0, self.max_len - 1)
+            need = pages_needed(int(p.max()) + 1, self.page_sz)
+            rung = next(r for r in self.decode_rungs if r >= need)
+            phase = self._seen(("decode", int(self.slots), rung), "decode")
             if sp:
+                alive = self._table[:, 0] < self.n_pages
                 sp.set(phase=phase, rows=int(self.slots), live=int(live),
                        decode=True, paged=True,
                        pages_live=self.allocator.used_pages,
+                       kv_positions_read=self.slots * rung * self.page_sz,
+                       kv_positions_live=int((p[alive] + 1).sum()),
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
             logits, self._cache_k, self._cache_v = self._jit_pdecode(
                 self.params, self.head, self._cache_k, self._cache_v,
-                tok, jnp.asarray(self._table), p, *self._scale_args())
+                tok, self._table[:, :rung], p, *self._scale_args())
         return self._fetch_logits(logits, "decode.device_wait",
                                   "decode.fetch")
 
@@ -1370,8 +1406,11 @@ class PagedDecodeEngine(DecodeEngine):
                                [self.slots], [0])
         self._flush_cow(force=True)
         tok = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        self.decode_batch(tok, pos, live=0)
+        for rung in self.decode_rungs:
+            # all-dead rows (sentinel tables write nothing) at a position
+            # only this rung reaches
+            pos = np.full((self.slots,), rung * self.page_sz - 1, np.int32)
+            self.decode_batch(tok, pos, live=0)
 
     def kv_snapshot(self) -> Dict:
         """Budget block + the paged story: allocator occupancy/free

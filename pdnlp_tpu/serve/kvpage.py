@@ -6,7 +6,7 @@ half of the paged rebase (the math half is ``models.decoder``'s
 ``paged_*`` programs; the serving half is ``serve.decode``'s
 ``PagedDecodeEngine``):
 
-- **pages**: K/V storage is ``[L, n_pages, page_sz, N, D]``; a stream
+- **pages**: K/V storage is ``[L, n_pages, page_sz, hidden]``; a stream
   holds pages for the positions it actually uses (``ceil((prompt +
   max_new) / page_sz)``, reserved IN FULL at claim time — no mid-decode
   page faults, no preemption machinery, and the capacity math stays

@@ -145,3 +145,68 @@ def test_compiles_for_v5e(case, grid_hints, one_chip):
     hinted = [b for b in bodies
               if b"dimension_semantics" in b and b"parallel" in b]
     assert len(hinted) == (len(bodies) if grid_hints else 0)
+
+
+# ---------------------------------------------------- the paged KV programs
+
+def _paged_case(program):
+    """A paged program at bert-base's widths (two layers: the layout is the
+    pool's ``[page_sz, hidden]`` tail, not its depth), 128 rows, pools of
+    16 384 pages donated (0.75 GiB each: far above the step's gathered
+    K/V and logits).  -> (fn, donate_argnums, shapes, pool bytes)."""
+    from pdnlp_tpu.models import decoder
+
+    cfg = get_config("bert-base", num_labels=C, dropout=0.0,
+                     attn_dropout=0.0, num_layers=2)
+    params = jax.eval_shape(lambda: bert.init_params(jax.random.key(0), cfg))
+    head = jax.eval_shape(lambda: decoder.init_lm_head(jax.random.key(0),
+                                                       cfg))
+    P, ps, rows, MP = 16384, 16, 128, 32
+    S, i32, bf = jax.ShapeDtypeStruct, jnp.int32, jnp.bfloat16
+    pool = S((cfg.num_layers, P, ps, H), bf)
+    nbytes = cfg.num_layers * P * ps * H * 2
+    if program == "decode":
+        def fn(params, head, pk, pv, tok, table, pos):
+            return decoder.paged_decode_step(params, head, cfg, tok, pk, pv,
+                                             table, pos, dtype=bf)
+        return fn, (2, 3), (params, head, pool, pool, S((rows, 1), i32),
+                            S((rows, MP), i32), S((rows,), i32)), nbytes
+    if program == "chunk":
+        def fn(params, head, pk, pv, tok, table, start, nreal):
+            return decoder.paged_chunk_step(params, head, cfg, tok, pk, pv,
+                                            table, start, nreal, dtype=bf)
+        return fn, (2, 3), (params, head, pool, pool, S((8, 256), i32),
+                            S((8, MP), i32), S((8,), i32),
+                            S((8,), i32)), nbytes
+    kv = S((cfg.num_layers, 8, 256, N, D), bf)
+    return decoder.paged_insert, (0, 1), (pool, pool, kv, kv,
+                                          S((8, 256 // ps), i32)), nbytes
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "insert"])
+def test_paged_programs_leave_the_pool_where_it_lies(program, one_chip):
+    """The chip's compiler at the chip's layout: the donated pools are
+    aliased to the outputs, no temporary is as large as one pool, and the
+    pool enters row-major (a ``[.., heads, 64]`` tail makes the PAGE axis
+    the chip's minor one, and every program then converts the whole pool
+    there and back — PERF.md, PR 26)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    fn, donate, shapes, pool_bytes = _paged_case(program)
+    shapes = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * pool_bytes
+    assert m.temp_size_in_bytes < pool_bytes
+    entry = compiled.as_text().split("ENTRY", 1)[1]
+    pools = re.findall(r"bf16\[2,16384,16,768\]\{([0-9,]+):", entry)
+    assert pools and set(pools) == {"3,2,1,0"}

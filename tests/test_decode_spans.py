@@ -395,6 +395,37 @@ def test_a_submitter_never_waits_on_the_workers_device_wait(
     assert max(took) < 0.02, took
 
 
+# ------------------------------------------------ KV read amplification
+
+def test_dispatch_leaves_count_what_attention_covers_and_what_is_live(
+        traced, engine):
+    """``decode.dispatch`` and ``chunk.dispatch`` carry the cached positions
+    the call's attention covers (rows x extent) beside the positions that
+    are live; ``summarize`` prints their ratio per decode step."""
+    recs = traced["records"]
+    dec = [r["attrs"] for r in recs if r["name"] == "decode.dispatch"]
+    chk = [r["attrs"] for r in recs if r["name"] == "chunk.dispatch"]
+    assert dec and chk
+    extents = {r * engine.page_sz * engine.slots for r in engine.decode_rungs}
+    for a in dec:
+        assert a["kv_positions_read"] in extents
+        assert 0 < a["kv_positions_live"] <= a["kv_positions_read"]
+    # 19-token prompts and 6 new tokens stay on the second rung of four
+    assert {a["kv_positions_read"] for a in dec} == {sorted(extents)[1]}
+    for a in chk:
+        assert a["kv_positions_read"] == engine.prefill_rows * engine.max_len
+        assert 0 < a["kv_positions_live"] <= a["kv_positions_read"]
+    worker = decode_host_phases(recs)["0"]
+    amp = worker["kv_read_amplification"]
+    assert amp == pytest.approx(
+        sum(a["kv_positions_read"] for a in dec)
+        / sum(a["kv_positions_live"] for a in dec), abs=1e-3)
+    assert 1.0 <= amp < engine.max_len
+    assert worker["chunk_kv_read_amplification"] >= 1.0
+    text = format_decode_table({"0": worker})
+    assert f"KV read amplification per decode step: {amp:.3f}" in text
+
+
 # ------------------------------------------------- trace_tpu.py summarize
 
 def test_summarize_prints_the_decode_workers_host_phase_table():

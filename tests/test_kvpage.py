@@ -321,8 +321,18 @@ def test_oversized_stream_refused_in_page_units(tok):
 
 # ------------------------------------------------------------ zero retrace
 
-def test_paged_decode_path_never_retraces_after_warmup(tok, pag):
+def test_paged_decode_path_never_retraces_after_warmup(tok, pag,
+                                                       monkeypatch):
     baseline = pag.metrics.cache_misses.value
+    traced = pag.metrics.retraces.value
+    widths = set()
+    real = pag._jit_pdecode
+
+    def spy(params, head, pk, pv, tokens, table, *rest):
+        widths.add(int(table.shape[1]))
+        return real(params, head, pk, pv, tokens, table, *rest)
+
+    monkeypatch.setattr(pag, "_jit_pdecode", spy)
     b = DecodeBatcher(pag, replica=0)
     b.eos_id = -1
     b.start()
@@ -332,9 +342,16 @@ def test_paged_decode_path_never_retraces_after_warmup(tok, pag):
     streams += [b.submit_ids(p, max_new_tokens=6) for p in ps[:2]]
     for s in streams:
         s.result(timeout=180)
+    # and lengths that reach EVERY rung of the decode extent: a stream
+    # that decodes on from 28 positions past the second page boundary
+    assert pag.decode_rungs == [1, 2, 3]
+    long = prompts(1, seed=22, lo=28, hi=29, vocab=tok.vocab_size)[0]
+    b.submit_ids(long, max_new_tokens=12).result(timeout=180)
     b.stop()
+    assert widths == set(pag.decode_rungs)
     assert pag.metrics.cache_misses.value == baseline, \
         "paged decode path retraced after warmup"
+    assert pag.metrics.retraces.value == traced
 
 
 # --------------------------------------------------------- kill recovery
