@@ -2,7 +2,7 @@
 """The standing proof that the trainer and the server start on a TPU chip.
 
     python chip_smoke.py            # one chip: device, train, train-packed,
-                                    # serve, decode
+                                    # serve, decode, decode-latent
     python chip_smoke.py --chips 4  # four chips: device, mesh-train (dp and
                                     # zero against one device), replicas
 
@@ -654,6 +654,49 @@ def phase_decode(ctx) -> dict:
                             "stream_owners", "index_entries")}}
 
 
+def phase_decode_latent(ctx) -> dict:
+    """The second model family (latent attention over a one-pool page cache,
+    sparse experts told which they hold) through the same ``serve_tpu.py
+    --decode``: the published widths at one dense + one expert layer, seeded
+    weights (the family has no trainer), a repeated prompt."""
+    import serve_tpu
+
+    model = "ax-k1-share-tiny" if ctx.rehearse else "ax-k1-ep16-share-l2"
+    a, b = request_lines(ctx, 2, 8, 24)
+    prompts = [a, b, a]
+    max_new = 8
+    metrics = os.path.join(ctx.out, "decode_latent_metrics.json")
+    argv = ["--model", model, "--dtype", "bfloat16", "--max_seq_len", "128",
+            "--seed", str(ctx.seed), "--data_path", ctx.corpus,
+            "--vocab_path", ctx.vocab, "--decode", "--decode_slots", "2",
+            # a directory of its own: no checkpoint of another family in it
+            "--output_dir", os.path.join(ctx.out, "latent"), "--buckets", "32", "--max_new_tokens",
+            str(max_new), "--metrics_path", metrics]
+    with captured(serve_tpu, "build_decode_pool") as pools:
+        out = run_cli(serve_tpu.main, argv, "\n".join(prompts) + "\n")
+    rows = [l.split("\t") for l in out.splitlines() if l.strip()]
+    check(not [r for r in rows if r[1] == "ERROR"], f"stream errors: {out}")
+    gens = {int(r[0]): r[2] if len(r) > 2 else "" for r in rows
+            if r[1] == "gen"}
+    check(sorted(gens) == [0, 1, 2], f"missing generations: {sorted(gens)}")
+    check(gens[2] == gens[0], f"the repeated prompt differs: {gens}")
+    engine = pools[0].engine(0)
+    check(engine.family.name == "latent_moe" and len(engine._pools) == 1,
+          f"family {engine.family.name}, {len(engine._pools)} pools")
+    with open(metrics) as f:
+        rep = json.load(f)["replicas"]["0"]
+    kv = rep["kv"]
+    check(kv["prefix"]["hits_full"] == 1, f"prefix index: {kv['prefix']}")
+    leak = engine.leak_check()
+    check(leak["ok"] and leak["leaked_pages"] == 0, f"leak check: {leak}")
+    return {"model": model, "prompts": len(prompts), "repeats": 1,
+            "tokens_streamed": sum(r[1] == "tok" for r in rows),
+            "cache_bytes_per_token": engine.token_bytes,
+            "kv_pool_bytes": kv["kv_pool_bytes"],
+            "weights_bytes": kv["weights_bytes"],
+            "compile_cache": rep["engine"]["compile_cache"]}
+
+
 # ---------------------------------------------------------- four chips
 
 
@@ -860,6 +903,7 @@ def main(argv=None) -> int:
         run_phase(ctx, "train-packed", phase_train_packed)
         run_phase(ctx, "serve", phase_serve)
         run_phase(ctx, "decode", phase_decode)
+        run_phase(ctx, "decode-latent", phase_decode_latent)
     if ctx.rehearse:
         print("chip_smoke: rehearsal walked every phase; this is not a chip "
               "run", file=sys.stderr)

@@ -48,12 +48,81 @@ class BertConfig:
                                   # ceil(cf * k * tokens / E); tokens over
                                   # capacity fall back to the residual path
 
+    #: which entry of ``models.families`` runs this config (a class
+    #: attribute, not a field: a preset cannot change its family)
+    family = "bert"
+
     @property
     def head_dim(self) -> int:
         assert self.hidden_size % self.num_heads == 0
         return self.hidden_size // self.num_heads
 
     def replace(self, **kw) -> "BertConfig":
+        return dataclasses.replace(self, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    """A pre-norm decoder with latent attention (MLA) and sparse experts,
+    served only (``models/latent_moe.py``).  Field names are the published
+    ``config.json``'s where it has one; the defaults are A.X-K1's widths
+    (https://huggingface.co/skt/A.X-K1/blob/main/config.json).
+
+    ``experts_held`` / ``expert_first`` say which of the ``n_routed_experts``
+    THIS process holds (expert parallelism's share): the router keeps its
+    full width, its groups and its experts per token; only the held
+    experts' part of a layer's result is computed."""
+    vocab_size: int = 163_840
+    hidden_size: int = 7168
+    num_layers: int = 61          # leading dense layers + expert layers
+    first_k_dense: int = 1
+    num_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 18_432     # the dense layers' feed-forward
+    moe_intermediate_size: int = 2048   # one expert's (and the shared one's)
+    n_routed_experts: int = 192         # the router's width
+    experts_held: int = 192             # ... of which this process holds
+    expert_first: int = 0               # ... starting at this one
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10_000.0
+    rope_factor: float = 32.0           # yarn
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    max_position: int = 131_072
+    weight_dtype: str = "bfloat16"      # how the weights are STORED
+
+    family = "latent_moe"
+
+    @property
+    def latent_width(self) -> int:
+        """One cached position of one layer: ``[c_kv | k_rope]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """... as the pool holds it: padded to whole lane tiles of 128.  A
+        ``[page_sz, 576]`` tail is 4.5 tiles, and the chip's default layout
+        then puts the PAGE axis on the lanes and every program converts the
+        whole pool (read from a described-v5e compile, PERF.md section 6)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    def replace(self, **kw) -> "LatentMoEConfig":
         return dataclasses.replace(self, **kw)
 
 
@@ -78,28 +147,60 @@ _REGISTRY = {
     "bert-base-long": BertConfig(max_position=2048),
     "bert-tiny-long": BertConfig(hidden_size=128, num_layers=2, num_heads=2,
                                  intermediate_size=512, max_position=512),
+    # one chip's share of A.X-K1 when 16 chips share each layer (experts 16
+    # ways: 12 held; the vocabulary 8 ways comes from the tokenizer): every
+    # width as published, the dense layer and 5 of the 60 expert layers
+    "ax-k1-ep16-share": LatentMoEConfig(num_layers=6, experts_held=12,
+                                        vocab_size=20_480),
+    # the same share cut to the dense layer and ONE expert layer: what
+    # chip_smoke.py builds, so that the standing proof is quick
+    "ax-k1-ep16-share-l2": LatentMoEConfig(num_layers=2, experts_held=12,
+                                           vocab_size=20_480),
+    # the same family at a size the CPU tests run: 1 dense + 2 expert
+    # layers, 8 experts in 4 groups of which 2 groups and 3 experts a token
+    # are taken and 4 experts held
+    "ax-k1-share-tiny": LatentMoEConfig(
+        vocab_size=1000, hidden_size=128, num_layers=3, num_heads=4,
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=256,
+        moe_intermediate_size=64, n_routed_experts=8, experts_held=4,
+        num_experts_per_tok=3, n_group=4, topk_group=2, max_position=4096),
 }
 
 
 def get_config(name: str, vocab_size: Optional[int] = None,
-               num_labels: Optional[int] = None, **overrides) -> BertConfig:
+               num_labels: Optional[int] = None, **overrides):
     """Look up a registered architecture, overriding data-dependent fields
-    (vocab size comes from the corpus-built vocab at runtime)."""
+    (vocab size comes from the corpus-built vocab at runtime).  An override
+    a family has no field for (``num_labels`` or dropout on a served-only
+    decoder) is left out: the callers pass one set for every family."""
     try:
         cfg = _REGISTRY[name]
     except KeyError:
+        by_family = "; ".join(
+            f"{fam}: {', '.join(names)}"
+            for fam, names in sorted(models_by_family().items()))
         raise ValueError(
-            f"unknown model {name!r}; use one of {available_models()}") from None
+            f"unknown model {name!r}; use one of {by_family}") from None
     kw = dict(overrides)
     if vocab_size is not None:
         kw["vocab_size"] = vocab_size
     if num_labels is not None:
         kw["num_labels"] = num_labels
+    fields = {f.name for f in dataclasses.fields(cfg)}
+    kw = {k: v for k, v in kw.items() if k in fields}
     return cfg.replace(**kw) if kw else cfg
 
 
 def available_models():
     return sorted(_REGISTRY)
+
+
+def models_by_family() -> dict:
+    out: dict = {}
+    for name in available_models():
+        out.setdefault(_REGISTRY[name].family, []).append(name)
+    return out
 
 
 def args_overrides(args) -> dict:

@@ -120,9 +120,11 @@ def _finish_layer(x, lp, cfg, attn, dtype):
 def _check_dense_trunk(layers: Params) -> None:
     if "gate" in layers:
         raise ValueError(
-            "generative decoding over an MoE trunk is not supported — the "
-            "expert dispatch has no cached single-token form yet; serve a "
-            "dense checkpoint (--model without -moe)")
+            "generative decoding over this trunk's capacity-routed experts "
+            "is not supported (the dispatch drops overflow and has no "
+            "cached single-token form); serve a dense checkpoint (--model "
+            "without -moe), or a preset of the latent_moe family, whose "
+            "expert layer is dropless (models/latent_moe.py)")
 
 
 # ----------------------------------------------------------------- prefill
@@ -311,6 +313,37 @@ def _layer_rows(flat: jax.Array, n_layers: int, per_layer: int) -> jax.Array:
                      n_layers * per_layer)
 
 
+def insert_pool(pages: jax.Array,      # [L, P, page_sz, W]
+                new: jax.Array,        # [L, B, S, ...] (W values a position)
+                flat_pos: jax.Array,   # [B, S // unit] int32 (OOB drop)
+                scale: Optional[jax.Array] = None) -> jax.Array:
+    """Scatter a prefill's rows into ONE pool's pages.  ``flat_pos``'s WIDTH
+    says what one index moves: ``[B, S]`` — ``flat_pos[b, s]`` is the flat
+    position ``page * page_sz + offset`` of prompt b's position s — or ``[B,
+    S // page_sz]`` — entry j is the PHYSICAL PAGE of positions ``j *
+    page_sz ..``, written whole (the chip's scatters cost by the index, not
+    by the byte: 16x fewer).  A whole page takes the padded tail's rows with
+    it, at positions no query can see until a later write replaces them; a
+    prefilled prompt's pages are its stream's own.  Padding and filler carry
+    the OOB sentinel (``P * page_sz``, or ``P`` for pages), so they can never
+    touch a live page.  ``scale``: quantize to the int8 pool first."""
+    L, P, ps, W = pages.shape
+    S = new.shape[2]
+    unit = S // flat_pos.shape[1]
+    if unit not in (1, ps) or unit * flat_pos.shape[1] != S:
+        raise ValueError(f"flat_pos {flat_pos.shape} addresses neither the "
+                         f"{S} positions nor whole pages of {ps}")
+    if scale is not None:
+        new = quantize_kv(new, scale[:, None, None])
+    per_layer = P * ps // unit
+    idx = _layer_rows(flat_pos, L, per_layer).reshape(-1)
+    view = (L * per_layer, W) if unit == 1 else (L * per_layer, unit, W)
+    flat = pages.reshape(view)
+    flat = flat.at[idx].set(
+        new.reshape(idx.shape[0], *view[1:]).astype(pages.dtype), mode="drop")
+    return flat.reshape(pages.shape)
+
+
 def paged_insert(pages_k: jax.Array,   # [L, P, page_sz, H]
                  pages_v: jax.Array,
                  ks: jax.Array,        # [L, B, S, N, D] (prefill output)
@@ -319,82 +352,66 @@ def paged_insert(pages_k: jax.Array,   # [L, P, page_sz, H]
                  *, kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """Scatter a prefill's K/V into pages: the paged analogue of the slot
-    engine's cache insert.  ``flat_pos``'s WIDTH says what one index moves:
-    ``[B, S]`` — ``flat_pos[b, s]`` is the flat position ``page * page_sz +
-    offset`` of prompt b's position s — or ``[B, S // page_sz]`` — entry j
-    is the PHYSICAL PAGE of positions ``j * page_sz ..``, written whole (the
-    chip's scatters cost by the index, not by the byte: 16x fewer).  A whole
-    page takes the padded tail's K/V with it, at positions no query can see
-    until a later write replaces them; a prefilled prompt's pages are its
-    stream's own.  Padding and filler carry the OOB sentinel (``P *
-    page_sz``, or ``P`` for pages), so they can never touch a live page."""
-    L, P, ps, H = pages_k.shape
-    B, S = ks.shape[1], ks.shape[2]
-    unit = S // flat_pos.shape[1]
-    if unit not in (1, ps) or unit * flat_pos.shape[1] != S:
-        raise ValueError(f"flat_pos {flat_pos.shape} addresses neither the "
-                         f"{S} positions nor whole pages of {ps}")
-    if kv_scales is not None:
-        ks = quantize_kv(ks, kv_scales[0][:, None, None])
-        vs = quantize_kv(vs, kv_scales[1][:, None, None])
-    per_layer = P * ps // unit
-    idx = _layer_rows(flat_pos, L, per_layer).reshape(-1)
-    view = (L * per_layer, H) if unit == 1 else (L * per_layer, unit, H)
-
-    def put(pages, new):
-        flat = pages.reshape(view)
-        flat = flat.at[idx].set(
-            new.reshape(idx.shape[0], *view[1:]).astype(pages.dtype),
-            mode="drop")
-        return flat.reshape(pages.shape)
-
-    return put(pages_k, ks), put(pages_v, vs)
+    engine's cache insert, :func:`insert_pool` for K and for V."""
+    ks_l, vs_l = kv_scales or (None, None)
+    return (insert_pool(pages_k, ks, flat_pos, ks_l),
+            insert_pool(pages_v, vs, flat_pos, vs_l))
 
 
-def copy_pages(pages_k: jax.Array, pages_v: jax.Array,
-               src: jax.Array,      # [n] physical page ids (OOB = no-op)
-               dst: jax.Array       # [n]
-               ) -> Tuple[jax.Array, jax.Array]:
-    """Copy-on-write page duplication: ``pages[dst[i]] = pages[src[i]]``
-    across all layers.  Unused rows carry the OOB sentinel ``P`` on both
-    sides (``mode="fill"`` reads zeros, ``mode="drop"`` discards the
-    write), so ONE fixed row count serves every claim round."""
-    sk = jnp.take(pages_k, src, axis=1, mode="fill", fill_value=0)
-    sv = jnp.take(pages_v, src, axis=1, mode="fill", fill_value=0)
-    pages_k = pages_k.at[:, dst].set(sk, mode="drop")
-    pages_v = pages_v.at[:, dst].set(sv, mode="drop")
-    return pages_k, pages_v
+def copy_pool(pages: jax.Array,
+              src: jax.Array,      # [n] physical page ids (OOB = no-op)
+              dst: jax.Array       # [n]
+              ) -> jax.Array:
+    """Copy-on-write page duplication in ONE pool: ``pages[dst[i]] =
+    pages[src[i]]`` across all layers.  Unused rows carry the OOB sentinel
+    ``P`` on both sides (``mode="fill"`` reads zeros, ``mode="drop"``
+    discards the write), so ONE fixed row count serves every claim round."""
+    got = jnp.take(pages, src, axis=1, mode="fill", fill_value=0)
+    return pages.at[:, dst].set(got, mode="drop")
 
 
-def gather_pages(pages_k: jax.Array, pages_v: jax.Array,
-                 src: jax.Array       # [rows] physical page ids (OOB = 0s)
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Export one stream's pages into a dense ``[L, rows, page_sz, H]``
-    payload for a KV handoff.  ``src`` is ALWAYS the fixed
+def copy_pages(pages_k: jax.Array, pages_v: jax.Array, src: jax.Array,
+               dst: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """:func:`copy_pool` for K and for V."""
+    return copy_pool(pages_k, src, dst), copy_pool(pages_v, src, dst)
+
+
+def gather_pool(pages: jax.Array,
+                src: jax.Array       # [rows] physical page ids (OOB = 0s)
+                ) -> jax.Array:
+    """Export one stream's pages of ONE pool into a dense ``[L, rows,
+    page_sz, W]`` payload for a KV handoff.  ``src`` is ALWAYS the fixed
     ``pages_per_stream`` extent, padded with the OOB sentinel ``P``
     (``mode="fill"`` reads zeros there), so one compiled program serves
     every stream regardless of how many pages it actually holds — the
     real page count rides the page ids, never the shape."""
-    out_k = jnp.take(pages_k, src, axis=1, mode="fill", fill_value=0)
-    out_v = jnp.take(pages_v, src, axis=1, mode="fill", fill_value=0)
-    return out_k, out_v
+    return jnp.take(pages, src, axis=1, mode="fill", fill_value=0)
+
+
+def gather_pages(pages_k: jax.Array, pages_v: jax.Array, src: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`gather_pool` for K and for V."""
+    return gather_pool(pages_k, src), gather_pool(pages_v, src)
+
+
+def scatter_pool(pages: jax.Array,
+                 payload: jax.Array,    # [L, rows, page_sz, W]
+                 dst: jax.Array         # [rows] physical page ids (OOB drop)
+                 ) -> jax.Array:
+    """Import a handoff payload into freshly-allocated pages of ONE pool:
+    the receive half of :func:`gather_pool`.  ``dst`` rows past the
+    stream's real page count carry the OOB sentinel ``P`` and their
+    (zero-filled) payload rows are dropped, so the import is the same ONE
+    fixed-shape program for every stream."""
+    return pages.at[:, dst].set(payload.astype(pages.dtype), mode="drop")
 
 
 def scatter_pages(pages_k: jax.Array, pages_v: jax.Array,
-                  payload_k: jax.Array,  # [L, rows, page_sz, H]
-                  payload_v: jax.Array,
-                  dst: jax.Array         # [rows] physical page ids (OOB drop)
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """Import a handoff payload into freshly-allocated pages: the receive
-    half of :func:`gather_pages`.  ``dst`` rows past the stream's real
-    page count carry the OOB sentinel ``P`` and their (zero-filled)
-    payload rows are dropped, so the import is the same ONE fixed-shape
-    program for every stream."""
-    pages_k = pages_k.at[:, dst].set(payload_k.astype(pages_k.dtype),
-                                     mode="drop")
-    pages_v = pages_v.at[:, dst].set(payload_v.astype(pages_v.dtype),
-                                     mode="drop")
-    return pages_k, pages_v
+                  payload_k: jax.Array, payload_v: jax.Array,
+                  dst: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """:func:`scatter_pool` for K and for V."""
+    return (scatter_pool(pages_k, payload_k, dst),
+            scatter_pool(pages_v, payload_v, dst))
 
 
 #: query rows (window positions x heads) up to which attention runs with the
@@ -608,13 +625,14 @@ def infill_logits(params: Params, head: Params, cfg: BertConfig,
 
 # ------------------------------------------------------------- calibration
 
-def kv_cache_bytes(cfg: BertConfig, slots: int, max_len: int,
-                   kv_dtype) -> int:
-    """Preallocated K+V cache bytes for a slot block — the number the
-    ``--kv_hbm_mb`` budget (obs.memory.KVBudget) is checked against."""
-    itemsize = np.dtype(kv_dtype).itemsize
-    return int(2 * cfg.num_layers * slots * max_len * cfg.hidden_size
-               * itemsize)
+def kv_cache_bytes(cfg, slots: int, max_len: int, kv_dtype) -> int:
+    """Preallocated cache bytes for ``slots x max_len`` positions of
+    whatever ``cfg``'s family caches (K and V pools, or one latent pool) —
+    the number the ``--kv_hbm_mb`` budget (obs.memory.KVBudget) is checked
+    against.  Computed in ONE place: ``families.token_bytes``."""
+    from pdnlp_tpu.models import families
+
+    return int(slots * max_len * families.token_bytes(cfg, kv_dtype))
 
 
 def calibrate_kv_scales(params: Params, cfg: BertConfig, *,

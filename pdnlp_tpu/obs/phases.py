@@ -561,9 +561,16 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     extent) over the positions that were live (``kv_positions_live``); per
     decode step it is the same ratio, both sums having ``steps`` terms.
     ``chunk_kv_read_amplification`` the same over ``chunk.dispatch``.
+    ``expert_load`` (a family with sparse experts; from ``decode.fetch``'s
+    ``expert_assignments`` / ``expert_tokens_max`` / ``experts_idle``):
+    assignments to the held experts a decode step, the busiest held expert's
+    tokens a step, idle held experts a step, and ``cache_bytes_per_token``
+    as ``decode.dispatch`` states it.
     Empty when the stream holds no leaf."""
     per: Dict[object, Dict[str, List[float]]] = {}
     kv: Dict[object, Dict[str, List[int]]] = {}    # rep -> call -> [read, live]
+    experts: Dict[object, List[int]] = {}   # rep -> [launches, sum, max, idle]
+    token_bytes: Dict[object, int] = {}
     edges: Dict[object, List[float]] = {}
     compiling: Dict[object, bool] = {}   # tid -> inside a compiling call
     for r in records:
@@ -585,6 +592,14 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             acc = kv.setdefault(rep, {}).setdefault(name, [0, 0])
             acc[0] += int(attrs["kv_positions_read"])
             acc[1] += int(attrs.get("kv_positions_live", 0))
+        if "cache_bytes_per_token" in attrs:
+            token_bytes[rep] = int(attrs["cache_bytes_per_token"])
+        if name == "decode.fetch" and "expert_assignments" in attrs:
+            acc = experts.setdefault(rep, [0, 0, 0, 0])
+            acc[0] += 1
+            acc[1] += int(attrs["expert_assignments"])
+            acc[2] += int(attrs.get("expert_tokens_max", 0))
+            acc[3] += int(attrs.get("experts_idle", 0))
         e = edges.setdefault(rep, [t0, t0 + dur])
         e[0], e[1] = min(e[0], t0), max(e[1], t0 + dur)
     out: Dict[str, Dict] = {}
@@ -599,6 +614,14 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             read, live = kv.get(rep, {}).get(call, (0, 0))
             if live:
                 amplification[key] = round(read / live, 4)
+        if rep in experts:
+            n, total, most, idle = experts[rep]
+            amplification["expert_load"] = {
+                "assignments_per_step": round(total / n, 3),
+                "busiest_expert_tokens_per_step": round(most / n, 3),
+                "idle_experts_per_step": round(idle / n, 3)}
+        if rep in token_bytes:
+            amplification["cache_bytes_per_token"] = token_bytes[rep]
         out[str(rep)] = {
             "steps": steps, "wall_sec": round(wall, 6),
             "host_exposed_share": round((wall - waited) / wall, 4)
@@ -640,6 +663,17 @@ def format_decode_table(by_replica: Dict) -> str:
                 + (f"; per chunk launch "
                    f"{b['chunk_kv_read_amplification']:.3f}"
                    if "chunk_kv_read_amplification" in b else ""))
+        if "expert_load" in b:
+            e = b["expert_load"]
+            lines.append(
+                f"  expert load per decode step: "
+                f"{e['assignments_per_step']:.1f} assignments to held "
+                f"experts (all layers), the busiest expert's "
+                f"{e['busiest_expert_tokens_per_step']:.1f}, "
+                f"{e['idle_experts_per_step']:.2f} held experts idle")
+        if "cache_bytes_per_token" in b:
+            lines.append(f"  cache bytes per token: "
+                         f"{b['cache_bytes_per_token']}")
         header = (f"  {'leaf':<22} {'count':>7} {'ms/step':>10} "
                   f"{'mean_ms':>10} {'p95_ms':>10}")
         lines += [header, "  " + "-" * (len(header) - 2)]

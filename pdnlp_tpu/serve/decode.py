@@ -110,7 +110,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pdnlp_tpu.models import decoder
+from pdnlp_tpu.models import decoder, families
 from pdnlp_tpu.obs.decision import mint_decision_id, record_decision
 from pdnlp_tpu.obs.memory import KVBudget
 from pdnlp_tpu.obs.request import mint_request_id, record_hop
@@ -192,17 +192,29 @@ class DecodeEngine(InferenceEngine):
             raise ValueError(f"kv_dtype must be auto|fp32|bf16|int8, "
                              f"got {kv_req!r}")
         self.kv_int8 = kv_req == "int8"
+        family = self.family
+        if self.kv_int8 and not family.int8:
+            family.refuse("an int8 cache (--kv_dtype int8)",
+                          "its latent cache is stored in bf16")
+        if not self.paged and not family.slot_layout:
+            family.refuse("the slot cache layout (--kv_layout slots)",
+                          "use --kv_layout paged")
         self.kv_dtype = (jnp.int8 if self.kv_int8
                          else {"fp32": jnp.float32,
                                "bf16": jnp.bfloat16}.get(kv_req, self.dtype))
         self._kv_scales = None  # (k_scale, v_scale) [L, N, D] once known
+        #: assignments to each held expert, summed over the launches whose
+        #: fetch leaf recorded (a family with experts; else stays None)
+        self.expert_load: Optional[np.ndarray] = None
 
         # the declared HBM budget gates the PREALLOCATION (loud refusal at
         # construction, never an allocator OOM) and caps slots to what it
         # covers; admission re-checks per stream (KVBudgetExceeded)
         self.budget = KVBudget(getattr(args, "kv_hbm_mb", 0))
         requested = int(slots or getattr(args, "decode_slots", 8))
-        self.token_bytes = decoder.kv_cache_bytes(cfg, 1, 1, self.kv_dtype)
+        # bytes a cached position takes: ONE place computes it, from the
+        # family's pools, for budgets, refusals and snapshots alike
+        self.token_bytes = families.token_bytes(cfg, self.kv_dtype)
         self.slots = self._resolve_capacity(requested)
         self.prefill_rows = self.pad_rows(
             min(self.slots, int(prefill_rows or 8)))
@@ -217,9 +229,14 @@ class DecodeEngine(InferenceEngine):
         # LM head: MLM-shaped, seeded beside the trunk template; a
         # trained head loads via load_lm_head.  int8 weight serving
         # quantizes it through the same serving-form door as the trunk.
-        self._head_template = decoder.init_lm_head(
-            jax.random.key(args.seed + 1), cfg)
-        self.head = self._put(self._serving_form(self._head_template))
+        head_key = jax.random.key(args.seed + 1)
+        if family.lazy_weights:
+            self._head_template = jax.eval_shape(
+                lambda: family.init_head(head_key, cfg))
+            self.head = lambda: self._put(family.init_head(head_key, cfg))
+        else:
+            self._head_template = family.init_head(head_key, cfg)
+            self.head = self._put(self._serving_form(self._head_template))
         self.head_path: Optional[str] = None
 
         self._cache_k = self._cache_v = None
@@ -230,8 +247,9 @@ class DecodeEngine(InferenceEngine):
 
         def _prefill_fn(params, head, ids, mask, last_pos):
             metrics_ref.retraces.inc()  # body runs only while tracing
-            return decoder.prefill(params, head, cfg, ids, mask, last_pos,
-                                   dtype=dtype)
+            # -> (logits, aux, the new rows of every pool)
+            return family.prefill(params, head, cfg, ids, mask, last_pos,
+                                  dtype)
 
         if self.kv_int8:
             def _insert_fn(ck, cv, k, v, slot_ids, ks, vs):
@@ -266,6 +284,18 @@ class DecodeEngine(InferenceEngine):
         self._jit_prefill = jax.jit(_prefill_fn)
         self._jit_insert = jax.jit(_insert_fn, donate_argnums=(0, 1))
         self._jit_decode = jax.jit(_decode_fn, donate_argnums=(2, 3))
+
+    @property
+    def head(self):
+        """The LM head (made on first read for a lazy family, like
+        :attr:`params`)."""
+        if callable(self._head):
+            self._head = self._head()
+        return self._head
+
+    @head.setter
+    def head(self, value) -> None:
+        self._head = value
 
     #: layout marker — :class:`PagedDecodeEngine` flips it; the batcher
     #: and router branch on behavior hooks, never on this flag, but
@@ -486,13 +516,15 @@ class DecodeEngine(InferenceEngine):
         self._seen_shapes.add(key)
         return "compile"
 
-    def _fetch_logits(self, logits, wait_leaf: str,
-                      fetch_leaf: str) -> np.ndarray:
+    def _fetch_logits(self, logits, wait_leaf: str, fetch_leaf: str,
+                      aux=None) -> np.ndarray:
         """The two leaves that end an engine call: ``<p>.device_wait`` —
         ``block_until_ready`` on the logits, nothing else, so the device's
         time is never smeared into the host's — then ``<p>.fetch``, the
         ``device_get`` to numpy.  Untraced, the fetch is the one barrier
-        it always was."""
+        it always was.  ``aux`` (a family with experts: this launch's
+        assignments to each held expert, summed over the layers) is fetched
+        with the logits and lands on the fetch leaf."""
         tr = self.tracer
         sp = tr.leaf(wait_leaf, self.span_attrs)
         if sp:
@@ -502,6 +534,13 @@ class DecodeEngine(InferenceEngine):
             out = np.asarray(jax.device_get(logits))
             if sp:
                 sp.set(bytes=int(out.nbytes))
+                if aux is not None:
+                    load = np.asarray(jax.device_get(aux), np.int64)
+                    self.expert_load = load if self.expert_load is None \
+                        else self.expert_load + load
+                    sp.set(expert_assignments=int(load.sum()),
+                           expert_tokens_max=int(load.max()),
+                           experts_idle=int((load == 0).sum()))
         return out
 
     def prefill_ids(self, id_lists: Sequence[Sequence[int]],
@@ -538,7 +577,7 @@ class DecodeEngine(InferenceEngine):
                        streams=int(n), prefill=True,
                        tokens=int(mask.sum()), dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, ks, vs = self._jit_prefill(
+            logits, _, (ks, vs) = self._jit_prefill(
                 self.params, self.head, sharded["ids"], sharded["mask"],
                 last)
             self._cache_k, self._cache_v = self._jit_insert(
@@ -640,8 +679,7 @@ class DecodeEngine(InferenceEngine):
             "slots": int(self.slots),
             "max_len": int(self.max_len),
             "kv_dtype": self._kv_label(),
-            "cache_bytes": decoder.kv_cache_bytes(
-                self.cfg, self.slots, self.max_len, self.kv_dtype),
+            "cache_bytes": self.slots * self.max_len * self.token_bytes,
         }
 
 
@@ -714,6 +752,9 @@ class PagedDecodeEngine(DecodeEngine):
     which keeps each pool device-local anyway."""
 
     paged = True
+    #: the cache: one ``[L, n_pages, page_sz, width]`` array per pool of
+    #: the family (``models.families``), every one donated to each program
+    _pools: tuple = ()
     #: fixed copy-on-write batch rows — one compiled ``copy_pages``
     #: program per engine; unused rows ride the OOB sentinel
     COW_ROWS = 4
@@ -741,80 +782,74 @@ class PagedDecodeEngine(DecodeEngine):
         dtype = self.dtype
         metrics_ref = self.metrics
 
-        if self.kv_int8:
-            def _pinsert_fn(pk, pv, ks_new, vs_new, flat_pos, ks, vs):
-                metrics_ref.retraces.inc()
-                return decoder.paged_insert(pk, pv, ks_new, vs_new,
-                                            flat_pos, kv_scales=(ks, vs))
+        family = self.family
 
-            def _pdecode_fn(params, head, pk, pv, tokens, table, pos,
-                            ks, vs):
-                metrics_ref.retraces.inc()
-                return decoder.paged_decode_step(
-                    params, head, cfg, tokens, pk, pv, table, pos,
-                    kv_scales=(ks, vs), dtype=dtype)
-
-            def _pchunk_fn(params, head, pk, pv, tokens, table, start,
-                           nreal, ks, vs):
-                metrics_ref.retraces.inc()
-                return decoder.paged_chunk_step(
-                    params, head, cfg, tokens, pk, pv, table, start,
-                    nreal, kv_scales=(ks, vs), dtype=dtype)
-
-            def _pverify_fn(params, head, pk, pv, tokens, table, start,
-                            nreal, ks, vs):
-                metrics_ref.retraces.inc()
-                return decoder.paged_verify_step(
-                    params, head, cfg, tokens, pk, pv, table, start,
-                    nreal, kv_scales=(ks, vs), dtype=dtype)
-        else:
-            def _pinsert_fn(pk, pv, ks_new, vs_new, flat_pos):
-                metrics_ref.retraces.inc()
-                return decoder.paged_insert(pk, pv, ks_new, vs_new,
-                                            flat_pos)
-
-            def _pdecode_fn(params, head, pk, pv, tokens, table, pos):
-                metrics_ref.retraces.inc()
-                return decoder.paged_decode_step(
-                    params, head, cfg, tokens, pk, pv, table, pos,
-                    dtype=dtype)
-
-            def _pchunk_fn(params, head, pk, pv, tokens, table, start,
-                           nreal):
-                metrics_ref.retraces.inc()
-                return decoder.paged_chunk_step(
-                    params, head, cfg, tokens, pk, pv, table, start,
-                    nreal, dtype=dtype)
-
-            def _pverify_fn(params, head, pk, pv, tokens, table, start,
-                            nreal):
-                metrics_ref.retraces.inc()
-                return decoder.paged_verify_step(
-                    params, head, cfg, tokens, pk, pv, table, start,
-                    nreal, dtype=dtype)
-
-        def _pcow_fn(pk, pv, src, dst):
+        # every program takes the cache as ONE tuple of pools (twin K and V
+        # pools, or the one latent pool: the family's), donated, and the
+        # int8 scale tables — none for a float cache — as trailing
+        # arguments; what a family counts per launch rides back as ``aux``
+        def _pinsert_fn(pools, news, flat_pos, *scales):
             metrics_ref.retraces.inc()
-            return decoder.copy_pages(pk, pv, src, dst)
+            return families.insert(pools, news, flat_pos, scales or None)
 
-        def _pexport_fn(pk, pv, src):
+        def _pdecode_fn(params, head, pools, tokens, table, pos, *scales):
             metrics_ref.retraces.inc()
-            return decoder.gather_pages(pk, pv, src)
+            return family.attend(params, head, cfg, tokens, pools, table,
+                                 pos, None, "last", scales or None, dtype)
 
-        def _pimport_fn(pk, pv, payload_k, payload_v, dst):
+        def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
+                       *scales):
             metrics_ref.retraces.inc()
-            return decoder.scatter_pages(pk, pv, payload_k, payload_v,
-                                         dst)
+            return family.attend(params, head, cfg, tokens, pools, table,
+                                 start, nreal, "last", scales or None, dtype)
 
-        self._jit_pinsert = jax.jit(_pinsert_fn, donate_argnums=(0, 1))
-        self._jit_pdecode = jax.jit(_pdecode_fn, donate_argnums=(2, 3))
-        self._jit_pchunk = jax.jit(_pchunk_fn, donate_argnums=(2, 3))
-        self._jit_pverify = jax.jit(_pverify_fn, donate_argnums=(2, 3))
-        self._jit_pcow = jax.jit(_pcow_fn, donate_argnums=(0, 1))
+        def _pverify_fn(params, head, pools, tokens, table, start, nreal,
+                        *scales):
+            metrics_ref.retraces.inc()
+            return family.attend(params, head, cfg, tokens, pools, table,
+                                 start, nreal, "all", scales or None, dtype)
+
+        def _pcow_fn(pools, src, dst):
+            metrics_ref.retraces.inc()
+            return tuple(decoder.copy_pool(p, src, dst) for p in pools)
+
+        def _pexport_fn(pools, src):
+            metrics_ref.retraces.inc()
+            return tuple(decoder.gather_pool(p, src) for p in pools)
+
+        def _pimport_fn(pools, payloads, dst):
+            metrics_ref.retraces.inc()
+            return tuple(decoder.scatter_pool(p, x, dst)
+                         for p, x in zip(pools, payloads))
+
+        self._jit_pinsert = jax.jit(_pinsert_fn, donate_argnums=(0,))
+        self._jit_pdecode = jax.jit(_pdecode_fn, donate_argnums=(2,))
+        self._jit_pchunk = jax.jit(_pchunk_fn, donate_argnums=(2,))
+        self._jit_pverify = jax.jit(_pverify_fn, donate_argnums=(2,))
+        self._jit_pcow = jax.jit(_pcow_fn, donate_argnums=(0,))
         # export reads the pool (no donation — the sender keeps serving
         # from it); import donates like every other cache writer
         self._jit_pexport = jax.jit(_pexport_fn)
-        self._jit_pimport = jax.jit(_pimport_fn, donate_argnums=(0, 1))
+        self._jit_pimport = jax.jit(_pimport_fn, donate_argnums=(0,))
+
+    # the twin pools under the names every reader of a BERT engine knows
+    # (a one-pool family has no second)
+    @property
+    def _cache_k(self):
+        return self._pools[0] if self._pools else None
+
+    @_cache_k.setter
+    def _cache_k(self, value):
+        self._pools = (value,) + tuple(self._pools[1:])
+
+    @property
+    def _cache_v(self):
+        return self._pools[1] if len(self._pools) > 1 else None
+
+    @_cache_v.setter
+    def _cache_v(self, value):
+        if len(self._pools) > 1:
+            self._pools = (self._pools[0], value)
 
     # --------------------------------------------------------- capacity
     def _resolve_capacity(self, requested: int) -> int:
@@ -849,17 +884,16 @@ class PagedDecodeEngine(DecodeEngine):
         """(Re)allocate the page pool + a fresh allocator/index/table —
         construction and post-chaos :meth:`reset_cache`, never hot."""
         cfg = self.cfg
-        # heads folded into ONE minor axis: [page_sz, hidden] tiles the
-        # chip's layout exactly (models.decoder, paged-cache note)
-        shape = (cfg.num_layers, self.n_pages, self.page_sz,
-                 cfg.hidden_size)
 
-        def alloc():
-            # two SEPARATE buffers (donation aliasing — base note)
-            return jax.device_put(jnp.zeros(shape, self.kv_dtype))
+        def alloc(width):
+            # one position's values of one pool are ONE minor axis: [page_sz,
+            # width] is what the chip tiles (models.decoder, paged-cache
+            # note); SEPARATE buffers (donation aliasing — base note)
+            return jax.device_put(jnp.zeros(
+                (cfg.num_layers, self.n_pages, self.page_sz, width),
+                self.kv_dtype))
 
-        self._cache_k = alloc()
-        self._cache_v = alloc()
+        self._pools = tuple(alloc(w) for w in self.family.pool_widths(cfg))
         self.allocator = PageAllocator(self.n_pages, self.page_sz,
                                        self.page_bytes)
         self.prefix = PrefixIndex(self.allocator, self.page_sz,
@@ -1062,6 +1096,13 @@ class PagedDecodeEngine(DecodeEngine):
     # sentinel-padded (jaxlint R18 polices the per-stream-count
     # retrace spelling), so one compiled export and one compiled import
     # serve every stream.
+    def require_handoff(self) -> None:
+        """Refuse, loudly, for a family whose cache has no payload form."""
+        if not self.family.handoff:
+            self.family.refuse(
+                "the disaggregated handoff (a stream's pages as a payload)",
+                "prefill and decode on one engine (--disagg off)")
+
     def export_pages(self, slot: int, request_ids=None):
         """Export ``slot``'s pages as a host ``[L, pages_per_stream,
         page_sz, H]`` payload pair (K, V) — raw cache bytes (int8
@@ -1069,6 +1110,7 @@ class PagedDecodeEngine(DecodeEngine):
         from the same params, so no rescaling crosses the wire).  An
         out-of-range ``slot`` exports the sentinel row (zero payload) —
         the warmup path.  Compile key ``("export", pages_per_stream)``."""
+        self.require_handoff()
         self._flush_cow()
         if 0 <= slot < self.slots:
             src = np.asarray(self._table[slot], np.int32)
@@ -1087,7 +1129,7 @@ class PagedDecodeEngine(DecodeEngine):
                               pages=int(self.pages_per_stream),
                               **self._telemetry_attrs(request_ids),
                               **self.span_attrs):
-            k, v = self._jit_pexport(self._cache_k, self._cache_v, src)
+            k, v = self._jit_pexport(self._pools, src)
             out_k = np.asarray(jax.device_get(k))
             out_v = np.asarray(jax.device_get(v))
         return out_k, out_v
@@ -1128,9 +1170,9 @@ class PagedDecodeEngine(DecodeEngine):
                               pages=int(self.pages_per_stream),
                               **self._telemetry_attrs(request_ids),
                               **self.span_attrs):
-            self._cache_k, self._cache_v = self._jit_pimport(
-                self._cache_k, self._cache_v, jnp.asarray(payload_k),
-                jnp.asarray(payload_v), dst)
+            self._pools = self._jit_pimport(
+                self._pools, (jnp.asarray(payload_k),
+                              jnp.asarray(payload_v)), dst)
 
     def begin_handoff(self, slot: int):
         """Stage ``slot``'s stream for handoff: move its page refs to
@@ -1217,8 +1259,7 @@ class PagedDecodeEngine(DecodeEngine):
                 phase = self._seen(("cow", rows), "prefill")
                 if sp:
                     sp.set(phase=phase, cow=True, cow_pages=len(batch))
-                self._cache_k, self._cache_v = self._jit_pcow(
-                    self._cache_k, self._cache_v, src, dst)
+                self._pools = self._jit_pcow(self._pools, src, dst)
 
     def prefill_ids(self, id_lists: Sequence[Sequence[int]],
                     slot_ids: Sequence[int],
@@ -1264,14 +1305,13 @@ class PagedDecodeEngine(DecodeEngine):
                        streams=int(n), prefill=True, paged=True,
                        tokens=int(mask.sum()), dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, ks, vs = self._jit_prefill(
+            logits, aux, news = self._jit_prefill(
                 self.params, self.head, sharded["ids"], sharded["mask"],
                 last)
-            self._cache_k, self._cache_v = self._jit_pinsert(
-                self._cache_k, self._cache_v, ks, vs, flat,
-                *self._scale_args())
+            self._pools = self._jit_pinsert(self._pools, news, flat,
+                                            *self._scale_args())
         return self._fetch_logits(logits, "prefill.device_wait",
-                                  "prefill.fetch")[:n]
+                                  "prefill.fetch", aux)[:n]
 
     def prefill_chunk(self, suffixes: Sequence[Sequence[int]],
                       slot_ids: Sequence[int], starts: Sequence[int],
@@ -1309,13 +1349,14 @@ class PagedDecodeEngine(DecodeEngine):
                        cached=int(sum(int(s) for s in starts)),
                        kv_positions_read=rows * self.max_len,
                        kv_positions_live=int((start + nreal)[:n].sum()),
+                       cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, self._cache_k, self._cache_v = self._jit_pchunk(
-                self.params, self.head, self._cache_k, self._cache_v,
-                tokens, table, start, nreal, *self._scale_args())
+            logits, aux, self._pools = self._jit_pchunk(
+                self.params, self.head, self._pools, tokens, table, start,
+                nreal, *self._scale_args())
         return self._fetch_logits(logits, "chunk.device_wait",
-                                  "chunk.fetch")[:n]
+                                  "chunk.fetch", aux)[:n]
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
                      live: int, request_ids=None) -> np.ndarray:
@@ -1339,13 +1380,14 @@ class PagedDecodeEngine(DecodeEngine):
                        pages_live=self.allocator.used_pages,
                        kv_positions_read=self.slots * rung * self.page_sz,
                        kv_positions_live=int((p[alive] + 1).sum()),
+                       cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
-            logits, self._cache_k, self._cache_v = self._jit_pdecode(
-                self.params, self.head, self._cache_k, self._cache_v,
-                tok, self._table[:, :rung], p, *self._scale_args())
+            logits, aux, self._pools = self._jit_pdecode(
+                self.params, self.head, self._pools, tok,
+                self._table[:, :rung], p, *self._scale_args())
         return self._fetch_logits(logits, "decode.device_wait",
-                                  "decode.fetch")
+                                  "decode.fetch", aux)
 
     def verify_ids(self, window: np.ndarray, pos: np.ndarray,
                    nreal: np.ndarray, live: int,
@@ -1375,12 +1417,11 @@ class PagedDecodeEngine(DecodeEngine):
                        pages_live=self.allocator.used_pages,
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
-            logits, self._cache_k, self._cache_v = self._jit_pverify(
-                self.params, self.head, self._cache_k, self._cache_v,
-                tok, jnp.asarray(self._table), start, nr,
-                *self._scale_args())
+            logits, aux, self._pools = self._jit_pverify(
+                self.params, self.head, self._pools, tok,
+                jnp.asarray(self._table), start, nr, *self._scale_args())
         return self._fetch_logits(logits, "verify.device_wait",
-                                  "verify.fetch")
+                                  "verify.fetch", aux)
 
     def warmup_verify(self, k1: int) -> None:
         """Pre-trace the ``("verify", slots, k1)`` program (all-dead
@@ -1422,8 +1463,11 @@ class PagedDecodeEngine(DecodeEngine):
             "slots": int(self.slots),
             "max_len": int(self.max_len),
             "kv_dtype": self._kv_label(),
-            "cache_bytes": decoder.kv_cache_bytes(
-                self.cfg, self.n_pages, self.page_sz, self.kv_dtype),
+            "cache_bytes": self.n_pages * self.page_bytes,
+            "kv_pool_bytes": int(sum(p.nbytes for p in self._pools)),
+            "weights_bytes": int(sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(
+                    (self.params, self.head)))),
             "pages": self.allocator.snapshot(),
             "prefix": self.prefix.snapshot(),
         }
@@ -1583,6 +1627,11 @@ class DecodeBatcher:
         self._spec_drafted = 0
         self._spec_accepted = 0
         if drafter is not None:
+            for e in (engine, drafter):
+                if not e.family.verify:
+                    e.family.refuse(
+                        "the speculative pair (a drafter engine and its "
+                        "verify window)", "decode with the primary alone")
             if not (engine.paged and drafter.paged):
                 raise ValueError(
                     "speculative decoding needs PAGED engines on both "
@@ -2468,6 +2517,7 @@ class PrefillWorker:
             raise ValueError(
                 "disaggregated prefill needs a PAGED engine "
                 "(--kv_layout paged): the handoff exports page custody")
+        engine.require_handoff()
         self.engine = engine
         self.tracer = engine.tracer
         self.replica = int(replica)

@@ -35,7 +35,7 @@ import numpy as np
 
 from pdnlp_tpu.data.collate import pad_ids_to_bucket
 from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, get_or_build_vocab
-from pdnlp_tpu.models import bert, get_config
+from pdnlp_tpu.models import bert, families, get_config
 from pdnlp_tpu.models.config import args_overrides
 from pdnlp_tpu.obs.request import EXEMPLAR_CAP
 from pdnlp_tpu.serve.metrics import ServeMetrics
@@ -113,11 +113,28 @@ class InferenceEngine:
         # optimizer state serving never needs).  int8 mode quantizes the
         # template too, so the params' pytree STRUCTURE is identical before
         # and after every load — checkpoint swap stays retrace-free.
-        self._template = bert.init_params(jax.random.key(args.seed), self.cfg)
-        # the serving-form template is also the int8 swap template — built
-        # once here, not re-quantized on every load_checkpoint
-        self._serving_template = self._serving_form(self._template)
-        self.params = self._put(self._serving_template)
+        # what builds and runs this config: looked up ONCE (models.families)
+        self.family = families.of(self.cfg)
+        if self.serve_dtype == "int8" and not self.family.int8:
+            self.family.refuse("int8 weights (--serve_dtype int8)",
+                               "serve it in the dtype it stores")
+        key = jax.random.key(args.seed)
+        if self.family.lazy_weights:
+            # gigabytes of weights: made on the device, and the template is
+            # their SHAPES, so that the model is never held twice
+            self._template = jax.eval_shape(
+                lambda: self.family.init_params(key, self.cfg))
+            self._serving_template = self._template
+            # ... and only when something first reads them: a caller that
+            # brings its own weights sets ``params`` and these never exist
+            self.params = lambda: self._put(
+                self.family.init_params(key, self.cfg))
+        else:
+            self._template = self.family.init_params(key, self.cfg)
+            # the serving-form template is also the int8 swap template —
+            # built once here, not re-quantized on every load_checkpoint
+            self._serving_template = self._serving_form(self._template)
+            self.params = self._put(self._serving_template)
         self.checkpoint_path: Optional[str] = None
         self._seen_shapes: set = set()
         # extra attrs stamped on every forward/compile span — the replica
@@ -157,6 +174,18 @@ class InferenceEngine:
             self._jit_forward = jax.jit(_forward)
 
     # ------------------------------------------------------------ params
+    @property
+    def params(self):
+        """The served weights.  A family whose weights are made lazily
+        (``Family.lazy_weights``) stores a thunk until the first read."""
+        if callable(self._params):
+            self._params = self._params()
+        return self._params
+
+    @params.setter
+    def params(self, value) -> None:
+        self._params = value
+
     def _put(self, host_params):
         if self.mesh is not None:
             from pdnlp_tpu.parallel.sharding import replicated

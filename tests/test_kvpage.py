@@ -328,9 +328,9 @@ def test_paged_decode_path_never_retraces_after_warmup(tok, pag,
     widths = set()
     real = pag._jit_pdecode
 
-    def spy(params, head, pk, pv, tokens, table, *rest):
+    def spy(params, head, pools, tokens, table, *rest):
         widths.add(int(table.shape[1]))
-        return real(params, head, pk, pv, tokens, table, *rest)
+        return real(params, head, pools, tokens, table, *rest)
 
     monkeypatch.setattr(pag, "_jit_pdecode", spy)
     b = DecodeBatcher(pag, replica=0)
