@@ -83,11 +83,12 @@ SERVE_PHASES = ("queue_wait", "forward", "compile", "swap", "prefill",
 #:   call(s) enqueued; carries ``phase`` — the SERVE_PHASES name of the
 #:   call, ``compile`` on a first-seen shape — the shape attrs and the
 #:   bounded ``request_ids`` exemplars), ``<p>.device_wait``
-#:   (``block_until_ready`` on the logits, nothing else) and ``<p>.fetch``
-#:   (``device_get`` to numpy; ``bytes``); a COW flush is its dispatch
-#:   alone;
-#: - ``<p>.emit`` — the batcher's work on the fetched logits: argmax per
-#:   row, advance, push, detach, gauges (``rows``).
+#:   (``block_until_ready`` on what the host reads back, nothing else) and
+#:   ``<p>.fetch`` (``device_get`` to numpy; ``bytes``: the ids the launch
+#:   chose, 4 a row — the logits only of a verify window); a COW flush is
+#:   its dispatch alone;
+#: - ``<p>.emit`` — the batcher's work on the fetched ids: the block's
+#:   advance in one pass, push, detach, gauges (``rows``).
 #:
 #: Every leaf carries ``replica`` and ``round`` (the worker's round
 #: counter: the span that caused it).  The ``prefill`` / ``decode`` /
@@ -566,10 +567,14 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     assignments to the held experts a decode step, the busiest held expert's
     tokens a step, idle held experts a step, and ``cache_bytes_per_token``
     as ``decode.dispatch`` states it.
+    ``decode_fetch_bytes_per_step`` = the ``bytes`` of the ``decode.fetch``
+    leaves over their count: what a decode launch hands the host (the
+    chosen ids, 4 bytes a slot, plus a family's expert counts).
     Empty when the stream holds no leaf."""
     per: Dict[object, Dict[str, List[float]]] = {}
     kv: Dict[object, Dict[str, List[int]]] = {}    # rep -> call -> [read, live]
     experts: Dict[object, List[int]] = {}   # rep -> [launches, sum, max, idle]
+    fetched: Dict[object, List[int]] = {}   # rep -> [decode fetches, bytes]
     token_bytes: Dict[object, int] = {}
     edges: Dict[object, List[float]] = {}
     compiling: Dict[object, bool] = {}   # tid -> inside a compiling call
@@ -594,6 +599,10 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             acc[1] += int(attrs.get("kv_positions_live", 0))
         if "cache_bytes_per_token" in attrs:
             token_bytes[rep] = int(attrs["cache_bytes_per_token"])
+        if name == "decode.fetch" and "bytes" in attrs:
+            acc = fetched.setdefault(rep, [0, 0])
+            acc[0] += 1
+            acc[1] += int(attrs["bytes"])
         if name == "decode.fetch" and "expert_assignments" in attrs:
             acc = experts.setdefault(rep, [0, 0, 0, 0])
             acc[0] += 1
@@ -622,6 +631,9 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
                 "idle_experts_per_step": round(idle / n, 3)}
         if rep in token_bytes:
             amplification["cache_bytes_per_token"] = token_bytes[rep]
+        if rep in fetched:
+            amplification["decode_fetch_bytes_per_step"] = round(
+                fetched[rep][1] / fetched[rep][0], 1)
         out[str(rep)] = {
             "steps": steps, "wall_sec": round(wall, 6),
             "host_exposed_share": round((wall - waited) / wall, 4)
@@ -674,6 +686,13 @@ def format_decode_table(by_replica: Dict) -> str:
         if "cache_bytes_per_token" in b:
             lines.append(f"  cache bytes per token: "
                          f"{b['cache_bytes_per_token']}")
+        if "decode_fetch_bytes_per_step" in b:
+            ms = b["leaves"].get("decode.fetch", {}).get("mean_ms")
+            lines.append(
+                f"  fetched per decode step: "
+                f"{b['decode_fetch_bytes_per_step']:.0f} bytes"
+                + (f" in {ms:.3f} ms" if ms is not None else "")
+                + " (decode.fetch)")
         header = (f"  {'leaf':<22} {'count':>7} {'ms/step':>10} "
                   f"{'mean_ms':>10} {'p95_ms':>10}")
         lines += [header, "  " + "-" * (len(header) - 2)]

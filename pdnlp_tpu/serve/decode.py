@@ -153,6 +153,61 @@ def detokenize(tokenizer, ids: Sequence[int]) -> str:
     return " ".join(out)
 
 
+def greedy_ids(logits):
+    """The greedy choice INSIDE a jitted program: ``int32`` argmax over the
+    vocabulary axis of the float32 logits the program produces, first
+    index on ties as ``np.argmax`` has it.  One more output of the same
+    program — the engine's wrappers call it, never a family's step
+    function — so every family and width gets it alike; sampling in the
+    same program (ROADMAP, Reach B8) would replace this line."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+class Chosen:
+    """What a prefill / chunk / decode launch hands the host: the chosen
+    token of each row, ALREADY fetched (``ids``, ``int32 [rows]`` — the
+    ``<p>.fetch`` leaf's bytes), and the launch's float32 logits LEFT ON
+    THE DEVICE.  A caller that wants the logits reads this like the array
+    it used to be — ``np.asarray(result)``, ``result[i]``, ``np.argmax(
+    result, -1)`` — and pays the ``[rows, vocab]`` fetch then, once.
+    ``ids_device`` is the same choice as a device array, for a launch
+    that would take it without a round trip."""
+
+    __slots__ = ("ids", "ids_device", "_logits", "_rows", "_host")
+
+    def __init__(self, ids: np.ndarray, ids_device, logits, rows: int):
+        self.ids = ids
+        self.ids_device = ids_device
+        self._logits = logits
+        self._rows = int(rows)
+        self._host: Optional[np.ndarray] = None
+
+    def __len__(self) -> int:
+        return self._rows
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        if self._host is None:
+            self._host = np.asarray(
+                jax.device_get(self._logits))[:self._rows]
+        out = self._host if dtype is None \
+            else self._host.astype(dtype, copy=False)
+        return out.copy() if copy else out
+
+    def __getitem__(self, key):
+        return self.__array__()[key]
+
+
+def chosen_ids(result) -> List[int]:
+    """Each row's next token as Python ints: the ids a launch chose on
+    the device where the engine's result carries them, the host argmax of
+    an ARRAY where a wrapper of the engine call (a test, the benchmark's
+    planted fault) handed the batcher one."""
+    ids = getattr(result, "ids", None)
+    if ids is None:
+        ids = np.argmax(np.asarray(result), axis=-1)
+    return ids.tolist()
+
+
 class DecodeEngine(InferenceEngine):
     """The classifier engine's checkpoint/mesh/metrics machinery with a
     generative decode path on top: LM head, slot KV cache, jitted
@@ -247,9 +302,10 @@ class DecodeEngine(InferenceEngine):
 
         def _prefill_fn(params, head, ids, mask, last_pos):
             metrics_ref.retraces.inc()  # body runs only while tracing
-            # -> (logits, aux, the new rows of every pool)
-            return family.prefill(params, head, cfg, ids, mask, last_pos,
-                                  dtype)
+            # -> (logits, chosen ids, aux, the new rows of every pool)
+            logits, aux, news = family.prefill(params, head, cfg, ids, mask,
+                                               last_pos, dtype)
+            return logits, greedy_ids(logits), aux, news
 
         if self.kv_int8:
             def _insert_fn(ck, cv, k, v, slot_ids, ks, vs):
@@ -263,9 +319,10 @@ class DecodeEngine(InferenceEngine):
 
             def _decode_fn(params, head, ck, cv, tokens, pos, ks, vs):
                 metrics_ref.retraces.inc()
-                return decoder.decode_step(params, head, cfg, tokens, ck,
-                                           cv, pos, kv_scales=(ks, vs),
-                                           dtype=dtype)
+                logits, ck, cv = decoder.decode_step(
+                    params, head, cfg, tokens, ck, cv, pos,
+                    kv_scales=(ks, vs), dtype=dtype)
+                return logits, greedy_ids(logits), ck, cv
         else:
             def _insert_fn(ck, cv, k, v, slot_ids):
                 metrics_ref.retraces.inc()
@@ -278,8 +335,9 @@ class DecodeEngine(InferenceEngine):
 
             def _decode_fn(params, head, ck, cv, tokens, pos):
                 metrics_ref.retraces.inc()
-                return decoder.decode_step(params, head, cfg, tokens, ck,
-                                           cv, pos, dtype=dtype)
+                logits, ck, cv = decoder.decode_step(
+                    params, head, cfg, tokens, ck, cv, pos, dtype=dtype)
+                return logits, greedy_ids(logits), ck, cv
 
         self._jit_prefill = jax.jit(_prefill_fn)
         self._jit_insert = jax.jit(_insert_fn, donate_argnums=(0, 1))
@@ -516,39 +574,56 @@ class DecodeEngine(InferenceEngine):
         self._seen_shapes.add(key)
         return "compile"
 
-    def _fetch_logits(self, logits, wait_leaf: str, fetch_leaf: str,
-                      aux=None) -> np.ndarray:
+    def _fetch(self, value, wait_leaf: str, fetch_leaf: str,
+               aux=None) -> np.ndarray:
         """The two leaves that end an engine call: ``<p>.device_wait`` —
-        ``block_until_ready`` on the logits, nothing else, so the device's
-        time is never smeared into the host's — then ``<p>.fetch``, the
-        ``device_get`` to numpy.  Untraced, the fetch is the one barrier
-        it always was.  ``aux`` (a family with experts: this launch's
-        assignments to each held expert, summed over the layers) is fetched
-        with the logits and lands on the fetch leaf."""
+        ``block_until_ready`` on ``value`` (what the host reads back of the
+        launch: the chosen ids; the logits of a verify window), nothing
+        else, so the device's time is never smeared into the host's — then
+        ``<p>.fetch``, the ``device_get`` to numpy, with the ``bytes`` it
+        moved.  Untraced, the fetch is the one barrier it always was.
+        ``aux`` (a family with experts: this launch's assignments to each
+        held expert, summed over the layers) is fetched with it and lands
+        on the fetch leaf."""
         tr = self.tracer
         sp = tr.leaf(wait_leaf, self.span_attrs)
         if sp:
             with sp:
-                jax.block_until_ready(logits)
+                jax.block_until_ready(value)
         with tr.leaf(fetch_leaf, self.span_attrs) as sp:
-            out = np.asarray(jax.device_get(logits))
+            out = np.asarray(jax.device_get(value))
             if sp:
-                sp.set(bytes=int(out.nbytes))
+                nbytes = int(out.nbytes)
                 if aux is not None:
-                    load = np.asarray(jax.device_get(aux), np.int64)
+                    load = np.asarray(jax.device_get(aux))
+                    nbytes += int(load.nbytes)
+                    load = load.astype(np.int64)
                     self.expert_load = load if self.expert_load is None \
                         else self.expert_load + load
                     sp.set(expert_assignments=int(load.sum()),
                            expert_tokens_max=int(load.max()),
                            experts_idle=int((load == 0).sum()))
+                sp.set(bytes=nbytes)
         return out
+
+    def _fetch_chosen(self, logits, ids, leaf: str, aux=None,
+                      rows: Optional[int] = None) -> Chosen:
+        """End a ``leaf`` (``decode`` / ``prefill`` / ``chunk``) call: the
+        host reads back the ``[rows]`` ids the program chose — 4 bytes a
+        row where the ``[rows, vocab]`` float32 logits used to cross — and
+        the logits stay on the device behind the result (:class:`Chosen`)
+        for a caller that asks for them."""
+        host = self._fetch(ids, leaf + ".device_wait", leaf + ".fetch", aux)
+        rows = len(host) if rows is None else rows
+        return Chosen(host[:rows], ids, logits, rows)
 
     def prefill_ids(self, id_lists: Sequence[Sequence[int]],
                     slot_ids: Sequence[int],
-                    request_ids=None) -> np.ndarray:
+                    request_ids=None) -> Chosen:
         """Prefill up to ``prefill_rows`` prompts into their claimed slots:
         bucketed causal forward + K/V scatter; returns each prompt's
-        FIRST-token logits ``[n, vocab]`` (fp32, host).
+        FIRST token (``.ids``, host) over its ``[n, vocab]`` fp32 logits
+        (on the device until read: :class:`Chosen`).
 
         Filler rows carry slot id ``self.slots`` — out of bounds, so the
         scatter DROPS them and a filler row can never touch a live slot.
@@ -577,21 +652,22 @@ class DecodeEngine(InferenceEngine):
                        streams=int(n), prefill=True,
                        tokens=int(mask.sum()), dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, _, (ks, vs) = self._jit_prefill(
+            logits, chosen, _, (ks, vs) = self._jit_prefill(
                 self.params, self.head, sharded["ids"], sharded["mask"],
                 last)
             self._cache_k, self._cache_v = self._jit_insert(
                 self._cache_k, self._cache_v, ks, vs, slot_arr,
                 *self._scale_args())
-        return self._fetch_logits(logits, "prefill.device_wait",
-                                  "prefill.fetch")[:n]
+        return self._fetch_chosen(logits, chosen, "prefill", rows=n)
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
-                     live: int, request_ids=None) -> np.ndarray:
+                     live: int, request_ids=None) -> Chosen:
         """One fixed-shape decode step over the whole slot block: tokens
         ``[slots]`` (current token per slot; dead slots ride with junk),
-        ``pos`` ``[slots]`` write positions.  Returns next-token logits
-        ``[slots, vocab]`` (fp32, host).  The ONE compile-cache key is
+        ``pos`` ``[slots]`` write positions.  Returns each slot's next
+        token (``.ids``: the ``slots x 4`` bytes the host fetches) over
+        the ``[slots, vocab]`` fp32 logits, which stay on the device until
+        a caller reads them (:class:`Chosen`).  The ONE compile-cache key is
         ``("decode", slots)`` — retrace-free after warmup by
         construction."""
         with self.tracer.leaf("decode.dispatch", self.span_attrs) as sp:
@@ -603,11 +679,11 @@ class DecodeEngine(InferenceEngine):
                        decode=True, dtype=self.dtype_label,
                        kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
-            logits, self._cache_k, self._cache_v = self._jit_decode(
-                self.params, self.head, self._cache_k, self._cache_v,
-                tok, p, *self._scale_args())
-        return self._fetch_logits(logits, "decode.device_wait",
-                                  "decode.fetch")
+            logits, chosen, self._cache_k, self._cache_v = \
+                self._jit_decode(
+                    self.params, self.head, self._cache_k, self._cache_v,
+                    tok, p, *self._scale_args())
+        return self._fetch_chosen(logits, chosen, "decode")
 
     def _kv_label(self) -> str:
         return "int8" if self.kv_int8 else np.dtype(self.kv_dtype).name
@@ -794,14 +870,18 @@ class PagedDecodeEngine(DecodeEngine):
 
         def _pdecode_fn(params, head, pools, tokens, table, pos, *scales):
             metrics_ref.retraces.inc()
-            return family.attend(params, head, cfg, tokens, pools, table,
-                                 pos, None, "last", scales or None, dtype)
+            logits, aux, pools = family.attend(
+                params, head, cfg, tokens, pools, table, pos, None, "last",
+                scales or None, dtype)
+            return logits, greedy_ids(logits), aux, pools
 
         def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
                        *scales):
             metrics_ref.retraces.inc()
-            return family.attend(params, head, cfg, tokens, pools, table,
-                                 start, nreal, "last", scales or None, dtype)
+            logits, aux, pools = family.attend(
+                params, head, cfg, tokens, pools, table, start, nreal,
+                "last", scales or None, dtype)
+            return logits, greedy_ids(logits), aux, pools
 
         def _pverify_fn(params, head, pools, tokens, table, start, nreal,
                         *scales):
@@ -1263,7 +1343,7 @@ class PagedDecodeEngine(DecodeEngine):
 
     def prefill_ids(self, id_lists: Sequence[Sequence[int]],
                     slot_ids: Sequence[int],
-                    request_ids=None) -> np.ndarray:
+                    request_ids=None) -> Chosen:
         """Cold-path prefill: the SAME bucketed causal forward as the
         slot engine (bitwise-identical K/V for identical prompts — the
         sharing contract rests on this), scattered into pages through
@@ -1305,23 +1385,22 @@ class PagedDecodeEngine(DecodeEngine):
                        streams=int(n), prefill=True, paged=True,
                        tokens=int(mask.sum()), dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, aux, news = self._jit_prefill(
+            logits, chosen, aux, news = self._jit_prefill(
                 self.params, self.head, sharded["ids"], sharded["mask"],
                 last)
             self._pools = self._jit_pinsert(self._pools, news, flat,
                                             *self._scale_args())
-        return self._fetch_logits(logits, "prefill.device_wait",
-                                  "prefill.fetch", aux)[:n]
+        return self._fetch_chosen(logits, chosen, "prefill", aux, rows=n)
 
     def prefill_chunk(self, suffixes: Sequence[Sequence[int]],
                       slot_ids: Sequence[int], starts: Sequence[int],
-                      request_ids=None) -> np.ndarray:
+                      request_ids=None) -> Chosen:
         """Partial-hit prefill: only the divergent SUFFIX runs
         (``decoder.paged_chunk_step`` — the chunk attends to the shared
         prefix pages through the table), bucketed over the same ladder
         as prompts (compile key ``(bucket, rows, "chunk")``; warmup
-        pre-traces every bucket).  Returns each suffix's last-token
-        logits ``[n, vocab]``."""
+        pre-traces every bucket).  Returns each suffix's next token over
+        its last-token logits ``[n, vocab]`` (:class:`Chosen`)."""
         self._flush_cow()
         n = len(suffixes)
         assert n and n <= self.prefill_rows
@@ -1352,14 +1431,13 @@ class PagedDecodeEngine(DecodeEngine):
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, aux, self._pools = self._jit_pchunk(
+            logits, chosen, aux, self._pools = self._jit_pchunk(
                 self.params, self.head, self._pools, tokens, table, start,
                 nreal, *self._scale_args())
-        return self._fetch_logits(logits, "chunk.device_wait",
-                                  "chunk.fetch", aux)[:n]
+        return self._fetch_chosen(logits, chosen, "chunk", aux, rows=n)
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
-                     live: int, request_ids=None) -> np.ndarray:
+                     live: int, request_ids=None) -> Chosen:
         """One fixed-shape decode step over the slot block, reading whole
         pages through the per-slot page tables.  The table is data, not
         shape; its WIDTH is the attention extent, cut to the smallest
@@ -1383,11 +1461,10 @@ class PagedDecodeEngine(DecodeEngine):
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
-            logits, aux, self._pools = self._jit_pdecode(
+            logits, chosen, aux, self._pools = self._jit_pdecode(
                 self.params, self.head, self._pools, tok,
                 self._table[:, :rung], p, *self._scale_args())
-        return self._fetch_logits(logits, "decode.device_wait",
-                                  "decode.fetch", aux)
+        return self._fetch_chosen(logits, chosen, "decode", aux)
 
     def verify_ids(self, window: np.ndarray, pos: np.ndarray,
                    nreal: np.ndarray, live: int,
@@ -1420,8 +1497,8 @@ class PagedDecodeEngine(DecodeEngine):
             logits, aux, self._pools = self._jit_pverify(
                 self.params, self.head, self._pools, tok,
                 jnp.asarray(self._table), start, nr, *self._scale_args())
-        return self._fetch_logits(logits, "verify.device_wait",
-                                  "verify.fetch", aux)
+        return self._fetch(logits, "verify.device_wait", "verify.fetch",
+                           aux)
 
     def warmup_verify(self, k1: int) -> None:
         """Pre-trace the ``("verify", slots, k1)`` program (all-dead
@@ -1510,22 +1587,26 @@ class DecodeStream:
         self.replica: Optional[int] = None
         self.slot: Optional[int] = None
         self.spec_accepted = 0  # cumulative accepted drafts (monotone)
-        self._q: "queue.Queue" = queue.Queue()
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._event = threading.Event()
         self._error: Optional[BaseException] = None
 
     # --- worker half ---
-    def _push(self, token: int) -> float:
+    def _push(self, token: int, now: Optional[float] = None) -> float:
         """Record one generated token; returns the inter-token gap in
-        seconds (0.0 for the first — the caller observes ttft instead)."""
-        now = self._clock()
+        seconds (0.0 for the first — the caller observes ttft instead).
+        ``now``: the stamp of a caller that read the clock once for a
+        whole block of rows."""
+        if now is None:
+            now = self._clock()
         gap = 0.0 if self.last_token_at is None \
             else now - self.last_token_at
         if self.first_token_at is None:
             self.first_token_at = now
         self.last_token_at = now
-        self.emitted.append(int(token))
-        self._q.put(int(token))
+        token = int(token)
+        self.emitted.append(token)
+        self._q.put(token)
         return gap
 
     def _finish(self, error: Optional[BaseException] = None) -> bool:
@@ -1584,6 +1665,8 @@ def record_request(tracer, stream: DecodeStream, replica: int,
 
 
 class _Slot:
+    """A live row of the slot table.  ``stream`` is fixed for the seat's
+    life; ``pos`` / ``next_token`` move in place, one step at a time."""
     __slots__ = ("stream", "pos", "next_token")
 
     def __init__(self, stream: DecodeStream, pos: int, next_token: int):
@@ -2090,51 +2173,38 @@ class DecodeBatcher:
         if full:
             # no engine call to follow: the index stored the first token
             with tr.leaf("prefill.emit", attrs) as sp:
-                now = tr.now()
-                for slot, stream, claim in full:
-                    ntok = len(claim.tokens)
-                    record_hop(tr, stream.rid, "prefill", slot=slot,
-                               tokens_in=ntok, replica=self.replica,
-                               prefix_hit="full", cached_tokens=ntok)
-                    self.metrics.ttft_ms.observe((now - stream.born) * 1e3)
-                    # refresh the index entry's LRU standing (register of
-                    # an existing key is a touch, not a re-insert)
-                    self.engine.register_slot(slot, claim.first_token)
-                    if sp:
-                        stream.traced = True
-                    self._advance(slot, stream, int(claim.first_token),
-                                  pos=ntok)
+                # the index entry's LRU standing is refreshed with it
+                # (register of an existing key is a touch, not a re-insert)
+                self._first_tokens(
+                    [(slot, stream, int(c.first_token), len(c.tokens),
+                      {"tokens_in": len(c.tokens), "prefix_hit": "full",
+                       "cached_tokens": len(c.tokens)})
+                     for slot, stream, c in full], bool(sp))
                 if sp:
                     sp.set(rows=len(full), prefix_hit="full")
         for i in range(0, len(cold), rows):
             chunk = cold[i:i + rows]
             prompts = [s.prompt_ids + s.emitted for _, s, _ in chunk]
-            logits = self.engine.prefill_ids(
+            first = self.engine.prefill_ids(
                 prompts, [slot for slot, _, _ in chunk],
                 request_ids=[s.rid for _, s, _ in chunk])
             with tr.leaf("prefill.emit", attrs) as sp:
                 self.metrics.prefills_total.inc()
                 self.metrics.prefill_tokens_total.inc(
                     sum(len(p) for p in prompts))
-                now = tr.now()
-                for j, (slot, stream, claim) in enumerate(chunk):
-                    extra = {"prefix_hit": "miss"} \
-                        if claim is not None else {}
-                    record_hop(tr, stream.rid, "prefill", slot=slot,
-                               tokens_in=len(prompts[j]),
-                               replica=self.replica, **extra)
-                    self.metrics.ttft_ms.observe((now - stream.born) * 1e3)
-                    tok = int(np.argmax(logits[j]))
-                    self.engine.register_slot(slot, tok)
-                    if sp:
-                        stream.traced = True
-                    self._advance(slot, stream, tok, pos=len(prompts[j]))
+                toks = chosen_ids(first)
+                self._first_tokens(
+                    [(slot, stream, toks[j], len(prompts[j]),
+                      {"tokens_in": len(prompts[j]),
+                       **({"prefix_hit": "miss"} if c is not None else {})})
+                     for j, (slot, stream, c) in enumerate(chunk)],
+                    bool(sp))
                 if sp:
                     sp.set(rows=len(chunk))
         for i in range(0, len(part), rows):
             chunk = part[i:i + rows]
             suffixes = [c.suffix for _, _, c in chunk]
-            logits = self.engine.prefill_chunk(
+            first = self.engine.prefill_chunk(
                 suffixes, [slot for slot, _, _ in chunk],
                 [c.start for _, _, c in chunk],
                 request_ids=[s.rid for _, s, _ in chunk])
@@ -2142,49 +2212,85 @@ class DecodeBatcher:
                 self.metrics.prefills_total.inc()
                 self.metrics.prefill_tokens_total.inc(
                     sum(len(x) for x in suffixes))
-                now = tr.now()
-                for j, (slot, stream, claim) in enumerate(chunk):
-                    record_hop(tr, stream.rid, "prefill", slot=slot,
-                               tokens_in=len(suffixes[j]),
-                               replica=self.replica, prefix_hit="partial",
-                               cached_tokens=claim.start)
-                    self.metrics.ttft_ms.observe((now - stream.born) * 1e3)
-                    tok = int(np.argmax(logits[j]))
-                    self.engine.register_slot(slot, tok)
-                    if sp:
-                        stream.traced = True
-                    self._advance(slot, stream, tok,
-                                  pos=len(claim.tokens))
+                toks = chosen_ids(first)
+                self._first_tokens(
+                    [(slot, stream, toks[j], len(c.tokens),
+                      {"tokens_in": len(suffixes[j]),
+                       "prefix_hit": "partial", "cached_tokens": c.start})
+                     for j, (slot, stream, c) in enumerate(chunk)],
+                    bool(sp))
                 if sp:
                     sp.set(rows=len(chunk))
-        self._update_kv_gauge()
+
+    def _first_tokens(self, firsts: List[tuple], traced: bool) -> None:
+        """The first token of each freshly prefilled stream — ``(slot,
+        stream, token, pos, prefill-hop attrs)`` a row: the ``prefill`` hop,
+        the time to first token, the prompt's entry in the prefix index,
+        then the block's advance.  ``traced``: the emit leaf is recording,
+        so each stream is owed its ``request`` record."""
+        tr = self.tracer
+        now = tr.now()
+        for slot, stream, tok, _pos, hop in firsts:
+            if tr.enabled:
+                record_hop(tr, stream.rid, "prefill", slot=slot,
+                           replica=self.replica, **hop)
+            self.metrics.ttft_ms.observe((now - stream.born) * 1e3)
+            self.engine.register_slot(slot, tok)
+            if traced:
+                stream.traced = True
+        self._advance_rows([f[:4] for f in firsts], now)
 
     def _advance(self, slot: int, stream: DecodeStream, tok: int, *,
                  pos: int) -> None:
-        """Handle one newly produced token for ``stream``: emit it (or
-        the EOS/stop decision), and either keep the slot live with the
-        token as the next decode input or finish + free the slot.
-        ``pos`` = the write position the NEXT decode step would use."""
-        remaining = stream.max_new_tokens - len(stream.emitted)
-        finish = False
-        if tok == self.eos_id or remaining <= 0:
-            finish = True       # EOS is a stop decision, not an emission
-        else:
-            gap = stream._push(tok)
+        """One row's advance (the speculative round emits a row's accepted
+        tokens one after another): :meth:`_advance_rows` of a block of
+        one."""
+        self._advance_rows([(slot, stream, tok, pos)], self.tracer.now())
+
+    def _advance_rows(self, rows: List[tuple], now: float) -> None:
+        """Apply a block of newly produced tokens — ``(slot, stream, token,
+        pos)`` a row, ``pos`` the write position the NEXT decode step would
+        use — in one pass: emit each token (or take the EOS/stop decision),
+        then keep the row live with the token as its next decode input, or
+        finish the stream and free its slot.  ``now`` stamps every token of
+        the block (one clock read a step).  The slot table is touched under
+        ONE acquisition of the lock; pushes to streams stay outside it."""
+        eos, max_len = self.eos_id, self.engine.max_len
+        keep: List[tuple] = []
+        done: List[tuple] = []
+        gaps: List[float] = []
+        pushed = 0
+        for row in rows:
+            _, stream, tok, pos = row
+            n = len(stream.emitted)
+            if tok == eos or n >= stream.max_new_tokens:
+                done.append(row)  # EOS is a stop decision, not an emission
+                continue
+            gap = stream._push(tok, now)
+            pushed += 1
             if gap > 0.0:
-                self.metrics.intertoken_ms.observe(gap * 1e3)
-            self.metrics.tokens_out_total.inc()
-            if (len(stream.emitted) >= stream.max_new_tokens
-                    or pos >= self.engine.max_len):
-                finish = True
-        with self._lock:
-            if finish:
-                self._slots[slot] = None
-                self._free.append(slot)
-                self._freed_at[slot] = time.monotonic()
+                gaps.append(gap * 1e3)
+            if n + 1 >= stream.max_new_tokens or pos >= max_len:
+                done.append(row)
             else:
-                self._slots[slot] = _Slot(stream, pos, tok)
-        if finish:
+                keep.append(row)
+        if gaps:
+            self.metrics.intertoken_ms.observe_many(gaps)
+        if pushed:
+            self.metrics.tokens_out_total.inc(pushed)
+        with self._lock:
+            for slot, _, tok, pos in keep:
+                sl = self._slots[slot]
+                sl.pos = pos
+                sl.next_token = tok
+            if done:
+                freed = time.monotonic()
+                for slot, _, _, _ in done:
+                    self._slots[slot] = None
+                    self._free.append(slot)
+                    self._freed_at[slot] = freed
+            live_tokens, live_slots = self._kv_live_locked()
+        for slot, stream, _, _ in done:
             # release the stream's pages (refcount decrement — shared
             # prefix pages stay live under the index / other streams);
             # worker-only, so after the lock is fine
@@ -2196,40 +2302,47 @@ class DecodeBatcher:
                            replica=self.replica, slot=slot,
                            tokens_out=len(stream.emitted))
                 record_request(self.tracer, stream, self.replica)
+        self._set_kv_gauge(live_tokens, live_slots)
 
     def _decode_step(self) -> None:
         """ONE fixed-shape decode step over the slot block; live rows
-        advance their streams, dead rows ride as junk."""
-        tokens = np.zeros((self.engine.slots,), np.int32)
-        pos = np.zeros((self.engine.slots,), np.int32)
+        advance their streams, dead rows ride as junk.  What comes back is
+        the token each row chose on the device (:func:`chosen_ids`)."""
+        eng, tr = self.engine, self.tracer
+        tokens = np.zeros((eng.slots,), np.int32)
+        pos = np.zeros((eng.slots,), np.int32)
         with self._lock:
             live = [(i, sl) for i, sl in enumerate(self._slots)
                     if sl is not None]
-            for i, sl in live:
-                tokens[i] = sl.next_token
-                pos[i] = sl.pos
         if not live:
             return
-        logits = self.engine.decode_batch(
+        # pos / next_token of a live row move on this thread alone
+        idx = [i for i, _ in live]
+        tokens[idx] = [sl.next_token for _, sl in live]
+        pos[idx] = [sl.pos for _, sl in live]
+        result = eng.decode_batch(
             tokens, pos, live=len(live),
-            request_ids=[sl.stream.rid for _, sl in live])
-        with self.tracer.leaf("decode.emit", self.engine.span_attrs) as sp:
+            request_ids=[sl.stream.rid for _, sl in live]
+            if tr.recording else None)
+        with tr.leaf("decode.emit", eng.span_attrs) as sp:
+            toks = chosen_ids(result)
             self.metrics.decode_steps_total.inc()
             self.rmetrics.slot_occupancy.observe(
-                len(live) / float(self.engine.slots))
+                len(live) / float(eng.slots))
             self.rmetrics.batches_total.inc()
-            for i, sl in live:
-                tok = int(np.argmax(logits[i]))
-                # hop BEFORE _advance so a completing stream's terminal
+            if tr.enabled:
+                # hops BEFORE the advance so a completing stream's terminal
                 # stays last; tokens_out = cumulative emissions including
                 # this step (EOS is a stop decision, not an emission)
-                emitted = len(sl.stream.emitted)
-                record_hop(self.tracer, sl.stream.rid, "decode", slot=i,
-                           step=emitted,
-                           tokens_out=emitted + (tok != self.eos_id),
-                           replica=self.replica)
-                self._advance(i, sl.stream, tok, pos=sl.pos + 1)
-            self._update_kv_gauge()
+                for i, sl in live:
+                    emitted = len(sl.stream.emitted)
+                    record_hop(tr, sl.stream.rid, "decode", slot=i,
+                               step=emitted,
+                               tokens_out=emitted + (toks[i] != self.eos_id),
+                               replica=self.replica)
+            self._advance_rows(
+                [(i, sl.stream, toks[i], sl.pos + 1) for i, sl in live],
+                tr.now())
             if sp:
                 sp.set(rows=len(live))
 
@@ -2416,11 +2529,18 @@ class DecodeBatcher:
             }
         return out
 
+    def _kv_live_locked(self) -> tuple:
+        """(cached positions, live rows) of the slot table; caller holds
+        ``_lock``."""
+        held = [sl.pos for sl in self._slots if sl is not None]
+        return sum(held), len(held)
+
     def _update_kv_gauge(self) -> None:
         with self._lock:
-            live_tokens = sum(sl.pos for sl in self._slots
-                              if sl is not None)
-            live_slots = self._live_count()
+            live_tokens, live_slots = self._kv_live_locked()
+        self._set_kv_gauge(live_tokens, live_slots)
+
+    def _set_kv_gauge(self, live_tokens: int, live_slots: int) -> None:
         nbytes = live_tokens * self.engine.token_bytes
         self.engine.budget.set_live(nbytes)
         self.metrics.kv_bytes_live.set(nbytes)

@@ -78,15 +78,22 @@ class Histogram:
         self.max: Optional[float] = None
 
     def observe(self, v: float) -> None:
-        v = float(v)
+        self.observe_many((float(v),))
+
+    def observe_many(self, values: Sequence[float]) -> None:
+        """A block of observations under ONE acquisition of the lock (a
+        decode step's inter-token gaps: one a live row)."""
+        if not values:
+            return
+        lo, hi = min(values), max(values)
         with self._lock:
-            self.count += 1
-            self.total += v
-            self.min = v if self.min is None else min(self.min, v)
-            self.max = v if self.max is None else max(self.max, v)
-            if len(self._recent) < self._window:
-                self._recent.append(v)
-            else:
+            self.count += len(values)
+            self.total += sum(values)
+            self.min = lo if self.min is None else min(self.min, lo)
+            self.max = hi if self.max is None else max(self.max, hi)
+            room = max(0, self._window - len(self._recent))
+            self._recent.extend(values[:room])
+            for v in values[room:]:   # the window is full: a ring
                 self._recent[self._pos] = v
                 self._pos = (self._pos + 1) % self._window
 

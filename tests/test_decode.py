@@ -26,8 +26,12 @@ from pdnlp_tpu.models import bert, decoder, get_config
 from pdnlp_tpu.obs.memory import KVBudget, KVBudgetExceeded
 from pdnlp_tpu.obs.request import chain_issues, validate_chains
 from pdnlp_tpu.ops.attention import causal_bias, dot_product_attention
-from pdnlp_tpu.serve import DecodeBatcher, DecodeEngine, DecodeRouter
-from pdnlp_tpu.serve.decode import detokenize
+from pdnlp_tpu.serve import (
+    DecodeBatcher, DecodeEngine, DecodeRouter, PagedDecodeEngine,
+)
+from pdnlp_tpu.serve.decode import (
+    Chosen, DecodeStream, _Slot, chosen_ids, detokenize, greedy_ids,
+)
 from pdnlp_tpu.utils.config import Args
 
 TEXTS = ["天地人你我", "好坏大小上下来去" * 5, "爱恨喜怒哀乐" * 15]
@@ -515,3 +519,292 @@ def test_router_all_replicas_dead_fails_loudly(tok, eng4):
     with pytest.raises(RuntimeError):
         router.submit_ids([5, 6, 7])
     router.stop()
+
+
+# ------------------------------------------- the choice made on the device
+# What crosses from device to host after a launch is the chosen token of
+# each row (``Chosen.ids``); the logits stay behind it.  Both engines, and
+# both model families through the paged one, as cases of each test.
+
+KINDS = ("slots-bert", "paged-bert", "paged-latent")
+
+
+@pytest.fixture(scope="module", params=KINDS)
+def choosing(request, tok):
+    """ONE warmed engine a kind (8 slots of 64 positions), untraced."""
+    kind = request.param
+    model = "ax-k1-share-tiny" if kind == "paged-latent" else "bert-tiny"
+    args = Args(model=model, decode_slots=8, decode_max_len=64,
+                max_seq_len=64, max_new_tokens=8)
+    cls = DecodeEngine if kind == "slots-bert" else PagedDecodeEngine
+    eng = cls(args, tokenizer=tok, mesh=None, buckets=BUCKETS)
+    eng.warmup_decode()
+    return eng
+
+
+def spied(eng, rows):
+    """Record what every engine call handed its caller: ``(name, the slots
+    a prefill wrote, the launch's logits as an array)``."""
+    for name in ("prefill_ids", "prefill_chunk", "decode_batch"):
+        real = getattr(type(eng), name, None)
+        if real is None:
+            continue
+
+        def spy(*a, _real=real, _name=name, **k):
+            out = _real(eng, *a, **k)
+            rows.append((_name, list(a[1]) if _name != "decode_batch"
+                         else None, np.array(out)))
+            return out
+
+        setattr(eng, name, spy)
+
+
+def unspied(eng):
+    for name in ("prefill_ids", "prefill_chunk", "decode_batch"):
+        eng.__dict__.pop(name, None)
+
+
+def vocab_of(eng, tok):
+    return min(eng.cfg.vocab_size, tok.vocab_size)
+
+
+def test_greedy_ids_takes_the_first_index_on_an_exact_tie():
+    x = np.zeros((5, 33), np.float32)
+    x[0, [7, 20]] = 2.5              # two equal maxima
+    x[1, :] = -1.0                   # every id ties
+    x[2, [32, 3, 11]] = 9.0          # the last id among them
+    x[3, 0] = x[3, 32] = 1e30
+    x[4] = np.linspace(-1, 1, 33)    # no tie
+    got = np.asarray(jax.jit(greedy_ids)(jnp.asarray(x)))
+    assert got.dtype == np.int32
+    assert got.tolist() == np.argmax(x, -1).tolist() == [7, 0, 3, 0, 32]
+
+
+def plant_ties(eng, ids=(3, 9, 17)):
+    """Make every logits row tie exactly at its maximum: the vocabulary
+    rows of ``ids`` become copies of one row and every other row zero, so a
+    row's logits are ``x`` at ``ids`` and exactly 0 elsewhere — whichever
+    side wins, more than one id holds the maximum.  -> undo()."""
+    params, head = eng.params, eng.head
+    ids = list(ids)
+    if "kernel" in head:             # the latent family's untied head
+        k = np.asarray(head["kernel"].astype(jnp.float32))
+        new = np.zeros_like(k)
+        new[:, ids] = k[:, ids[:1]]
+        eng.head = {"kernel": jnp.asarray(new).astype(head["kernel"].dtype)}
+    else:                            # BERT: tied to the word embeddings
+        w = np.asarray(params["embeddings"]["word"].astype(jnp.float32))
+        new = np.zeros_like(w)
+        new[ids] = w[ids[:1]]
+        emb = dict(params["embeddings"])
+        emb["word"] = jnp.asarray(new).astype(
+            params["embeddings"]["word"].dtype)
+        eng.params = {**params, "embeddings": emb}
+
+    def undo():
+        eng.params, eng.head = params, head
+
+    return undo
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["as-served", "ties"])
+def test_a_launchs_ids_are_the_argmax_of_its_own_logits(choosing, tok, ties):
+    """Prefill, (paged) chunk and every decode step: ``ids`` equals
+    ``np.argmax`` of the SAME launch's float32 logits on every row, live or
+    junk; with an exact tie planted at every row's maximum the first index
+    wins, as on the host."""
+    eng = choosing
+    undo = plant_ties(eng) if ties else (lambda: None)
+    try:
+        V = vocab_of(eng, tok)
+        ps = prompts(3, seed=31, lo=5, hi=15, vocab=V)
+        streams = [DecodeStream(p, 8) for p in ps]
+        for slot, st in enumerate(streams):
+            eng.attach_stream(slot, st, share=False)
+        launches = [eng.prefill_ids(ps, [0, 1, 2])]
+        if eng.paged:
+            launches.append(eng.prefill_chunk([p[-3:] for p in ps],
+                                              [0, 1, 2],
+                                              [len(p) - 3 for p in ps]))
+        t = np.zeros((eng.slots,), np.int32)
+        po = np.zeros((eng.slots,), np.int32)
+        t[:3] = launches[0].ids
+        po[:3] = [len(p) for p in ps]
+        for _ in range(5):
+            out = eng.decode_batch(t, po, live=3)
+            launches.append(out)
+            t[:3] = out.ids[:3]
+            po[:3] += 1
+        for out in launches:
+            assert isinstance(out, Chosen)
+            logits = np.asarray(out)
+            assert logits.dtype == np.float32 and out.ids.dtype == np.int32
+            assert out.ids.shape == (len(out),) == logits.shape[:1]
+            assert out.ids.tolist() == np.argmax(logits, -1).tolist()
+            assert out.ids.tolist() == np.asarray(out.ids_device)[
+                :len(out)].tolist() == chosen_ids(out)
+            assert np.array_equal(out[0], logits[0])
+            if ties:
+                top = logits.max(-1, keepdims=True)
+                assert ((logits == top).sum(-1) >= 2).all()
+                assert set(out.ids.tolist()) <= {0, 3}
+        assert chosen_ids(np.asarray(launches[-1])) == \
+            launches[-1].ids.tolist()
+    finally:
+        for slot in range(3):
+            eng.detach_slot(slot)
+        undo()
+
+
+def serve_block(eng, ps, news, wrap=None, eos=-1):
+    """``ps`` (at most a stream a slot, so no slot is reused) through a
+    fresh batcher; ``wrap`` stands between the engine's ``decode_batch``
+    and the batcher.  -> (streams, what every engine call handed out)."""
+    rows = []
+    spied(eng, rows)
+    if wrap is not None:
+        inner = eng.decode_batch
+        eng.decode_batch = lambda *a, **k: wrap(inner(*a, **k))
+    try:
+        b = DecodeBatcher(eng, replica=0)
+        b.eos_id = eos
+        b.start()
+        streams = [b.submit_ids(p, max_new_tokens=n)
+                   for p, n in zip(ps, news)]
+        for s in streams:
+            s.result(timeout=300)
+        b.stop()
+    finally:
+        unspied(eng)
+    return streams, rows
+
+
+def test_what_a_batcher_emits_is_the_host_argmax_of_each_steps_logits(
+        choosing, tok):
+    """A replay that takes ``np.argmax`` of the logits of each launch a
+    stream rode — the rule the batcher applied on the host before the
+    choice moved into the program — gives exactly its ``emitted``."""
+    eng = choosing
+    ps = prompts(6, seed=41, lo=5, hi=15, vocab=vocab_of(eng, tok))
+    news = [9, 3, 1, 12, 7, 5]
+    streams, rows = serve_block(eng, ps, news)
+    assert any(n == "decode_batch" for n, _, _ in rows)
+    for s, n in zip(streams, news):
+        replay = []
+        for name, slots_written, logits in rows:
+            if name == "decode_batch":
+                if replay:           # live from its prefill on
+                    replay.append(int(np.argmax(logits[s.slot])))
+            elif s.slot in slots_written:
+                assert not replay
+                replay.append(int(np.argmax(
+                    logits[slots_written.index(s.slot)])))
+        assert len(s.emitted) == n
+        assert s.emitted == replay[:n]
+    leak = eng.leak_check()
+    assert leak is None or leak["ok"], leak
+
+
+@pytest.mark.parametrize("wrap,same", [
+    (np.asarray, True),
+    (lambda out: np.roll(out, 1, axis=-1), False),
+], ids=["an-array-of-the-logits", "rolled-along-the-last-axis"])
+def test_an_array_handed_to_the_batcher_is_argmaxed_on_the_host(
+        choosing, tok, wrap, same):
+    """A wrapper of ``decode_batch`` that hands the batcher an ARRAY has its
+    rows argmaxed on the host: the same tokens for the logits themselves,
+    other tokens for logits rolled one id along — the benchmark's planted
+    fault (``benchmark/tests/test_correct.py::wrong_token``), at this size."""
+    eng = choosing
+    ps = prompts(5, seed=43, lo=5, hi=15, vocab=vocab_of(eng, tok))
+    news = [8] * 5
+    sound, _ = serve_block(eng, ps, news)
+    wrapped, _ = serve_block(eng, ps, news, wrap=wrap)
+    a = [s.emitted for s in sound]
+    z = [s.emitted for s in wrapped]
+    assert all(len(x) == 8 for x in a + z)
+    if same:
+        assert a == z
+    else:
+        # the first token comes from the prefill, which is not wrapped
+        assert [x[0] for x in a] == [x[0] for x in z]
+        assert all(x[1] != y[1] for x, y in zip(a, z))
+
+
+class CountingLock:
+    """``DecodeBatcher._lock`` with its acquisitions counted."""
+
+    def __init__(self, lock):
+        self.lock, self.taken = lock, 0
+
+    def __enter__(self):
+        self.taken += 1
+        return self.lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self.lock.__exit__(*exc)
+
+
+def per_row_rule(tok_id, emitted, max_new, pos, eos, max_len):
+    """``DecodeBatcher._advance`` as it stood before the block pass, one
+    row at a time: -> (the token is emitted, the stream is finished)."""
+    if tok_id == eos or max_new - emitted <= 0:
+        return False, True           # EOS is a stop decision, not an emission
+    return True, (emitted + 1 >= max_new or pos >= max_len)
+
+
+def test_the_block_pass_finishes_rows_as_the_per_row_rule_did(choosing):
+    """One block holding every case at once — EOS (not emitted), a budget
+    already spent, ``max_new_tokens`` reached, ``pos == max_len``, past it,
+    and rows that live on — against the old rule; the table is touched
+    under ONE acquisition of the lock, a live row's ``_Slot`` is the same
+    object moved in place, every token of the block carries one stamp."""
+    eng = choosing
+    EOS, M = 77, eng.max_len
+    #        tok  emitted  max_new  pos
+    cases = [(EOS, 2, 8, 10),        # EOS
+             (11, 3, 3, 10),         # nothing left to emit
+             (12, 4, 5, 10),         # reaches max_new_tokens
+             (13, 1, 8, M),          # pos == max_len
+             (14, 1, 8, M + 1),      # past it
+             (15, 0, 8, 10),         # a first token, lives on
+             (16, 5, 8, M - 1),      # lives on, one position left
+             (17, 2, 8, 20)]         # lives on
+    assert len(cases) == eng.slots
+    b = DecodeBatcher(eng, replica=0)
+    b.eos_id = EOS
+    rows, seats = [], []
+    for slot, (tk, emitted, max_new, pos) in enumerate(cases):
+        st = DecodeStream([5, 6, 7], max_new, clock=b.tracer.now)
+        for k in range(emitted):
+            st._push(100 + k, 1.0)
+        seat = _Slot(st, pos - 1, 99)
+        b._slots[slot] = seat
+        seats.append(seat)
+        rows.append((slot, st, tk, pos))
+    b._free.clear()
+    before = b.metrics.tokens_out_total.value
+    b._lock = lock = CountingLock(b._lock)
+    b._advance_rows(rows, 2.5)
+    assert lock.taken == 1
+    pushed = 0
+    for slot, (tk, emitted, max_new, pos) in enumerate(cases):
+        emits, finishes = per_row_rule(tk, emitted, max_new, pos, EOS, M)
+        st, seat = rows[slot][1], seats[slot]
+        assert len(st.emitted) == emitted + emits, cases[slot]
+        assert st.done() == finishes, cases[slot]
+        if emits:
+            pushed += 1
+            assert st.emitted[-1] == tk and st.last_token_at == 2.5
+        else:
+            assert tk not in st.emitted
+        if finishes:
+            assert b._slots[slot] is None and slot in b._free
+        else:
+            assert b._slots[slot] is seat      # moved in place
+            assert (seat.pos, seat.next_token) == (pos, tk)
+    assert pushed == 6
+    assert b.metrics.tokens_out_total.value == before + pushed
+    assert sorted(b._free) == [0, 1, 2, 3, 4]
+    # gaps: one a row that had a token before (five of the six pushed)
+    assert b.metrics.intertoken_ms.count == 5

@@ -210,6 +210,28 @@ def test_every_leaf_of_the_round_is_recorded_with_its_cause(traced, name):
         assert a["phase"] in ("prefill", "decode")     # warmed: no compile
 
 
+def test_a_served_launch_hands_the_host_ids_not_logits(traced, engine):
+    """What a served launch's ``<p>.fetch`` moves is the chosen id of each
+    row — 4 bytes a row, plus a family's expert counts (BERT has none) —
+    and never the ``[rows, vocab]`` float32 logits; its ``<p>.emit`` still
+    says how many rows it advanced."""
+    recs = traced["records"]
+    vocab = engine.cfg.vocab_size
+    for call, rows in (("decode", engine.slots),
+                       ("prefill", engine.prefill_rows),
+                       ("chunk", engine.prefill_rows)):
+        fetched = [r["attrs"]["bytes"] for r in recs
+                   if r["name"] == call + ".fetch"]
+        assert fetched and set(fetched) == {rows * 4}, (call, fetched)
+        assert rows * 4 < rows * vocab * 4
+        emits = [r["attrs"] for r in recs if r["name"] == call + ".emit"]
+        assert emits and all(1 <= a["rows"] <= rows for a in emits)
+    worker = decode_host_phases(recs)["0"]
+    assert worker["decode_fetch_bytes_per_step"] == engine.slots * 4
+    assert (f"fetched per decode step: {engine.slots * 4} bytes in "
+            in format_decode_table({"0": worker}))
+
+
 def test_rounds_count_up_and_a_rounds_leaves_share_its_number(traced):
     leaves = worker_leaves(traced["records"])
     rounds = [r["attrs"]["round"] for r in leaves]
@@ -444,6 +466,11 @@ def test_summarize_prints_the_decode_workers_host_phase_table():
     assert set(table) == set(LEAF_NAMES)
     assert table["decode.dispatch"][0] == "15"       # compiles left out
     assert table["decode.emit"][0] == "15"
+    # the bytes a decode step's fetch moved, beside its milliseconds (this
+    # file was recorded when the fetch still was the logits: 4 x 24 x 4)
+    fetched = next(ln for ln in lines if "fetched per decode step" in ln)
+    assert fetched.split(":")[1].split()[:4] == [
+        "384", "bytes", "in", f"{float(table['decode.fetch'][2]):.3f}"]
     as_json = subprocess.run(
         [sys.executable, os.path.join(ROOT, "trace_tpu.py"), "summarize",
          RECORDED, "--json"], capture_output=True, text=True, check=True,

@@ -210,6 +210,10 @@ def test_the_leaves_carry_expert_load_and_cache_bytes(served):
     for a in fetch:
         assert {"expert_assignments", "expert_tokens_max",
                 "experts_idle"} <= set(a)
+    # what a served launch hands the host: the chosen id of each of the 4
+    # slots and the held experts' counts, never the [slots, vocab] logits
+    held = served["sizes"]["n_routed_experts"]
+    assert {a["bytes"] for a in fetch} == {4 * 4 + 4 * held}
     tb = served["kv"]["pages"]["page_bytes"] // PAGE
     assert all(a["cache_bytes_per_token"] == tb for a in steps)
     assert all(a["kv_positions_read"] >= a["kv_positions_live"] > 0

@@ -64,6 +64,26 @@ def test_histogram_percentiles():
     assert h.snapshot()["p50"] is not None
 
 
+@pytest.mark.parametrize("blocks", [(3, 4), (7, 9, 5), (2, 25, 1), (0, 6)],
+                         ids=["under-the-window", "across-its-edge",
+                              "a-block-wider-than-it", "an-empty-block"])
+def test_histogram_block_equals_one_by_one(blocks):
+    """``observe_many`` of a block = ``observe`` of each value in turn:
+    totals, extremes, and WHICH values the ring still holds."""
+    one, many = Histogram(window=10), Histogram(window=10)
+    v = 0.0
+    for n in blocks:
+        block = [v + 1.5 * k for k in range(n)][::-1]
+        v += 100.0
+        for x in block:
+            one.observe(x)
+        many.observe_many(block)
+    assert (many.count, many.min, many.max) == (one.count, one.min, one.max)
+    assert many.total == pytest.approx(one.total)
+    assert many._recent == one._recent and many._pos == one._pos
+    assert many.percentiles((50, 99)) == one.percentiles((50, 99))
+
+
 # ------------------------------------------------------------------- batcher
 def test_batcher_flushes_on_size(engine):
     # wait bound effectively infinite: only the size trigger can flush
