@@ -8,7 +8,7 @@
 
 One process, no children that need the chip.  Every phase drives the entry
 point a user would call (``train.run.build_parallel_trainer`` — the path
-``bench.py`` and every ``multi-tpu-*.py`` run — and ``serve_tpu.main``) at
+every ``multi-tpu-*.py`` runs — and ``serve_tpu.main``) at
 ``bert-base``'s full width over a corpus, a vocabulary and a checkpoint this
 run makes from ``--seed`` under ``--out``; nothing that merely lies in the
 checkout (``output/``, ``*.msgpack``, a prebuilt ``libwordpiece.so``) is read.
@@ -247,14 +247,14 @@ def phase_device(ctx) -> dict:
         check(len(devices) == 4,
               f"--chips 4 needs four devices, JAX reports {len(devices)}")
 
-    import bench
     from pdnlp_tpu.data import native
     from pdnlp_tpu.utils.config import enable_compilation_cache
+    from pdnlp_tpu.utils.profiling import bf16_peak
 
     cache_dir = enable_compilation_cache()
-    peak = bench.bf16_peak(d)
+    peak = bf16_peak(d)
     if not ctx.rehearse:
-        check(peak is not None, f"bench.BF16_PEAK_BY_KIND has no entry for "
+        check(peak is not None, f"profiling.BF16_PEAK_BY_KIND has no entry for "
                                 f"device kind {d.device_kind!r}")
     # never bind a binary this run did not build: rebuild from the committed
     # sources, and if that cannot be done take the stale one out of

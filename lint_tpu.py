@@ -8,7 +8,7 @@ syntax.
 
 Usage:
     python lint_tpu.py                         # scan the standard surface
-    python lint_tpu.py --json pdnlp_tpu scripts bench.py serve_tpu.py
+    python lint_tpu.py --json pdnlp_tpu scripts serve_tpu.py
     python lint_tpu.py --fix-hints             # show suggested rewrites
     python lint_tpu.py --write-baseline        # re-record the ratchet
     python lint_tpu.py --list-rules

@@ -65,7 +65,7 @@ def main(args: Args) -> float:
         # loader already yields device-ready batches)
         train_step.lower(state, wb).compile()
     if args.probe_steps:
-        # the controlled hot-loop rate (run_matrix's probe column), user-
+        # the controlled hot-loop rate (the probe), user-
         # style: re-fed steps on a state copy — train_step donates its
         # argument, so the copy keeps the real state's buffers alive
         import jax.numpy as jnp

@@ -1,5 +1,4 @@
-"""Mesh data-parallel training — the DDP analog and the benchmark's
-north-star entrypoint (``BASELINE.json``).
+"""Mesh data-parallel training — the DDP analog.
 
 Capability twin of ``/root/reference/multi-gpu-distributed-cls.py``:
 ``dist.init_process_group`` -> ``jax.distributed`` rendezvous (env vars or

@@ -29,8 +29,8 @@ _SKIP_DIRS = {"__pycache__", ".git", "output", "results", "node_modules",
 
 def default_paths(root: str = ".") -> List[str]:
     """The repo's hazard surface: the package, the sweep/probe scripts,
-    every strategy entrypoint, and the bench/serve CLIs."""
-    names = ["pdnlp_tpu", "scripts", "bench.py", "serve_tpu.py",
+    every strategy entrypoint, and the serve CLIs."""
+    names = ["pdnlp_tpu", "scripts", "serve_tpu.py",
              "predict_tpu.py", "pretrain-tpu.py", "single-tpu-cls.py",
              "test_tpu.py", "lint_tpu.py", "trace_tpu.py"]
     out = [os.path.join(root, n) for n in names

@@ -413,7 +413,7 @@ class ModuleInfo:
 
 #: (abspath, display_path) -> (stat key, ModuleInfo-or-None).  One
 #: shared parse per file across the three suites and across repeated
-#: ``analyze_paths`` calls (the pytest ratchet, the bench lint gate and
+#: ``analyze_paths`` calls (the pytest ratchet, ``scripts/lint_gate.sh`` and
 #: the CLI all re-scan the same surface); keyed by (mtime_ns, size) so
 #: an edited file re-parses.  ModuleInfo is read-only after
 #: construction (its lazy caches are idempotent), so sharing is safe.

@@ -2,7 +2,7 @@
 
 The generative decode hot path lives or dies on two properties the serve
 engine gets by construction (``pdnlp_tpu.serve.decode``): the KV cache is
-PREALLOCATED (``[L, slots, max_len, N, D]``, donated across steps — decode
+PREALLOCATED (``[L, n_pages, page_sz, H]``, donated across steps — decode
 never allocates HBM) and the decode step has ONE fixed shape (``[rows,
 1]`` — retrace-free after warmup).  The textbook anti-pattern breaks both
 at once::
@@ -15,7 +15,7 @@ Every token reallocates the whole cache (O(T²) bytes moved over a
 generation) and, under jit, the growing shape retraces the step on every
 single token — the decode analog of the R7/R9 step-loop stalls.
 
-The PAGED layout (``pdnlp_tpu.serve.kvpage``) has its own spelling of the
+The paged cache (``pdnlp_tpu.serve.kvpage``) has its own spelling of the
 same bug: the per-stream page TABLE rebuilt by concatenate as pages are
 claimed, or the page arrays re-stacked per token::
 
@@ -72,8 +72,8 @@ class KVCacheReallocInDecodeLoop(Rule):
             "or a paged pool with a fixed [slots, pages_per_stream] page "
             "table updated in place) and write new K/V with "
             "cache.at[rows, pos].set(...) or lax.dynamic_update_slice "
-            "into a DONATED buffer (pdnlp_tpu.serve.decode.DecodeEngine / "
-            "PagedDecodeEngine are the engine forms) — a concatenate "
+            "into a DONATED buffer (pdnlp_tpu.serve.decode."
+            "PagedDecodeEngine is the engine form) — a concatenate "
             "rebuild reallocates the whole cache or table every token "
             "and the growing shape retraces the jitted step per "
             "generated token")

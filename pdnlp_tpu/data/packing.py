@@ -218,7 +218,7 @@ class PackedClassificationDataset(EncodedDataset):
         self.source_rows = source_rows
 
     def stats(self) -> Dict[str, float]:
-        """Packing efficiency numbers for the bench smoke."""
+        """Packing efficiency numbers (fill, segments a row)."""
         seg_counts = (self.arrays["example_weight"] > 0).sum(1)
         tokens_real = int(self.arrays["attention_mask"].sum())
         return {
@@ -256,7 +256,7 @@ class MultiWidthPackedDataset:
     holding one 300-token document backfills with ~200 tokens of short
     documents instead of padding.  Without backfill the per-row residue
     caps fill near the mean member length over the width (~0.75); with it
-    the measured fill clears the 0.85 gate (``bench.py --longcontext``).
+    the fill clears 0.85 on the long-document mix.
 
     Rows live in ONE global index space (width groups concatenated in
     ascending width order); batching rides the ordinary
@@ -267,8 +267,7 @@ class MultiWidthPackedDataset:
     epoch-invariant, exactly the bucket-mode contract.  Not an
     :class:`~pdnlp_tpu.data.collate.EncodedDataset` (there is no single
     rectangular array), so the device-resident pipeline declines it and
-    ``--pipeline auto`` falls back to prefetch — documented, measured in
-    ``bench.py --longcontext``.
+    ``--pipeline auto`` falls back to prefetch (``tests/test_longcontext.py``).
     """
 
     def __init__(self, encoded: EncodedDataset, widths: Sequence[int],

@@ -25,7 +25,8 @@ interface (:func:`build_pipeline`) move it out:
   prefetch the flat reference never had.  Fallback for corpora over
   budget, multi-process runs, and custom batch placements (sp/pp).
 - ``"sync"`` — the reference behavior: upload inline in the step loop
-  (kept for A/B measurement; ``bench.py --pipeline`` compares all three).
+  (kept as the parity reference: ``tests/test_pipeline.py`` holds the other
+  two to its losses, bit for bit).
 
 Every mode feeds :meth:`Trainer.train` through ``macro_batches(fuse)``,
 yielding ``(device_batch, n_steps, fused, examples)`` — fused groups arrive

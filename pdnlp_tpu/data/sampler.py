@@ -146,7 +146,8 @@ def resolve_length_mode(args) -> str:
     ``auto`` resolves to ``full``: bucket/pack keep per-example math intact
     but change batch COMPOSITION (which examples co-occur in a step), so
     every committed loss trace and golden run stays reference-exact unless
-    a run opts in.  ``bench.py --length`` measures what opting in buys."""
+    a run opts in (what opting in buys: PERF.md, the ``finetune-pack128``
+    cell still to add)."""
     mode = getattr(args, "length_mode", "auto") or "auto"
     if mode not in ("auto", "full", "bucket", "pack"):
         raise ValueError(f"unknown length_mode {mode!r}; use "

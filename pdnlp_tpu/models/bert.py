@@ -39,7 +39,8 @@ def _fuse_qkv() -> bool:
     Trace-time switch (``PDNLP_FUSE_QKV``), default OFF: the fused form is
     the textbook win on GPU, but on v5e it measured 3% SLOWER than three
     separate projections (33.1 -> 32.0 probe steps/s — XLA materializes the
-    weight concat each step instead of folding it; results/profile_r05.json)
+    weight concat each step instead of folding it; a record older than the
+    ledger, removed, not re-measured)
     and the split form keeps tp's per-tensor output sharding natural.  The
     path stays for A/B profiling on other TPU generations."""
     import os
@@ -53,17 +54,18 @@ def _gelu(x, form: str = "erf"):
     ``"erf"`` is the exact form — the reference BERT's activation
     (``transformers`` ``hidden_act="gelu"``).  ``"tanh"`` trades the erf
     backward (a VPU transcendental chain the step profile priced at
-    ~3.3 ms — ``results/profile_r05.json`` "exact-GELU backward") for a
+    ~3.3 ms; record removed, not re-measured) for a
     cheaper polynomial; max |Δ| vs erf is ~4e-4, and the shipped recipe
     measured +7% step rate AND +0.7pt fine-tune accuracy when pretrained
-    with it end to end (0.5887 vs erf's 0.5813 — bench.py recipe note).
+    with it end to end (0.5887 vs erf's 0.5813; a record older than the
+    ledger, not re-measured).
     ``PDNLP_GELU_TANH=1`` force-enables tanh regardless of config — the
-    A/B profiling override (``scripts/profile_step.py``)."""
+    A/B profiling override."""
     import os
 
     if form not in ("erf", "tanh"):
         # loud: a typo'd --gelu would otherwise silently run erf while
-        # bench.py keys its pretrain cache on the raw string
+        # a pretrain cache is keyed on the raw string
         raise ValueError(f"gelu must be 'erf' or 'tanh', got {form!r}")
     approx = form == "tanh" or os.environ.get("PDNLP_GELU_TANH", "0") == "1"
     return jax.nn.gelu(x, approximate=approx)

@@ -33,8 +33,8 @@ class BertConfig:
                                   # measured +7% fused-step rate at batch 64
                                   # on v5e and +0.7pt fine-tune accuracy
                                   # when pretrained with it end to end
-                                  # (results/profile_r05.json gelu_tanh*,
-                                  # bench recipe note)
+                                  # (records older than the ledger,
+                                  # removed; not re-measured)
     # --- mixture-of-experts (0 experts = dense MLP; no reference twin) ---
     moe_experts: int = 0          # experts per layer's MLP
     moe_top_k: int = 2            # experts combined per token

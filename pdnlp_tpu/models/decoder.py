@@ -4,7 +4,7 @@ The serving tier was classification-shaped: one forward, one logit row per
 request.  Generative decoding inverts the cost structure — autoregressive
 decode is memory-bandwidth-bound, so tokens/s is won on *not recomputing*
 the prompt every token.  This module is the pure-math half of that story
-(the serving half — slots, continuous batching, budgets — lives in
+(the serving half — slots, pages, continuous batching, budgets — lives in
 ``pdnlp_tpu.serve.decode``):
 
 - **one trunk, three programs**: the decoder reuses the classifier's param
@@ -13,27 +13,24 @@ the prompt every token.  This module is the pure-math half of that story
   LayerNorm + decoder TIED to the word embeddings), so any strategy
   checkpoint serves generatively without conversion.  :func:`prefill`
   runs the prompt causally and RETURNS the per-layer K/V it computed;
-  :func:`decode_step` advances one token against a slot-indexed cache;
-  :func:`infill_logits` is the bidirectional MLM-infilling scorer (same
-  trunk, no causal mask — BERT's native objective served online).
-- **KV cache layout** ``[L, slots, max_len, N, D]``: layer-major so the
-  layer scan streams one ``[slots, max_len, N, D]`` slab per step;
-  ``max_len`` ahead of heads so cached keys keep the trunk's ``[B, S, N,
-  D]`` attention layout — cached and recomputed attention then share ONE
-  einsum/reduction shape, which is what makes the bitwise decode-parity
-  contract below provable instead of approximate.
-- **the bitwise contract**: incremental decode over a live cache is
-  bitwise equal, per step, to a FULL RECOMPUTE from a cold cache — a
-  fresh prefill of the prompt plus a from-scratch replay of every
-  generated token, nothing reused (``tests/test_decode.py`` pins it; the
-  bench gates it mid-storm).  The contract is provable because every
-  decode shape is FIXED (``[rows, 1]`` tokens, ``[rows]`` positions, the
-  preallocated cache), so both sides run identical programs on
-  bitwise-equal inputs, and the -1e9 additive masks zero invisible keys'
-  probabilities EXACTLY (masked cache rows contribute exact ``+0.0``
-  regardless of their stale contents).  Against the one-shot WIDE causal
-  forward the comparison is argmax-exact within ~1e-6 instead: XLA's CPU
-  gemm blocks the contraction differently per row extent (measured:
+  :func:`paged_decode_step` advances one token against the paged cache
+  (one core, :func:`paged_attend_layers`, also behind the chunk and verify
+  steps); :func:`infill_logits` is the bidirectional MLM-infilling scorer
+  (same trunk, no causal mask — BERT's native objective served online).
+- **KV cache layout** ``[L, n_pages, page_sz, H]``: the paged-cache note
+  below says why the heads are folded and what every program promises.
+- **the parity contract**: incremental decode over a live cache is
+  bitwise equal, per step, to a FULL RECOMPUTE from a cold cache through
+  the same programs — a fresh prefill of the prompt plus a from-scratch
+  replay of every generated token, nothing reused
+  (``tests/test_decode.py``).  That holds because every decode shape is
+  FIXED (``[rows, 1]`` tokens, ``[rows]`` positions, the preallocated
+  pools), so both sides run identical programs on bitwise-equal inputs,
+  and the -1e9 additive masks zero invisible keys' probabilities EXACTLY
+  (masked cache rows contribute exact ``+0.0`` regardless of their stale
+  contents).  Against the one-shot WIDE causal forward — the tests'
+  oracle — the comparison is argmax-exact within ~1e-6 instead: XLA's
+  CPU gemm blocks the contraction differently per row extent (measured:
   ``[3, 512] @ [512, 128]`` vs the same rows at extent 96 differ by
   ULPs), so a ``[rows, 1]`` pass and a ``[rows, S]`` pass are only
   accumulation-order-equal, not bit-equal, on that backend.
@@ -43,14 +40,14 @@ the prompt every token.  This module is the pure-math half of that story
   CALIBRATED (:func:`calibrate_kv_scales` — a seeded synthetic forward,
   identical math offline in ``scripts/quantize_ckpt.py --kv_calib`` and
   online at engine warmup, so the two routes can never disagree); new K/V
-  quantize on write, the whole cache dequantizes by one broadcast
-  multiply on read, and no fp32 copy of the cache ever persists.
+  quantize on write, the pages read dequantize by one broadcast
+  multiply, and no fp32 copy of the cache ever persists.
 
 The hot decode shapes are fixed by construction — ``[rows, 1]`` tokens,
-``[rows]`` positions, the preallocated cache — so a jitted
-:func:`decode_step` can never retrace after its first trace (the serve
-engine donates the cache buffers across steps; jaxlint R16 polices the
-rebuild-the-cache anti-pattern).
+``[rows]`` positions, a table cut to a warmed rung, the preallocated pools
+— so a jitted :func:`paged_decode_step` can never retrace after its first
+trace per rung (the serve engine donates the pools across steps; jaxlint
+R16 polices the rebuild-the-cache anti-pattern).
 """
 from __future__ import annotations
 
@@ -135,7 +132,7 @@ def run_layers_kv(layers: Params, cfg: BertConfig, x: jax.Array, *,
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Causal layer scan that also RETURNS what it computed: hidden
     [B, S, H] plus per-layer K/V stacked ``[L, B, S, N, D]`` — the arrays
-    the serve engine scatters into its slot cache, at zero extra compute
+    the serve engine scatters into its pages, at zero extra compute
     (prefill had to build them anyway; the classifier path just threw
     them away).  Attention rides ``ops.attention`` (the causal
     composition and its routing live there, not here)."""
@@ -199,69 +196,6 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-def decode_step(params: Params, head: Params, cfg: BertConfig,
-                tokens: jax.Array,   # [B, 1] int32: the CURRENT token
-                cache_k: jax.Array,  # [L, B, max_len, N, D] (fp or int8)
-                cache_v: jax.Array,
-                pos: jax.Array,      # [B] int32: write position of `tokens`
-                *, kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None,
-                dtype=jnp.float32, unroll=True
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One fixed-shape decode step: embed ``tokens`` at ``pos``, write
-    their K/V into the cache at ``pos`` (``.at[].set`` — an in-place
-    dynamic update on a donated buffer, never a rebuild), attend over
-    positions ``<= pos``, return (next-token logits [B, vocab] fp32,
-    cache_k', cache_v').
-
-    Every shape here is static — [B, 1] tokens, [B] positions, the
-    preallocated cache — so the jitted form holds exactly ONE compiled
-    program (retrace-free by the same construction as ``infer_packed``).
-    ``kv_scales`` = (k_scale, v_scale) ``[L, N, D]`` switches the cache to
-    int8: new rows quantize before the write, slabs dequantize per layer
-    at read.  The CURRENT token's K/V round-trips through the cache too —
-    the step attends to what FUTURE steps will see, so int8 error is
-    consistent across the stream instead of hidden on the diagonal."""
-    _check_dense_trunk(params["layers"])
-    B = tokens.shape[0]
-    max_len = cache_k.shape[2]
-    pos = pos.astype(jnp.int32)
-    x, _ = bert.embed(params, cfg, tokens, jnp.zeros_like(tokens),
-                      dtype=dtype, deterministic=True,
-                      position_ids=pos[:, None])
-    # linear visibility mask: key j visible iff j <= pos (prompt + already
-    # decoded + the token just written); never a [S, S] term
-    visible = (jnp.arange(max_len)[None, :] <= pos[:, None])
-    bias = mask_bias(visible.astype(jnp.float32), jnp.float32)
-    rows = jnp.arange(B)
-
-    def layer(carry, scanned):
-        x = carry
-        if kv_scales is None:
-            lp, _, ck, cv = scanned
-        else:
-            lp, _, ck, cv, ks_l, vs_l = scanned
-        q, k_new, v_new = _qkv(x, lp, cfg, dtype)         # [B, 1, N, D]
-        if kv_scales is None:
-            ck = ck.at[rows, pos].set(k_new[:, 0])
-            cv = cv.at[rows, pos].set(v_new[:, 0])
-            kf, vf = ck, cv
-        else:
-            ck = ck.at[rows, pos].set(quantize_kv(k_new[:, 0], ks_l))
-            cv = cv.at[rows, pos].set(quantize_kv(v_new[:, 0], vs_l))
-            kf = dequantize_kv(ck, ks_l, dtype)
-            vf = dequantize_kv(cv, vs_l, dtype)
-        attn = dot_product_attention(q, kf, vf, bias, impl="auto")
-        return _finish_layer(x, lp, cfg, attn, dtype), (ck, cv)
-
-    li = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], li, cache_k, cache_v)
-    if kv_scales is not None:
-        xs = xs + (kv_scales[0], kv_scales[1])
-    x, (cache_k, cache_v) = jax.lax.scan(layer, x, xs, unroll=unroll)
-    logits = lm_logits(params, head, cfg, x, dtype=dtype)[:, 0]
-    return logits, cache_k, cache_v
-
-
 # ------------------------------------------------------------- paged cache
 #
 # The paged layout stores K/V as fixed-size pages ``[L, n_pages, page_sz,
@@ -296,10 +230,11 @@ def decode_step(params: Params, head: Params, cfg: BertConfig,
 #   probability is exactly 0.0 after the float32 softmax, and pool contents
 #   are finite), or a dead row whose logits the caller discards.
 #
-# The mathematics is the slot step's: same visibility (``j <= pos``), the
-# current token attends to its own cache entry, float32 softmax and logits.
-# A shorter extent changes which exact zeros are summed, so a paged stream is
-# TOKEN-identical to the slot engine's, not bitwise-equal in its logits.
+# The mathematics is the wide causal forward's: same visibility (``j <=
+# pos``), the current token attends to its own cache entry, float32 softmax
+# and logits.  A shorter extent changes which exact zeros are summed, so a
+# stream served over one rung is TOKEN-identical to the same stream over
+# another (or to the one-shot recompute), not bitwise-equal in its logits.
 
 
 def _layer_rows(flat: jax.Array, n_layers: int, per_layer: int) -> jax.Array:
@@ -351,8 +286,8 @@ def paged_insert(pages_k: jax.Array,   # [L, P, page_sz, H]
                  flat_pos: jax.Array,  # [B, S // unit] int32 (OOB drop)
                  *, kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None
                  ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter a prefill's K/V into pages: the paged analogue of the slot
-    engine's cache insert, :func:`insert_pool` for K and for V."""
+    """Scatter a prefill's K/V into pages: :func:`insert_pool` for K and
+    for V."""
     ks_l, vs_l = kv_scales or (None, None)
     return (insert_pool(pages_k, ks, flat_pos, ks_l),
             insert_pool(pages_v, vs, flat_pos, vs_l))
@@ -370,12 +305,6 @@ def copy_pool(pages: jax.Array,
     return pages.at[:, dst].set(got, mode="drop")
 
 
-def copy_pages(pages_k: jax.Array, pages_v: jax.Array, src: jax.Array,
-               dst: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """:func:`copy_pool` for K and for V."""
-    return copy_pool(pages_k, src, dst), copy_pool(pages_v, src, dst)
-
-
 def gather_pool(pages: jax.Array,
                 src: jax.Array       # [rows] physical page ids (OOB = 0s)
                 ) -> jax.Array:
@@ -388,12 +317,6 @@ def gather_pool(pages: jax.Array,
     return jnp.take(pages, src, axis=1, mode="fill", fill_value=0)
 
 
-def gather_pages(pages_k: jax.Array, pages_v: jax.Array, src: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """:func:`gather_pool` for K and for V."""
-    return gather_pool(pages_k, src), gather_pool(pages_v, src)
-
-
 def scatter_pool(pages: jax.Array,
                  payload: jax.Array,    # [L, rows, page_sz, W]
                  dst: jax.Array         # [rows] physical page ids (OOB drop)
@@ -404,14 +327,6 @@ def scatter_pool(pages: jax.Array,
     (zero-filled) payload rows are dropped, so the import is the same ONE
     fixed-shape program for every stream."""
     return pages.at[:, dst].set(payload.astype(pages.dtype), mode="drop")
-
-
-def scatter_pages(pages_k: jax.Array, pages_v: jax.Array,
-                  payload_k: jax.Array, payload_v: jax.Array,
-                  dst: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """:func:`scatter_pool` for K and for V."""
-    return (scatter_pool(pages_k, payload_k, dst),
-            scatter_pool(pages_v, payload_v, dst))
 
 
 #: query rows (window positions x heads) up to which attention runs with the
@@ -561,7 +476,7 @@ def paged_decode_step(params: Params, head: Params, cfg: BertConfig,
                       page_table: jax.Array,  # [B, <= MP] int32 (sentinel P)
                       pos: jax.Array,         # [B] int32 write positions
                       **kw) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """:func:`decode_step` over a paged cache — the core at T = 1: write the
+    """One decode step over a paged cache — the core at T = 1: write the
     current token's K/V through the table, attend over the table's extent,
     next-token logits ``[B, vocab]``.  The table is data, so one compiled
     program serves every step at a given table width."""
@@ -624,16 +539,6 @@ def infill_logits(params: Params, head: Params, cfg: BertConfig,
 
 
 # ------------------------------------------------------------- calibration
-
-def kv_cache_bytes(cfg, slots: int, max_len: int, kv_dtype) -> int:
-    """Preallocated cache bytes for ``slots x max_len`` positions of
-    whatever ``cfg``'s family caches (K and V pools, or one latent pool) —
-    the number the ``--kv_hbm_mb`` budget (obs.memory.KVBudget) is checked
-    against.  Computed in ONE place: ``families.token_bytes``."""
-    from pdnlp_tpu.models import families
-
-    return int(slots * max_len * families.token_bytes(cfg, kv_dtype))
-
 
 def calibrate_kv_scales(params: Params, cfg: BertConfig, *,
                         seq_len: Optional[int] = None,

@@ -40,7 +40,6 @@ class Family:
     #: model of gigabytes is never held twice)
     lazy_weights: bool = False
     #: what the family can be asked for besides prefill / chunk / decode
-    slot_layout: bool = True      # the unpaged DecodeEngine
     int8: bool = True             # int8 weights or an int8 cache
     verify: bool = True           # the speculative pair's scoring window
     handoff: bool = True          # exporting / importing a stream's pages
@@ -89,7 +88,7 @@ FAMILIES = {
         init_head=latent_moe.init_head,
         pool_widths=lambda cfg: (cfg.cache_width,),
         prefill=_latent_prefill, attend=_latent_attend,
-        lazy_weights=True, slot_layout=False, int8=False, verify=False,
+        lazy_weights=True, int8=False, verify=False,
         handoff=False),
 }
 

@@ -16,8 +16,8 @@ offline half (``summarize`` / ``diff`` / ``export`` / ``merge`` /
 
 Off by default: entrypoints enable tracing with ``--trace`` (spans land
 under ``<output_dir>/trace/trace_proc<i>.jsonl``), the live exporter with
-``--metrics_port``; ``bench.py --trace`` and ``bench.py --telemetry`` pin
-the enabled-mode overheads under their tolerances.
+``--metrics_port``; what the instrumentation costs when it is on is in
+PERF.md (sections 6-7).
 """
 from pdnlp_tpu.obs.exporter import MetricsExporter, prometheus_text
 from pdnlp_tpu.obs.memory import MemorySampler, device_memory_stats, \

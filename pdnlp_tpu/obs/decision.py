@@ -36,7 +36,7 @@ exactly one ``action`` and ends with exactly one ``outcome`` — an action
 without an outcome means the controller actuated and never came back to
 judge it, which is precisely the unaccountable-autotuner failure mode this
 layer exists to make impossible (``trace_tpu.py decisions`` exits 1 on
-it, and the ``bench.py --replay`` smoke gates on zero).
+it; ``tests/test_controller.py`` holds every law to zero).
 """
 from __future__ import annotations
 
@@ -123,8 +123,8 @@ def decision_issues(chain: Sequence[Dict]) -> List[str]:
 
 
 def validate_decisions(records: Sequence[Dict]) -> Dict:
-    """Chain-integrity report over a span stream — the ``bench.py
-    --replay`` gate's input: every actuation must carry a complete
+    """Chain-integrity report over a span stream (``trace_tpu.py
+    decisions``' input): every actuation must carry a complete
     cause -> action -> outcome chain, and the revert count is how many
     actuations the controller judged harmful and undid."""
     by_id = decision_chains(records)

@@ -103,18 +103,21 @@ class KVBudgetExceeded(RuntimeError):
 class KVBudget:
     """Declared KV-cache HBM budget for one decode engine.
 
-    The decode engine preallocates its slot cache ONCE (``[L, slots,
-    max_len, N, D]`` ×2, donated across steps — decode never allocates),
-    so the budget decision happens at two doors, both loud:
+    The decode engine preallocates its page pools ONCE (``[L, n_pages,
+    page_sz, width]`` a pool, donated across steps — decode never
+    allocates), so the budget decision happens at two doors, both loud:
 
-    - **construction**: :meth:`cap_slots` returns how many slots the
+    - **construction**: :meth:`cap_pages` returns how many pages the
       declared budget actually covers — the engine allocates THAT many
       (stderr-noted when capped below the request) and refuses outright
-      (:class:`KVBudgetExceeded`) when not even one slot fits;
-    - **admission**: :meth:`check_stream` refuses a stream whose
-      worst-case footprint (``prompt + max_new_tokens`` positions) cannot
-      fit a slot under the budget — the caller gets the budget math, not
-      a mid-decode OOM.
+      (:class:`KVBudgetExceeded`) when not even one maximum-length stream
+      fits;
+    - **admission**: a stream whose worst-case footprint (``prompt +
+      max_new_tokens`` positions) passes its page table's extent is
+      refused in the budget's units
+      (``PagedDecodeEngine.check_stream_admissible``;
+      :meth:`check_stream` is the same door for a caller without an
+      engine) — the caller gets the budget math, not a mid-decode OOM.
 
     Live occupancy (:meth:`set_live` / :attr:`live_bytes`) is the
     ``/metrics`` gauge: positions actually WRITTEN across live slots ×
@@ -129,23 +132,9 @@ class KVBudget:
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------- doors
-    def cap_slots(self, requested: int, slot_bytes: int) -> int:
-        """Slots the budget covers (= ``requested`` when unbudgeted);
-        raises :class:`KVBudgetExceeded` when it cannot cover one."""
-        if self.budget_bytes is None:
-            return int(requested)
-        fit = self.budget_bytes // max(1, int(slot_bytes))
-        if fit < 1:
-            raise KVBudgetExceeded(
-                f"kv_hbm_mb={self.budget_bytes / 2**20:.1f} cannot hold "
-                f"even one decode slot ({slot_bytes / 2**20:.1f} MB of KV "
-                "at this max_len/model) — raise --kv_hbm_mb or shrink "
-                "--decode_max_len")
-        return min(int(requested), int(fit))
-
     def cap_pages(self, requested: int, page_bytes: int,
                   min_pages: int = 1) -> int:
-        """Paged-layout construction door (``serve.kvpage``): how many
+        """Construction door (``serve.kvpage``): how many
         fixed-size KV pages the declared budget covers (= ``requested``
         when unbudgeted).  ``min_pages`` is the floor the engine needs to
         hold ONE maximum-length stream — a budget that cannot cover it

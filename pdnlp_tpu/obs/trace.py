@@ -27,8 +27,8 @@ The tracer records host-side spans into a ring buffer:
   cross-process coordination in the hot path;
 - **off by default, cheap when on**: a disabled tracer's ``span`` returns
   one shared no-op object (no allocation); enabled spans cost two
-  ``perf_counter`` reads and a deque append (``bench.py --trace`` pins the
-  end-to-end overhead under its tolerance).
+  ``perf_counter`` reads and a deque append (what that costs end to end:
+  PERF.md section 6, PR 25).
 
 - **leaf spans, on two clocks**: ``leaf(name)`` is a span that is ALSO a
   ``jax.profiler.TraceAnnotation`` — the same interval lies in this ring
@@ -256,9 +256,8 @@ class Tracer:
         """Zero-duration instant record, hot-path cheap: ONE clock read,
         no thread-state lookup (tid 0), the caller's dict adopted as-is.
         The per-request hop stream (``obs.request``) runs through here —
-        at serve request rates a few extra µs per record is the
-        difference between passing and failing the ``bench.py
-        --telemetry`` 1% overhead gate."""
+        at serve request rates a few extra µs per record show in the
+        round (PERF.md section 6, PR 24-25)."""
         if not self.enabled:
             return
         rec = {"name": name, "t0": self.clock(), "dur": 0.0, "tid": 0,

@@ -86,8 +86,8 @@ def resolve_impl(requested: str, *, segmented: bool = False,
     ``"auto"`` becomes pallas for segmented (packed) batches on a real TPU
     backend and XLA everywhere else (the measured-faster choice — see the
     module docstring).  Shape/dropout feasibility is :func:`routed_impl`.
-    ``backend`` overrides the running backend — how the bench reports the
-    TPU routing policy from a CPU host without pretending to measure it."""
+    ``backend`` overrides the running backend — how a CPU host reports the
+    TPU routing policy without pretending to measure it."""
     if requested == "auto":
         backend = backend or jax.default_backend()
         return "pallas" if segmented and backend == "tpu" else "xla"
@@ -125,7 +125,7 @@ def routed_impl(requested: str, seq_len: int, *, segmented: bool = False,
                 backend: Optional[str] = None) -> str:
     """The impl that will actually execute for this (static) configuration
     — the single decision :func:`dot_product_attention`, the trainer's
-    ``step_dispatch`` span attr, and the bench JSON all share, so the
+    ``step_dispatch`` span attr, and a run's report all share, so the
     surfaced impl can never drift from the routed one.
 
     ``"auto"`` first applies the backend-level rule (:func:`resolve_impl`)
@@ -146,8 +146,8 @@ def routed_impl(requested: str, seq_len: int, *, segmented: bool = False,
             return "xla"
         if measured == "pallas":
             # a measured win routes pallas even where the static rule is
-            # conservative (e.g. dense long widths after a kernel change,
-            # re-measured by bench.py --longcontext) — still TPU-only:
+            # conservative (e.g. dense long widths after a kernel change)
+            # — still TPU-only:
             # the kernel interprets (slowly) everywhere else
             bk = backend or jax.default_backend()
             impl = "pallas" if bk == "tpu" else "xla"

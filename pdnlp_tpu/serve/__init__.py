@@ -17,10 +17,9 @@ requests) — this package applies the same treatment to inference:
   health ejection with requeue/retry, warmup-gated reintegration, and
   rolling checkpoint hot-swap (``serve_tpu.py --replicas N``);
 - :mod:`pdnlp_tpu.serve.metrics` — latency/occupancy/cache observability
-  (plus router/per-replica instruments), JSON-snapshot compatible with the
-  ``results/`` artifacts;
+  (plus router/per-replica instruments), plain-JSON snapshots;
 - :mod:`pdnlp_tpu.serve.offline` — high-throughput whole-file scoring over
-  the same bucketing (the deterministic surface tests and ``bench.py`` use);
+  the same bucketing (the deterministic surface the tests use);
 - :mod:`pdnlp_tpu.serve.controller` — the feedback control plane: a
   :class:`ServeController` thread that closes the telemetry loop, auto-
   tuning replica count (warm-standby scaling), ``hedge_ms``, the flush age
@@ -29,9 +28,9 @@ requests) — this package applies the same treatment to inference:
 - :mod:`pdnlp_tpu.serve.replay` — trace-driven load replay: recorded
   request-hop chains reconstructed into arrival schedules, reshaped
   (steady / diurnal ramp / flash crowd) and re-driven at 1x/5x/20x speed
-  (``bench.py --replay``);
-- :mod:`pdnlp_tpu.serve.decode` — generative decoding: a paged (default)
-  or slot-indexed donated KV cache (optionally int8 against calibrated
+  (``tests/test_controller.py``);
+- :mod:`pdnlp_tpu.serve.decode` — generative decoding: a paged, donated
+  KV cache (optionally int8 against calibrated
   per-channel scale tables), bucketed prefill / one fixed-shape decode
   step, continuous batching with streaming responses, a declared KV HBM
   budget (``--kv_hbm_mb``), a decode replica router whose
@@ -46,7 +45,7 @@ requests) — this package applies the same treatment to inference:
   exported page payloads between the disaggregated pools — the
   single-host rehearsal of a cross-process serving tier;
 - :mod:`pdnlp_tpu.serve.kvpage` — the paged KV memory subsystem behind
-  ``--kv_layout paged``: refcounted fixed-size page allocator with a
+  the decode engine: refcounted fixed-size page allocator with a
   free list, loud :class:`KVPagesExhausted` refusals, a leak-check
   ledger audit, and an LRU prefix index that shares repeated prompt
   prefixes across requests at page granularity (copy-on-write at the
@@ -60,7 +59,7 @@ from pdnlp_tpu.serve.batcher import (  # noqa: F401
 )
 from pdnlp_tpu.serve.controller import KnobSpec, ServeController  # noqa: F401
 from pdnlp_tpu.serve.decode import (  # noqa: F401
-    DecodeBatcher, DecodeEngine, DecodeRouter, DecodeStream,
+    DecodeBatcher, DecodeRouter, DecodeStream,
     DisaggDecodeRouter, PagedDecodeEngine, PrefillWorker,
 )
 from pdnlp_tpu.serve.engine import InferenceEngine  # noqa: F401
@@ -85,7 +84,6 @@ __all__ = [
     "AdmissionControl",
     "DeadlineExceeded",
     "DecodeBatcher",
-    "DecodeEngine",
     "DecodeMetrics",
     "DecodeRouter",
     "DecodeStream",

@@ -114,7 +114,7 @@ def resolve_serve_pack(mode: str, pack_width: int,
     in-kernel and the win is pure.
     Elsewhere (CPU tests, non-tiling widths) the XLA fallback materializes
     the ``[B,1,S,S]`` segment bias per batch — packing still usually wins
-    on padding waste (``on`` forces it; the bench gates it), but it is an
+    on padding waste (``on`` forces it; ``tests/test_serve_pack.py``), but it is an
     opt-in, not a default."""
     if mode not in ("auto", "on", "off"):
         raise ValueError(f"serve_pack must be 'auto', 'on' or 'off', "

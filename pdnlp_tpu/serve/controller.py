@@ -41,11 +41,11 @@ skipped, actuation errors surface in :meth:`snapshot` (the exporter's
 ``controller`` source), and :meth:`stop` resolves every pending
 evaluation so flushed traces always validate.
 
-Proving ground: ``bench.py --replay`` replays recorded arrival processes
-(:mod:`pdnlp_tpu.serve.replay`) through controller-vs-static pools across
-steady / diurnal-ramp / flash-crowd shapes with a mid-storm replica kill,
-and gates that the controller wins the p99 x throughput frontier while
-auto-reverting an injected bad actuation.
+Pinned by ``tests/test_controller.py`` (hysteresis, cooldown, clamps, the
+auto-revert of an injected bad actuation, the standby cycle with zero
+retraces, complete decision chains).  Whether any law wins a p99 x
+throughput frontier against a hand-tuned constant is NOT measured: no
+benchmark cell turns the controller on (ROADMAP, Design 8).
 """
 from __future__ import annotations
 
@@ -395,7 +395,7 @@ class ServeController:
     # ---------------------------------------------------------------- sense
     def step(self) -> Optional[_Sense]:
         """One full control tick: sense -> evaluate pending -> decide ->
-        actuate.  Public so tests (and the bench) can drive the loop with
+        actuate.  Public so tests can drive the loop with
         an injected clock instead of the thread."""
         sense = self._sense()
         if sense is None:
@@ -808,8 +808,8 @@ class ServeController:
                ) -> bool:
         """Chaos/test hook: push an actuation through the SAME ``_actuate``
         choke point (clamped, decision-recorded, evaluated) bypassing only
-        cooldown/hold — the ``bench.py --replay`` smoke injects a bad
-        value here and gates that the evaluation window auto-reverts it."""
+        cooldown/hold — ``tests/test_controller.py`` injects a bad value
+        here and holds the evaluation window to auto-reverting it."""
         return self._actuate(knob, value, {"note": cause_label},
                              force=True)
 

@@ -1,4 +1,4 @@
-"""Generative decode engine: sharded slot KV cache, prefill/decode split,
+"""Generative decode engine: paged KV cache, prefill/decode split,
 continuous batching.
 
 The serving tier built since PR 6 scales a SCORER — one forward, one logit
@@ -8,22 +8,24 @@ or lost on (a) never recomputing the prompt (the KV cache), (b) never
 retracing (fixed shapes, donated buffers), and (c) never running the
 decode batch partially empty (continuous batching).
 
-- **slot-indexed KV cache**: one preallocated pair of ``[L, slots,
-  max_len, N, D]`` buffers per engine (``models.decoder`` layout note),
-  DONATED across steps — steady-state decode allocates nothing.  A slot
-  is the unit of admission: a stream claims one at prefill, writes
-  forward as it decodes, and frees it between steps when it finishes —
-  slot reuse is ``form_packed_batch``'s row-reuse idea made stateful.
-  On a mesh the slot axis shards over ``data`` like every serve batch.
+- **paged KV cache** (:class:`PagedDecodeEngine`, ``serve.kvpage``): one
+  preallocated ``[L, n_pages, page_sz, width]`` array per pool of the
+  model family (``models.decoder`` layout note), DONATED across steps —
+  steady-state decode allocates nothing.  A slot is a row of the decode
+  batch and the unit of admission; PAGES are the unit of capacity: a
+  stream reserves every page it can touch when it is seated, writes
+  forward through its page table as it decodes, and frees them between
+  steps when it finishes.  Pages replicate on a mesh; several chips are
+  served by replicas (:class:`DecodeRouter`).
 - **prefill/decode split**: prompts execute as bucketed ``[prefill_rows,
   bucket]`` causal forwards riding the same compile-cache discipline as
   the classifier engine (one trace per bucket, warmup pre-traces all);
-  their K/V scatter into claimed slots (``.at[slots].set`` with
-  out-of-bounds filler rows DROPPED — filler never touches a live slot).
-  Decode is ONE ``[slots, 1]`` program — retrace-free by the same
-  construction as ``infer_packed``: after :meth:`DecodeEngine.warmup`
-  there is exactly one compiled decode step and nothing live traffic
-  does can create another.
+  their new rows scatter into the claimed pages (out-of-bounds filler
+  indices DROPPED — filler never touches a live page).  Decode is a
+  ``[slots, 1]`` program per rung of the attention extent — retrace-free
+  by the same construction as ``infer_packed``: after
+  :meth:`PagedDecodeEngine.warmup_decode` every rung is compiled and
+  nothing live traffic does can create another.
 - **continuous batching** (:class:`DecodeBatcher`): between decode steps,
   finished streams leave and waiting streams claim freed slots (prefill
   rides the same worker, so the decode batch is re-filled before the
@@ -61,7 +63,7 @@ decode batch partially empty (continuous batching).
   two-owner draft custody (``kvpage.draft_owner`` + ``transfer``) until
   a later round commits across them.  Greedy verification makes the
   emitted sequence IDENTICAL to primary-only decode — every emitted
-  token is a primary argmax — which the bench gates stream-for-stream.
+  token is a primary argmax (``tests/test_speculate.py``).
   A drafter death degrades the pair to primary-only decode (loud,
   decision-recorded); parity is unaffected because the primary cache
   already holds every committed token.
@@ -74,12 +76,12 @@ decode batch partially empty (continuous batching).
   prefill-role engines (bucketed/chunked prefill only) and decode-role
   engines (steady fixed-shape decode only); a finished prefill's pages
   move to a decode engine via the KV **handoff**: a fixed-shape jitted
-  page export (``models.decoder.gather_pages`` over the sentinel-padded
+  page export (``models.decoder.gather_pool`` over the sentinel-padded
   table row — one compiled program whatever the stream's real page
   count), staged custody on the sender
   (``kvpage.stage_handoff`` — refcounts never blip, both allocators'
   ``leak_check`` reconcile to zero), and a fixed-shape import
-  (``scatter_pages``) into the receiver's fresh cold reservation.
+  (``scatter_pool``) into the receiver's fresh cold reservation.
   Cross-pool the payload rides ``serve.handoff``'s length-prefixed
   stdlib-socket transport (loopback; the repo's first RPC boundary).
   The pool split is the controller's first STRUCTURAL knob
@@ -112,7 +114,7 @@ import numpy as np
 
 from pdnlp_tpu.models import decoder, families
 from pdnlp_tpu.obs.decision import mint_decision_id, record_decision
-from pdnlp_tpu.obs.memory import KVBudget
+from pdnlp_tpu.obs.memory import KVBudget, KVBudgetExceeded
 from pdnlp_tpu.obs.request import mint_request_id, record_hop
 from pdnlp_tpu.serve.batcher import (
     DEFAULT_BUCKETS, DeadlineExceeded, QueueFullError, pick_bucket,
@@ -208,10 +210,34 @@ def chosen_ids(result) -> List[int]:
     return ids.tolist()
 
 
-class DecodeEngine(InferenceEngine):
+class _PageClaim:
+    """One stream's page reservation (``PagedDecodeEngine`` slot state):
+    which kind of prefix hit it attached with, the continuation tokens it
+    covers, and what the prefill phase still owes it (nothing for a full
+    hit; the divergent suffix for a partial one)."""
+
+    __slots__ = ("owner", "kind", "tokens", "n_prompt_pages",
+                 "first_token", "suffix", "start", "draft_from")
+
+    def __init__(self, owner: str, kind: str, tokens: List[int],
+                 n_prompt_pages: int, first_token: Optional[int] = None,
+                 suffix: Optional[List[int]] = None, start: int = 0):
+        self.owner = owner
+        self.kind = kind                    # "cold" | "partial" | "full"
+        self.tokens = tokens                # prompt + emitted at attach
+        self.n_prompt_pages = n_prompt_pages
+        self.first_token = first_token      # full hits: stored token 0
+        self.suffix = suffix or []          # partial hits: the chunk
+        self.start = start                  # partial hits: suffix offset
+        self.draft_from = None              # drafter engines: first page
+        #                                     index under draft custody
+
+
+class PagedDecodeEngine(InferenceEngine):
     """The classifier engine's checkpoint/mesh/metrics machinery with a
-    generative decode path on top: LM head, slot KV cache, jitted
-    prefill / cache-insert / decode-step programs, and the KV budget.
+    generative decode path on top: LM head, paged KV pools
+    (``serve.kvpage``), jitted prefill / page-insert / chunk / decode-step
+    programs, and the KV budget.
 
     The inherited pieces carry over unchanged: template-validated
     checkpoint swap (trunk only — the LM head is its own small tree),
@@ -219,13 +245,61 @@ class DecodeEngine(InferenceEngine):
     through ``serve.quant``), per-batch HBM sampling, span conventions
     (``compile`` on a first-seen shape, the steady-state name after).
     Single-dispatcher contract: all decode/prefill calls come from ONE
-    worker thread (:class:`DecodeBatcher`)."""
+    worker thread (:class:`DecodeBatcher`).
+
+    Storage is ``[L, n_pages, page_sz, width]`` pages, one array per pool
+    of the family (``models.families``; the layout the chip tiles exactly
+    — ``models.decoder``'s paged-cache note), a per-stream page table
+    drives every program's page reads, and capacity is PAGES, not slots:
+    slots are pure decode-batch rows while ``--kv_hbm_mb`` caps the page
+    pool, so short streams do not pay for ``max_len`` stripes and admitted
+    concurrency scales with what streams actually use.
+
+    The pool stays where it lies: every paged program
+    (``models.decoder.paged_attend_layers`` behind ``paged_decode_step`` /
+    ``paged_chunk_step`` / ``paged_verify_step``, and ``paged_insert``)
+    takes the pools donated, writes rows or whole pages in place and
+    reads whole pages through the table.  The decode step attends over a
+    RUNG of page counts (:attr:`decode_rungs`, quarters of a stream's
+    pages) chosen per step by the longest row: fixed-shape programs, one
+    per rung, all traced in :meth:`warmup_decode`.
+
+    Prefix sharing rides the :class:`~pdnlp_tpu.serve.kvpage.PrefixIndex`:
+    a repeated prompt maps the indexed pages at refcount+1 and skips its
+    prefill entirely (**full hit** — the stored first token is emitted
+    straight from the index, so TTFT is bounded by one decode-step
+    latency); a shared-prefix prompt maps the matching full pages and
+    runs only the divergent suffix (**partial hit** —
+    ``paged_chunk_step``); copy-on-write duplicates a full hit's trailing
+    partial page before the stream writes into it.  Full pages are
+    immutable once written, which is what makes sharing safe without
+    copies.
+
+    Parity contract: shared-prefix streams reuse K/V that is bitwise what
+    their own prefill would have produced (same program, same inputs), so
+    greedy continuations are TOKEN-identical to a cold engine's
+    (``prefix_share=False``; ``tests/test_kvpage.py``) the same way
+    re-prefilled kill survivors always have been.
+
+    Pages replicate on a mesh (no ``NamedSharding`` axis): the page ->
+    stream mapping is dynamic, so there is no static batch axis to shard;
+    several chips are served by replicas, one engine a chip
+    (:class:`DecodeRouter`)."""
+
+    #: fixed copy-on-write batch rows — one compiled ``copy_pool``
+    #: program per engine; unused rows ride the OOB sentinel
+    COW_ROWS = 4
+    #: rungs of the decode step's attention extent (quarters of a stream's
+    #: pages): each is one compiled program, all traced in warmup
+    DECODE_RUNGS = 4
 
     def __init__(self, args, tokenizer=None, *, mesh=None, metrics=None,
                  tracer=None, slots: Optional[int] = None,
                  max_len: Optional[int] = None,
                  buckets: Sequence[int] = DEFAULT_BUCKETS,
-                 prefill_rows: Optional[int] = None):
+                 prefill_rows: Optional[int] = None,
+                 page_sz: Optional[int] = None, prefix_share: bool = True,
+                 index_entries: int = 4096):
         super().__init__(args, tokenizer, mesh=mesh, metrics=metrics,
                          tracer=tracer)
         # the worker's leaf spans record under --trace OR whenever a JAX
@@ -251,9 +325,6 @@ class DecodeEngine(InferenceEngine):
         if self.kv_int8 and not family.int8:
             family.refuse("an int8 cache (--kv_dtype int8)",
                           "its latent cache is stored in bf16")
-        if not self.paged and not family.slot_layout:
-            family.refuse("the slot cache layout (--kv_layout slots)",
-                          "use --kv_layout paged")
         self.kv_dtype = (jnp.int8 if self.kv_int8
                          else {"fp32": jnp.float32,
                                "bf16": jnp.bfloat16}.get(kv_req, self.dtype))
@@ -262,15 +333,39 @@ class DecodeEngine(InferenceEngine):
         #: fetch leaf recorded (a family with experts; else stays None)
         self.expert_load: Optional[np.ndarray] = None
 
-        # the declared HBM budget gates the PREALLOCATION (loud refusal at
-        # construction, never an allocator OOM) and caps slots to what it
-        # covers; admission re-checks per stream (KVBudgetExceeded)
+        # --- capacity.  Pages, not slots, are the budgeted unit: the
+        # declared HBM budget caps the page POOL (loud refusal at
+        # construction, never an allocator OOM; floor: one maximum-length
+        # stream) and the slot count stays the requested batch width —
+        # admitted concurrency is bounded by what streams actually
+        # RESERVE; admission re-checks per stream (KVBudgetExceeded)
         self.budget = KVBudget(getattr(args, "kv_hbm_mb", 0))
         requested = int(slots or getattr(args, "decode_slots", 8))
         # bytes a cached position takes: ONE place computes it, from the
         # family's pools, for budgets, refusals and snapshots alike
         self.token_bytes = families.token_bytes(cfg, self.kv_dtype)
-        self.slots = self._resolve_capacity(requested)
+        ps = max(1, min(int(page_sz or getattr(args, "kv_page_sz", 0)
+                            or 16), self.max_len))
+        self.page_sz = ps
+        self.pages_per_stream = pages_needed(self.max_len, ps)
+        # the decode step's attention extents, in pages: one warmed
+        # program per rung, chosen per step by the longest live row
+        mp = self.pages_per_stream
+        self.decode_rungs = sorted(
+            {-(-mp * i // self.DECODE_RUNGS)
+             for i in range(1, self.DECODE_RUNGS + 1)})
+        self.page_bytes = self.token_bytes * ps
+        req_pages = requested * self.pages_per_stream
+        self.n_pages = self.budget.cap_pages(
+            req_pages, self.page_bytes, min_pages=self.pages_per_stream)
+        if self.n_pages < req_pages:
+            print(f"[serve.decode] kv_hbm_mb caps KV pages "
+                  f"{req_pages} -> {self.n_pages} "
+                  f"({self.page_bytes / 2**20:.2f} MB/page, "
+                  f"{self.pages_per_stream}/stream worst case)",
+                  file=sys.stderr)
+        m = self.rows_multiple
+        self.slots = max(m, (requested // m) * m)
         self.prefill_rows = self.pad_rows(
             min(self.slots, int(prefill_rows or 8)))
         # prompt buckets: the serve bucket ladder capped at max_len, with
@@ -294,9 +389,16 @@ class DecodeEngine(InferenceEngine):
             self.head = self._put(self._serving_form(self._head_template))
         self.head_path: Optional[str] = None
 
-        self._cache_k = self._cache_v = None
+        # --- pools, allocator, prefix index, page tables
+        self.prefix_share = bool(prefix_share)
+        self._index_entries = int(index_entries)
         self._alloc_cache()
 
+        # --- programs.  Every one takes the cache as ONE tuple of pools
+        # (twin K and V pools, or the one latent pool: the family's),
+        # donated, and the int8 scale tables — none for a float cache — as
+        # trailing arguments; what a family counts per launch rides back
+        # as ``aux``
         metrics_ref = self.metrics
         dtype = self.dtype
 
@@ -307,41 +409,65 @@ class DecodeEngine(InferenceEngine):
                                                last_pos, dtype)
             return logits, greedy_ids(logits), aux, news
 
-        if self.kv_int8:
-            def _insert_fn(ck, cv, k, v, slot_ids, ks, vs):
-                metrics_ref.retraces.inc()
-                k = decoder.quantize_kv(k, ks[:, None, None])
-                v = decoder.quantize_kv(v, vs[:, None, None])
-                S = k.shape[2]
-                ck = ck.at[:, slot_ids, :S].set(k, mode="drop")
-                cv = cv.at[:, slot_ids, :S].set(v, mode="drop")
-                return ck, cv
+        def _pinsert_fn(pools, news, flat_pos, *scales):
+            metrics_ref.retraces.inc()
+            return families.insert(pools, news, flat_pos, scales or None)
 
-            def _decode_fn(params, head, ck, cv, tokens, pos, ks, vs):
-                metrics_ref.retraces.inc()
-                logits, ck, cv = decoder.decode_step(
-                    params, head, cfg, tokens, ck, cv, pos,
-                    kv_scales=(ks, vs), dtype=dtype)
-                return logits, greedy_ids(logits), ck, cv
-        else:
-            def _insert_fn(ck, cv, k, v, slot_ids):
-                metrics_ref.retraces.inc()
-                S = k.shape[2]
-                ck = ck.at[:, slot_ids, :S].set(k.astype(ck.dtype),
-                                                mode="drop")
-                cv = cv.at[:, slot_ids, :S].set(v.astype(cv.dtype),
-                                                mode="drop")
-                return ck, cv
+        def _pdecode_fn(params, head, pools, tokens, table, pos, *scales):
+            metrics_ref.retraces.inc()
+            logits, aux, pools = family.attend(
+                params, head, cfg, tokens, pools, table, pos, None, "last",
+                scales or None, dtype)
+            return logits, greedy_ids(logits), aux, pools
 
-            def _decode_fn(params, head, ck, cv, tokens, pos):
-                metrics_ref.retraces.inc()
-                logits, ck, cv = decoder.decode_step(
-                    params, head, cfg, tokens, ck, cv, pos, dtype=dtype)
-                return logits, greedy_ids(logits), ck, cv
+        def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
+                       *scales):
+            metrics_ref.retraces.inc()
+            logits, aux, pools = family.attend(
+                params, head, cfg, tokens, pools, table, start, nreal,
+                "last", scales or None, dtype)
+            return logits, greedy_ids(logits), aux, pools
+
+        def _pverify_fn(params, head, pools, tokens, table, start, nreal,
+                        *scales):
+            metrics_ref.retraces.inc()
+            return family.attend(params, head, cfg, tokens, pools, table,
+                                 start, nreal, "all", scales or None, dtype)
+
+        def _pcow_fn(pools, src, dst):
+            metrics_ref.retraces.inc()
+            return tuple(decoder.copy_pool(p, src, dst) for p in pools)
+
+        def _pexport_fn(pools, src):
+            metrics_ref.retraces.inc()
+            return tuple(decoder.gather_pool(p, src) for p in pools)
+
+        def _pimport_fn(pools, payloads, dst):
+            metrics_ref.retraces.inc()
+            return tuple(decoder.scatter_pool(p, x, dst)
+                         for p, x in zip(pools, payloads))
 
         self._jit_prefill = jax.jit(_prefill_fn)
-        self._jit_insert = jax.jit(_insert_fn, donate_argnums=(0, 1))
-        self._jit_decode = jax.jit(_decode_fn, donate_argnums=(2, 3))
+        self._jit_pinsert = jax.jit(_pinsert_fn, donate_argnums=(0,))
+        self._jit_pdecode = jax.jit(_pdecode_fn, donate_argnums=(2,))
+        self._jit_pchunk = jax.jit(_pchunk_fn, donate_argnums=(2,))
+        self._jit_pverify = jax.jit(_pverify_fn, donate_argnums=(2,))
+        self._jit_pcow = jax.jit(_pcow_fn, donate_argnums=(0,))
+        # export reads the pool (no donation — the sender keeps serving
+        # from it); import donates like every other cache writer
+        self._jit_pexport = jax.jit(_pexport_fn)
+        self._jit_pimport = jax.jit(_pimport_fn, donate_argnums=(0,))
+
+    # the twin pools under the names the benchmark's kinds read
+    # (``engine._cache_k.shape``); read-only views of ``_pools``, and a
+    # one-pool family has no second
+    @property
+    def _cache_k(self):
+        return self._pools[0]
+
+    @property
+    def _cache_v(self):
+        return self._pools[1] if len(self._pools) > 1 else None
 
     @property
     def head(self):
@@ -355,86 +481,38 @@ class DecodeEngine(InferenceEngine):
     def head(self, value) -> None:
         self._head = value
 
-    #: layout marker — :class:`PagedDecodeEngine` flips it; the batcher
-    #: and router branch on behavior hooks, never on this flag, but
-    #: snapshots and bench reports name the layout through it
-    paged = False
-
-    def _resolve_capacity(self, requested: int) -> int:
-        """How many decode slots this engine runs: the ``--kv_hbm_mb``
-        budget caps the SLOT count here (the slot layout's capacity
-        unit); the paged engine overrides this to cap PAGES instead and
-        leave slots as pure batch rows."""
-        slot_bytes = self.token_bytes * self.max_len
-        capped = self.budget.cap_slots(requested, slot_bytes)
-        # slots must tile the mesh's data axis; FLOOR so the cap holds
-        m = self.rows_multiple
-        slots_n = max(m, (capped // m) * m)
-        if slots_n * slot_bytes > (self.budget.budget_bytes or
-                                   slots_n * slot_bytes):
-            raise ValueError(
-                f"kv_hbm_mb cannot cover the {m}-slot mesh minimum "
-                f"({m * slot_bytes / 2**20:.1f} MB)")
-        if slots_n < requested:
-            print(f"[serve.decode] kv_hbm_mb caps decode slots "
-                  f"{requested} -> {slots_n} "
-                  f"({slot_bytes / 2**20:.1f} MB/slot)", file=sys.stderr)
-        return slots_n
-
-    # ---------------------------------------------------- paging hooks
-    # The batcher drives BOTH layouts through these; on the slot layout
-    # they are no-ops (a slot IS the reservation), on the paged engine
-    # they are the allocator/prefix-index transaction per stream.
-    def peek_prefix(self, ids: Sequence[int]) -> Optional[str]:
-        """Admission-time prefix peek for the ``admit`` hop's
-        ``prefix_hit`` attr (None = layout has no prefix sharing)."""
-        return None
-
-    def attach_stream(self, slot: int, stream: "DecodeStream", *,
-                      share: bool = True):
-        """Reserve cache capacity for ``stream`` in ``slot``; returns a
-        claim descriptor (None on the slot layout — the slot claim
-        already IS the reservation).  ``share=False`` forces a COLD
-        claim even when the prefix index would hit: the KV-handoff
-        import path scatters a payload into the reservation, which must
-        never write into shared prefix pages."""
-        return None
-
-    def detach_slot(self, slot: int) -> None:
-        """Release ``slot``'s cache reservation (no-op on slots)."""
-
-    def register_slot(self, slot: int, first_token: int) -> None:
-        """Index ``slot``'s freshly prefilled prompt for later sharing
-        (no-op on the slot layout)."""
-
-    def leak_check(self) -> Optional[Dict]:
-        """Allocator ledger audit (None on the slot layout)."""
-        return None
-
     # ----------------------------------------------------------- lifecycle
     def _alloc_cache(self) -> None:
-        """(Re)allocate the slot cache — construction, and
-        :meth:`reset_cache` after tests/chaos; never on the hot path."""
+        """(Re)allocate the page pool + a fresh allocator/index/table —
+        construction and post-chaos :meth:`reset_cache`, never hot."""
         cfg = self.cfg
-        shape = (cfg.num_layers, self.slots, self.max_len,
-                 cfg.num_heads, cfg.head_dim)
-        sh = None
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
 
-            sh = NamedSharding(self.mesh,
-                               PartitionSpec(None, "data", None, None, None))
+        def alloc(width):
+            # one position's values of one pool are ONE minor axis: [page_sz,
+            # width] is what the chip tiles (models.decoder, paged-cache
+            # note).  SEPARATE buffers: device_put of one shared zeros
+            # array would alias the pools, and a donating program would
+            # then donate the same buffer twice
+            return jax.device_put(jnp.zeros(
+                (cfg.num_layers, self.n_pages, self.page_sz, width),
+                self.kv_dtype))
 
-        def alloc():
-            # two SEPARATE buffers: device_put of one shared zeros array
-            # would alias K and V, and the donated insert/decode calls
-            # would then donate the same buffer twice
-            z = jnp.zeros(shape, self.kv_dtype)
-            return jax.device_put(z, sh) if sh is not None \
-                else jax.device_put(z)
-
-        self._cache_k = alloc()
-        self._cache_v = alloc()
+        #: the cache: one array per pool of the family (``models.families``),
+        #: every one donated to each program
+        self._pools = tuple(alloc(w) for w in self.family.pool_widths(cfg))
+        self.allocator = PageAllocator(self.n_pages, self.page_sz,
+                                       self.page_bytes)
+        self.prefix = PrefixIndex(self.allocator, self.page_sz,
+                                  max_entries=self._index_entries)
+        if self.prefix_share:
+            self.allocator.reclaimer = self.prefix.evict
+        # per-slot page tables, host-resident and updated IN PLACE at
+        # attach/detach (never rebuilt per step — jaxlint R16 polices
+        # the rebuild-by-concatenate idiom); sentinel n_pages = dead row
+        self._table = np.full((self.slots, self.pages_per_stream),
+                              self.n_pages, np.int32)
+        self._slot_state: List[Optional[_PageClaim]] = [None] * self.slots
+        self._pending_cow: List[tuple] = []
 
     def reset_cache(self) -> None:
         self._alloc_cache()
@@ -444,13 +522,14 @@ class DecodeEngine(InferenceEngine):
         """Longest admissible prompt (the widest prefill bucket)."""
         return int(self.prefill_buckets[-1])
 
+    # -------------------------------------------------------- admission
     def check_stream_admissible(self, prompt_len: int,
                                 max_new: int) -> None:
-        """The admission door's capacity + budget math, in one place.
-        On a BUDGETED engine an oversized stream refuses in the budget's
-        own units (:class:`~pdnlp_tpu.obs.memory.KVBudgetExceeded` with
+        """The admission door's capacity + budget math, in one place.  On
+        a BUDGETED engine an oversized stream refuses in the budget's own
+        units, pages (:class:`~pdnlp_tpu.obs.memory.KVBudgetExceeded` with
         the MB math) — the refusal that replaces a mid-decode OOM; an
-        unbudgeted engine reports plain slot capacity."""
+        unbudgeted engine reports the plain page-table extent."""
         total = int(prompt_len) + int(max_new)
         if max_new < 1:
             raise ValueError("max_new_tokens must be >= 1")
@@ -458,23 +537,18 @@ class DecodeEngine(InferenceEngine):
             raise ValueError(
                 f"prompt of {prompt_len} tokens exceeds the "
                 f"{self.prompt_limit}-token prefill limit")
-        # (no separate budget.check_stream call: construction guarantees
-        # budget >= one slot = max_len positions, so any stream the
-        # budget would refuse also exceeds max_len — ONE door below, in
-        # the budget's units when a budget is declared)
         if total > self.max_len:
+            need = pages_needed(total, self.page_sz)
             if self.budget.budget_bytes is not None:
-                from pdnlp_tpu.obs.memory import KVBudgetExceeded
-
                 raise KVBudgetExceeded(
-                    f"stream needs {total} KV positions "
-                    f"({total * self.token_bytes / 2**20:.1f} MB) but "
-                    f"the budgeted slot holds {self.max_len} "
-                    f"({self.max_len * self.token_bytes / 2**20:.1f} MB "
-                    "under --kv_hbm_mb)")
+                    f"stream needs {need} KV pages ({total} positions, "
+                    f"{need * self.page_bytes / 2**20:.2f} MB) but a "
+                    f"stream's page table holds {self.pages_per_stream} "
+                    f"pages ({self.max_len} positions) under --kv_hbm_mb")
             raise ValueError(
                 f"prompt + max_new_tokens = {total} exceeds the "
-                f"{self.max_len}-position KV slot (--decode_max_len)")
+                f"{self.max_len}-position page-table extent "
+                "(--decode_max_len)")
 
     # ------------------------------------------------------------ KV int8
     def load_kv_scales(self, path: str) -> None:
@@ -565,8 +639,9 @@ class DecodeEngine(InferenceEngine):
 
     def _seen(self, key: tuple, steady: str) -> str:
         """Compile-cache bookkeeping of one engine call: the ``phase`` its
-        dispatch leaf carries — ``"compile"`` for a first-seen shape key
-        (the call will trace), ``steady`` for a replay."""
+        dispatch leaf carries (or its span's name) — ``"compile"`` for a
+        first-seen shape key (the call will trace), ``steady`` for a
+        replay."""
         if key in self._seen_shapes:
             self.metrics.cache_hits.inc()
             return steady
@@ -617,74 +692,6 @@ class DecodeEngine(InferenceEngine):
         rows = len(host) if rows is None else rows
         return Chosen(host[:rows], ids, logits, rows)
 
-    def prefill_ids(self, id_lists: Sequence[Sequence[int]],
-                    slot_ids: Sequence[int],
-                    request_ids=None) -> Chosen:
-        """Prefill up to ``prefill_rows`` prompts into their claimed slots:
-        bucketed causal forward + K/V scatter; returns each prompt's
-        FIRST token (``.ids``, host) over its ``[n, vocab]`` fp32 logits
-        (on the device until read: :class:`Chosen`).
-
-        Filler rows carry slot id ``self.slots`` — out of bounds, so the
-        scatter DROPS them and a filler row can never touch a live slot.
-        The compile-cache key is ``(bucket, rows, "prefill")``; warmup
-        pre-traces every bucket so steady traffic never compiles."""
-        n = len(id_lists)
-        assert n and n <= self.prefill_rows
-        with self.tracer.leaf("prefill.dispatch", self.span_attrs) as sp:
-            bucket = pick_bucket(max(len(x) for x in id_lists),
-                                 self.prefill_buckets)
-            rows = self.prefill_rows
-            ids = np.zeros((rows, bucket), np.int32)
-            mask = np.zeros((rows, bucket), np.int32)
-            last = np.zeros((rows,), np.int32)
-            slot_arr = np.full((rows,), self.slots, np.int32)  # OOB = dropped
-            for i, (x, s) in enumerate(zip(id_lists, slot_ids)):
-                ids[i, :len(x)] = x
-                mask[i, :len(x)] = 1
-                last[i] = len(x) - 1
-                slot_arr[i] = s
-            phase = self._seen((int(bucket), int(rows), "prefill"),
-                               "prefill")
-            sharded = self._shard_batch({"ids": ids, "mask": mask})
-            if sp:
-                sp.set(phase=phase, seq=int(bucket), rows=int(rows),
-                       streams=int(n), prefill=True,
-                       tokens=int(mask.sum()), dtype=self.dtype_label,
-                       **self._telemetry_attrs(request_ids))
-            logits, chosen, _, (ks, vs) = self._jit_prefill(
-                self.params, self.head, sharded["ids"], sharded["mask"],
-                last)
-            self._cache_k, self._cache_v = self._jit_insert(
-                self._cache_k, self._cache_v, ks, vs, slot_arr,
-                *self._scale_args())
-        return self._fetch_chosen(logits, chosen, "prefill", rows=n)
-
-    def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
-                     live: int, request_ids=None) -> Chosen:
-        """One fixed-shape decode step over the whole slot block: tokens
-        ``[slots]`` (current token per slot; dead slots ride with junk),
-        ``pos`` ``[slots]`` write positions.  Returns each slot's next
-        token (``.ids``: the ``slots x 4`` bytes the host fetches) over
-        the ``[slots, vocab]`` fp32 logits, which stay on the device until
-        a caller reads them (:class:`Chosen`).  The ONE compile-cache key is
-        ``("decode", slots)`` — retrace-free after warmup by
-        construction."""
-        with self.tracer.leaf("decode.dispatch", self.span_attrs) as sp:
-            phase = self._seen(("decode", int(self.slots)), "decode")
-            tok = np.asarray(tokens, np.int32).reshape(self.slots, 1)
-            p = np.clip(np.asarray(pos, np.int32), 0, self.max_len - 1)
-            if sp:
-                sp.set(phase=phase, rows=int(self.slots), live=int(live),
-                       decode=True, dtype=self.dtype_label,
-                       kv=self._kv_label(),
-                       **self._telemetry_attrs(request_ids))
-            logits, chosen, self._cache_k, self._cache_v = \
-                self._jit_decode(
-                    self.params, self.head, self._cache_k, self._cache_v,
-                    tok, p, *self._scale_args())
-        return self._fetch_chosen(logits, chosen, "decode")
-
     def _kv_label(self) -> str:
         return "int8" if self.kv_int8 else np.dtype(self.kv_dtype).name
 
@@ -704,14 +711,7 @@ class DecodeEngine(InferenceEngine):
         for i, x in enumerate(id_lists):
             ids[i, :len(x)] = x
             mask[i, :len(x)] = 1
-        key = (int(bucket), int(rows), "infill")
-        if key in self._seen_shapes:
-            self.metrics.cache_hits.inc()
-            span_name = "forward"
-        else:
-            self.metrics.cache_misses.inc()
-            self._seen_shapes.add(key)
-            span_name = "compile"
+        span_name = self._seen((int(bucket), int(rows), "infill"), "forward")
         if not hasattr(self, "_jit_infill"):
             metrics_ref = self.metrics
             cfg, dtype = self.cfg, self.dtype
@@ -732,291 +732,10 @@ class DecodeEngine(InferenceEngine):
             out = np.asarray(jax.device_get(logits))
         return out[:n]
 
-    def warmup_decode(self) -> None:
-        """Pre-trace every reachable decode-path shape: one prefill +
-        insert per bucket (filler slot ids — the cache is untouched), the
-        ONE decode step, and the int8 calibration if pending.  After this
-        call live traffic cannot compile."""
-        self._scale_args()  # int8: calibrate before anything traces
-        for b in self.prefill_buckets:
-            # a bucket-FILLING dummy, so each bucket traces ITS shape
-            # (prefill_ids picks the smallest covering bucket from the
-            # ids' length); the OOB slot id drops the cache write
-            self.prefill_ids([[self.tokenizer.cls_id] * b], [self.slots])
-        tok = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        self.decode_batch(tok, pos, live=0)
-
-    def kv_snapshot(self) -> Dict:
-        """JSON-ready KV/budget block for snapshots and ``/metrics``."""
-        return {
-            **self.budget.snapshot(),
-            "layout": "slots",
-            "slots": int(self.slots),
-            "max_len": int(self.max_len),
-            "kv_dtype": self._kv_label(),
-            "cache_bytes": self.slots * self.max_len * self.token_bytes,
-        }
-
-
-class _PageClaim:
-    """One stream's page reservation (``PagedDecodeEngine`` slot state):
-    which kind of prefix hit it attached with, the continuation tokens it
-    covers, and what the prefill phase still owes it (nothing for a full
-    hit; the divergent suffix for a partial one)."""
-
-    __slots__ = ("owner", "kind", "tokens", "n_prompt_pages",
-                 "first_token", "suffix", "start", "draft_from")
-
-    def __init__(self, owner: str, kind: str, tokens: List[int],
-                 n_prompt_pages: int, first_token: Optional[int] = None,
-                 suffix: Optional[List[int]] = None, start: int = 0):
-        self.owner = owner
-        self.kind = kind                    # "cold" | "partial" | "full"
-        self.tokens = tokens                # prompt + emitted at attach
-        self.n_prompt_pages = n_prompt_pages
-        self.first_token = first_token      # full hits: stored token 0
-        self.suffix = suffix or []          # partial hits: the chunk
-        self.start = start                  # partial hits: suffix offset
-        self.draft_from = None              # drafter engines: first page
-        #                                     index under draft custody
-
-
-class PagedDecodeEngine(DecodeEngine):
-    """:class:`DecodeEngine` rebased onto the paged KV subsystem
-    (``serve.kvpage``): storage is ``[L, n_pages, page_sz, hidden]`` pages
-    (the layout the chip tiles exactly — ``models.decoder``'s paged-cache
-    note), a per-stream page table drives every program's page reads, and
-    capacity is PAGES, not slots: slots become pure decode-batch rows while
-    ``--kv_hbm_mb`` caps the page pool, so short streams stop paying for
-    ``max_len`` stripes and admitted concurrency scales with what streams
-    actually use.
-
-    The pool stays where it lies: every paged program
-    (``models.decoder.paged_attend_layers`` behind ``paged_decode_step`` /
-    ``paged_chunk_step`` / ``paged_verify_step``, and ``paged_insert``)
-    takes both pools donated, writes rows or whole pages in place and
-    reads whole pages through the table.  The decode step attends over a
-    RUNG of page counts (:attr:`decode_rungs`, quarters of a stream's
-    pages) chosen per step by the longest row: fixed-shape programs, one
-    per rung, all traced in :meth:`warmup_decode`.
-
-    Prefix sharing rides the :class:`~pdnlp_tpu.serve.kvpage.PrefixIndex`:
-    a repeated prompt maps the indexed pages at refcount+1 and skips its
-    prefill entirely (**full hit** — the stored first token is emitted
-    straight from the index, so TTFT is bounded by one decode-step
-    latency); a shared-prefix prompt maps the matching full pages and
-    runs only the divergent suffix (**partial hit** —
-    ``paged_chunk_step``); copy-on-write duplicates a full hit's trailing
-    partial page before the stream writes into it.  Full pages are
-    immutable once written, which is what makes sharing safe without
-    copies.
-
-    Parity contract: a COLD paged stream runs the exact slot-engine
-    prefill program and a decode step of the same mathematics over the
-    same values at every visible position, summed over a shorter extent —
-    TOKEN-identical continuations, not bitwise-equal logits (the bench
-    storm gates paged-vs-slot equality stream by stream).  Shared-prefix
-    streams reuse K/V that is bitwise what their own prefill would have
-    produced (same program, same inputs), so greedy continuations match
-    the cold baseline the same way re-prefilled kill survivors always
-    have.
-
-    Pages replicate on a mesh (no ``NamedSharding`` axis): the page ->
-    stream mapping is dynamic, so there is no static batch axis to shard
-    the way slot stripes sharded; decode pools run per-replica meshes,
-    which keeps each pool device-local anyway."""
-
-    paged = True
-    #: the cache: one ``[L, n_pages, page_sz, width]`` array per pool of
-    #: the family (``models.families``), every one donated to each program
-    _pools: tuple = ()
-    #: fixed copy-on-write batch rows — one compiled ``copy_pages``
-    #: program per engine; unused rows ride the OOB sentinel
-    COW_ROWS = 4
-    #: rungs of the decode step's attention extent (quarters of a stream's
-    #: pages): each is one compiled program, all traced in warmup
-    DECODE_RUNGS = 4
-
-    def __init__(self, args, tokenizer=None, *, mesh=None, metrics=None,
-                 tracer=None, slots: Optional[int] = None,
-                 max_len: Optional[int] = None,
-                 buckets: Sequence[int] = DEFAULT_BUCKETS,
-                 prefill_rows: Optional[int] = None,
-                 page_sz: Optional[int] = None, prefix_share: bool = True,
-                 index_entries: int = 4096):
-        # consumed by _resolve_capacity / _alloc_cache, which the base
-        # constructor calls — set before super().__init__
-        self._req_page_sz = int(page_sz
-                                or getattr(args, "kv_page_sz", 0) or 16)
-        self.prefix_share = bool(prefix_share)
-        self._index_entries = int(index_entries)
-        super().__init__(args, tokenizer, mesh=mesh, metrics=metrics,
-                         tracer=tracer, slots=slots, max_len=max_len,
-                         buckets=buckets, prefill_rows=prefill_rows)
-        cfg = self.cfg
-        dtype = self.dtype
-        metrics_ref = self.metrics
-
-        family = self.family
-
-        # every program takes the cache as ONE tuple of pools (twin K and V
-        # pools, or the one latent pool: the family's), donated, and the
-        # int8 scale tables — none for a float cache — as trailing
-        # arguments; what a family counts per launch rides back as ``aux``
-        def _pinsert_fn(pools, news, flat_pos, *scales):
-            metrics_ref.retraces.inc()
-            return families.insert(pools, news, flat_pos, scales or None)
-
-        def _pdecode_fn(params, head, pools, tokens, table, pos, *scales):
-            metrics_ref.retraces.inc()
-            logits, aux, pools = family.attend(
-                params, head, cfg, tokens, pools, table, pos, None, "last",
-                scales or None, dtype)
-            return logits, greedy_ids(logits), aux, pools
-
-        def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
-                       *scales):
-            metrics_ref.retraces.inc()
-            logits, aux, pools = family.attend(
-                params, head, cfg, tokens, pools, table, start, nreal,
-                "last", scales or None, dtype)
-            return logits, greedy_ids(logits), aux, pools
-
-        def _pverify_fn(params, head, pools, tokens, table, start, nreal,
-                        *scales):
-            metrics_ref.retraces.inc()
-            return family.attend(params, head, cfg, tokens, pools, table,
-                                 start, nreal, "all", scales or None, dtype)
-
-        def _pcow_fn(pools, src, dst):
-            metrics_ref.retraces.inc()
-            return tuple(decoder.copy_pool(p, src, dst) for p in pools)
-
-        def _pexport_fn(pools, src):
-            metrics_ref.retraces.inc()
-            return tuple(decoder.gather_pool(p, src) for p in pools)
-
-        def _pimport_fn(pools, payloads, dst):
-            metrics_ref.retraces.inc()
-            return tuple(decoder.scatter_pool(p, x, dst)
-                         for p, x in zip(pools, payloads))
-
-        self._jit_pinsert = jax.jit(_pinsert_fn, donate_argnums=(0,))
-        self._jit_pdecode = jax.jit(_pdecode_fn, donate_argnums=(2,))
-        self._jit_pchunk = jax.jit(_pchunk_fn, donate_argnums=(2,))
-        self._jit_pverify = jax.jit(_pverify_fn, donate_argnums=(2,))
-        self._jit_pcow = jax.jit(_pcow_fn, donate_argnums=(0,))
-        # export reads the pool (no donation — the sender keeps serving
-        # from it); import donates like every other cache writer
-        self._jit_pexport = jax.jit(_pexport_fn)
-        self._jit_pimport = jax.jit(_pimport_fn, donate_argnums=(0,))
-
-    # the twin pools under the names every reader of a BERT engine knows
-    # (a one-pool family has no second)
-    @property
-    def _cache_k(self):
-        return self._pools[0] if self._pools else None
-
-    @_cache_k.setter
-    def _cache_k(self, value):
-        self._pools = (value,) + tuple(self._pools[1:])
-
-    @property
-    def _cache_v(self):
-        return self._pools[1] if len(self._pools) > 1 else None
-
-    @_cache_v.setter
-    def _cache_v(self, value):
-        if len(self._pools) > 1:
-            self._pools = (self._pools[0], value)
-
-    # --------------------------------------------------------- capacity
-    def _resolve_capacity(self, requested: int) -> int:
-        """Pages, not slots, are the budgeted unit: ``--kv_hbm_mb`` caps
-        the page pool (floor: one maximum-length stream) and the slot
-        count stays the requested batch width — admitted concurrency is
-        then bounded by what streams actually RESERVE, which is the
-        whole capacity story of paging."""
-        ps = max(1, min(self._req_page_sz, self.max_len))
-        self.page_sz = ps
-        self.pages_per_stream = pages_needed(self.max_len, ps)
-        # the decode step's attention extents, in pages: one warmed
-        # program per rung, chosen per step by the longest live row
-        mp = self.pages_per_stream
-        self.decode_rungs = sorted(
-            {-(-mp * i // self.DECODE_RUNGS)
-             for i in range(1, self.DECODE_RUNGS + 1)})
-        self.page_bytes = self.token_bytes * ps
-        req_pages = int(requested) * self.pages_per_stream
-        self.n_pages = self.budget.cap_pages(
-            req_pages, self.page_bytes, min_pages=self.pages_per_stream)
-        if self.n_pages < req_pages:
-            print(f"[serve.decode] kv_hbm_mb caps KV pages "
-                  f"{req_pages} -> {self.n_pages} "
-                  f"({self.page_bytes / 2**20:.2f} MB/page, "
-                  f"{self.pages_per_stream}/stream worst case)",
-                  file=sys.stderr)
-        m = self.rows_multiple
-        return max(m, (int(requested) // m) * m)
-
-    def _alloc_cache(self) -> None:
-        """(Re)allocate the page pool + a fresh allocator/index/table —
-        construction and post-chaos :meth:`reset_cache`, never hot."""
-        cfg = self.cfg
-
-        def alloc(width):
-            # one position's values of one pool are ONE minor axis: [page_sz,
-            # width] is what the chip tiles (models.decoder, paged-cache
-            # note); SEPARATE buffers (donation aliasing — base note)
-            return jax.device_put(jnp.zeros(
-                (cfg.num_layers, self.n_pages, self.page_sz, width),
-                self.kv_dtype))
-
-        self._pools = tuple(alloc(w) for w in self.family.pool_widths(cfg))
-        self.allocator = PageAllocator(self.n_pages, self.page_sz,
-                                       self.page_bytes)
-        self.prefix = PrefixIndex(self.allocator, self.page_sz,
-                                  max_entries=self._index_entries)
-        if self.prefix_share:
-            self.allocator.reclaimer = self.prefix.evict
-        # per-slot page tables, host-resident and updated IN PLACE at
-        # attach/detach (never rebuilt per step — jaxlint R16 polices
-        # the rebuild-by-concatenate idiom); sentinel n_pages = dead row
-        self._table = np.full((self.slots, self.pages_per_stream),
-                              self.n_pages, np.int32)
-        self._slot_state: List[Optional[_PageClaim]] = [None] * self.slots
-        self._pending_cow: List[tuple] = []
-
-    # -------------------------------------------------------- admission
-    def check_stream_admissible(self, prompt_len: int,
-                                max_new: int) -> None:
-        """Base capacity rules, with the budgeted refusal in PAGE units
-        (the admission door the router quotes)."""
-        total = int(prompt_len) + int(max_new)
-        if max_new < 1:
-            raise ValueError("max_new_tokens must be >= 1")
-        if prompt_len > self.prompt_limit:
-            raise ValueError(
-                f"prompt of {prompt_len} tokens exceeds the "
-                f"{self.prompt_limit}-token prefill limit")
-        if total > self.max_len:
-            need = pages_needed(total, self.page_sz)
-            if self.budget.budget_bytes is not None:
-                from pdnlp_tpu.obs.memory import KVBudgetExceeded
-
-                raise KVBudgetExceeded(
-                    f"stream needs {need} KV pages ({total} positions, "
-                    f"{need * self.page_bytes / 2**20:.2f} MB) but a "
-                    f"stream's page table holds {self.pages_per_stream} "
-                    f"pages ({self.max_len} positions) under --kv_hbm_mb")
-            raise ValueError(
-                f"prompt + max_new_tokens = {total} exceeds the "
-                f"{self.max_len}-position page-table extent "
-                "(--decode_max_len)")
-
-    # ----------------------------------------------------- paging hooks
+    # ------------------------------------- a stream's pages, attach to detach
     def peek_prefix(self, ids: Sequence[int]) -> Optional[str]:
+        """Admission-time prefix peek for the ``admit`` hop's
+        ``prefix_hit`` attr (None = this engine does not share)."""
         if not self.prefix_share:
             return None
         return self.prefix.lookup(ids, count=False).kind
@@ -1109,6 +828,7 @@ class PagedDecodeEngine(DecodeEngine):
         return claim
 
     def detach_slot(self, slot: int) -> None:
+        """Release ``slot``'s page reservation."""
         if not (0 <= slot < self.slots):
             return
         st = self._slot_state[slot]
@@ -1197,14 +917,8 @@ class PagedDecodeEngine(DecodeEngine):
         else:
             src = np.full((self.pages_per_stream,), self.n_pages,
                           np.int32)
-        key = ("export", int(self.pages_per_stream))
-        if key in self._seen_shapes:
-            self.metrics.cache_hits.inc()
-            span_name = "handoff"
-        else:
-            self.metrics.cache_misses.inc()
-            self._seen_shapes.add(key)
-            span_name = "compile"
+        span_name = self._seen(("export", int(self.pages_per_stream)),
+                               "handoff")
         with self.tracer.span(span_name, export=True, paged=True,
                               pages=int(self.pages_per_stream),
                               **self._telemetry_attrs(request_ids),
@@ -1238,14 +952,8 @@ class PagedDecodeEngine(DecodeEngine):
         else:
             dst = np.full((self.pages_per_stream,), self.n_pages,
                           np.int32)
-        key = ("import", int(self.pages_per_stream))
-        if key in self._seen_shapes:
-            self.metrics.cache_hits.inc()
-            span_name = "handoff"
-        else:
-            self.metrics.cache_misses.inc()
-            self._seen_shapes.add(key)
-            span_name = "compile"
+        span_name = self._seen(("import", int(self.pages_per_stream)),
+                               "handoff")
         with self.tracer.span(span_name, import_=True, paged=True,
                               pages=int(self.pages_per_stream),
                               **self._telemetry_attrs(request_ids),
@@ -1293,6 +1001,7 @@ class PagedDecodeEngine(DecodeEngine):
         self.import_pages(self.slots, pk, pv)
 
     def register_slot(self, slot: int, first_token: int) -> None:
+        """Index ``slot``'s freshly prefilled prompt for later sharing."""
         if not self.prefix_share:
             return
         st = self._slot_state[slot] if 0 <= slot < self.slots else None
@@ -1304,8 +1013,8 @@ class PagedDecodeEngine(DecodeEngine):
 
     def leak_check(self) -> Dict:
         """Allocator ledger audit + who still holds pages — the chaos
-        tests and the bench storm call this after drain (every non-index
-        owner must be gone, the refcount ledger must reconcile)."""
+        tests call this after drain (every non-index owner must be gone,
+        the refcount ledger must reconcile)."""
         audit = self.allocator.leak_check()
         audit["stream_owners"] = [o for o in self.allocator.owners()
                                   if o != INDEX_OWNER]
@@ -1344,11 +1053,16 @@ class PagedDecodeEngine(DecodeEngine):
     def prefill_ids(self, id_lists: Sequence[Sequence[int]],
                     slot_ids: Sequence[int],
                     request_ids=None) -> Chosen:
-        """Cold-path prefill: the SAME bucketed causal forward as the
-        slot engine (bitwise-identical K/V for identical prompts — the
-        sharing contract rests on this), scattered into pages through
-        each claimed slot's table.  Filler rows and padding carry the
-        OOB sentinel, so they can never touch a live page."""
+        """Cold-path prefill of up to ``prefill_rows`` prompts into their
+        claimed slots: ONE bucketed causal forward whatever the slot
+        (bitwise-identical K/V for identical prompts — the sharing
+        contract rests on this), scattered into pages through each
+        claimed slot's table; returns each prompt's FIRST token (``.ids``,
+        host) over its ``[n, vocab]`` fp32 logits (on the device until
+        read: :class:`Chosen`).  Filler rows and padding carry the OOB
+        sentinel, so they can never touch a live page.  The compile-cache
+        key is ``(bucket, rows, "prefill")``; warmup pre-traces every
+        bucket so steady traffic never compiles."""
         self._flush_cow()
         n = len(id_lists)
         assert n and n <= self.prefill_rows
@@ -1438,8 +1152,13 @@ class PagedDecodeEngine(DecodeEngine):
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
                      live: int, request_ids=None) -> Chosen:
-        """One fixed-shape decode step over the slot block, reading whole
-        pages through the per-slot page tables.  The table is data, not
+        """One fixed-shape decode step over the whole slot block: tokens
+        ``[slots]`` (current token per slot; dead slots ride with junk),
+        ``pos`` ``[slots]`` write positions, reading whole pages through
+        the per-slot page tables.  Returns each slot's next token
+        (``.ids``: the ``slots x 4`` bytes the host fetches) over the
+        ``[slots, vocab]`` fp32 logits, which stay on the device until a
+        caller reads them (:class:`Chosen`).  The table is data, not
         shape; its WIDTH is the attention extent, cut to the smallest
         rung of :attr:`decode_rungs` that reaches the longest row — compile
         key ``("decode", slots, rung)``, every rung traced in warmup, so
@@ -1517,8 +1236,9 @@ class PagedDecodeEngine(DecodeEngine):
         fixed COW copy, and the int8 calibration if pending."""
         self._scale_args()
         for b in self.prefill_buckets:
+            # a bucket-FILLING dummy, so each bucket traces ITS shape;
             # OOB slot id: filler tables/flat sentinels — no live page
-            # is touched, exactly like the slot engine's warmup
+            # is touched
             self.prefill_ids([[self.tokenizer.cls_id] * b], [self.slots])
             self.prefill_chunk([[self.tokenizer.cls_id] * b],
                                [self.slots], [0])
@@ -1676,7 +1396,7 @@ class _Slot:
 
 
 class DecodeBatcher:
-    """Continuous batching over one :class:`DecodeEngine`: a single
+    """Continuous batching over one :class:`PagedDecodeEngine`: a single
     worker owns the engine (the repo's one-dispatcher contract) and loops
     claim → prefill → decode-step, with streams joining freed slots and
     finished streams leaving BETWEEN steps — the decode batch shape never
@@ -1690,19 +1410,19 @@ class DecodeBatcher:
     #: clamps inside it; ``0`` = speculation off)
     DRAFT_K_MAX = 8
 
-    def __init__(self, engine: DecodeEngine, *, max_waiting: int = 256,
+    def __init__(self, engine: PagedDecodeEngine, *, max_waiting: int = 256,
                  default_max_new: Optional[int] = None, replica: int = 0,
                  on_death: Optional[Callable] = None,
                  rmetrics: Optional[ReplicaMetrics] = None,
                  dmetrics: Optional[DecodeMetrics] = None,
-                 drafter: Optional[DecodeEngine] = None,
+                 drafter: Optional[PagedDecodeEngine] = None,
                  draft_k: int = 4):
         self.engine = engine
         self.tracer = engine.tracer
         self.replica = int(replica)
         engine.span_attrs.setdefault("replica", self.replica)
         # --- speculative decoding: a paired cheap drafter engine ---
-        self.drafter: Optional[DecodeEngine] = None
+        self.drafter: Optional[PagedDecodeEngine] = None
         self.drafter_model = ""
         self.draft_k = max(0, min(int(draft_k), self.DRAFT_K_MAX))
         self._drafter_poison: Optional[BaseException] = None
@@ -1715,12 +1435,6 @@ class DecodeBatcher:
                     e.family.refuse(
                         "the speculative pair (a drafter engine and its "
                         "verify window)", "decode with the primary alone")
-            if not (engine.paged and drafter.paged):
-                raise ValueError(
-                    "speculative decoding needs PAGED engines on both "
-                    "sides (--kv_layout paged): the verify commit and "
-                    "the draft-page custody both write through page "
-                    "tables")
             if (drafter.slots != engine.slots
                     or drafter.max_len != engine.max_len):
                 raise ValueError(
@@ -1820,7 +1534,7 @@ class DecodeBatcher:
         self.stop()
 
     def kill(self, error: Optional[BaseException] = None) -> None:
-        """Chaos hook (tests / ``bench.py --decode``): the worker raises
+        """Chaos hook (tests): the worker raises
         ``error`` before its next step — exactly the path a real engine
         failure takes."""
         with self._lock:
@@ -1910,8 +1624,6 @@ class DecodeBatcher:
         front door admitted it); ``False`` when this batcher cannot
         take it (dead/stopping), so the dispatcher tries the next
         decode engine — the payload is engine-agnostic."""
-        if not self.engine.paged:
-            return False  # handoff needs page custody on the receiver
         with self._lock:
             if self.dead or self._stop or self._worker is None:
                 return False
@@ -2025,8 +1737,8 @@ class DecodeBatcher:
             slot = self._free.popleft()
             stream = self._waiting.popleft()
             try:
-                # paged engines reserve the stream's pages here (sharing
-                # any indexed prefix); slot engines no-op.  Exhausted
+                # the engine reserves the stream's pages here (sharing
+                # any indexed prefix).  Exhausted
                 # pool = put both back and wait for live streams to
                 # drain — head-of-line order is preserved, and the pool
                 # floor (>= one max-length stream) guarantees an empty
@@ -2062,7 +1774,7 @@ class DecodeBatcher:
             stream.slot = slot
             if stream.seated_at is None:
                 stream.seated_at = now()
-                stream.prefix_hit = getattr(claim, "kind", None)
+                stream.prefix_hit = claim.kind
             # placeholder NOW: if the prefill below dies, the claimed
             # stream is already in _slots and the death path re-homes it
             # instead of losing it
@@ -2127,15 +1839,14 @@ class DecodeBatcher:
     def _prefill(self, claims: List[tuple]) -> None:
         """Prefill claimed streams and emit each stream's FIRST token.
 
-        Paged claims split three ways by prefix-hit kind: **full** hits
-        run NO forward at all — the index stored the prompt's first
-        greedy token, so it is emitted right here (``prefills_total``
-        does not move: the bench's zero-prefill gate is structural);
-        **partial** hits forward only the divergent suffix
-        (:meth:`PagedDecodeEngine.prefill_chunk`); **cold** claims (and
-        every slot-engine claim, whose attach hook returns ``None``)
-        take the classic bucketed prefill, chunked to the engine's fixed
-        prefill rows.  Every stream still records a ``prefill`` hop —
+        Claims split three ways by prefix-hit kind: **full** hits run NO
+        forward at all — the index stored the prompt's first greedy
+        token, so it is emitted right here (``prefills_total`` does not
+        move: a zero-prefill full hit is structural,
+        ``tests/test_kvpage.py``); **partial** hits forward only the
+        divergent suffix (:meth:`PagedDecodeEngine.prefill_chunk`);
+        **cold** claims take the classic bucketed prefill, chunked to the
+        engine's fixed prefill rows.  Every stream still records a ``prefill`` hop —
         the chain contract (no ``decode`` before ``prefill``) holds for
         hits too, with ``prefix_hit``/``cached_tokens`` telling the
         story."""
@@ -2163,12 +1874,9 @@ class DecodeBatcher:
                             slot, len(s.prompt_ids) + len(s.emitted))
             except BaseException as e:  # noqa: BLE001
                 self._degrade_drafter(e)
-        full = [c for c in claims
-                if c[2] is not None and c[2].kind == "full"]
-        part = [c for c in claims
-                if c[2] is not None and c[2].kind == "partial"]
-        cold = [c for c in claims
-                if c[2] is None or c[2].kind == "cold"]
+        full = [c for c in claims if c[2].kind == "full"]
+        part = [c for c in claims if c[2].kind == "partial"]
+        cold = [c for c in claims if c[2].kind == "cold"]
         tr, attrs = self.tracer, self.engine.span_attrs
         if full:
             # no engine call to follow: the index stored the first token
@@ -2195,9 +1903,8 @@ class DecodeBatcher:
                 toks = chosen_ids(first)
                 self._first_tokens(
                     [(slot, stream, toks[j], len(prompts[j]),
-                      {"tokens_in": len(prompts[j]),
-                       **({"prefix_hit": "miss"} if c is not None else {})})
-                     for j, (slot, stream, c) in enumerate(chunk)],
+                      {"tokens_in": len(prompts[j]), "prefix_hit": "miss"})
+                     for j, (slot, stream, _) in enumerate(chunk)],
                     bool(sp))
                 if sp:
                     sp.set(rows=len(chunk))
@@ -2483,7 +2190,7 @@ class DecodeBatcher:
                 pass               # engine may be the thing that died
 
     def kill_drafter(self, error: Optional[BaseException] = None) -> None:
-        """Chaos hook (tests / ``bench.py --decode``): the next
+        """Chaos hook (tests): the next
         speculation round sees the drafter raise — exactly the path a
         real drafter engine failure takes."""
         self._drafter_poison = error or RuntimeError(
@@ -2548,10 +2255,9 @@ class DecodeBatcher:
         if live_slots > self._peak_live:
             self._peak_live = live_slots
             self.metrics.peak_live_streams.set(live_slots)
-        if self.engine.paged:
-            alloc = self.engine.allocator
-            self.metrics.kv_pages_live.set(alloc.used_pages)
-            self.metrics.kv_pages_free.set(alloc.free_pages)
+        alloc = self.engine.allocator
+        self.metrics.kv_pages_live.set(alloc.used_pages)
+        self.metrics.kv_pages_free.set(alloc.free_pages)
 
     def _die(self, error: BaseException) -> None:
         """Worker death: collect every stream this replica owes an answer
@@ -2605,7 +2311,7 @@ class DecodeBatcher:
 
 class PrefillWorker:
     """Prefill-role half of a disaggregated pool: one worker owns one
-    PAGED engine and runs ONLY the prefill phase — bucketed cold
+    engine and runs ONLY the prefill phase — bucketed cold
     forwards, prefix full/partial hits, chunked suffixes — then moves
     each stream's pages to a decode-role engine through the KV handoff.
     Decode-role engines never see a prefill after warmup, so a prefill
@@ -2627,16 +2333,12 @@ class PrefillWorker:
     completes right here and never hands off — same ``complete``
     semantics as the interleaved batcher's prefill-time finish."""
 
-    def __init__(self, engine: DecodeEngine, *,
+    def __init__(self, engine: PagedDecodeEngine, *,
                  dispatch: Callable, max_waiting: int = 256,
                  default_max_new: Optional[int] = None, replica: int = 0,
                  on_death: Optional[Callable] = None,
                  rmetrics: Optional[ReplicaMetrics] = None,
                  dmetrics: Optional[DecodeMetrics] = None):
-        if not engine.paged:
-            raise ValueError(
-                "disaggregated prefill needs a PAGED engine "
-                "(--kv_layout paged): the handoff exports page custody")
         engine.require_handoff()
         self.engine = engine
         self.tracer = engine.tracer
@@ -3069,16 +2771,16 @@ class DecodeRouter:
     and on a replica death the orphan streams RE-PREFILL on survivors
     from ``prompt + emitted`` — greedy decode is deterministic, so the
     continuation yields exactly the tokens the dead replica would have
-    produced (the ``--decode`` bench gates no-duplicate/no-loss through a
-    mid-storm kill).  Deliberately lean next to :class:`ReplicaRouter`:
+    produced (no duplicate, no loss through a mid-decode kill:
+    ``tests/test_decode.py``).  Deliberately lean next to :class:`ReplicaRouter`:
     decode streams are long-lived and slot-bound, so health is the
     worker's own liveness (an engine failure IS the worker dying), not a
     heartbeat sidecar."""
 
-    def __init__(self, engines: Sequence[DecodeEngine], *,
+    def __init__(self, engines: Sequence[PagedDecodeEngine], *,
                  max_waiting: int = 256,
                  default_max_new: Optional[int] = None,
-                 drafters: Optional[Sequence[DecodeEngine]] = None,
+                 drafters: Optional[Sequence[PagedDecodeEngine]] = None,
                  draft_k: int = 4):
         assert engines
         self.tracer = engines[0].tracer
@@ -3108,7 +2810,7 @@ class DecodeRouter:
         for b in self.batchers:
             b.stop(drain=drain)
 
-    def engine(self, i: int = 0) -> DecodeEngine:
+    def engine(self, i: int = 0) -> PagedDecodeEngine:
         return self.batchers[i].engine
 
     def alive(self) -> List[DecodeBatcher]:
@@ -3227,7 +2929,7 @@ class DecodeRouter:
             kv = b.engine.kv_snapshot()
             rep: Dict = {"alive": int(not b.dead), "load": b.load,
                          "peak_live_streams": b._peak_live,
-                         "layout": kv.get("layout", "slots")}
+                         "layout": kv["layout"]}
             if b.drafter is not None or b._spec_rounds:
                 sp = b.spec_snapshot()
                 rep["speculation"] = sp
@@ -3298,9 +3000,9 @@ class DisaggDecodeRouter:
     continuation is bitwise unchanged).  Engines keep their jit caches
     across re-roles and :meth:`warmup` pre-traces EVERY program on
     EVERY engine, so neither a re-role nor a handoff ever compiles
-    post-warmup — the bench's zero-retrace gate covers both pools."""
+    post-warmup (``tests/test_disagg.py`` counts retraces in both pools)."""
 
-    def __init__(self, engines: Sequence[DecodeEngine], *,
+    def __init__(self, engines: Sequence[PagedDecodeEngine], *,
                  prefill_engines: int = 1, max_waiting: int = 256,
                  default_max_new: Optional[int] = None,
                  transport: str = "local"):
@@ -3308,12 +3010,6 @@ class DisaggDecodeRouter:
             raise ValueError(
                 "disaggregated serving needs >= 2 engines (at least "
                 "one per role); use DecodeRouter for a single engine")
-        for e in engines:
-            if not e.paged:
-                raise ValueError(
-                    "disaggregated serving needs PAGED engines "
-                    "(--kv_layout paged): the handoff moves page "
-                    "custody between allocators")
         if transport not in ("local", "socket"):
             raise ValueError(f"unknown handoff transport {transport!r}")
         self.engines = list(engines)
@@ -3480,7 +3176,7 @@ class DisaggDecodeRouter:
         for srv in servers:
             srv.stop()
 
-    def engine(self, i: int = 0) -> DecodeEngine:
+    def engine(self, i: int = 0) -> PagedDecodeEngine:
         return self.engines[i]
 
     def alive(self) -> List[object]:

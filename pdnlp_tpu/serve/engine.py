@@ -89,8 +89,8 @@ class InferenceEngine:
         else:  # int8 weights compute against bf16 activations
             self.dtype = resolve_dtype("bfloat16")
         # the impl the jitted forward routes to at the engine's max width
-        # (deterministic serve: no dropout) — the headline the bench JSONs
-        # report.  Routing is PER BUCKET WIDTH (sub-128 buckets fall back
+        # (deterministic serve: no dropout) — the headline a snapshot
+        # reports.  Routing is PER BUCKET WIDTH (sub-128 buckets fall back
         # to XLA), so spans stamp :meth:`routed_attn` of their actual seq,
         # never this attribute.
         from pdnlp_tpu.ops.attention import (
@@ -415,8 +415,8 @@ class InferenceEngine:
     @property
     def attn_impl_by_seq(self) -> Dict[int, str]:
         """{bucket width: routed impl} for every width this engine has
-        routed so far — the honest per-bucket adoption record the bench
-        JSONs embed alongside the max-width headline."""
+        routed so far — the honest per-bucket adoption record beside the
+        max-width headline."""
         return dict(self._impl_by_seq)
 
     @property

@@ -2,7 +2,7 @@
 
 Disaggregated serving (prefill-role vs decode-role engine pools) needs to
 move one stream's KV pages between engines.  Same-host the payload is a
-pair of device arrays (``models.decoder.gather_pages`` output) handed
+pair of device arrays (``models.decoder.gather_pool`` output) handed
 straight to the importing engine; cross-pool it crosses the repo's first
 real RPC boundary — this module's thin stdlib-socket transport, modeled
 on ``obs/exporter.py``'s stdlib-server idiom (no framework, no new

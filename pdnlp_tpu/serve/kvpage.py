@@ -1,8 +1,9 @@
 """Paged KV memory: page allocator + refcounted cross-request prefix index.
 
-The slot cache (PR 14) charged every stream a full ``max_len`` stripe and
-stored two identical system prompts twice.  This module is the memory
-half of the paged rebase (the math half is ``models.decoder``'s
+A cache of ``max_len`` stripes, a stream each (the slot layout, PR 14-28),
+charges every stream its worst case and stores two identical system
+prompts twice.  This module is the memory half of the paged cache (the
+math half is ``models.decoder``'s
 ``paged_*`` programs; the serving half is ``serve.decode``'s
 ``PagedDecodeEngine``):
 
@@ -16,7 +17,7 @@ half of the paged rebase (the math half is ``models.decoder``'s
   page has one refcount; a stream's claim increments it, completion/kill
   decrements it, and a page returns to the free list exactly when its
   count reaches zero.  Per-owner accounting makes :meth:`leak_check` a
-  real audit (the chaos tests and the bench storm call it after drain),
+  real audit (the chaos tests call it after drain),
   and exhaustion is a LOUD :class:`KVPagesExhausted` with the page math
   — never an OOM three layers deep.
 - **:class:`PrefixIndex`**: page-granularity prefix sharing.  Every FULL
@@ -29,7 +30,7 @@ half of the paged rebase (the math half is ``models.decoder``'s
   matching full pages and run only the divergent suffix
   (``decoder.paged_chunk_step``).  Copy-on-write: a full hit whose last
   page is partial copies THAT page before the stream writes into it
-  (``decoder.copy_pages``); full pages are immutable once written, so
+  (``decoder.copy_pool``); full pages are immutable once written, so
   they share without copying.
 - **eviction**: the index holds its own reference on every registered
   page, so a "cached" prompt's pages survive the stream that computed
@@ -39,7 +40,7 @@ half of the paged rebase (the math half is ``models.decoder``'s
   streams still hold can be dropped too (they just stop being
   shareable).  Evictions are counted and surfaced, never silent.
 
-``snapshot()`` blocks ride ``DecodeEngine.kv_snapshot`` ->
+``snapshot()`` blocks ride ``PagedDecodeEngine.kv_snapshot`` ->
 ``router.snapshot()``/``control_snapshot()`` -> the Prometheus exporter,
 so page occupancy, free-list depth, prefix-hit rate and copy-on-write
 counts are one scrape away.
@@ -299,8 +300,7 @@ class PageAllocator:
         must cover the pool.  ``leaked_pages`` counts pages that are
         unreachable (nonzero refcount with NO owner holding them) —
         after a drained storm releases every stream and the index is
-        cleared, it must be 0.  Called by the chaos tests and the bench
-        storm gate."""
+        cleared, it must be 0.  Called by the chaos tests."""
         with self._lock:
             held = Counter()
             for owned in self._owned.values():

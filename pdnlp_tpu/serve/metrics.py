@@ -1,9 +1,8 @@
 """Serving observability: one object the engine/batcher/offline paths share.
 
 Built from the primitives in ``pdnlp_tpu.utils.metrics`` (Counter / Gauge /
-Histogram).  ``snapshot()`` returns a plain-JSON dict in the same artifact
-style as the training results under ``results/`` — ``bench.py --serve``
-writes one as ``results/serve_smoke.json``.
+Histogram).  ``snapshot()`` returns a plain-JSON dict (``serve_tpu.py
+--metrics_path`` writes one).
 
 What each instrument answers:
 
@@ -34,7 +33,7 @@ The multi-replica router adds :class:`RouterMetrics` (pool-level: per-tier
 admission counts, requeues/retries/hedges, ejections, swap + recovery
 accounting) and :class:`ReplicaMetrics` (replica-labelled queue depth,
 occupancy, requeue/retry/ejection counters) — composed by
-``ReplicaRouter.snapshot()`` into the ``bench.py --serve-load`` report.
+``ReplicaRouter.snapshot()``.
 """
 from __future__ import annotations
 
@@ -84,7 +83,7 @@ class ServeMetrics:
         }
 
     def save(self, path: str) -> None:
-        """Atomic JSON dump (the ``results/`` artifact convention)."""
+        """Atomic JSON dump."""
         _save_json(self.snapshot(), path)
 
 
@@ -168,23 +167,22 @@ class DecodeMetrics:
     - ``prefills_total`` / ``prefill_tokens_total`` — bucketed prompt
       forwards and the prompt tokens they consumed;
     - ``decode_steps_total`` / ``tokens_out_total`` — fixed-shape decode
-      dispatches and the tokens they produced (tokens/s/chip = the bench
-      headline);
+      dispatches and the tokens they produced (tokens/s/chip: the benchmark's
+      ``decode_tokens_per_s`` is taken by its own load generator);
     - ``ttft_ms`` — submit -> first token (the prefill-visible latency);
     - ``intertoken_ms`` — gap between consecutive tokens of one stream
-      (p99 is the streaming SLO ``bench.py --decode`` gates);
+      (the benchmark's ``itl_p90_ms`` is taken by its own load generator);
     - ``waiting`` — streams queued for a free slot;
     - ``kv_bytes_live`` / ``kv_slots_live`` — live KV occupancy (the
       ``--kv_hbm_mb`` budget gauge on ``/metrics``);
-    - ``kv_pages_live`` / ``kv_pages_free`` — paged layout only: page
+    - ``kv_pages_live`` / ``kv_pages_free`` — page
       pool occupancy and free-list depth (allocator/index detail rides
       ``kv_snapshot()``/``control_snapshot()``);
     - ``peak_live_streams`` — high-water concurrent live streams (the
-      admitted-concurrency headline the paged-vs-slot bench gates).
+      admitted-concurrency headline).
 
     Speculative decoding (draft-k / verify-1) adds its acceptance
-    accounting — the live signal the controller's ``draft_k`` law and
-    the bench's speedup gate both read:
+    accounting — the live signal the controller's ``draft_k`` law reads:
 
     - ``draft_tokens_total`` / ``accepted_tokens_total`` — tokens the
       cheap drafter proposed / tokens the primary's verify call kept
@@ -206,7 +204,7 @@ class DecodeMetrics:
     - ``handoff_failures_total`` — dispatches no decode engine took
       (each one re-prefilled at the sender: recovery, not loss);
     - ``handoff_ms`` — export→ack latency per handoff (the
-      disaggregation tax ``bench.py --decode`` phase F budgets).
+      disaggregation tax; no cell measures it yet).
     """
 
     def __init__(self) -> None:
@@ -319,8 +317,8 @@ class RouterMetrics:
     """Pool-level router observability: admission tiers, failure handling,
     and the recovery loop.  Per-tier shed accounting
     (``admission`` block: backpressure waits / sheds / hard rejects) is
-    what the ``bench.py --serve-load`` report gates on — "tiered shedding
-    engaged" must be a recorded number, not an inference."""
+    what ``tests/test_router.py`` reads — "tiered shedding engaged" must be
+    a recorded number, not an inference."""
 
     def __init__(self) -> None:
         self.requests_total = Counter()

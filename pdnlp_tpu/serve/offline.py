@@ -4,8 +4,8 @@ The online batcher optimizes tail latency; this path optimizes throughput
 over a corpus that is fully known up front.  Same bucketing, no queueing:
 texts are encoded ragged, grouped by covering bucket, chunked into
 fixed-shape batches, and results are re-assembled in input order — so it is
-deterministic, which makes it the parity surface ``tests/test_serve.py`` and
-``bench.py --serve`` drive (and a useful tool in its own right:
+deterministic, which makes it the parity surface ``tests/test_serve.py``
+drives (and a useful tool in its own right:
 ``serve_tpu.py --input file.txt``).
 """
 from __future__ import annotations

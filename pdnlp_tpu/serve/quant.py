@@ -19,7 +19,7 @@ whose routing is precision-sensitive) stay fp32.
 Calibration is weight-only (symmetric max per output channel) — no
 activation statistics needed, so ``scripts/quantize_ckpt.py`` can produce
 the artifact offline from any committed checkpoint.  Accuracy parity is
-gated in ``bench.py --kernels`` and pinned in ``tests/test_kernels.py``.
+pinned in ``tests/test_kernels.py``.
 """
 from __future__ import annotations
 

@@ -27,8 +27,7 @@ closes the loop:
 - :func:`replay` — drive a schedule through any ``submit_ids``-shaped
   callable open-loop (arrivals happen when the schedule says, whether or
   not the pool is keeping up — that is the point), collecting per-request
-  outcomes and the goodput/latency numbers the ``bench.py --replay``
-  frontier gate compares.
+  outcomes and goodput/latency numbers.
 
 Everything is stdlib + injectable clocks; nothing here imports jax.
 """
@@ -204,7 +203,7 @@ def replay(submit_ids: Callable, schedule: Sequence[Arrival], *,
     slips on a loaded host are measured into ``max_lag_s``, never
     silently absorbed), futures are resolved at the end, and the report
     carries the outcome split + goodput.  ``on_tick(i)`` (optional) runs
-    before arrival ``i`` — the bench's kill/injection hook."""
+    before arrival ``i`` — a kill/injection hook."""
     from pdnlp_tpu.serve.batcher import (
         DeadlineExceeded, LoadShedError, QueueFullError,
     )

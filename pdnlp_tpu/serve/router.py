@@ -1095,7 +1095,7 @@ class ReplicaRouter:
 
     # ------------------------------------------------------------ recovery
     def kill_replica(self, index: int, kind: str = "crash") -> None:
-        """Chaos hook (tests, ``bench.py --serve-load``): make replica
+        """Chaos hook (tests): make replica
         ``index`` die like a SIGKILL'd process (``crash``: worker dies,
         beats stop) or wedge like a stuck device stream (``hang``: worker
         holds its batch, beats stop)."""
@@ -1509,8 +1509,7 @@ class ReplicaRouter:
 
     def snapshot(self) -> Dict:
         """Router + per-replica metrics (incl. each replica's device-slice
-        HBM state), JSON-ready (the ``results/serve_load_smoke.json``
-        building block and the live exporter's ``serve`` source)."""
+        HBM state), JSON-ready (the live exporter's ``serve`` source)."""
         def replica_memory(s: _Slot):
             fn = getattr(s.replica.engine, "memory_snapshot", None) \
                 if s.replica else None

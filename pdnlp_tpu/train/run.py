@@ -60,7 +60,7 @@ def build_parallel_trainer(
     if not explicit_collectives:
         # the jit strategies let GSPMD partition the step, which Mosaic
         # kernels cannot follow; pinned HERE so the steps, the Trainer's
-        # surfaced impl and the bench JSON all read the same args
+        # surfaced impl and a run's report all read the same args
         from pdnlp_tpu.ops.attention import pin_auto_for_mesh
 
         args = args.replace(

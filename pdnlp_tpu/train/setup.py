@@ -64,8 +64,8 @@ def setup_data(args, *, num_shards: int = 1, shard_id: int = 0,
 def build_length_train_loader(args, train, col, train_enc, *, batch_size,
                               num_shards: int = 1, shard_id: int = 0):
     """The train ``DataLoader`` under ``--length_mode`` — ONE place, shared
-    by ``setup_data`` and ``bench.py --length``, so the mode wiring cannot
-    drift between the entrypoints and the smoke that measures them.
+    by ``setup_data`` and ``tests/test_length.py``, so the mode wiring cannot
+    drift between the entrypoints and the tests that hold them.
 
     - ``full``: the reference path — seeded shard sampler, every batch
       padded to ``max_seq_len``.

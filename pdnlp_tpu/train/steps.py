@@ -179,7 +179,7 @@ def build_train_step(cfg: BertConfig, tx: optax.GradientTransformation, args,
             # chains) moves half the bytes.  The mu/nu ACCUMULATORS stay
             # fp32, but each increment is computed from the bf16 grad (nu's
             # g**2 squares in bf16) — measured NEUTRAL to -6% on v5e and
-            # non-default for that reason (results/profile_r05.json).
+            # non-default for that reason (record removed, not re-measured).
             params = cast_kernels(params, dtype)
         (_, (loss, correct)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch, rng
@@ -234,7 +234,7 @@ def build_multi_step(step_fn: Callable) -> Callable:
     v5e (BERT-base, batch 32; record removed, not re-measured on this
     code): scan-carried weights cost ~6% device-step speed (XLA loses some
     layout freedom), bought back wherever per-step dispatch is the larger
-    term, which is why ``bench.py`` ships ``fuse_steps=4``.  Where dispatch
+    term, which is why the benchmark's recipe sets ``fuse_steps=4``.  Where dispatch
     is cheap ``fuse_steps=1`` may be marginally faster — a chip cell has to
     say.
     """

@@ -102,8 +102,7 @@ class Trainer:
 
         self.tracer = tracer if tracer is not None \
             else _trace.configure_from_args(args)
-        # per-phase mean/p50/p95 of the LAST train() call (None untraced) —
-        # bench.py --trace embeds it in its JSON
+        # per-phase mean/p50/p95 of the LAST train() call (None untraced)
         self.trace_summary = None
         self.best_accuracy = 0.0
         self._best_params = None  # device-held copy; written once at end
@@ -121,7 +120,7 @@ class Trainer:
         # manifest meta of the snapshot load_resume restored (None = fresh)
         self._restored_meta = None
         # (minutes-since-train-start, dev accuracy) per in-loop eval: the
-        # time-to-accuracy record bench.py reports (minutes_to_target)
+        # time-to-accuracy record (minutes to a target accuracy)
         self.eval_history: list = []
         self._t0: Optional[float] = None
         # device-resident eval batches, keyed by loader identity (the held
@@ -163,7 +162,7 @@ class Trainer:
     # -------------------------------------------------- warmup / probe
     def warmup_compile(self, train_loader, dev_loader=None) -> None:
         """AOT-compile the step programs before the timed epoch (the
-        warm-CUDA-context analog; ``bench.py`` does the same inline).
+        warm-CUDA-context analog).
         Steps without ``.lower`` (the lazily-built shard_map pipelines)
         compile on their first real call instead — cheap under a warmed
         persistent compile cache.  ``dev_loader`` supplies the eval step's
@@ -482,7 +481,7 @@ class Trainer:
                     # counter advances K at a time, so when K does not divide
                     # eval_step the eval lands up to K-1 steps late (count per
                     # epoch preserved).  Pick eval_step divisible by fuse_steps
-                    # (bench.py: 48 under K=4) for exact reference cadence;
+                    # (48 under K=4) for exact reference cadence;
                     # AutoTrainer instead rejects non-divisible combinations.
                     if dev_loader is not None and args.dev and \
                             gstep // args.eval_step != prev // args.eval_step:

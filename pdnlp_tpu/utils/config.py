@@ -81,8 +81,7 @@ class Args:
                                                   # dtype — measured NEUTRAL
                                                   # to -6% on v5e (XLA re-fuses
                                                   # the assembly worse); kept
-                                                  # for A/B (results/
-                                                  # profile_r05.json)
+                                                  # for A/B (record removed)
     rng_impl: str = "rbg"                         # dropout PRNG (utils.seeding.train_key)
     strategy: str = "single"                      # single|pmap|dp|shardmap|zero|...
     mode: str = "dp"                              # spawn launcher sharding mode:
@@ -172,8 +171,7 @@ class Args:
                                                   # bucket/pack change batch
                                                   # COMPOSITION (not per-
                                                   # example math), so they
-                                                  # are opt-in; bench.py
-                                                  # --length measures the win
+                                                  # are opt-in
     length_buckets: str = "32,64,128"             # bucket widths; values over
                                                   # max_seq_len are dropped
                                                   # and max_seq_len is always
@@ -217,21 +215,15 @@ class Args:
     kv_hbm_mb: float = 0.0                        # declared KV-cache HBM
                                                   # budget per decode
                                                   # engine (obs.memory.
-                                                  # KVBudget): caps slots
-                                                  # (slot layout) or pages
-                                                  # (paged layout) at
+                                                  # KVBudget): caps pages at
                                                   # construction, loud
                                                   # refusal (never OOM) at
                                                   # admission; 0 = off
-    kv_layout: str = "paged"                      # decode KV cache layout:
-                                                  # paged (page allocator +
-                                                  # refcounted prefix
-                                                  # sharing, serve/kvpage.
-                                                  # py) | slots (the PR-14
-                                                  # per-stream stripes —
-                                                  # kept as the capacity/
-                                                  # parity baseline)
-    kv_page_sz: int = 16                          # paged layout: KV
+    kv_layout: str = "paged"                      # one value: the benchmark
+                                                  # still passes it (ROADMAP,
+                                                  # Design); anything else is
+                                                  # refused in __post_init__
+    kv_page_sz: int = 16                          # KV
                                                   # positions per page (the
                                                   # sharing granularity —
                                                   # prefixes share in whole
@@ -258,7 +250,6 @@ class Args:
                                                   # regression detector;
                                                   # off by default, <2%
                                                   # steps/s when on
-                                                  # (bench.py --trace)
     trace_dir: Optional[str] = None               # span files (trace_proc
                                                   # <i>.jsonl); default
                                                   # <output_dir>/trace
@@ -287,7 +278,6 @@ class Args:
     probe_steps: int = 0                          # N re-fed steps probed
                                                   # before the epoch; prints
                                                   # the controlled steps/s
-                                                  # (run_matrix's probe col)
 
     # --- multi-host runtime (NCCL/TCPStore rendezvous analog) ---
     coordinator_address: Optional[str] = None     # e.g. "localhost:12345"
@@ -324,6 +314,13 @@ class Args:
     restart_backoff: float = 1.0                  # seconds before restart 1;
                                                   # doubles per restart
     restart_backoff_cap: float = 30.0             # exponential backoff ceiling
+
+    def __post_init__(self) -> None:
+        if self.kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={self.kv_layout!r}: the slot KV layout is gone "
+                "(PR 29); the decode cache is paged, and 'paged' is the "
+                "only value this field takes")
 
     def replace(self, **kw) -> "Args":
         return dataclasses.replace(self, **kw)
@@ -412,8 +409,7 @@ def enable_compilation_cache() -> str:
 
 def pop_cli_flag(argv, name: str, default=None, cast=str):
     """``(argv_without_the_pair, value)`` for a script-local ``--name value``
-    flag that is NOT an ``Args`` field — shared by ``serve_tpu.py`` and
-    ``bench.py --serve`` so the extraction behavior can't drift.  The
+    flag that is NOT an ``Args`` field (``serve_tpu.py``'s).  The
     returned argv is a new list; the input is not mutated."""
     argv = list(argv)
     if name in argv:
@@ -439,4 +435,7 @@ def parse_cli(argv=None, base: Optional[Args] = None) -> Args:
                    help="alias for --attention_impl (auto|xla|pallas)")
     ns = p.parse_args(argv)
     enable_compilation_cache()
-    return Args(**vars(ns))
+    try:
+        return Args(**vars(ns))
+    except ValueError as e:  # a field's one-sentence refusal (__post_init__)
+        p.error(str(e))

@@ -16,8 +16,8 @@ Two halves:
   pipeline (``pdnlp_tpu.data.pipeline``): bytes uploaded (split into
   steady-state in-loop uploads vs amortized one-time/epoch uploads),
   put-wait seconds, padding-waste ratio, and the prefetch in-flight
-  high-water mark.  ``bench.py --pipeline`` snapshots these so the
-  zero-transport claim of the device-resident mode is measured, not
+  high-water mark.  ``tests/test_pipeline.py`` reads these so the
+  zero-transport claim of the device-resident mode is counted, not
   asserted.
 """
 from __future__ import annotations
@@ -105,7 +105,7 @@ class Histogram:
         ``/metrics`` scrape reads p50/p95/p99 of five histograms per
         tick, and converting the 8k-observation window per percentile
         (3x per histogram) was measurable GIL/lock pressure against the
-        serve worker (``bench.py --telemetry``)."""
+        serve worker."""
         with self._lock:
             if not self._recent:
                 return None
@@ -240,7 +240,7 @@ class TransportStats:
         return 1.0 - self.tokens_real / self.tokens if self.tokens else 0.0
 
     def snapshot(self) -> Dict[str, object]:
-        """JSON-ready summary (the bench's ``transport`` block)."""
+        """JSON-ready summary (a report's ``transport`` block)."""
         with self._lock:
             snap = {
                 "mode": self.mode,

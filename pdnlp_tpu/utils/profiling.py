@@ -10,6 +10,9 @@ The reference's only timing is a wall-clock print around the epoch loop
   trace shows steady state, not compilation.
 - ``StepStats`` turns the epoch wall-clock into the derived rates the
   reference's README table reports informally (steps/s, examples/s).
+- ``bf16_peak`` is the chip's bf16 peak by device kind, for a program that
+  reports its own MFU (``chip_smoke.py``; the benchmark keeps its own
+  ``benchmark/peaks.json`` and reads nothing here).
 """
 from __future__ import annotations
 
@@ -17,6 +20,25 @@ import dataclasses
 from typing import Optional
 
 from pdnlp_tpu.utils.logging import rank0_print
+
+#: per-chip bf16 peak FLOP/s by device kind (prefix-matched); MFU is only
+#: reported when the running chip is recognized
+BF16_PEAK_BY_KIND = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,    # v5e
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,    # v6e / Trillium
+    "TPU v6e": 918e12,
+}
+
+
+def bf16_peak(device) -> Optional[float]:
+    kind = getattr(device, "device_kind", "")
+    for prefix, peak in BF16_PEAK_BY_KIND.items():
+        if kind.startswith(prefix):
+            return peak
+    return None
 
 
 class Profiler:

@@ -3,9 +3,8 @@
 Every sweep script takes name tokens on the CLI to re-run a subset of its
 grid.  Plain substring matching has a real failure mode in these grids:
 ``b64_lr6e-05_ema0.99_3ep`` is a SUBSTRING of its ``tanh_...`` sibling, so
-selecting the erf row silently re-ran the tanh row's chip time too (ADVICE
-round-5 item 1).  The fix, applied first in ``scripts/bench_longcontext.py``
-and ``scripts/sweep_b64.py`` and now shared by every sweep via this module:
+selecting the erf row silently re-ran the tanh row's chip time too.  The
+fix, shared by every sweep script via this module:
 
 - a token that EXACTLY names a grid row selects only that row;
 - substring matching applies only to tokens that are NOT themselves grid

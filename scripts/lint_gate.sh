@@ -1,6 +1,5 @@
 #!/usr/bin/env bash
-# jaxlint gate — the documented pre-push step (and what bench.py's smokes
-# re-check before burning accelerator time).
+# jaxlint gate — the documented pre-push step.
 #
 # Runs ALL suites (tracing R* + concurrency T* + lifecycle L*) over the
 # repo's standard hazard surface, enforces the committed count-based baseline

@@ -20,7 +20,7 @@ channel) symmetric scales from the SEEDED synthetic causal forward in
 the engine runs when self-calibrating at warmup, so the offline artifact
 and the online fallback can never disagree.  The tables land beside the
 INPUT checkpoint as ``<stem>.kvscales.msgpack`` through the same
-crash-atomic manifest-verified publish, and ``DecodeEngine`` auto-loads
+crash-atomic manifest-verified publish, and ``PagedDecodeEngine`` auto-loads
 them when the checkpoint swaps in.  The decoder's LM head is MLM-shaped
 (its ``transform`` dense block): pointing this script at a saved head
 artifact quantizes it through the identical per-channel path.

@@ -2,7 +2,7 @@
 """Accuracy sweep for the batch-64 headline recipe (r5).
 
 Batch 64 amortizes the step's fixed optimizer cost (+36% examples/s,
-~49% MFU — results/profile_r05.json); this sweeps lr x ema_decay x epochs
+~49% MFU; record removed); this sweeps lr x ema_decay x epochs
 at that batch from the two-phase pretrain warm start and records the full
 in-loop eval history so time-to-accuracy can be read per config.
 
@@ -89,8 +89,8 @@ def main():
     # tanh round: the fully tanh-pretrained trunk (pretrained-tanh.msgpack)
     # shifted the optimum — a single COMPRESSED-schedule epoch measured
     # 0.5975 (vs 0.5887 at 3ep), so sweep the epoch count down and lr
-    # around it.  gelu must match the trunk's activation (bench.py cache
-    # keying note).
+    # around it.  gelu must match the trunk's activation (a pretrain cache
+    # is keyed on it).
     tanh = dict(gelu="tanh", init_from="output/pretrained-tanh.msgpack")
     for lr in (4.5e-5, 6e-5, 8e-5, 1e-4):
         grid[f"tanh_b64_lr{lr:g}_ema0.99_1ep"] = dict(

@@ -44,7 +44,7 @@ RE_ACC = re.compile(r"accuracy：([\d.]+)")
 def main() -> None:
     os.chdir(ROOT)
     if not os.path.exists(MLM):
-        sys.exit(f"{MLM} missing — run pretrain-tpu.py (or bench.py) first")
+        sys.exit(f"{MLM} missing — run pretrain-tpu.py first")
     rows = {}
     for label, extra in GRID:
         argv = [sys.executable, "multi-tpu-jax-cls.py", "--dtype", "bfloat16",

@@ -31,7 +31,7 @@ dropped), and flush the metrics snapshot + trace span files before exit.
 
     # offline: score a file, dump metrics
     python serve_tpu.py --checkpoint output/dp-cls.msgpack \
-        --input texts.txt --output preds.tsv --metrics_path results/serve.json
+        --input texts.txt --output preds.tsv --metrics_path output/serve.json
 
 ``--serve_pack auto|on|off`` picks packed online batching: admitted
 requests bin-pack many-per-row into fixed ``[rows, pack_width]`` batches
@@ -300,14 +300,13 @@ def build_decode_pool(args: Args, replicas: int, *,
                       max_waiting: int = 256,
                       speculate: Optional[str] = None, draft_k: int = 4,
                       disagg: str = "off", prefill_engines: int = 1):
-    """Generative serving pool: ``replicas`` :class:`DecodeEngine`\\ s —
-    placed by :func:`replica_meshes` — behind a :class:`DecodeRouter`
-    (1 replica included: the router is the one submit/kill/snapshot
-    surface either way).  ``--kv_layout paged``
-    (the default) gives each engine a refcounted page pool with
-    cross-request prefix sharing; ``--kv_layout slots`` keeps the classic
-    preallocated slot cache (``--decode_slots`` × ``--decode_max_len``
-    positions, ``--kv_dtype`` precision, gated by ``--kv_hbm_mb``).
+    """Generative serving pool: ``replicas``
+    :class:`PagedDecodeEngine`\\ s — placed by :func:`replica_meshes` —
+    behind a :class:`DecodeRouter` (1 replica included: the router is the
+    one submit/kill/snapshot surface either way).  Each engine holds a
+    refcounted page pool with cross-request prefix sharing
+    (``--decode_slots`` batch rows, ``--decode_max_len`` positions a
+    stream, ``--kv_dtype`` precision, pages capped by ``--kv_hbm_mb``).
 
     ``speculate`` (``--speculate id=ckpt[:dtype]`` or a bare checkpoint
     path) pairs every primary replica with a drafter engine built from
@@ -327,16 +326,12 @@ def build_decode_pool(args: Args, replicas: int, *,
     initial split (the controller's ``prefill_share`` knob re-balances
     it live)."""
     from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, get_or_build_vocab
-    from pdnlp_tpu.serve import DecodeEngine, DecodeRouter, PagedDecodeEngine
+    from pdnlp_tpu.serve import DecodeRouter, PagedDecodeEngine
     from pdnlp_tpu.serve.decode import DisaggDecodeRouter
 
     groups = replica_meshes(args, replicas, use_mesh)
     tok = WordPieceTokenizer(get_or_build_vocab(args))
-    paged = getattr(args, "kv_layout", "paged") != "slots"
     if disagg != "off":
-        if not paged:
-            sys.exit("serve_tpu: --disagg needs --kv_layout paged (the "
-                     "handoff moves page custody between engines)")
         if speculate:
             sys.exit("serve_tpu: --disagg and --speculate are exclusive "
                      "for now — decode-role engines run without "
@@ -344,9 +339,8 @@ def build_decode_pool(args: Args, replicas: int, *,
         if replicas < 2:
             sys.exit("serve_tpu: --disagg needs --replicas >= 2 (at "
                      "least one engine per role)")
-    cls = PagedDecodeEngine if paged else DecodeEngine
-    engines = [cls(args, tokenizer=tok, mesh=groups[i],
-                   buckets=buckets) for i in range(replicas)]
+    engines = [PagedDecodeEngine(args, tokenizer=tok, mesh=groups[i],
+                                 buckets=buckets) for i in range(replicas)]
     tracer = engines[0].tracer
     for e in engines[1:]:
         e.tracer = tracer  # one span/hop stream for the whole pool
@@ -366,9 +360,6 @@ def build_decode_pool(args: Args, replicas: int, *,
 
         from pdnlp_tpu.serve import parse_speculate_spec
 
-        if not paged:
-            sys.exit("serve_tpu: --speculate needs --kv_layout paged "
-                     "(draft custody lives in the page table)")
         dspec = parse_speculate_spec(speculate)
         # the drafter serves its own architecture/precision — one Args
         # copy per spec, exactly the fleet's per-model pattern; the

@@ -59,3 +59,18 @@ def corpus_path(tmp_path_factory):
     p = tmp_path_factory.mktemp("data") / "train.json"
     p.write_text(json.dumps(rows, ensure_ascii=False), encoding="utf-8")
     return str(p)
+
+
+@pytest.fixture(scope="session")
+def corpus_files(corpus_path, tmp_path_factory):
+    """``Args`` fields for the corpus and ONE vocabulary file built from it:
+    what a real-process gang and the in-process run it is compared with
+    both have to be given (``corpus_cli``: the same as command-line arguments)."""
+    return {"data_path": corpus_path,
+            "vocab_path": str(tmp_path_factory.mktemp("vocab") / "vocab.txt")}
+
+
+@pytest.fixture(scope="session")
+def corpus_cli(corpus_files):
+    """``corpus_files`` as command-line arguments."""
+    return [x for k, v in corpus_files.items() for x in (f"--{k}", v)]

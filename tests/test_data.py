@@ -73,9 +73,14 @@ def test_encode_truncation(tok):
     assert ids[0] == tok.cls_id and ids[-1] == tok.sep_id
 
 
-def test_oov_latin_decomposes(tok):
+def test_oov_latin_decomposes(data):
     # A latin word unseen as a whole token must split into continuation
     # pieces whose characters are in the vocab — not collapse to [UNK].
+    # The word's LETTERS have to be in the vocabulary for that: the real
+    # corpus holds latin text, conftest's synthetic one holds none, so one
+    # line of it is added to what the vocabulary is built from.
+    tok = WordPieceTokenizer(build_vocab(
+        [t for t, _ in data[:300]] + ["ok go look"], size=8000))
     word = "ok" * 8  # 'okokokok...' — certainly not a whole corpus token
     pieces = tok.tokenize(word)
     assert "[UNK]" not in pieces

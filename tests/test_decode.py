@@ -4,9 +4,9 @@ zero-retrace guarantee, KV budgets, the streaming hop-chain contract, and
 chain integrity through a mid-decode replica kill.
 
 The bitwise gate compares incremental decode against a FULL RECOMPUTE
-from a cold cache in the same slot geometry — every cached value
+from a cold cache in the same page geometry — every cached value
 recomputed from scratch, nothing reused — which is exactly the property
-the KV cache + slot machinery claims (slot aliasing, stale-KV leaks,
+the KV cache + page machinery claims (page aliasing, stale-KV leaks,
 donation bugs and wrong masks all break it).  Against the one-shot WIDE
 causal forward the comparison is argmax-exact within 5e-6: XLA's CPU gemm
 blocks the contraction differently per row extent (measured in
@@ -27,7 +27,7 @@ from pdnlp_tpu.obs.memory import KVBudget, KVBudgetExceeded
 from pdnlp_tpu.obs.request import chain_issues, validate_chains
 from pdnlp_tpu.ops.attention import causal_bias, dot_product_attention
 from pdnlp_tpu.serve import (
-    DecodeBatcher, DecodeEngine, DecodeRouter, PagedDecodeEngine,
+    DecodeBatcher, DecodeRouter, PagedDecodeEngine,
 )
 from pdnlp_tpu.serve.decode import (
     Chosen, DecodeStream, _Slot, chosen_ids, detokenize, greedy_ids,
@@ -62,10 +62,29 @@ def eng4(tok):
     tests below: stream counters live on each (fresh) DecodeBatcher, not
     the engine, so sharing the engine only shares its compiled jits —
     which is exactly what keeps this file inside the tier-1 budget."""
-    eng = DecodeEngine(make_args(trace=True), tokenizer=tok, mesh=None,
-                       buckets=BUCKETS)
+    eng = PagedDecodeEngine(make_args(trace=True), tokenizer=tok, mesh=None,
+                            buckets=BUCKETS)
     eng.warmup_decode()
     return eng
+
+
+PS = 16  # page size of the model-level tests' pools
+
+
+def identity_cache(cfg, rows, width, ks, vs):
+    """Float32 K and V pools holding a prefill's rows under an IDENTITY
+    table: row i owns pages ``i * MP .. (i + 1) * MP - 1`` in order, so the
+    paged step reads exactly the positions a dense ``[rows, width]`` cache
+    would hold."""
+    mp = width // PS
+    table = np.arange(rows * mp, dtype=np.int32).reshape(rows, mp)
+    shape = (cfg.num_layers, rows * mp, PS, cfg.hidden_size)
+    p = np.arange(ks.shape[2])
+    flat = table[:, p // PS] * PS + p % PS
+    pk, pv = decoder.paged_insert(jnp.zeros(shape, jnp.float32),
+                                  jnp.zeros(shape, jnp.float32), ks, vs,
+                                  flat)
+    return table, pk, pv
 
 
 def run_streams(batcher, ps, max_new=8, eos=-1, timeout=120):
@@ -95,11 +114,10 @@ def test_decode_step_bitwise_equals_full_recompute(tok):
     cfg = get_config("bert-tiny", vocab_size=tok.vocab_size, num_labels=6)
     params = bert.init_params(jax.random.key(0), cfg)
     head = decoder.init_lm_head(jax.random.key(1), cfg)
-    L, N, D = cfg.num_layers, cfg.num_heads, cfg.head_dim
     B, W, bucket, steps = 3, 32, 16, 5
     ps = prompts(3, seed=7, hi=10, vocab=tok.vocab_size)
     pf = jax.jit(decoder.prefill, static_argnums=(2,))
-    step = jax.jit(decoder.decode_step, static_argnums=(2,))
+    step = jax.jit(decoder.paged_decode_step, static_argnums=(2,))
 
     def run_chain():
         """prefill once, then decode `steps` tokens greedily, returning
@@ -112,13 +130,13 @@ def test_decode_step_bitwise_equals_full_recompute(tok):
             mask[i, :len(p)] = 1
         last = np.asarray([len(p) - 1 for p in ps], np.int32)
         lg, ks, vs = pf(params, head, cfg, ids, mask, last)
-        ck = jnp.zeros((L, B, W, N, D), jnp.float32).at[:, :, :bucket].set(ks)
-        cv = jnp.zeros((L, B, W, N, D), jnp.float32).at[:, :, :bucket].set(vs)
+        table, ck, cv = identity_cache(cfg, B, W, ks, vs)
         out = [np.asarray(lg)]
         cur = np.argmax(out[0], -1).astype(np.int32)
         pos = last + 1
         for _ in range(steps):
-            lg, ck, cv = step(params, head, cfg, cur[:, None], ck, cv, pos)
+            lg, ck, cv = step(params, head, cfg, cur[:, None], ck, cv,
+                              table, pos)
             out.append(np.asarray(lg))
             cur = np.argmax(out[-1], -1).astype(np.int32)
             pos = pos + 1
@@ -137,11 +155,10 @@ def test_decode_matches_wide_forward_oracle(tok):
     cfg = get_config("bert-tiny", vocab_size=tok.vocab_size, num_labels=6)
     params = bert.init_params(jax.random.key(0), cfg)
     head = decoder.init_lm_head(jax.random.key(1), cfg)
-    L, N, D = cfg.num_layers, cfg.num_heads, cfg.head_dim
     B, W, bucket = 3, 32, 16
     ps = prompts(3, seed=9, hi=10, vocab=tok.vocab_size)
     pf = jax.jit(decoder.prefill, static_argnums=(2,))
-    step = jax.jit(decoder.decode_step, static_argnums=(2,))
+    step = jax.jit(decoder.paged_decode_step, static_argnums=(2,))
 
     ids = np.zeros((B, bucket), np.int32)
     mask = np.zeros((B, bucket), np.int32)
@@ -150,13 +167,13 @@ def test_decode_matches_wide_forward_oracle(tok):
         mask[i, :len(p)] = 1
     last = np.asarray([len(p) - 1 for p in ps], np.int32)
     lg, ks, vs = pf(params, head, cfg, ids, mask, last)
-    ck = jnp.zeros((L, B, W, N, D), jnp.float32).at[:, :, :bucket].set(ks)
-    cv = jnp.zeros((L, B, W, N, D), jnp.float32).at[:, :, :bucket].set(vs)
+    table, ck, cv = identity_cache(cfg, B, W, ks, vs)
     gen = [[] for _ in range(B)]
     cur = np.argmax(np.asarray(lg), -1).astype(np.int32)
     pos = last + 1
     for t in range(5):
-        lg, ck, cv = step(params, head, cfg, cur[:, None], ck, cv, pos)
+        lg, ck, cv = step(params, head, cfg, cur[:, None], ck, cv, table,
+                          pos)
         oid = np.zeros((B, W), np.int32)
         om = np.zeros((B, W), np.int32)
         for i, p in enumerate(ps):
@@ -173,45 +190,55 @@ def test_decode_matches_wide_forward_oracle(tok):
         pos = pos + 1
 
 
-def test_engine_slot_reuse_is_bitwise_clean(tok):
-    """A stream decoded in a REUSED slot (stale K/V from a previous
-    occupant beyond its positions) is bitwise identical to the same
-    stream on a fresh engine — the visibility mask proves stale cache
-    contents contribute exact zeros."""
-    args = make_args()
+def test_a_stream_over_reused_pages_is_bitwise_the_stream_over_fresh_ones(
+        tok):
+    """A stream decoded over REUSED pages (stale K/V of a previous occupant
+    in every page of the pool, beyond and below its own positions) is
+    bitwise identical to the same stream on a fresh engine — the
+    visibility mask proves stale page contents contribute exact zeros."""
+    args = make_args(decode_slots=1)   # one stream's pages = the whole pool
     p = prompts(1, seed=11, vocab=tok.vocab_size)[0]
 
+    def occupy(engine, prompt, max_new):
+        engine.attach_stream(0, DecodeStream(prompt, max_new), share=False)
+        first = engine.prefill_ids([prompt], [0])
+        return first, int(np.argmax(first[0]))
+
     def drive(engine, warm_garbage):
-        slot = 2
-        if warm_garbage:  # a previous occupant fills slot 2 end to end
-            g = list(range(5, 15))
-            engine.prefill_ids([g], [slot])
-            t = np.zeros((engine.slots,), np.int32)
-            po = np.zeros((engine.slots,), np.int32)
-            po[slot] = len(g)
-            for k in range(engine.max_len - len(g)):
-                lg = engine.decode_batch(t, po, live=1)
-                t[slot] = int(np.argmax(lg[slot]))
-                po[slot] += 1
-        logits0 = engine.prefill_ids([p], [slot])
-        out = [logits0[0]]
         t = np.zeros((engine.slots,), np.int32)
         po = np.zeros((engine.slots,), np.int32)
-        t[slot] = int(np.argmax(logits0[0]))
-        po[slot] = len(p)
+        if warm_garbage:  # a previous occupant fills EVERY page end to end
+            g = list(range(5, 15))
+            _, t[0] = occupy(engine, g, engine.max_len - len(g))
+            po[0] = len(g)
+            for _ in range(engine.max_len - len(g)):
+                lg = engine.decode_batch(t, po, live=1)
+                t[0] = int(np.argmax(lg[0]))
+                po[0] += 1
+            assert engine.allocator.used_pages == engine.n_pages
+            engine.detach_slot(0)
+            stale = np.abs(np.asarray(engine._pools[0])).sum(axis=(2, 3))
+            assert (stale > 0).all()   # every page of every layer is dirty
+        logits0, t[0] = occupy(engine, p, 6)
+        out = [logits0[0]]
+        po[0] = len(p)
         for _ in range(6):
             lg = engine.decode_batch(t, po, live=1)
-            out.append(lg[slot])
-            t[slot] = int(np.argmax(lg[slot]))
-            po[slot] += 1
+            out.append(lg[0])
+            t[0] = int(np.argmax(lg[0]))
+            po[0] += 1
+        engine.detach_slot(0)
+        assert engine.leak_check()["ok"]
         return out
 
-    a = drive(DecodeEngine(args, tokenizer=tok, mesh=None,
-                           buckets=BUCKETS), warm_garbage=True)
-    b = drive(DecodeEngine(args, tokenizer=tok, mesh=None,
-                           buckets=BUCKETS), warm_garbage=False)
+    a = drive(PagedDecodeEngine(args, tokenizer=tok, mesh=None,
+                                buckets=BUCKETS, prefix_share=False),
+              warm_garbage=True)
+    b = drive(PagedDecodeEngine(args, tokenizer=tok, mesh=None,
+                                buckets=BUCKETS, prefix_share=False),
+              warm_garbage=False)
     for t, (x, y) in enumerate(zip(a, b)):
-        assert np.array_equal(x, y), f"step {t}: stale slot leaked"
+        assert np.array_equal(x, y), f"step {t}: stale page leaked"
 
 
 # ------------------------------------------------------- continuous batching
@@ -265,9 +292,9 @@ def test_zero_retraces_50_mixed_streams(tok):
     """The acceptance bar: across 50 mixed-length streams, neither the
     bucketed prefill nor the ONE fixed decode shape compiles after
     warmup (retrace counter AND compile-cache misses stay flat)."""
-    eng = DecodeEngine(make_args(decode_slots=8, decode_max_len=64,
-                                 max_new_tokens=12),
-                       tokenizer=tok, mesh=None, buckets=BUCKETS)
+    eng = PagedDecodeEngine(make_args(decode_slots=8, decode_max_len=64,
+                                      max_new_tokens=12),
+                            tokenizer=tok, mesh=None, buckets=BUCKETS)
     b = DecodeBatcher(eng).start()
     b.warmup()
     retr0 = eng.metrics.retraces.value
@@ -294,8 +321,8 @@ def test_kv_int8_argmax_parity(tok, eng4):
         b.stop()
         return outs
 
-    int8_eng = DecodeEngine(make_args(kv_dtype="int8"), tokenizer=tok,
-                            mesh=None, buckets=BUCKETS)
+    int8_eng = PagedDecodeEngine(make_args(kv_dtype="int8"), tokenizer=tok,
+                                 mesh=None, buckets=BUCKETS)
     assert gen(eng4) == gen(int8_eng)
 
 
@@ -322,12 +349,12 @@ def test_kv_scales_offline_artifact_matches_self_calibration(tok, tmp_path):
     assert os.path.exists(sidecar)
     assert os.path.exists(sidecar + ".manifest.json")
 
-    eng = DecodeEngine(make_args(kv_dtype="int8"), tokenizer=tok,
-                       mesh=None, buckets=BUCKETS)
+    eng = PagedDecodeEngine(make_args(kv_dtype="int8"), tokenizer=tok,
+                            mesh=None, buckets=BUCKETS)
     eng.load_checkpoint(path)          # auto-loads the sidecar
     loaded_k = np.asarray(eng._kv_scales[0])
-    eng2 = DecodeEngine(make_args(kv_dtype="int8"), tokenizer=tok,
-                        mesh=None, buckets=BUCKETS)
+    eng2 = PagedDecodeEngine(make_args(kv_dtype="int8"), tokenizer=tok,
+                             mesh=None, buckets=BUCKETS)
     eng2.load_checkpoint(path)
     eng2._kv_scales = None             # force self-calibration instead
     eng2.calibrate_kv()
@@ -337,25 +364,27 @@ def test_kv_scales_offline_artifact_matches_self_calibration(tok, tmp_path):
 # ---------------------------------------------------------------- KV budget
 
 def test_kv_budget_doors(tok, eng4):
-    args = make_args()
-    slot_mb = decoder.kv_cache_bytes(eng4.cfg, 1, args.decode_max_len,
-                                     np.float32) / 2**20
-    # (a) construction refusal: not even one slot fits
+    # one maximum-length stream's pages, in MB (3 pages of 16 at 48)
+    stream_mb = eng4.pages_per_stream * eng4.page_bytes / 2**20
+    assert eng4.n_pages == 4 * eng4.pages_per_stream      # unbudgeted
+    # (a) construction refusal: not even one maximum-length stream fits
     with pytest.raises(KVBudgetExceeded):
-        DecodeEngine(make_args(kv_hbm_mb=slot_mb / 2), tokenizer=tok,
-                     mesh=None, buckets=BUCKETS)
-    # (b) loud slot cap: budget covers 2 of the 4 requested slots
-    capped = DecodeEngine(make_args(kv_hbm_mb=2.2 * slot_mb),
-                          tokenizer=tok, mesh=None, buckets=BUCKETS)
-    assert capped.slots == 2
+        PagedDecodeEngine(make_args(kv_hbm_mb=stream_mb / 2), tokenizer=tok,
+                          mesh=None, buckets=BUCKETS)
+    # (b) loud page cap: the budget covers 2.2 of the 4 requested streams'
+    # pages; the slots stay the batch width
+    capped = PagedDecodeEngine(make_args(kv_hbm_mb=2.2 * stream_mb),
+                               tokenizer=tok, mesh=None, buckets=BUCKETS)
+    assert capped.n_pages == int(2.2 * eng4.pages_per_stream)
+    assert capped.slots == 4
     assert capped.kv_snapshot()["budget_mb"] == pytest.approx(
-        2.2 * slot_mb, abs=1e-3)
+        2.2 * stream_mb, abs=1e-3)
     # (c) admission refusal in budget units: a stream that cannot fit
     b = DecodeBatcher(capped).start()
     with pytest.raises(KVBudgetExceeded):
         b.submit_ids(list(range(5, 15)), max_new_tokens=10_000)
     # (d) live occupancy gauge moves while streams decode (and returns
-    # to zero when the slot frees)
+    # to zero when the stream's pages free)
     b.warmup()
     b.eos_id = -1
     s = b.submit_ids(list(range(5, 12)), max_new_tokens=30)
@@ -379,14 +408,17 @@ def test_kv_budget_unbudgeted_plain_capacity_error(tok, eng4):
 
 def test_kv_budget_pure_policy():
     bgt = KVBudget(1.0)  # 1 MB
-    assert bgt.cap_slots(8, 2**19) == 2          # two 0.5 MB slots fit
+    assert bgt.cap_pages(8, 2**19) == 2          # two 0.5 MB pages fit
+    assert bgt.cap_pages(1, 2**19) == 1          # never more than asked
     with pytest.raises(KVBudgetExceeded):
-        bgt.cap_slots(8, 2**21)                  # a 2 MB slot never fits
+        bgt.cap_pages(8, 2**21)                  # a 2 MB page never fits
+    with pytest.raises(KVBudgetExceeded):        # nor a stream of 3 pages
+        bgt.cap_pages(8, 2**19, min_pages=3)
     with pytest.raises(KVBudgetExceeded):
         bgt.check_stream(tokens_total=2048, token_bytes=1024)
     bgt.set_live(4096)
     assert bgt.snapshot()["live_bytes"] == 4096
-    assert KVBudget(0).cap_slots(8, 2**40) == 8  # unbudgeted: no checks
+    assert KVBudget(0).cap_pages(8, 2**40) == 8  # unbudgeted: no checks
 
 
 # ------------------------------------------------------------------ infill
@@ -472,7 +504,10 @@ def test_mid_decode_replica_kill_no_dup_no_loss(tok):
                      max_new_tokens=64, trace=True)
     ps = prompts(30, seed=3, lo=3, hi=14, vocab=tok.vocab_size)
 
-    ref_eng = DecodeEngine(args, tokenizer=tok, mesh=None, buckets=BUCKETS)
+    # prefix_share off: this test is the kill contract alone (the sharing
+    # variant of it is tests/test_kvpage.py's)
+    ref_eng = PagedDecodeEngine(args, tokenizer=tok, mesh=None,
+                                buckets=BUCKETS, prefix_share=False)
     rb = DecodeBatcher(ref_eng).start()
     rb.warmup()
     _, refs = run_streams(rb, ps, max_new=48)
@@ -480,10 +515,11 @@ def test_mid_decode_replica_kill_no_dup_no_loss(tok):
 
     # the reference engine rides again as the to-be-killed replica: its
     # jits are already compiled and the kill contract is about batcher +
-    # slot state, which a stopped batcher leaves clean
+    # page state, which a stopped batcher leaves clean
+    assert ref_eng.leak_check()["ok"]
     engines = [ref_eng,
-               DecodeEngine(args, tokenizer=tok, mesh=None,
-                            buckets=BUCKETS)]
+               PagedDecodeEngine(args, tokenizer=tok, mesh=None,
+                                 buckets=BUCKETS, prefix_share=False)]
     tracer = engines[0].tracer
     for e in engines[1:]:
         e.tracer = tracer
@@ -491,6 +527,7 @@ def test_mid_decode_replica_kill_no_dup_no_loss(tok):
     for b in router.batchers:
         b.eos_id = -1
     router.warmup()
+    traced0 = sum(e.metrics.retraces.value for e in engines)
     streams = [router.submit_ids(p, max_new_tokens=48) for p in ps]
     deadline = time.monotonic() + 60
     while (router.batchers[0].metrics.tokens_out_total.value < 100
@@ -502,6 +539,9 @@ def test_mid_decode_replica_kill_no_dup_no_loss(tok):
 
     assert router.batchers[0].dead and not router.batchers[1].dead
     assert outs == refs, "kill recovery duplicated or lost tokens"
+    # both replicas were warmed: the kill, the requeue and the survivor's
+    # re-prefills (continuations longer than any prompt) compile nothing
+    assert sum(e.metrics.retraces.value for e in engines) == traced0
     report = validate_chains(tracer.records(), [s.rid for s in streams])
     assert report["incomplete"] == {}
     assert report["complete"] == len(streams)
@@ -523,10 +563,10 @@ def test_router_all_replicas_dead_fails_loudly(tok, eng4):
 
 # ------------------------------------------- the choice made on the device
 # What crosses from device to host after a launch is the chosen token of
-# each row (``Chosen.ids``); the logits stay behind it.  Both engines, and
-# both model families through the paged one, as cases of each test.
+# each row (``Chosen.ids``); the logits stay behind it.  Both model
+# families through the one engine, as cases of each test.
 
-KINDS = ("slots-bert", "paged-bert", "paged-latent")
+KINDS = ("paged-bert", "paged-latent")
 
 
 @pytest.fixture(scope="module", params=KINDS)
@@ -536,8 +576,7 @@ def choosing(request, tok):
     model = "ax-k1-share-tiny" if kind == "paged-latent" else "bert-tiny"
     args = Args(model=model, decode_slots=8, decode_max_len=64,
                 max_seq_len=64, max_new_tokens=8)
-    cls = DecodeEngine if kind == "slots-bert" else PagedDecodeEngine
-    eng = cls(args, tokenizer=tok, mesh=None, buckets=BUCKETS)
+    eng = PagedDecodeEngine(args, tokenizer=tok, mesh=None, buckets=BUCKETS)
     eng.warmup_decode()
     return eng
 
@@ -546,9 +585,7 @@ def spied(eng, rows):
     """Record what every engine call handed its caller: ``(name, the slots
     a prefill wrote, the launch's logits as an array)``."""
     for name in ("prefill_ids", "prefill_chunk", "decode_batch"):
-        real = getattr(type(eng), name, None)
-        if real is None:
-            continue
+        real = getattr(type(eng), name)
 
         def spy(*a, _real=real, _name=name, **k):
             out = _real(eng, *a, **k)
@@ -609,7 +646,7 @@ def plant_ties(eng, ids=(3, 9, 17)):
 
 @pytest.mark.parametrize("ties", [False, True], ids=["as-served", "ties"])
 def test_a_launchs_ids_are_the_argmax_of_its_own_logits(choosing, tok, ties):
-    """Prefill, (paged) chunk and every decode step: ``ids`` equals
+    """Prefill, chunk and every decode step: ``ids`` equals
     ``np.argmax`` of the SAME launch's float32 logits on every row, live or
     junk; with an exact tie planted at every row's maximum the first index
     wins, as on the host."""
@@ -622,10 +659,8 @@ def test_a_launchs_ids_are_the_argmax_of_its_own_logits(choosing, tok, ties):
         for slot, st in enumerate(streams):
             eng.attach_stream(slot, st, share=False)
         launches = [eng.prefill_ids(ps, [0, 1, 2])]
-        if eng.paged:
-            launches.append(eng.prefill_chunk([p[-3:] for p in ps],
-                                              [0, 1, 2],
-                                              [len(p) - 3 for p in ps]))
+        launches.append(eng.prefill_chunk([p[-3:] for p in ps], [0, 1, 2],
+                                          [len(p) - 3 for p in ps]))
         t = np.zeros((eng.slots,), np.int32)
         po = np.zeros((eng.slots,), np.int32)
         t[:3] = launches[0].ids

@@ -28,7 +28,7 @@ from pdnlp_tpu.obs.decision import validate_decisions  # noqa: E402
 from pdnlp_tpu.obs.request import chain_issues, validate_chains  # noqa: E402
 from pdnlp_tpu.obs.trace import Tracer  # noqa: E402
 from pdnlp_tpu.serve import (  # noqa: E402
-    DecodeBatcher, DecodeEngine, PagedDecodeEngine, ServeController,
+    DecodeBatcher, PagedDecodeEngine, ServeController,
 )
 from pdnlp_tpu.serve.decode import (  # noqa: E402
     DecodeStream, DisaggDecodeRouter, PrefillWorker,
@@ -211,12 +211,12 @@ def test_disagg_ctor_validation(fleet, tok):
         DisaggDecodeRouter([fleet[0]])
     with pytest.raises(ValueError, match="transport"):
         DisaggDecodeRouter(fleet, transport="carrier-pigeon")
-    slot_eng = DecodeEngine(make_args(), tokenizer=tok, mesh=None,
-                            buckets=BUCKETS)
-    with pytest.raises(ValueError, match="PAGED"):
-        DisaggDecodeRouter([fleet[0], slot_eng])
-    with pytest.raises(ValueError, match="PAGED"):
-        PrefillWorker(slot_eng, dispatch=lambda *a: None)
+    # the split is clamped so that each role keeps at least one engine
+    for asked, want in ((99, len(fleet) - 1), (0, 1)):
+        r = DisaggDecodeRouter(fleet, prefill_engines=asked)
+        roles = [type(u).__name__ for u in r._units]
+        assert roles == ["PrefillWorker"] * want \
+            + ["DecodeBatcher"] * (len(fleet) - want)
 
 
 # ---------------------------------------------------- wire framing

@@ -32,7 +32,7 @@ COMMON_ARGS = [
 
 
 @pytest.fixture(scope="module")
-def elastic_run(tmp_path_factory):
+def elastic_run(tmp_path_factory, corpus_cli):
     """Elastic spawn (2 procs x 4 CPU devices) with rank 1 chaos-killed at
     step 8; snapshots every 3 steps -> the restart resumes from step 6."""
     out = tmp_path_factory.mktemp("elastic")
@@ -42,6 +42,9 @@ def elastic_run(tmp_path_factory):
         XLA_FLAGS="--xla_force_host_platform_device_count=4",
         PDNLP_FAULT_STEP="8",
         PDNLP_FAULT_PROC="1",
+        # own rendezvous port: the suite's other gang fixtures run beside
+        # this one under xdist (tests/test_spawn.py holds the default)
+        PDNLP_SPAWN_PORT="12393",
     )
     env.pop("COORDINATOR_ADDRESS", None)
     env.pop("PROCESS_ID", None)
@@ -53,7 +56,7 @@ def elastic_run(tmp_path_factory):
          # restart must keep the 2x4 layout: opt out of the default
          # evict-and-shrink policy (tests/test_chaos.py covers eviction)
          "--elastic_shrink", "false",
-         *COMMON_ARGS],
+         *COMMON_ARGS, *corpus_cli],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=1200,
     )
     return proc, out
@@ -174,7 +177,7 @@ def test_elastic_restart_completes(elastic_run):
 
 
 @pytest.fixture(scope="module")
-def undisturbed_run(tmp_path_factory):
+def undisturbed_run(tmp_path_factory, corpus_cli):
     """The SAME 2-proc x 4-device spawn configuration with no chaos hook —
     the layout-matched control for the byte-identical assert."""
     out = tmp_path_factory.mktemp("undisturbed")
@@ -191,7 +194,8 @@ def undisturbed_run(tmp_path_factory):
         env.pop(k, None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "multi-tpu-spawn-cls.py"),
-         "--num_processes", "2", "--output_dir", str(out), *COMMON_ARGS],
+         "--num_processes", "2", "--output_dir", str(out), *COMMON_ARGS,
+         *corpus_cli],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900,
     )
     return proc, out
@@ -228,7 +232,8 @@ def test_elastic_params_byte_identical_to_undisturbed_run(
         f" differ; max abs diff {np.abs(flat_elastic - flat_clean).max()}")
 
 
-def test_elastic_params_match_single_process_run(elastic_run, ndev):
+def test_elastic_params_match_single_process_run(elastic_run, ndev,
+                                                 corpus_files):
     """Cross-LAYOUT parity (2x4 spawn vs 8-device in-process): collective
     reassociation differs between layouts, so this is a float-tolerance
     check, not the byte-identical contract (which
@@ -246,7 +251,7 @@ def test_elastic_params_match_single_process_run(elastic_run, ndev):
     args = Args(strategy="spawn", model="bert-tiny", data_limit=600,
                 max_seq_len=32, train_batch_size=4, dtype="float32",
                 dropout=0.0, attn_dropout=0.0, epochs=1,
-                output_dir=str(out), log_every=10 ** 9)
+                output_dir=str(out), log_every=10 ** 9, **corpus_files)
     trainer, train_loader, _ = build_parallel_trainer(args, mode="dp")
     for batch in train_loader:
         trainer.state, m = trainer.train_step(trainer.state, trainer.put(batch))

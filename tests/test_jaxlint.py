@@ -715,24 +715,26 @@ def test_partial_suite_scopes_the_baseline(tmp_path):
     assert "refusing" in refused.stderr
 
 
-def test_bench_refuses_when_lint_gate_fails(monkeypatch):
-    """bench.py smokes refuse to run on a tree carrying NEW findings —
-    the leaked-env refusal pattern.  With the baseline emptied out, every
-    grandfathered finding reads as new and the gate must exit; against
-    the real committed baseline it must pass."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_gate_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench._lint_gate()  # real tree vs real baseline: clean
-
+def test_the_gate_passes_on_the_real_tree_and_fails_without_its_baseline(
+        monkeypatch, capsys):
+    """``lint_tpu.py`` (what ``scripts/lint_gate.sh`` runs) on the real tree
+    against the committed baseline: exit 0, nothing new and nothing FIXED
+    (the baseline holds no entry for a file that is gone).  With the
+    baseline emptied out every grandfathered finding reads as new and the
+    gate exits 1."""
     from pdnlp_tpu.analysis import baseline as baseline_mod
+    from pdnlp_tpu.analysis.cli import main as lint_main
+
+    monkeypatch.chdir(REPO)
+    assert lint_main(["--json"]) == 0
+    summary = json.loads(capsys.readouterr().out)["summary"]
+    assert summary["new"] == 0 and summary["fixed_vs_baseline"] == 0
+    for e in baseline_mod.load(baseline_mod.DEFAULT_BASELINE):
+        assert os.path.exists(os.path.join(REPO, e["file"])), e["file"]
+
     monkeypatch.setattr(baseline_mod, "load", lambda path: [])
-    with pytest.raises(SystemExit) as e:
-        bench._lint_gate()
-    assert "jaxlint gate FAILED" in str(e.value)
+    assert lint_main(["--json"]) == 1
+    assert json.loads(capsys.readouterr().out)["summary"]["new"] >= 1
 
 
 def test_findings_carry_exact_location_and_hint():

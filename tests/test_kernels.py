@@ -109,9 +109,27 @@ def test_bias_and_segment_ids_are_mutually_exclusive():
                               segment_ids=seg)
 
 
-def test_packed_classify_pallas_matches_xla():
+def _jaxpr_shapes(jaxpr, acc):
+    """Every intermediate's shape in ``jaxpr`` and the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        for ov in eqn.outvars:
+            shape = getattr(getattr(ov, "aval", None), "shape", None)
+            if shape:
+                acc.add(tuple(shape))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", None)
+                if inner is not None:
+                    _jaxpr_shapes(getattr(inner, "jaxpr", inner), acc)
+    return acc
+
+
+@pytest.mark.parametrize("check", ["logits", "no-bias-in-the-jaxpr"])
+def test_packed_classify_pallas_matches_xla(check):
     """End-to-end packed forward: per-segment logits identical whether the
-    block-diagonal mask is in-kernel (pallas) or materialized (XLA)."""
+    block-diagonal mask is in-kernel (pallas) or materialized (XLA) — and,
+    structurally, the pallas route's jaxpr holds NO ``[B, 1, S, S]`` value
+    (the segment bias never exists), while the XLA control's does."""
     cfg = get_config("bert-tiny", vocab_size=120).replace(max_position=128)
     params = bert.init_params(jax.random.key(0), cfg)
     r = np.random.RandomState(0)
@@ -131,6 +149,13 @@ def test_packed_classify_pallas_matches_xla():
         "label": jnp.zeros((B, M), jnp.int32),
         "example_weight": jnp.ones((B, M), jnp.float32),
     }
+    if check == "no-bias-in-the-jaxpr":
+        seen = {impl: _jaxpr_shapes(jax.make_jaxpr(
+            lambda p, bt: bert.classify(p, cfg, bt, attn_impl=impl))(
+                params, batch).jaxpr, set()) for impl in ("pallas", "xla")}
+        assert (B, 1, S, S) not in seen["pallas"]
+        assert (B, 1, S, S) in seen["xla"]   # the check has its control
+        return
     a = bert.classify(params, cfg, batch, attn_impl="xla")
     b = bert.classify(params, cfg, batch, attn_impl="pallas")
     assert a.shape == (B, M, cfg.num_labels)

@@ -1,7 +1,7 @@
 """Paged KV cache tests: allocator/refcount/leak-check units, the prefix
 index (full/partial hits, LRU eviction as the allocator's reclaimer),
-paged-vs-slot TOKEN PARITY (cold, full-hit, partial-hit and copy-on-write
-streams all continue identically to the slot-cache baseline), page-unit
+sharing-vs-not TOKEN PARITY (cold, full-hit, partial-hit and copy-on-write
+streams all continue identically to an engine that shares nothing), page-unit
 capacity under ``--kv_hbm_mb``, the zero-retrace guarantee on the paged
 decode path, pool-exhaustion queueing without deadlock, prefix-hit
 telemetry on the hop chain, and kill-recovery where re-prefilled orphans
@@ -16,7 +16,7 @@ from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
 from pdnlp_tpu.obs.exporter import prometheus_lines
 from pdnlp_tpu.obs.request import validate_chains
 from pdnlp_tpu.serve import (
-    DecodeBatcher, DecodeEngine, DecodeRouter, KVPagesExhausted,
+    DecodeBatcher, DecodeRouter, KVPagesExhausted,
     PagedDecodeEngine,
 )
 from pdnlp_tpu.serve.kvpage import (
@@ -63,9 +63,11 @@ def pag(tok):
 
 
 @pytest.fixture(scope="module")
-def slot_eng(tok):
-    eng = DecodeEngine(make_args(), tokenizer=tok, mesh=None,
-                       buckets=BUCKETS)
+def cold_eng(tok):
+    """The reference: the same engine with ``prefix_share=False`` — every
+    stream prefills its whole prompt into pages of its own."""
+    eng = PagedDecodeEngine(make_args(), tokenizer=tok, mesh=None,
+                            buckets=BUCKETS, prefix_share=False)
     eng.warmup_decode()
     return eng
 
@@ -194,12 +196,15 @@ def test_prefix_index_eviction_is_lru():
 
 # ------------------------------------------------- engine: parity + hits
 
-def test_paged_cold_streams_match_slot_engine(tok, pag, slot_eng):
-    """The parity pin: every cold paged stream's greedy continuation is
-    token-identical to the slot-cache baseline."""
+def test_paged_cold_streams_match_an_engine_that_shares_nothing(
+        tok, pag, cold_eng):
+    """The parity pin: every stream that MISSES the index continues token
+    for token as on an engine without an index, and registering its pages
+    on the way changes nothing."""
     ps = prompts(6, seed=3, vocab=tok.vocab_size)
-    assert drive_serial(pag, ps) == drive_serial(slot_eng, ps)
-    assert pag.leak_check()["ok"]
+    assert drive_serial(pag, ps) == drive_serial(cold_eng, ps)
+    assert pag.leak_check()["ok"] and cold_eng.leak_check()["ok"]
+    assert len(cold_eng.prefix) == 0 and len(pag.prefix) > 0
     pag.prefix.clear()
     assert pag.allocator.free_pages == pag.n_pages
 
@@ -225,14 +230,14 @@ def test_full_prefix_hit_skips_prefill_and_matches(tok, pag):
     assert pag.leak_check()["ok"]
 
 
-def test_partial_prefix_hit_matches_cold_reference(tok, pag, slot_eng):
+def test_partial_prefix_hit_matches_cold_reference(tok, pag, cold_eng):
     """A prompt sharing >= 1 full page with an indexed prefix forwards
-    only its suffix and still matches the slot-cache baseline (which the
-    parity test pins equal to a cold paged drive) token for token."""
+    only its suffix and still matches, token for token, the engine that
+    shares nothing (its whole prompt prefilled cold)."""
     base = prompts(1, seed=5, lo=20, hi=22, vocab=tok.vocab_size)[0]
     va = base + [7, 8, 9]
     vb = base + [3, 4, 5]   # diverges after base's full page(s)
-    ref = drive_serial(slot_eng, [vb])[0]
+    ref = drive_serial(cold_eng, [vb])[0]
 
     b = DecodeBatcher(pag, replica=0)
     b.eos_id = -1
@@ -269,19 +274,19 @@ def test_admit_and_prefill_hops_carry_prefix_hit(tok, pag):
 # ------------------------------------------------------ capacity / budget
 
 def test_paged_layout_admits_more_streams_at_equal_hbm(tok, pag):
-    """The capacity claim in miniature: at a budget that caps the slot
-    layout to its mesh minimum, the paged layout (short streams reserve
-    only the pages they need) seats strictly more concurrent streams."""
-    slot_mb = (pag.token_bytes * pag.max_len) / 2**20
-    budget = 2.2 * slot_mb                      # 2 slot-equivalents
-    capped_slot = DecodeEngine(make_args(kv_hbm_mb=budget), tokenizer=tok,
-                               mesh=None, buckets=BUCKETS)
-    assert capped_slot.slots == 2
+    """The capacity claim in miniature: a budget that would hold only two
+    ``max_len`` stripes (the arithmetic ``slots x max_len x token_bytes``
+    of a cache that gives every stream its worst case) seats strictly
+    more short streams as pages (each reserves only what it needs)."""
+    stripe_bytes = pag.token_bytes * pag.max_len
+    budget = 2.2 * stripe_bytes / 2**20         # MB: 2 stripe-equivalents
+    stripes = int(budget * 2**20) // stripe_bytes
+    assert stripes == 2
     capped_pag = paged_engine(tok, kv_hbm_mb=budget, decode_slots=8)
-    assert capped_pag.slots == 8                # slots are batch rows now
+    assert capped_pag.slots == 8                # slots are batch rows
     # short streams: prompt+max_new = 8 -> 1 page each
     per_stream = pages_needed(8, capped_pag.page_sz)
-    assert capped_pag.n_pages // per_stream > capped_slot.slots
+    assert capped_pag.n_pages // per_stream >= 3 * stripes
 
 
 def test_pool_exhaustion_queues_without_deadlock(tok):

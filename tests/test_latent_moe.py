@@ -25,8 +25,8 @@ import pytest
 
 import axk1_reference as ref
 from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
-from pdnlp_tpu.models import decoder, get_config, latent_moe as lm
-from pdnlp_tpu.serve import DecodeBatcher, DecodeEngine, PagedDecodeEngine
+from pdnlp_tpu.models import decoder, families, get_config, latent_moe as lm
+from pdnlp_tpu.serve import DecodeBatcher, PagedDecodeEngine
 from pdnlp_tpu.serve.decode import PrefillWorker
 from pdnlp_tpu.utils.config import Args
 
@@ -369,7 +369,7 @@ def test_engine_seam_is_bitwise_for_the_bert_family(tok):
     np.testing.assert_array_equal(np.asarray(eng._cache_v), np.asarray(pv))
 
 
-@pytest.mark.parametrize("what", ["kv_int8", "weights_int8", "slots",
+@pytest.mark.parametrize("what", ["kv_int8", "weights_int8",
                                   "speculative_pair", "handoff"])
 def test_refusals_are_loud_and_at_construction(tok, what):
     base = dict(model=MODEL, decode_slots=4, decode_max_len=64,
@@ -382,10 +382,6 @@ def test_refusals_are_loud_and_at_construction(tok, what):
         with pytest.raises(ValueError, match="int8 weights"):
             PagedDecodeEngine(Args(serve_dtype="int8", **base),
                               tokenizer=tok, mesh=None, buckets=(16,))
-    elif what == "slots":
-        with pytest.raises(ValueError, match="slot cache layout"):
-            DecodeEngine(Args(**base), tokenizer=tok, mesh=None,
-                         buckets=(16,))
     else:
         eng = PagedDecodeEngine(Args(**base), tokenizer=tok, mesh=None,
                                 buckets=(16,), prefix_share=False)
@@ -407,12 +403,11 @@ def test_token_bytes_come_from_the_familys_pools(tok):
     cfg = eng.cfg
     assert eng.token_bytes == cfg.num_layers * cfg.cache_width * 2
     assert eng.page_bytes == eng.token_bytes * eng.page_sz
-    assert decoder.kv_cache_bytes(cfg, 3, 5, jnp.bfloat16) \
-        == 15 * eng.token_bytes
+    assert families.token_bytes(cfg, jnp.bfloat16) == eng.token_bytes
     assert eng.kv_snapshot()["cache_bytes"] == eng.n_pages * eng.page_bytes
     bert = get_config("bert-tiny")
-    assert decoder.kv_cache_bytes(bert, 2, 7, jnp.float32) \
-        == 2 * bert.num_layers * 2 * 7 * bert.hidden_size * 4
+    assert families.token_bytes(bert, jnp.float32) \
+        == bert.num_layers * 2 * bert.hidden_size * 4
     assert eng._pools[0].shape == (cfg.num_layers, eng.n_pages, eng.page_sz,
                                    cfg.cache_width)
 

@@ -474,28 +474,3 @@ def test_long_width_validation_is_loud():
     with pytest.raises(ValueError, match="packed path"):
         DynamicBatcher(eng, buckets=(128,), serve_pack="off",
                        long_widths=(256,))
-
-
-# ------------------------------------------------------------- merge logic
-
-
-def test_bench_longcontext_merge_preserves_history(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    import bench_longcontext as blc
-
-    path = str(tmp_path / "longcontext.json")
-    hist = {"meta": {"device": "TPU v5 lite"},
-            "rows": {"seq512_b16_xla": {"steps_per_sec": 13.2},
-                     "broken": {"error": "oom"}}}
-    json.dump(hist, open(path, "w"))
-    res, merged = blc.merge_rows(
-        {"seq512_b16_xla": {"steps_per_sec": 1.0},   # must NOT clobber
-         "broken": {"steps_per_sec": 2.0},           # error row: replaced
-         "smoke_new": {"fill": 0.9}},                # new: merged
-        path=path, device="cpu")
-    assert sorted(merged) == ["broken", "smoke_new"]
-    on_disk = json.load(open(path))
-    assert on_disk["rows"]["seq512_b16_xla"] == {"steps_per_sec": 13.2}
-    assert on_disk["rows"]["broken"] == {"steps_per_sec": 2.0}
-    assert on_disk["rows"]["smoke_new"] == {"fill": 0.9}
-    assert on_disk["meta"]["device"] == "TPU v5 lite"  # history wins

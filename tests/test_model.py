@@ -125,7 +125,7 @@ def test_gelu_config_knob(cfg, params, batch):
     assert args_overrides(Args(gelu="tanh"))["gelu"] == "tanh"
     assert get_config("bert-base", **args_overrides(Args(gelu="tanh"))).gelu == "tanh"
 
-    # a typo'd value must fail loudly, not silently run erf (bench.py keys
-    # its pretrain cache on the raw string)
+    # a typo'd value must fail loudly, not silently run erf (a pretrain
+    # cache is keyed on the raw string)
     with pytest.raises(ValueError, match="gelu"):
         bert.classify(params, cfg.replace(gelu="Tanh"), batch)

@@ -22,6 +22,30 @@ SEQ = 16
 VOCAB = 100
 
 
+@pytest.fixture(scope="module")
+def learnable_corpus(corpus_path, tmp_path_factory):
+    """The two trainer-path tests below assert that the loss FALLS, which
+    takes labels a model can learn: the real corpus where it is there, else
+    a synthetic one whose label is a function of its text (conftest's
+    draws labels at random, which nothing can learn in two tiny epochs)."""
+    import json
+    import random
+
+    if "reference" in corpus_path:
+        return corpus_path
+    rng = random.Random(1)
+    chars = "天地人你我他好坏大小上下来去爱恨喜怒哀乐"
+    rows = []
+    for _ in range(600):
+        label = rng.randint(0, 5)
+        marks = chars[3 * label:3 * label + 3]   # a label's own three chars
+        text = " ".join(rng.choice(marks) for _ in range(rng.randint(4, 12)))
+        rows.append([text, label])
+    p = tmp_path_factory.mktemp("moe_data") / "train.json"
+    p.write_text(json.dumps(rows, ensure_ascii=False), encoding="utf-8")
+    return str(p)
+
+
 def tiny_args(**kw):
     base = dict(model="bert-tiny-moe", max_seq_len=SEQ, train_batch_size=4,
                 dropout=0.0, attn_dropout=0.0)
@@ -244,7 +268,7 @@ def test_upcycle_dense_checkpoint_into_moe(tmp_path):
                                np.asarray(dense_logits), atol=0.35)
 
 
-def test_moe_on_shardmap_path(ndev):
+def test_moe_on_shardmap_path(ndev, learnable_corpus, tmp_path):
     """The explicit-collectives (Horovod-analog) path trains MoE: the aux
     loss is computed per shard and joins the optimized objective, while the
     REPORTED first-step loss equals the jit dp path's bare CE exactly
@@ -256,8 +280,10 @@ def test_moe_on_shardmap_path(ndev):
     # assignment legitimately differs from the jit path's global-batch one
     # (drops fall elsewhere) — only the capacity-free dense combine is
     # bitwise path-independent
+    files = dict(data_path=learnable_corpus, output_dir=str(tmp_path),
+                 vocab_path=str(tmp_path / "vocab.txt"))
     args = tiny_args(data_limit=600, max_seq_len=16, train_batch_size=4,
-                     log_every=10 ** 9, moe_dispatch="dense")
+                     log_every=10 ** 9, moe_dispatch="dense", **files)
     tr_sm, loader_sm, _ = build_parallel_trainer(
         args, mode="dp", explicit_collectives=True)
     tr_dp, loader_dp, _ = build_parallel_trainer(args, mode="dp")
@@ -271,7 +297,7 @@ def test_moe_on_shardmap_path(ndev):
     losses = []
     tr2, loader2, _ = build_parallel_trainer(
         tiny_args(data_limit=600, max_seq_len=16, train_batch_size=4,
-                  learning_rate=1e-3, log_every=10 ** 9),
+                  learning_rate=1e-3, log_every=10 ** 9, **files),
         mode="dp", explicit_collectives=True)
     for epoch in range(2):
         loader2.set_epoch(epoch)
@@ -282,7 +308,7 @@ def test_moe_on_shardmap_path(ndev):
     assert np.isfinite(losses).all()
 
 
-def test_moe_on_pipeline_path(ndev):
+def test_moe_on_pipeline_path(ndev, learnable_corpus, tmp_path):
     """MoE composes with pipeline parallelism: expert stacks split their
     leading layer dim over stages and the load-balancing aux flows through
     the tick loop's backward.  Parity with dp is LOOSE here by design: a
@@ -298,7 +324,9 @@ def test_moe_on_pipeline_path(ndev):
     kw = dict(model="bert-tiny-moe", max_seq_len=16, train_batch_size=4,
               dropout=0.0, attn_dropout=0.0, data_limit=600,
               learning_rate=1e-3,  # visible decrease in 2 tiny epochs
-              log_every=10 ** 9)
+              log_every=10 ** 9, data_path=learnable_corpus,
+              output_dir=str(tmp_path),
+              vocab_path=str(tmp_path / "vocab.txt"))
     pp_args = Args(strategy="pp-moe", mesh_shape={"data": 4, "stage": 2},
                    microbatches=2, **kw)
     tr_pp, loader_pp, _ = build_pipeline_trainer(pp_args)

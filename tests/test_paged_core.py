@@ -2,7 +2,7 @@
 and its three thin callers, against a float32 DENSE recompute — T = 1, T > 1
 with ragged ``nreal``, the head at every position — plus what the core must
 never do (dead rows, sentinel tables and filler write nothing), the int8
-path, greedy-token parity with the slot engine over a stream that crosses
+path, greedy tokens equal to the dense recompute's over a stream that crosses
 every rung of the decode extent and every page boundary, and the structural
 guard: the donated pools are aliased to the outputs and no temporary is as
 large as a pool (the pool is never rebuilt)."""
@@ -14,7 +14,7 @@ import pytest
 from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
 from pdnlp_tpu.models import bert, decoder, get_config
 from pdnlp_tpu.ops.attention import mask_bias
-from pdnlp_tpu.serve import DecodeBatcher, DecodeEngine, PagedDecodeEngine
+from pdnlp_tpu.serve import DecodeBatcher, PagedDecodeEngine
 from pdnlp_tpu.utils.config import Args
 
 L_PAGES, PS, MP, ROWS = 24, 8, 6, 3       # pool pages, page size, table width
@@ -249,38 +249,45 @@ def test_a_live_row_writes_only_its_own_position(model):
 
 # ----------------------------------------------------------------- int8
 
-def test_int8_pool_round_trips_through_the_cache_like_the_slot_step(model):
-    """Same quantized values at the same positions as the slot engine's
-    int8 cache: the paged int8 step's logits follow the slot step's."""
+def test_int8_pool_round_trips_through_the_cache_like_the_dense_model(model):
+    """The int8 pool holds what ``quantize_kv`` makes of the prefill's rows,
+    position for position (exactly: the strong half), and the int8 step's
+    logits follow the float32 dense forward of the same tokens to within
+    the quantization's error (measured 4e-4 on these seeded weights, whose
+    logits reach 1.0; a read that skips the dequantize is off by orders)."""
     cfg, params, head = model
     ks, vs = decoder.calibrate_kv_scales(params, cfg, seq_len=32)
     scales = (jnp.asarray(ks), jnp.asarray(vs))
     n, steps = 9, 4
     ids = sequences(cfg.vocab_size, [n + steps] * ROWS, seed=17)
+    want = dense_logits(model, ids)
     mask = np.ones((ROWS, n), np.int32)
     _, pks, pvs = decoder.prefill(params, head, cfg, ids[:, :n], mask,
                                   np.full(ROWS, n - 1, np.int32))
-    # slot cache [L, B, max_len, N, D] int8
-    ck = jnp.zeros((cfg.num_layers, ROWS, MAX_LEN, cfg.num_heads,
-                    cfg.head_dim), jnp.int8)
-    cv = jnp.zeros_like(ck)
-    ck = ck.at[:, :, :n].set(decoder.quantize_kv(pks, ks[:, None, None]))
-    cv = cv.at[:, :, :n].set(decoder.quantize_kv(pvs, vs[:, None, None]))
     pk, pv = empty_pools(cfg, jnp.int8)
     table = tables(seed=7)
     p = np.arange(n)
     flat = table[:, p // PS] * PS + p % PS
     pk, pv = decoder.paged_insert(pk, pv, pks, pvs, flat, kv_scales=scales)
     assert pk.dtype == jnp.int8
+    H = cfg.hidden_size
+    qk = np.asarray(decoder.quantize_kv(pks, ks[:, None, None]))
+    for i in range(ROWS):
+        np.testing.assert_array_equal(
+            np.asarray(pk)[:, table[i, p // PS], p % PS],
+            qk[:, i].reshape(cfg.num_layers, n, H))
+    worst = 0.0
     for t in range(steps):
         tok = ids[:, n + t][:, None]
         pos = np.full(ROWS, n + t, np.int32)
-        want, ck, cv = decoder.decode_step(params, head, cfg, tok, ck, cv,
-                                           pos, kv_scales=scales)
         got, pk, pv = decoder.paged_decode_step(
             params, head, cfg, tok, pk, pv, table, pos, kv_scales=scales)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-4, rtol=2e-4)
+        got = np.asarray(got)
+        worst = max(worst, float(np.abs(got - want[:, n + t]).max()))
+        # the step wrote its own token's quantized K where the table says
+        assert np.abs(np.asarray(pk)[:, table[:, (n + t) // PS],
+                                     (n + t) % PS]).sum() > 0
+    assert worst < 2e-3, worst
 
 
 # ------------------------------------------- the engines, rung by rung
@@ -295,10 +302,8 @@ def engines():
                 max_new_tokens=8)
     pag = PagedDecodeEngine(args, tokenizer=tok, mesh=None, buckets=(16,),
                             page_sz=8)
-    slot = DecodeEngine(args, tokenizer=tok, mesh=None, buckets=(16,))
     pag.warmup_decode()
-    slot.warmup_decode()
-    return tok, pag, slot
+    return tok, pag
 
 
 def drive(eng, prompt, max_new):
@@ -310,17 +315,25 @@ def drive(eng, prompt, max_new):
     return out
 
 
-def test_greedy_tokens_equal_the_slot_engines_across_every_rung(engines):
+def test_greedy_tokens_equal_the_dense_recomputes_across_every_rung(engines):
     """One stream from 9 positions to the limit: it crosses every page
-    boundary and every rung of the decode extent; token for token the slot
-    engine's continuation, and nothing traced on the way."""
-    tok, pag, slot = engines
+    boundary and every rung of the decode extent; every token it serves is
+    the argmax of the float32 dense forward over everything before it (the
+    served sequence re-scored whole, one wide pass), and nothing is traced
+    on the way."""
+    tok, pag = engines
     assert pag.decode_rungs == [2, 4, 6, 8]
     before = pag.metrics.retraces.value
     seen0 = set(pag._seen_shapes)
     prompt = np.random.default_rng(11).integers(5, tok.vocab_size, 9).tolist()
     new = 64 - 9 - 1
-    assert drive(pag, prompt, new) == drive(slot, prompt, new)
+    served = drive(pag, prompt, new)
+    assert len(served) == new
+    want = dense_logits((pag.cfg, pag.params, pag.head),
+                        [prompt + served])[0]
+    # position t's logits choose token t + 1
+    chosen = np.argmax(want[len(prompt) - 1:-1], -1).tolist()
+    assert served == chosen
     assert pag.metrics.retraces.value == before
     assert set(pag._seen_shapes) == seen0
     assert {k[2] for k in seen0 if k[0] == "decode"} == {2, 4, 6, 8}

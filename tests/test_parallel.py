@@ -109,7 +109,7 @@ def single_device_reference(args, batch):
 @pytest.mark.parametrize("mode", ["dp", "zero"])
 def test_parallel_loss_matches_single_device(mode, ndev):
     """The north-star correctness check: the same global batch through the
-    mesh gives the same loss/metrics as one device (VERDICT.md item 3)."""
+    mesh gives the same loss/metrics as one device."""
     args = tiny_args()
     batch = fake_batch(32)
     ref_loss, ref_correct, ref_state = single_device_reference(args, batch)

@@ -127,13 +127,16 @@ def test_mask_tokens_never_touches_specials(tok):
 
 # ----------------------------------------------------------- end-to-end
 
-def test_pretrain_then_finetune_warmstart(tmp_path, ndev, capsys):
+def test_pretrain_then_finetune_warmstart(tmp_path, ndev, capsys,
+                                          corpus_path):
     """Tiny real pretrain run: loss decreases, checkpoint written, encoder
     loads into a fine-tune model with classifier left fresh, and the
     fine-tune entry (setup_sharded_model with init_from) accepts it."""
     args = Args(strategy="pretrain", model="bert-tiny", max_seq_len=32,
                 train_batch_size=8, epochs=3, learning_rate=1e-3,
                 pretrain_limit=300, output_dir=str(tmp_path),
+                data_path=corpus_path,
+                vocab_path=str(tmp_path / "vocab.txt"),
                 log_every=10 ** 9, dropout=0.0, attn_dropout=0.0)
     path = run_pretrain(args)
 
@@ -149,7 +152,8 @@ def test_pretrain_then_finetune_warmstart(tmp_path, ndev, capsys):
 
     vocab_size = len(get_or_build_vocab(args))
     ft_args = Args(model="bert-tiny", max_seq_len=32, init_from=path,
-                   output_dir=str(tmp_path), dropout=0.0, attn_dropout=0.0)
+                   output_dir=str(tmp_path), data_path=corpus_path,
+                   vocab_path=args.vocab_path, dropout=0.0, attn_dropout=0.0)
     mesh = make_mesh()
     cfg, tx, state, shardings = setup_sharded_model(ft_args, vocab_size, mesh, "dp")
     # warm-started encoder == pretrained encoder
@@ -166,31 +170,38 @@ def test_pretrain_then_finetune_warmstart(tmp_path, ndev, capsys):
         np.asarray(state["params"]["layers"]["q"]["kernel"]), rtol=0, atol=0)
 
 
-def test_supervised_corpus_is_disjoint_from_the_protocol_split():
+def test_supervised_corpus_is_disjoint_from_the_protocol_split(corpus_path):
     """The supervised stage trains only on labeled examples OUTSIDE the
-    reference's [:10000] slice, with dev-duplicate texts dropped — no label
-    of any dev text is ever seen."""
+    reference's [:10000] slice (the first two thirds of conftest's synthetic
+    corpus where the real one is absent), with dev-duplicate texts dropped
+    — no label of any dev text is ever seen."""
     from pdnlp_tpu.data.corpus import load_data, split_data
 
-    args = Args()
+    data = load_data(corpus_path)
+    real = len(data) > 35_000
+    args = Args(data_path=corpus_path) if real else \
+        Args(data_path=corpus_path, data_limit=2 * len(data) // 3)
     ext = build_supervised_corpus(args)
-    data = load_data(args.data_path)
     train, dev = split_data(data, seed=args.seed, limit=args.data_limit,
                             ratio=args.ratio)
     dev_texts = {t for t, _ in dev}
-    assert len(ext) > 25_000                       # the slice is actually used
+    # the slice is actually used
+    assert len(ext) > (25_000 if real else len(data) // 4)
     assert not any(t in dev_texts for t, _ in ext)  # zero dev leakage
     # exactly the post-slice examples minus dev-duplicate texts, in order
     expected = [(t, l) for t, l in data[args.data_limit:] if t not in dev_texts]
     assert ext == expected
 
 
-def test_supervised_stage_trains_and_head_restores(tmp_path, ndev):
+def test_supervised_stage_trains_and_head_restores(tmp_path, ndev,
+                                                   corpus_path):
     """Tiny real supervised stage: checkpoint carries pooler+classifier,
     --init_head restores them bit-exactly, and head=True on an MLM-only
     checkpoint fails loudly."""
-    common = dict(model="bert-tiny", max_seq_len=32, data_limit=500,
-                  output_dir=str(tmp_path), log_every=10 ** 9,
+    common = dict(model="bert-tiny", max_seq_len=32, data_limit=400,
+                  output_dir=str(tmp_path), data_path=corpus_path,
+                  vocab_path=str(tmp_path / "vocab.txt"),
+                  log_every=10 ** 9,
                   dropout=0.0, attn_dropout=0.0)
     mlm_path = run_pretrain(Args(strategy="pretrain", train_batch_size=8,
                                  epochs=1, learning_rate=1e-3,

@@ -29,7 +29,7 @@ COMMON_ARGS = [
 
 
 @pytest.fixture(scope="module")
-def spawn_run(tmp_path_factory):
+def spawn_run(tmp_path_factory, corpus_cli):
     """Run the spawn launcher once (2 procs x 4 virtual CPU devices)."""
     out = tmp_path_factory.mktemp("spawn")
     env = dict(os.environ)
@@ -41,7 +41,8 @@ def spawn_run(tmp_path_factory):
     env.pop("PROCESS_ID", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "multi-tpu-spawn-cls.py"),
-         "--num_processes", "2", "--output_dir", str(out), *COMMON_ARGS],
+         "--num_processes", "2", "--output_dir", str(out), *COMMON_ARGS,
+         *corpus_cli],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900,
     )
     return proc, out
@@ -57,7 +58,7 @@ def test_spawn_completes_and_checkpoints(spawn_run):
     assert "mesh: {'data': 8}" in proc.stdout
 
 
-def test_spawn_matches_single_process(spawn_run, ndev):
+def test_spawn_matches_single_process(spawn_run, ndev, corpus_files):
     """Same global batch (4 x 4 x 2 == 4 x 8), same seed, no dropout ->
     the 2-process run must reproduce the single-process loss trace and
     final parameters (up to collective reassociation)."""
@@ -71,7 +72,7 @@ def test_spawn_matches_single_process(spawn_run, ndev):
     args = Args(strategy="spawn", model="bert-tiny", data_limit=600,
                 max_seq_len=32, train_batch_size=4, dtype="float32",
                 dropout=0.0, attn_dropout=0.0, epochs=1,
-                output_dir=str(out), log_every=1)
+                output_dir=str(out), log_every=1, **corpus_files)
     trainer, train_loader, dev_loader = build_parallel_trainer(args, mode="dp")
     single_losses = []
     for batch in train_loader:
@@ -99,7 +100,7 @@ def test_spawn_matches_single_process(spawn_run, ndev):
 
 
 @pytest.fixture(scope="module")
-def spawn_zero_run(tmp_path_factory):
+def spawn_zero_run(tmp_path_factory, corpus_cli):
     """``--mode zero`` across 2 real processes x 2 CPU devices: a 4-way
     ``{"data": 4}`` mesh whose param/moment shards live on BOTH processes —
     the reference's actual DeepSpeed deployment shape
@@ -118,7 +119,7 @@ def spawn_zero_run(tmp_path_factory):
         [sys.executable, os.path.join(REPO, "multi-tpu-spawn-cls.py"),
          "--num_processes", "2", "--mode", "zero",
          "--ckpt_name", "zero-spawn.msgpack",
-         "--output_dir", str(out), *COMMON_ARGS],
+         "--output_dir", str(out), *COMMON_ARGS, *corpus_cli],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900,
     )
     return proc, out
@@ -136,7 +137,7 @@ def test_spawn_zero_executes_across_processes(spawn_zero_run):
     assert (out / "zero-spawn.msgpack").exists()
 
 
-def test_spawn_zero_matches_single_process(spawn_zero_run, ndev):
+def test_spawn_zero_matches_single_process(spawn_zero_run, ndev, corpus_files):
     """The 2-process ZeRO run must reproduce a single-process run of the
     same global configuration (4-way sharded state, global batch 16), and
     its consolidated checkpoint must reassemble the full parameters."""
@@ -150,7 +151,7 @@ def test_spawn_zero_matches_single_process(spawn_zero_run, ndev):
     args = Args(strategy="zero-spawn-ref", model="bert-tiny", data_limit=600,
                 max_seq_len=32, train_batch_size=4, dtype="float32",
                 dropout=0.0, attn_dropout=0.0, epochs=1, num_devices=4,
-                output_dir=str(out), log_every=1)
+                output_dir=str(out), log_every=1, **corpus_files)
     trainer, train_loader, _ = build_parallel_trainer(args, mode="zero")
     single_losses = []
     for batch in train_loader:
@@ -176,7 +177,7 @@ def test_spawn_zero_matches_single_process(spawn_zero_run, ndev):
 
 
 @pytest.fixture(scope="module")
-def spawn_pp_run(tmp_path_factory):
+def spawn_pp_run(tmp_path_factory, corpus_cli):
     """``--mode pp`` across 2 real processes x 1 CPU device each: a
     ``{"stage": 2}`` pipeline whose stage boundary IS the process boundary —
     every ``ppermute`` activation transfer crosses processes."""
@@ -194,7 +195,7 @@ def spawn_pp_run(tmp_path_factory):
          "--num_processes", "2", "--mode", "pp",
          "--mesh_shape", '{"stage": 2}', "--microbatches", "2",
          "--ckpt_name", "pp-spawn.msgpack",
-         "--output_dir", str(out), *COMMON_ARGS],
+         "--output_dir", str(out), *COMMON_ARGS, *corpus_cli],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900,
     )
     return proc, out
@@ -208,7 +209,7 @@ def test_spawn_pp_executes_across_processes(spawn_pp_run):
     assert (out / "pp-spawn.msgpack").exists()
 
 
-def test_spawn_pp_matches_single_process(spawn_pp_run, ndev):
+def test_spawn_pp_matches_single_process(spawn_pp_run, ndev, corpus_files):
     """The cross-process pipeline must reproduce an in-process run of the
     identical {"stage": 2} mesh (same global batch, same microbatching)."""
     proc, out = spawn_pp_run
@@ -222,7 +223,7 @@ def test_spawn_pp_matches_single_process(spawn_pp_run, ndev):
                 max_seq_len=32, train_batch_size=4, dtype="float32",
                 dropout=0.0, attn_dropout=0.0, epochs=1,
                 mesh_shape={"stage": 2}, microbatches=2,
-                output_dir=str(out), log_every=1)
+                output_dir=str(out), log_every=1, **corpus_files)
     trainer, train_loader, _ = build_pipeline_trainer(args)
     single_losses = []
     for batch in train_loader:
@@ -247,7 +248,7 @@ def test_spawn_pp_matches_single_process(spawn_pp_run, ndev):
     np.testing.assert_allclose(flat_a, flat_b, rtol=1e-3, atol=1e-5)
 
 
-def test_spawn_tp_across_processes(tmp_path):
+def test_spawn_tp_across_processes(tmp_path, corpus_files, corpus_cli):
     """``--mode tp`` with the MODEL axis spanning the process boundary
     (``{"data": 1, "model": 2}`` over 2 procs x 1 device): the data axis is
     process-replicated — every host feeds the full batch
@@ -267,7 +268,7 @@ def test_spawn_tp_across_processes(tmp_path):
          "--num_processes", "2", "--mode", "tp",
          "--mesh_shape", '{"data": 1, "model": 2}',
          "--ckpt_name", "tp-spawn.msgpack",
-         "--output_dir", str(tmp_path), *COMMON_ARGS,
+         "--output_dir", str(tmp_path), *COMMON_ARGS, *corpus_cli,
          "--data_limit", "300"],  # after COMMON_ARGS: the override wins
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900,
     )
@@ -284,7 +285,7 @@ def test_spawn_tp_across_processes(tmp_path):
                 max_seq_len=32, train_batch_size=4, dtype="float32",
                 dropout=0.0, attn_dropout=0.0, epochs=1, num_devices=2,
                 mesh_shape={"data": 1, "model": 2},
-                output_dir=str(tmp_path), log_every=1)
+                output_dir=str(tmp_path), log_every=1, **corpus_files)
     trainer, train_loader, _ = build_parallel_trainer(args, mode="tp")
     single_losses = []
     for batch in train_loader:
@@ -310,7 +311,7 @@ def test_spawn_tp_across_processes(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def spawn_sp_run(tmp_path_factory):
+def spawn_sp_run(tmp_path_factory, corpus_cli):
     """``--mode sp`` across 2 real processes x 1 CPU device each: a
     ``{"data": 1, "seq": 2}`` mesh whose sequence axis IS the process
     boundary — ring attention's ``ppermute`` KV rotation crosses processes
@@ -329,7 +330,7 @@ def spawn_sp_run(tmp_path_factory):
          "--num_processes", "2", "--mode", "sp",
          "--mesh_shape", '{"data": 1, "seq": 2}',
          "--ckpt_name", "sp-spawn.msgpack",
-         "--output_dir", str(out), *COMMON_ARGS],
+         "--output_dir", str(out), *COMMON_ARGS, *corpus_cli],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900,
     )
     return proc, out
@@ -343,7 +344,7 @@ def test_spawn_sp_executes_across_processes(spawn_sp_run):
     assert (out / "sp-spawn.msgpack").exists()
 
 
-def test_spawn_sp_matches_single_process(spawn_sp_run, ndev):
+def test_spawn_sp_matches_single_process(spawn_sp_run, ndev, corpus_files):
     """The cross-process ring must reproduce an in-process run of the
     identical {"data": 1, "seq": 2} mesh — same global batch, same seeded
     streams; the only difference is WHERE the ring's ppermute hops land."""
@@ -358,7 +359,7 @@ def test_spawn_sp_matches_single_process(spawn_sp_run, ndev):
                 max_seq_len=32, train_batch_size=4, dtype="float32",
                 dropout=0.0, attn_dropout=0.0, epochs=1,
                 mesh_shape={"data": 1, "seq": 2}, num_devices=2,
-                output_dir=str(out), log_every=1)
+                output_dir=str(out), log_every=1, **corpus_files)
     trainer, train_loader, _ = build_sp_trainer(args)
     single_losses = []
     for batch in train_loader:

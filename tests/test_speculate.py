@@ -10,8 +10,8 @@ seed): with untrained weights a genuinely different drafter never agrees
 with the primary's argmax, so the identical pair is what exercises the
 accept/commit machinery at a real acceptance ceiling — the parity
 contract itself is acceptance-independent (verify-1 commits only the
-primary's own greedy tokens), and ``bench.py --decode`` gates the
-speedup side with a host-calibrated cost model."""
+primary's own greedy tokens); the speedup side is not measured (no
+benchmark cell speculates: ROADMAP, Design 8)."""
 import os
 import sys
 
@@ -27,7 +27,7 @@ from pdnlp_tpu.obs.exporter import prometheus_lines  # noqa: E402
 from pdnlp_tpu.obs.request import chain_issues, validate_chains  # noqa: E402
 from pdnlp_tpu.obs.trace import Tracer  # noqa: E402
 from pdnlp_tpu.serve import (  # noqa: E402
-    DecodeBatcher, DecodeEngine, DecodeRouter, PagedDecodeEngine,
+    DecodeBatcher, DecodeRouter, PagedDecodeEngine,
     ServeController,
 )
 from pdnlp_tpu.utils.config import Args  # noqa: E402
@@ -389,13 +389,14 @@ def test_router_spec_knob_and_exporter_labels(spair):
 
 
 def test_batcher_rejects_bad_drafter_pairings(spair, tok):
-    """Ctor validation: slot engines cannot speculate (page custody is
-    the mechanism) and a prefix-sharing drafter is refused (its cold
-    prefill rewrites pages in place)."""
+    """Ctor validation: a prefix-sharing drafter is refused (its cold
+    prefill rewrites pages in place), and so is one whose geometry does not
+    line up with the primary's slots and positions."""
     eng, _ = spair
-    slot_eng = DecodeEngine(make_args(), tokenizer=tok, mesh=None,
-                            buckets=BUCKETS)
-    with pytest.raises(ValueError, match="PAGED"):
-        DecodeBatcher(eng, drafter=slot_eng)
     with pytest.raises(ValueError, match="prefix_share"):
         DecodeBatcher(eng, drafter=eng)  # primary shares prefixes
+    short = PagedDecodeEngine(make_args(decode_max_len=eng.max_len // 2),
+                              tokenizer=tok, mesh=None, buckets=BUCKETS,
+                              prefix_share=False)
+    with pytest.raises(ValueError, match="geometry"):
+        DecodeBatcher(eng, drafter=short)

@@ -207,7 +207,7 @@ def test_weighted_ce_label_smoothing():
     assert float(correct0) == float(correct1)  # accuracy ignores smoothing
 
 
-def test_ema_weights_tracked_and_evaluated():
+def test_ema_weights_tracked_and_evaluated(corpus_path, tmp_path):
     """--ema_decay: the state carries an EMA tree the step maintains
     (decay 0 -> EMA == live params exactly; 0<d<1 -> strictly between init
     and live), and eval/checkpoint read the EMA weights."""
@@ -222,7 +222,9 @@ def test_ema_weights_tracked_and_evaluated():
 
     kw = dict(model="bert-tiny", data_limit=400, max_seq_len=16,
               train_batch_size=8, dropout=0.0, attn_dropout=0.0,
-              learning_rate=1e-3, log_every=10 ** 9)
+              learning_rate=1e-3, log_every=10 ** 9, data_path=corpus_path,
+              vocab_path=str(tmp_path / "vocab.txt"),
+              output_dir=str(tmp_path))
     tr, loader, _ = build_parallel_trainer(
         Args(strategy="ema-t", ema_decay=0.9, **kw), mode="dp")
     assert "ema" in tr.state
