@@ -562,6 +562,9 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     extent) over the positions that were live (``kv_positions_live``); per
     decode step it is the same ratio, both sums having ``steps`` terms.
     ``chunk_kv_read_amplification`` the same over ``chunk.dispatch``.
+    ``decode_row_rungs`` = the share of decode steps launched at each row
+    extent (``rows`` of ``decode.dispatch``: the engine's row rung, chosen
+    by the highest attached slot), keyed by the extent.
     ``expert_load`` (a family with sparse experts; from ``decode.fetch``'s
     ``expert_assignments`` / ``expert_tokens_max`` / ``experts_idle``):
     assignments to the held experts a decode step, the busiest held expert's
@@ -575,6 +578,7 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     kv: Dict[object, Dict[str, List[int]]] = {}    # rep -> call -> [read, live]
     experts: Dict[object, List[int]] = {}   # rep -> [launches, sum, max, idle]
     fetched: Dict[object, List[int]] = {}   # rep -> [decode fetches, bytes]
+    rungs: Dict[object, Dict[int, int]] = {}   # rep -> rows launched -> steps
     token_bytes: Dict[object, int] = {}
     edges: Dict[object, List[float]] = {}
     compiling: Dict[object, bool] = {}   # tid -> inside a compiling call
@@ -599,6 +603,9 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             acc[1] += int(attrs.get("kv_positions_live", 0))
         if "cache_bytes_per_token" in attrs:
             token_bytes[rep] = int(attrs["cache_bytes_per_token"])
+        if name == "decode.dispatch" and "rows" in attrs:
+            acc, rows = rungs.setdefault(rep, {}), int(attrs["rows"])
+            acc[rows] = acc.get(rows, 0) + 1
         if name == "decode.fetch" and "bytes" in attrs:
             acc = fetched.setdefault(rep, [0, 0])
             acc[0] += 1
@@ -629,6 +636,10 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
                 "assignments_per_step": round(total / n, 3),
                 "busiest_expert_tokens_per_step": round(most / n, 3),
                 "idle_experts_per_step": round(idle / n, 3)}
+        if rep in rungs:
+            amplification["decode_row_rungs"] = {
+                str(rows): round(n / steps, 4)
+                for rows, n in sorted(rungs[rep].items())}
         if rep in token_bytes:
             amplification["cache_bytes_per_token"] = token_bytes[rep]
         if rep in fetched:
@@ -675,6 +686,10 @@ def format_decode_table(by_replica: Dict) -> str:
                 + (f"; per chunk launch "
                    f"{b['chunk_kv_read_amplification']:.3f}"
                    if "chunk_kv_read_amplification" in b else ""))
+        if "decode_row_rungs" in b:
+            lines.append("  decode steps by rows launched: " + ", ".join(
+                f"{share:.1%} at {rows}"
+                for rows, share in b["decode_row_rungs"].items()))
         if "expert_load" in b:
             e = b["expert_load"]
             lines.append(

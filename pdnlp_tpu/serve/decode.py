@@ -22,10 +22,12 @@ decode batch partially empty (continuous batching).
   the classifier engine (one trace per bucket, warmup pre-traces all);
   their new rows scatter into the claimed pages (out-of-bounds filler
   indices DROPPED — filler never touches a live page).  Decode is a
-  ``[slots, 1]`` program per rung of the attention extent — retrace-free
-  by the same construction as ``infer_packed``: after
-  :meth:`PagedDecodeEngine.warmup_decode` every rung is compiled and
-  nothing live traffic does can create another.
+  ``[rows, 1]`` program per rung of the row extent (the seated rows, which
+  the batcher keeps packed at the bottom of the slot block) and of the
+  attention extent — retrace-free by the same construction as
+  ``infer_packed``: after :meth:`PagedDecodeEngine.warmup_decode` every
+  pair of rungs is compiled and nothing live traffic does can create
+  another.
 - **continuous batching** (:class:`DecodeBatcher`): between decode steps,
   finished streams leave and waiting streams claim freed slots (prefill
   rides the same worker, so the decode batch is re-filled before the
@@ -101,6 +103,7 @@ custody story (``pages``/``bytes``/``from_replica``/``to_replica``/
 """
 from __future__ import annotations
 
+import heapq
 import queue
 import sys
 import threading
@@ -261,8 +264,9 @@ class PagedDecodeEngine(InferenceEngine):
     takes the pools donated, writes rows or whole pages in place and
     reads whole pages through the table.  The decode step attends over a
     RUNG of page counts (:attr:`decode_rungs`, quarters of a stream's
-    pages) chosen per step by the longest row: fixed-shape programs, one
-    per rung, all traced in :meth:`warmup_decode`.
+    pages) chosen per step by the longest row, for a RUNG of rows
+    (:attr:`row_rungs`) chosen by the highest attached slot: fixed-shape
+    programs, one per pair, all traced in :meth:`warmup_decode`.
 
     Prefix sharing rides the :class:`~pdnlp_tpu.serve.kvpage.PrefixIndex`:
     a repeated prompt maps the indexed pages at refcount+1 and skips its
@@ -292,6 +296,10 @@ class PagedDecodeEngine(InferenceEngine):
     #: rungs of the decode step's attention extent (quarters of a stream's
     #: pages): each is one compiled program, all traced in warmup
     DECODE_RUNGS = 4
+    #: the small rung of the decode step's ROW extent (one bf16 tile of
+    #: sublanes: fewer rows save the chip nothing), for an engine of at
+    #: least four times as many slots; a smaller engine launches every row
+    ROW_RUNG = 16
 
     def __init__(self, args, tokenizer=None, *, mesh=None, metrics=None,
                  tracer=None, slots: Optional[int] = None,
@@ -366,6 +374,11 @@ class PagedDecodeEngine(InferenceEngine):
                   file=sys.stderr)
         m = self.rows_multiple
         self.slots = max(m, (requested // m) * m)
+        # the decode step's row extents: one warmed program per (row rung,
+        # page rung), chosen per step by the highest attached slot
+        small = self.pad_rows(self.ROW_RUNG)
+        self.row_rungs = ((small, self.slots) if self.slots >= 4 * small
+                          else (self.slots,))
         self.prefill_rows = self.pad_rows(
             min(self.slots, int(prefill_rows or 8)))
         # prompt buckets: the serve bucket ladder capped at max_len, with
@@ -1152,37 +1165,52 @@ class PagedDecodeEngine(InferenceEngine):
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
                      live: int, request_ids=None) -> Chosen:
-        """One fixed-shape decode step over the whole slot block: tokens
-        ``[slots]`` (current token per slot; dead slots ride with junk),
-        ``pos`` ``[slots]`` write positions, reading whole pages through
-        the per-slot page tables.  Returns each slot's next token
-        (``.ids``: the ``slots x 4`` bytes the host fetches) over the
-        ``[slots, vocab]`` fp32 logits, which stay on the device until a
-        caller reads them (:class:`Chosen`).  The table is data, not
-        shape; its WIDTH is the attention extent, cut to the smallest
-        rung of :attr:`decode_rungs` that reaches the longest row — compile
-        key ``("decode", slots, rung)``, every rung traced in warmup, so
-        paging cannot retrace."""
+        """One fixed-shape decode step over the slot block's seated rows:
+        tokens ``[slots]`` (current token per slot; dead slots ride with
+        junk), ``pos`` ``[slots]`` write positions, reading whole pages
+        through the per-slot page tables.  The launch covers rows ``[0,
+        r)``, ``r`` the smallest rung of :attr:`row_rungs` above the highest
+        ATTACHED slot (the batcher seats the lowest free slot, so ``r``
+        follows how many streams are seated); rows at ``r`` and above are
+        not computed.  Returns each launched row's next token (``.ids``: the
+        ``r x 4`` bytes the host fetches) over the ``[r, vocab]`` fp32
+        logits, which stay on the device until a caller reads them
+        (:class:`Chosen`).  The table is data, not shape; its WIDTH is the
+        attention extent, cut to the smallest rung of :attr:`decode_rungs`
+        that reaches the longest of those rows — compile key ``("decode",
+        r, rung)``, every pair traced in warmup, so neither seating nor
+        paging can retrace."""
+        return self._decode_rows(tokens, pos, live, request_ids)
+
+    def _decode_rows(self, tokens: np.ndarray, pos: np.ndarray, live: int,
+                     request_ids=None, r: Optional[int] = None) -> Chosen:
+        """:meth:`decode_batch` at the row rung ``r`` (warmup names rungs no
+        attached slot calls for; ``None``: the one the table calls for)."""
         self._flush_cow()
         with self.tracer.leaf("decode.dispatch", self.span_attrs) as sp:
-            tok = np.asarray(tokens, np.int32).reshape(self.slots, 1)
-            p = np.clip(np.asarray(pos, np.int32), 0, self.max_len - 1)
+            if r is None:
+                attached = np.flatnonzero(self._table[:, 0] < self.n_pages)
+                top = int(attached[-1]) + 1 if len(attached) else 0
+                r = next(g for g in self.row_rungs if g >= top)
+            tok = np.asarray(tokens, np.int32).reshape(self.slots, 1)[:r]
+            p = np.clip(np.asarray(pos, np.int32), 0, self.max_len - 1)[:r]
+            table = self._table[:r]
             need = pages_needed(int(p.max()) + 1, self.page_sz)
-            rung = next(r for r in self.decode_rungs if r >= need)
-            phase = self._seen(("decode", int(self.slots), rung), "decode")
+            rung = next(g for g in self.decode_rungs if g >= need)
+            phase = self._seen(("decode", r, rung), "decode")
             if sp:
-                alive = self._table[:, 0] < self.n_pages
-                sp.set(phase=phase, rows=int(self.slots), live=int(live),
+                alive = table[:, 0] < self.n_pages
+                sp.set(phase=phase, rows=r, live=int(live),
                        decode=True, paged=True,
                        pages_live=self.allocator.used_pages,
-                       kv_positions_read=self.slots * rung * self.page_sz,
+                       kv_positions_read=r * rung * self.page_sz,
                        kv_positions_live=int((p[alive] + 1).sum()),
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
             logits, chosen, aux, self._pools = self._jit_pdecode(
                 self.params, self.head, self._pools, tok,
-                self._table[:, :rung], p, *self._scale_args())
+                table[:, :rung], p, *self._scale_args())
         return self._fetch_chosen(logits, chosen, "decode", aux)
 
     def verify_ids(self, window: np.ndarray, pos: np.ndarray,
@@ -1232,8 +1260,9 @@ class PagedDecodeEngine(InferenceEngine):
 
     def warmup_decode(self) -> None:
         """Pre-trace every reachable paged shape: per-bucket prefill +
-        paged insert, per-bucket suffix chunk, the ONE decode step, the
-        fixed COW copy, and the int8 calibration if pending."""
+        paged insert, per-bucket suffix chunk, the decode step of every
+        (row rung, page rung) pair, the fixed COW copy, and the int8
+        calibration if pending."""
         self._scale_args()
         for b in self.prefill_buckets:
             # a bucket-FILLING dummy, so each bucket traces ITS shape;
@@ -1248,7 +1277,8 @@ class PagedDecodeEngine(InferenceEngine):
             # all-dead rows (sentinel tables write nothing) at a position
             # only this rung reaches
             pos = np.full((self.slots,), rung * self.page_sz - 1, np.int32)
-            self.decode_batch(tok, pos, live=0)
+            for r in self.row_rungs:
+                self._decode_rows(tok, pos, live=0, r=r)
 
     def kv_snapshot(self) -> Dict:
         """Budget block + the paged story: allocator occupancy/free
@@ -1398,9 +1428,11 @@ class _Slot:
 class DecodeBatcher:
     """Continuous batching over one :class:`PagedDecodeEngine`: a single
     worker owns the engine (the repo's one-dispatcher contract) and loops
-    claim → prefill → decode-step, with streams joining freed slots and
-    finished streams leaving BETWEEN steps — the decode batch shape never
-    changes, only which rows are live.
+    claim → prefill → decode-step, with streams joining freed slots (the
+    lowest free index first: no row is ever moved, holes fill from the
+    bottom) and finished streams leaving BETWEEN steps — the decode batch
+    is one of the engine's warmed shapes, and only which rows are live
+    changes.
 
     ``on_death(replica, orphans, error)``: installed by
     :class:`DecodeRouter`; a worker that loses its engine hands over its
@@ -1467,7 +1499,10 @@ class DecodeBatcher:
         self.metrics = dmetrics or DecodeMetrics()
         self.rmetrics = rmetrics or ReplicaMetrics()
         self._slots: List[Optional[_Slot]] = [None] * engine.slots
-        self._free: deque = deque(range(engine.slots))
+        #: free slots as a heap: the LOWEST free index is seated next, so
+        #: the seated rows stay packed at the bottom of the block and the
+        #: engine's decode step covers a row rung, not all of them
+        self._free: List[int] = list(range(engine.slots))
         self._freed_at: Dict[int, float] = {}
         self._waiting: deque = deque()
         #: streams arriving by KV handoff (disaggregated pools): already
@@ -1515,7 +1550,7 @@ class DecodeBatcher:
             self._waiting.clear()
             self._handoffs.clear()
             self._slots = [None] * self.engine.slots
-            self._free = deque(range(self.engine.slots))
+            self._free = list(range(self.engine.slots))
         for i in still_live:
             self.engine.detach_slot(i)  # pages back; leak_check clean
             if self.drafter is not None:
@@ -1710,7 +1745,7 @@ class DecodeBatcher:
         # sunk on the prefill pool, and their payload pins host memory
         # until imported
         while self._free and self._handoffs:
-            slot = self._free.popleft()
+            slot = heapq.heappop(self._free)
             ho = self._handoffs.popleft()
             stream = ho[0]
             try:
@@ -1718,7 +1753,7 @@ class DecodeBatcher:
                 # raw bytes into these pages
                 self.engine.attach_stream(slot, stream, share=False)
             except KVPagesExhausted:
-                self._free.appendleft(slot)
+                heapq.heappush(self._free, slot)
                 self._handoffs.appendleft(ho)
                 break
             freed = self._freed_at.pop(slot, None)
@@ -1734,7 +1769,7 @@ class DecodeBatcher:
             self._slots[slot] = _Slot(stream, ho[1], ho[2])
             imports.append((slot,) + ho)
         while self._free and self._waiting:
-            slot = self._free.popleft()
+            slot = heapq.heappop(self._free)
             stream = self._waiting.popleft()
             try:
                 # the engine reserves the stream's pages here (sharing
@@ -1746,7 +1781,7 @@ class DecodeBatcher:
                 # deadlock.
                 claim = self.engine.attach_stream(slot, stream)
             except KVPagesExhausted:
-                self._free.appendleft(slot)
+                heapq.heappush(self._free, slot)
                 self._waiting.appendleft(stream)
                 break
             if dr is not None:
@@ -1758,7 +1793,7 @@ class DecodeBatcher:
                     # to drain (same no-deadlock floor argument as
                     # above, on the drafter's pool)
                     self.engine.detach_slot(slot)
-                    self._free.appendleft(slot)
+                    heapq.heappush(self._free, slot)
                     self._waiting.appendleft(stream)
                     break
                 except BaseException as e:  # noqa: BLE001
@@ -1829,7 +1864,7 @@ class DecodeBatcher:
             self._waiting.clear()
             self._handoffs.clear()
             self._slots = [None] * self.engine.slots
-            self._free = deque(range(self.engine.slots))
+            self._free = list(range(self.engine.slots))
         for i in still_live:
             self.engine.detach_slot(i)
             if self.drafter is not None:
@@ -1994,7 +2029,7 @@ class DecodeBatcher:
                 freed = time.monotonic()
                 for slot, _, _, _ in done:
                     self._slots[slot] = None
-                    self._free.append(slot)
+                    heapq.heappush(self._free, slot)
                     self._freed_at[slot] = freed
             live_tokens, live_slots = self._kv_live_locked()
         for slot, stream, _, _ in done:
@@ -2097,7 +2132,10 @@ class DecodeBatcher:
             for j in range(k):
                 dlogits = dr.decode_batch(cur, pos + j, live=len(live),
                                           request_ids=rids)
-                cur = np.argmax(dlogits, axis=-1).astype(np.int32)
+                # a launch answers for the rows below ITS row rung, every
+                # live row among them; the rest draft token 0 unread
+                cur = np.zeros_like(tokens)
+                cur[:len(dlogits)] = np.argmax(dlogits, axis=-1)
                 window[:, j + 1] = cur
         except BaseException as e:  # noqa: BLE001 — drafter death must
             self._degrade_drafter(e)  # never take the primary with it
@@ -2271,7 +2309,7 @@ class DecodeBatcher:
             self._waiting.clear()
             self._handoffs.clear()
             self._slots = [None] * self.engine.slots
-            self._free = deque(range(self.engine.slots))
+            self._free = list(range(self.engine.slots))
             self.rmetrics.ejections.inc()
             self._wake.notify_all()
         if self.on_death is not None:

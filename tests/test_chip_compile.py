@@ -151,7 +151,8 @@ def test_compiles_for_v5e(case, grid_hints, one_chip):
 
 def _paged_case(program):
     """A paged program at bert-base's widths (two layers: the layout is the
-    pool's ``[page_sz, hidden]`` tail, not its depth), 128 rows, pools of
+    pool's ``[page_sz, hidden]`` tail, not its depth), 128 rows (16 for the
+    decode step's small row rung), pools of
     16 384 pages donated (0.75 GiB each: far above the step's gathered
     K/V and logits).  -> (fn, donate_argnums, shapes, pool bytes)."""
     from pdnlp_tpu.models import decoder
@@ -161,11 +162,13 @@ def _paged_case(program):
     params = jax.eval_shape(lambda: bert.init_params(jax.random.key(0), cfg))
     head = jax.eval_shape(lambda: decoder.init_lm_head(jax.random.key(0),
                                                        cfg))
-    P, ps, rows, MP = 16384, 16, 128, 32
+    P, ps, MP = 16384, 16, 32
+    # the engine's two row rungs at 128 slots (PagedDecodeEngine.row_rungs)
+    rows = 16 if program == "decode-16-rows" else 128
     S, i32, bf = jax.ShapeDtypeStruct, jnp.int32, jnp.bfloat16
     pool = S((cfg.num_layers, P, ps, H), bf)
     nbytes = cfg.num_layers * P * ps * H * 2
-    if program == "decode":
+    if program.startswith("decode"):
         def fn(params, head, pk, pv, tok, table, pos):
             return decoder.paged_decode_step(params, head, cfg, tok, pk, pv,
                                              table, pos, dtype=bf)
@@ -183,7 +186,8 @@ def _paged_case(program):
                                           S((8, 256 // ps), i32)), nbytes
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk", "insert"])
+@pytest.mark.parametrize("program", ["decode", "decode-16-rows", "chunk",
+                                     "insert"])
 def test_paged_programs_leave_the_pool_where_it_lies(program, one_chip):
     """The chip's compiler at the chip's layout: the donated pools are
     aliased to the outputs, no temporary is as large as one pool, and the

@@ -448,6 +448,38 @@ def test_dispatch_leaves_count_what_attention_covers_and_what_is_live(
     assert f"KV read amplification per decode step: {amp:.3f}" in text
 
 
+def test_summarize_prints_the_share_of_decode_steps_at_each_row_rung(
+        traced, engine):
+    """``decode.dispatch``'s ``rows`` is the row rung the step launched
+    (an engine of four slots has the one); ``summarize`` prints the share
+    of decode steps at each, in the order of the rungs."""
+    recs = traced["records"]
+    worker = decode_host_phases(recs)["0"]
+    assert engine.row_rungs == (engine.slots,)
+    assert worker["decode_row_rungs"] == {str(engine.slots): 1.0}
+    assert (f"decode steps by rows launched: 100.0% at {engine.slots}"
+            in format_decode_table({"0": worker}))
+    # the same steps as an engine of 128 slots would state them: every
+    # fourth at the top rung
+    steps = 0
+    rewritten = []
+    for r in recs:
+        if r["name"] == "decode.dispatch" \
+                and r["attrs"].get("phase") != "compile":
+            r = {**r, "attrs": {**r["attrs"],
+                                "rows": 128 if steps % 4 == 3 else 16}}
+            steps += 1
+        rewritten.append(r)
+    worker = decode_host_phases(rewritten)["0"]
+    assert worker["steps"] == steps >= 8
+    top, small = steps // 4 / steps, (steps - steps // 4) / steps
+    assert worker["decode_row_rungs"] == {
+        "16": round(small, 4), "128": round(top, 4)}
+    assert (f"decode steps by rows launched: {round(small, 4):.1%} at 16, "
+            f"{round(top, 4):.1%} at 128"
+            in format_decode_table({"0": worker}))
+
+
 # ------------------------------------------------- trace_tpu.py summarize
 
 def test_summarize_prints_the_decode_workers_host_phase_table():
