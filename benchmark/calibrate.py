@@ -10,8 +10,15 @@ from.  Not part of a run; the driver never calls it.
         per seed: the served tokens' widest gap, and the control's.
     python -m benchmark.calibrate probe-train <cell> <batch> [<batch> ...]
         per-chip batch against peak memory and rate.
-    python -m benchmark.calibrate sweep <cell> <seconds> <rate> [<rate> ...]
-        one short run per session rate; prints tails and the backlog's growth.
+    python -m benchmark.calibrate sweep <cell> <seconds> [seed=<n>] <rate> ...
+        one short run per session rate (seeds <n>, <n>+1, ...; 1000 unless
+        given); prints tails, the backlog's growth, the rows seated and the
+        generator's lateness; stops after the first rate past the knee
+        (requests failed, or the second half's median time to first token
+        15 % and 10 ms above the first's).
+    python -m benchmark.calibrate probe-slots <cell> <seconds> <slots> ...
+        a closed-loop cell once a slot count (as many clients): tokens/s,
+        a plain round, the window's thirds and peak memory.
 
 Each seed or rate is a run of the cell's own kind in this process, one after
 another, so that the compile cache is shared.
@@ -119,25 +126,54 @@ def probe_train(cell_name, batches):
                     "checks": {r["check"]: r["value"] for r in res["checks"]}})
 
 
-def sweep(cell_name, seconds, rates):
+def probe_slots(cell_name, seconds, slots):
+    """Slots (= clients) against tokens/s and peak memory."""
+    import importlib
+
+    for k, n in enumerate(slots):
+        cell = common.Cell(cell_name)
+        cell.config["assumed"]["slots"] = cell.traffic["clients"] = n
+        kind = importlib.import_module("benchmark.kinds." + cell.traffic["kind"])
+        res = kind.run(cell, _ctx(cell, 3000 + k, seconds))
+        c = res["obs"]["counters"]
+        common.say({"slots": n, "memory_peak_bytes": res["memory_peak_bytes"],
+                    "end_to_end": res["end_to_end"], "failed": res["failed"],
+                    "thirds": res["obs"]["thirds"],
+                    "decode_steps_per_s": c["decode_steps"] / c["window_s"],
+                    "checks": {r["check"]: r["value"] for r in res["checks"]}})
+
+
+def sweep(cell_name, seconds, rates, seed=1000):
     import importlib
 
     for k, rate in enumerate(rates):
         cell = common.Cell(cell_name)
         cell.traffic["session_rate_per_s"] = rate
-        ctx = _ctx(cell, 1000 + k, seconds)
+        ctx = _ctx(cell, seed + k, seconds)
         kind = importlib.import_module("benchmark.kinds." + cell.traffic["kind"])
         res = kind.run(cell, ctx)
         c = res["obs"]["counters"]
         s = res["obs"]["samples"]
         half = len(s["ttft_ms"]) // 2
+        first = common.percentile(s["ttft_ms"][:half], 50)
+        second = common.percentile(s["ttft_ms"][half:], 50)
         common.say({"session_rate_per_s": rate, "end_to_end": res["end_to_end"],
                     "failed": res["failed"], "requests": len(s["ttft_ms"]),
-                    "ttft_p50_first_half_ms": common.percentile(s["ttft_ms"][:half], 50),
-                    "ttft_p50_second_half_ms": common.percentile(s["ttft_ms"][half:], 50),
+                    "ttft_p50_first_half_ms": first,
+                    "ttft_p50_second_half_ms": second,
+                    "gen_lateness_p99_ms": common.percentile(s["lateness_ms"], 99),
                     "tokens_per_s": c["tokens_seen"] / c["window_s"],
                     "slot_occupancy": c["live_rows_sum"] / max(1, c["slot_steps"]),
+                    "rows_seated": c["live_rows_sum"] / max(1, c["occupancy_n"]),
                     "decode_steps_per_s": c["decode_steps"] / c["window_s"]})
+        # below the knee the halves' medians agree to a tenth; a queue that
+        # fills from the ramp's start on makes them 1.67 to 1 at most (their
+        # midpoints' times since the start at a 15 s ramp), and one that
+        # fills slowly read 1.23 (PERF.md section 6, PR 34): "twice" never
+        # fires.  10 ms keeps the clock's grain out at the lowest rates
+        if res["failed"] or second > max(1.15 * first, first + 10.0):
+            common.say({"past_the_knee_at": rate})
+            break
 
 
 def main(argv=None):
@@ -153,8 +189,11 @@ def main(argv=None):
             readings_serve(argv[0], float(argv[1]), [int(x) for x in argv[2:]])
         elif what == "probe-train":
             probe_train(argv[0], [int(x) for x in argv[1:]])
+        elif what == "probe-slots":
+            probe_slots(argv[0], float(argv[1]), [int(x) for x in argv[2:]])
         elif what == "sweep":
-            sweep(argv[0], float(argv[1]), [float(x) for x in argv[2:]])
+            seed = int(argv.pop(2)[5:]) if argv[2].startswith("seed=") else 1000
+            sweep(argv[0], float(argv[1]), [float(x) for x in argv[2:]], seed)
         else:
             raise SystemExit(__doc__)
 

@@ -265,7 +265,8 @@ def run(cell, ctx) -> dict:
             prompt, new = next(source)
             submit(prompt, new, lv.client, now)
         if tracing is None and ctx.trace and now >= w_open - tr["trace_lead_s"]:
-            tracing = ctx.start_trace()   # stalls this thread: before the window
+            # stalls this thread: before the window
+            tracing = ctx.start_trace(python_tracer=False)
             continue
         if state["open"] is None and now >= w_open and new_tokens:
             state["open"], state["c0"] = now, counters.read()

@@ -16,6 +16,7 @@ the batcher's own worker.
 """
 from __future__ import annotations
 
+import collections
 import gc
 import json
 import os
@@ -23,7 +24,7 @@ import random
 import sys
 import time
 
-from benchmark import adapters, common, loadgen
+from benchmark import adapters, common, loadgen, spans
 
 POLL_S = 0.001
 DRAIN_S = 30.0
@@ -81,8 +82,9 @@ def build(cell, ctx, sizes, wd):
     adapters.check_same_tree(tree[0], like[0], "parameter")
     adapters.check_same_tree(tree[1], like[1], "LM head")
     engine.params, engine.head = tree
+    # every request names its own ``max_new``; the default is never used
     batcher = DecodeBatcher(engine, max_waiting=eng["max_waiting"],
-                            default_max_new=cell.traffic.get("new_tokens", 64))
+                            default_max_new=64)
     batcher.start()
     batcher.warmup()
     jax.block_until_ready((engine._cache_k, engine._cache_v))
@@ -140,6 +142,7 @@ def run(cell, ctx, closed: bool) -> dict:
     failed = attempted = 0
     lateness, ttft, itl = [], [], []
     bursts_at = []     # when, inside the window, new tokens were seen
+    marks = []         # closed loop: the counts at the window's thirds
     state = {"open": None, "close": None, "tokens": 0, "c0": None, "c1": None,
              "kv_sum": 0.0, "pages_sum": 0.0, "bursts": 0, "last_steps": 0}
     gc.collect()
@@ -197,6 +200,10 @@ def run(cell, ctx, closed: bool) -> dict:
                 finished.append(lv)
         return new_tokens, done
 
+    def mark(now):
+        marks.append((now, state["tokens"], attempted,
+                      batcher.metrics.prefills_total.value))
+
     tracing = None
     i = 0
     setup_s = None
@@ -224,7 +231,8 @@ def run(cell, ctx, closed: bool) -> dict:
                        lv.client)
         # ---- window edges
         if tracing is None and ctx.trace and now >= w_open - tr["trace_lead_s"]:
-            tracing = ctx.start_trace()   # stalls this thread: before the window
+            # stalls this thread: before the window
+            tracing = ctx.start_trace(python_tracer=False)
             continue
         if state["open"] is None and now >= w_open and (new_tokens or not closed):
             state["open"] = now
@@ -232,6 +240,8 @@ def run(cell, ctx, closed: bool) -> dict:
             state["last_steps"] = state["c0"]["decode_steps"]
             setup_s = common.process_age_s()
             w_open, w_close = (now, now + seconds) if closed else (w_open, w_close)
+            if closed:
+                mark(now)
         elif state["open"] is not None and state["close"] is None:
             if new_tokens:
                 state["tokens"] += new_tokens
@@ -244,9 +254,14 @@ def run(cell, ctx, closed: bool) -> dict:
                     state["kv_sum"] += d * sum(l.prompt_len + l.seen
                                                for l in live if l.seen)
                     state["pages_sum"] += d * engine.allocator.used_pages
+                if closed and len(marks) < 3 \
+                        and now >= w_open + len(marks) * seconds / 3.0:
+                    mark(now)
             if now >= w_close and (new_tokens or not closed):
                 state["close"] = now
                 state["c1"] = counters.read()
+                if closed:
+                    mark(now)
         if state["close"] is not None:
             waiting = [l for l in live if l.first_at is None
                        and w_open <= l.due < w_close]
@@ -301,6 +316,7 @@ def run(cell, ctx, closed: bool) -> dict:
         },
         "samples": {"lateness_ms": lateness, "ttft_ms": ttft, "itl_ms": itl},
         "trace": trace, "sizes": sizes, "peaks": ctx.peaks,
+        "thirds": thirds(marks),
     }
     gaps = sorted((b - a) * 1e3 for a, b in zip(bursts_at, bursts_at[1:]))
     common.say({"burst_gap_ms": {"p50": common.percentile(gaps, 50),
@@ -309,7 +325,9 @@ def run(cell, ctx, closed: bool) -> dict:
                 "window_s": window, "requests_due_in_window": len(ttft),
                 "itl_samples": len(itl), "finished": n_finished,
                 "tokens_seen": state["tokens"],
-                "lateness_p99_ms": common.percentile(lateness, 99) if lateness else None})
+                "lateness_p99_ms": common.percentile(lateness, 99) if lateness else None,
+                **({"thirds": obs["thirds"]} if closed else {}),
+                **({"decode_steps_by_rows": steps_by_rows(obs)} if ctx.trace else {})})
     # a latency metric is defined by its name: <sample>_p<N>_ms
     e2e = {"setup_s": setup_s, "decode_tokens_per_s": state["tokens"] / window}
     latency = {"ttft": ttft, "itl": itl}
@@ -320,6 +338,29 @@ def run(cell, ctx, closed: bool) -> dict:
     return {"checks": checks.rows, "correct": checks.correct, "attempted": attempted,
             "failed": failed, "end_to_end": e2e, "obs": obs,
             "memory_peak_bytes": peak}
+
+
+def thirds(marks) -> list:
+    """Per third of a closed loop's window, from the counts marked at its
+    edges ``(seconds, tokens seen, requests sent, prefill launches)``:
+    tokens a second, and rows a prefill launch (requests sent in the third
+    over the launches that seated them).  A window whose thirds agree lies
+    in a steady state; one that climbs lies in the mix's transient."""
+    out = []
+    for (t0, k0, r0, p0), (t1, k1, r1, p1) in zip(marks, marks[1:]):
+        out.append({"tokens_per_s": (k1 - k0) / (t1 - t0),
+                    "rows_per_prefill": (r1 - r0) / (p1 - p0)
+                    if p1 > p0 else None})
+    return out
+
+
+def steps_by_rows(obs) -> dict:
+    """Traced runs: the decode steps of the traced window by the rows their
+    launch computed (the ``rows`` of the program's ``decode.dispatch``
+    leaves; a program without the attribute gives nothing)."""
+    rows = ((r.get("attrs") or {}).get("rows") for r in spans.records(obs)
+            if r.get("name") == spans.STEP)
+    return dict(collections.Counter(str(n) for n in rows if n is not None))
 
 
 def ended_well(stream) -> bool:

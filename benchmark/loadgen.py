@@ -163,9 +163,17 @@ def closed_loop_prompts(traffic: dict, seed: int, vocab_size: int
                         ) -> Iterator[Tuple[List[int], int]]:
     """An endless stream of (prompt, max_new): prompt lengths cycle through
     the permuted multiset of ``traffic['prompt_tokens']``, reshuffled each
-    cycle; no two prompts share a prefix (ids are drawn fresh)."""
+    cycle; no two prompts share a prefix (ids are drawn fresh).
+
+    ``traffic['new_tokens']`` is a number, the same for every request, or a
+    distribution as ``prompt_tokens`` is: then the answer lengths are a
+    permuted multiset of their own, a cycle long, shuffled independently of
+    the prompts' — streams seated together do not finish together."""
     rng = random.Random(seed)
-    cycle = traffic["cycle"]
+    cycle, new = traffic["cycle"], traffic["new_tokens"]
     while True:
-        for n in permuted(traffic["prompt_tokens"], cycle, rng):
-            yield token_ids(rng, n, vocab_size), traffic["new_tokens"]
+        lengths = permuted(traffic["prompt_tokens"], cycle, rng)
+        news = permuted(new, cycle, rng) if isinstance(new, dict) \
+            else [new] * cycle
+        for n, k in zip(lengths, news):
+            yield token_ids(rng, n, vocab_size), k

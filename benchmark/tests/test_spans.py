@@ -150,3 +150,30 @@ def test_idle_gaps_fall_back_to_programs_when_short_leaves_share_a_gap():
     assert list(gaps) == ["after_jit__pchunk_fn__before_jit__pdecode_fn"]
     assert gaps["after_jit__pchunk_fn__before_jit__pdecode_fn"] == \
         pytest.approx(0.0035)
+
+
+def test_a_recorded_closed_loop_window_by_thirds():
+    """``kinds/serve.thirds`` on the marks of a recorded run (seconds, tokens
+    seen, requests sent, prefill launches at the window's four edges): the
+    first third climbs — clusters still merging — and the last two agree."""
+    from benchmark.kinds import serve
+
+    marks = [(100.0, 0, 256, 400), (110.004, 112_045, 1131, 900),
+             (120.001, 232_009, 2068, 1400), (130.003, 352_033, 3006, 1900)]
+    got = serve.thirds(marks)
+    assert [round(t["tokens_per_s"]) for t in got] == [11200, 12000, 12000]
+    assert [t["rows_per_prefill"] for t in got] == [1.75, 1.874, 1.876]
+    # a third without a prefill launch has no rows a launch, not a zero
+    assert serve.thirds([(0.0, 0, 4, 7), (1.0, 50, 4, 7)]) == [
+        {"tokens_per_s": 50.0, "rows_per_prefill": None}]
+    assert serve.thirds([]) == [] and serve.thirds(marks[:1]) == []
+
+
+def test_decode_steps_by_the_rows_launched():
+    from benchmark.kinds import serve
+
+    recs = [rec("decode.dispatch", 0, 1, rows=16), rec("decode.dispatch", 2, 1, rows=16),
+            rec("decode.dispatch", 4, 1, rows=256), rec("decode.emit", 5, 1, rows=3),
+            rec("decode.dispatch", 6, 1)]
+    assert serve.steps_by_rows({"spans": recs}) == {"16": 2, "256": 1}
+    assert serve.steps_by_rows({"spans": []}) == {}
