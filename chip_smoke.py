@@ -2,7 +2,8 @@
 """The standing proof that the trainer and the server start on a TPU chip.
 
     python chip_smoke.py            # one chip: device, train, train-packed,
-                                    # serve, decode, decode-latent
+                                    # serve, decode, decode-latent,
+                                    # decode-hybrid
     python chip_smoke.py --chips 4  # four chips: device, mesh-train (dp and
                                     # zero against one device), replicas
 
@@ -697,6 +698,62 @@ def phase_decode_latent(ctx) -> dict:
             "compile_cache": rep["engine"]["compile_cache"]}
 
 
+def phase_decode_hybrid(ctx) -> dict:
+    """The third model family (gated delta-rule linear attention with a
+    per-slot recurrent state beside paged GQA layers, sparse experts told
+    which they hold) through the same ``serve_tpu.py --decode``: the
+    published widths at ONE period of the layer pattern, seeded weights, a
+    repeated prompt — which is prefilled whole again (the family shares no
+    prefix) and must generate the same tokens from a re-used slot."""
+    import serve_tpu
+
+    model = ("solar-open2-share-tiny" if ctx.rehearse
+             else "solar-open2-ep16-share-l4")
+    a, b = request_lines(ctx, 2, 8, 24)
+    prompts = [a, b, a]
+    max_new = 8
+    metrics = os.path.join(ctx.out, "decode_hybrid_metrics.json")
+    argv = ["--model", model, "--dtype", "bfloat16", "--max_seq_len", "128",
+            "--seed", str(ctx.seed), "--data_path", ctx.corpus,
+            "--vocab_path", ctx.vocab, "--decode", "--decode_slots", "2",
+            # a directory of its own: no checkpoint of another family in it
+            "--output_dir", os.path.join(ctx.out, "hybrid"), "--buckets", "32",
+            "--max_new_tokens", str(max_new), "--metrics_path", metrics]
+    with captured(serve_tpu, "build_decode_pool") as pools:
+        out = run_cli(serve_tpu.main, argv, "\n".join(prompts) + "\n")
+    rows = [l.split("\t") for l in out.splitlines() if l.strip()]
+    check(not [r for r in rows if r[1] == "ERROR"], f"stream errors: {out}")
+    gens = {int(r[0]): r[2] if len(r) > 2 else "" for r in rows
+            if r[1] == "gen"}
+    check(sorted(gens) == [0, 1, 2], f"missing generations: {sorted(gens)}")
+    check(gens[2] == gens[0], f"the repeated prompt differs: {gens}")
+    engine = pools[0].engine(0)
+    cfg = engine.cfg
+    check(engine.family.name == "hybrid_linear" and len(engine._pools) == 2
+          and len(engine._states) == 2 * cfg.num_linear_layers,
+          f"family {engine.family.name}, {len(engine._pools)} pools, "
+          f"{len(engine._states)} state arrays")
+    check(engine._pools[0].shape[0] == cfg.num_gqa_layers,
+          f"pools over {engine._pools[0].shape[0]} layers")
+    with open(metrics) as f:
+        rep = json.load(f)["replicas"]["0"]
+    kv = rep["kv"]
+    check(kv["prefix"]["hits_full"] == 0 and kv["prefix"]["misses"] == 0,
+          f"prefix index: {kv['prefix']}")
+    check(kv["state_pool_bytes"] == engine.slots * engine.state_bytes > 0,
+          f"state pool: {kv['state_pool_bytes']}")
+    leak = engine.leak_check()
+    check(leak["ok"] and leak["leaked_pages"] == 0, f"leak check: {leak}")
+    return {"model": model, "prompts": len(prompts), "repeats": 1,
+            "tokens_streamed": sum(r[1] == "tok" for r in rows),
+            "cache_bytes_per_token": engine.token_bytes,
+            "state_bytes_per_slot": engine.state_bytes,
+            "kv_pool_bytes": kv["kv_pool_bytes"],
+            "state_pool_bytes": kv["state_pool_bytes"],
+            "weights_bytes": kv["weights_bytes"],
+            "compile_cache": rep["engine"]["compile_cache"]}
+
+
 # ---------------------------------------------------------- four chips
 
 
@@ -904,6 +961,7 @@ def main(argv=None) -> int:
         run_phase(ctx, "serve", phase_serve)
         run_phase(ctx, "decode", phase_decode)
         run_phase(ctx, "decode-latent", phase_decode_latent)
+        run_phase(ctx, "decode-hybrid", phase_decode_hybrid)
     if ctx.rehearse:
         print("chip_smoke: rehearsal walked every phase; this is not a chip "
               "run", file=sys.stderr)

@@ -126,6 +126,82 @@ class LatentMoEConfig:
         return dataclasses.replace(self, **kw)
 
 
+@dataclasses.dataclass(frozen=True)
+class HybridLinearConfig:
+    """A pre-norm decoder whose layers are of TWO kinds in a fixed period:
+    one softmax GQA layer without positions, gated, then ``gqa_interval``
+    gated delta-rule linear-attention layers with channel-wise decay and a
+    short causal convolution; sparse experts in every layer; served only
+    (``models/hybrid_linear.py``).  Field names are the published
+    ``config.json``'s where it has one; the defaults are Solar-Open2-250B's
+    (https://huggingface.co/upstage/Solar-Open2-250B/blob/main/config.json).
+
+    ``experts_held`` / ``expert_first``: the share, as ``LatentMoEConfig``'s
+    (the expert layer IS ``models/latent_moe.py``'s, told the same way)."""
+    vocab_size: int = 196_608
+    hidden_size: int = 4096
+    num_layers: int = 48          # whole periods of 1 + gqa_interval layers
+    gqa_interval: int = 3         # linear layers after each GQA layer
+    num_heads: int = 64
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    linear_num_heads: int = 64
+    linear_head_dim: int = 128    # d_k = d_v of the recurrent state
+    conv_kernel: int = 4          # the short convolution's width
+    low_rank: int = 128           # the decay's and the gate's inner width
+    moe_intermediate_size: int = 1280   # one expert's (and the shared one's)
+    n_routed_experts: int = 320         # the router's width
+    experts_held: int = 320             # ... of which this process holds
+    expert_first: int = 0               # ... starting at this one
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    n_group: int = 1                    # a plain top-k over all experts
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-5
+    max_position: int = 1_048_576       # no position table: a length limit
+    weight_dtype: str = "bfloat16"      # how the weights are STORED
+
+    family = "hybrid_linear"
+
+    def __post_init__(self):
+        if self.num_layers % self.period:
+            raise ValueError(
+                f"{self.num_layers} layers are no whole number of periods of "
+                f"{self.period} (1 GQA + {self.gqa_interval} linear)")
+
+    @property
+    def period(self) -> int:
+        return 1 + self.gqa_interval
+
+    def is_gqa(self, l: int) -> bool:
+        """Layer ``l`` is a softmax GQA layer (0, 4, 8, ...)."""
+        return l % self.period == 0
+
+    @property
+    def num_gqa_layers(self) -> int:
+        """The layers that PAGE: a cached position lives in these alone."""
+        return self.num_layers // self.period
+
+    @property
+    def num_linear_layers(self) -> int:
+        """The layers whose memory is a per-stream recurrent state."""
+        return self.num_layers - self.num_gqa_layers
+
+    @property
+    def kv_width(self) -> int:
+        """One cached position's keys (or values) of one GQA layer."""
+        return self.num_kv_heads * self.head_dim
+
+    @property
+    def linear_width(self) -> int:
+        """q, k or v of a linear layer, all heads."""
+        return self.linear_num_heads * self.linear_head_dim
+
+    def replace(self, **kw) -> "HybridLinearConfig":
+        return dataclasses.replace(self, **kw)
+
+
 _REGISTRY = {
     # chinese-bert-wwm-ext shape (BERT-base, ~102M params at vocab 21128)
     "bert-base": BertConfig(),
@@ -165,6 +241,21 @@ _REGISTRY = {
         qk_rope_head_dim=8, v_head_dim=16, intermediate_size=256,
         moe_intermediate_size=64, n_routed_experts=8, experts_held=4,
         num_experts_per_tok=3, n_group=4, topk_group=2, max_position=4096),
+    # one chip's share of Solar-Open2-250B when 16 chips share each layer
+    # (experts 16 ways: 20 held; the vocabulary 8 ways comes from the
+    # tokenizer): every width as published, two whole periods of the 12
+    "solar-open2-ep16-share": HybridLinearConfig(
+        num_layers=8, experts_held=20, vocab_size=24_576),
+    # the same share cut to ONE period: what chip_smoke.py builds
+    "solar-open2-ep16-share-l4": HybridLinearConfig(
+        num_layers=4, experts_held=20, vocab_size=24_576),
+    # the same family at a size the CPU tests run: 2 periods, 4 heads of 32
+    # over 2 KV heads, 8 experts of which 3 a token are taken and 4 held
+    "solar-open2-share-tiny": HybridLinearConfig(
+        vocab_size=1000, hidden_size=128, num_layers=8, num_heads=4,
+        num_kv_heads=2, head_dim=32, linear_num_heads=4, linear_head_dim=32,
+        low_rank=16, moe_intermediate_size=64, n_routed_experts=8,
+        experts_held=4, num_experts_per_tok=3, max_position=4096),
 }
 
 

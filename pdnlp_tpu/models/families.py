@@ -7,10 +7,15 @@ call.  ``of(cfg)`` is looked up once, at construction; no call site asks
 which family it serves.
 
 A step function takes and returns the cache as a TUPLE of pools ``[L, P,
-page_sz, width]`` — twin K and V pools for the BERT causal LM
-(``models/decoder.py``), one latent pool for the latent-attention decoder
-(``models/latent_moe.py``) — and returns ``aux`` beside the logits: what a
-launch counted (assignments to each held expert), or ``None``.
+page_sz, width]``, each over the layers that PAGE (``pool_layers``) — twin K
+and V pools for the BERT causal LM (``models/decoder.py``), one latent pool
+for the latent-attention decoder (``models/latent_moe.py``), twin pools over
+the GQA layers alone for the hybrid (``models/hybrid_linear.py``) — and a
+TUPLE of per-slot state arrays ``[slots, ...]`` (``state_shapes``: the
+hybrid's recurrent states and convolution tails; empty for the other two,
+whose programs an empty tuple adds no operand to).  It returns ``aux``
+beside the logits: what a launch counted (assignments to each held expert),
+or ``None``.
 """
 from __future__ import annotations
 
@@ -19,7 +24,7 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
-from pdnlp_tpu.models import bert, decoder, latent_moe
+from pdnlp_tpu.models import bert, decoder, hybrid_linear, latent_moe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,11 +36,17 @@ class Family:
     #: cfg -> the width of each pool (values a position a layer)
     pool_widths: Callable
     #: (params, head, cfg, ids, mask, last_pos, dtype)
-    #:   -> (logits [B, V], aux, new rows: one [L, B, S, ...] per pool)
+    #:   -> (logits [B, V], aux, new rows: one [L, B, S, ...] per pool, each
+    #:       prompt's final state: one [B, ...] per state array)
     prefill: Callable
-    #: (params, head, cfg, tokens, pools, table, start, nreal, logits_at,
-    #:  kv_scales, dtype) -> (logits, aux, pools)
+    #: (params, head, cfg, tokens, pools, states, table, start, nreal,
+    #:  logits_at, kv_scales, dtype) -> (logits, aux, pools, states)
     attend: Callable
+    #: cfg -> how many layers a cached position lives in (the pools' L)
+    pool_layers: Callable = lambda cfg: cfg.num_layers
+    #: (cfg, slots) -> ((shape, dtype or None = the compute dtype), ...) of
+    #: what a SLOT keeps besides its pages
+    state_shapes: Callable = lambda cfg, slots: ()
     #: weights are made on the device and the template is shapes only (a
     #: model of gigabytes is never held twice)
     lazy_weights: bool = False
@@ -43,6 +54,8 @@ class Family:
     int8: bool = True             # int8 weights or an int8 cache
     verify: bool = True           # the speculative pair's scoring window
     handoff: bool = True          # exporting / importing a stream's pages
+    prefix: bool = True           # sharing a prompt's prefix pages (and the
+                                  # suffix chunk that follows a hit)
 
     def refuse(self, what: str, use: str) -> None:
         raise ValueError(
@@ -52,29 +65,45 @@ class Family:
 def _bert_prefill(params, head, cfg, ids, mask, last_pos, dtype):
     logits, ks, vs = decoder.prefill(params, head, cfg, ids, mask, last_pos,
                                      dtype=dtype)
-    return logits, None, (ks, vs)
+    return logits, None, (ks, vs), ()
 
 
-def _bert_attend(params, head, cfg, tokens, pools, table, start, nreal,
-                 logits_at, kv_scales, dtype):
+def _bert_attend(params, head, cfg, tokens, pools, states, table, start,
+                 nreal, logits_at, kv_scales, dtype):
     logits, pk, pv = decoder.paged_attend_layers(
         params, head, cfg, tokens, pools[0], pools[1], table, start, nreal,
         logits_at=logits_at, kv_scales=kv_scales, dtype=dtype)
-    return logits, None, (pk, pv)
+    return logits, None, (pk, pv), states
 
 
 def _latent_prefill(params, head, cfg, ids, mask, last_pos, dtype):
     logits, counts, latents = latent_moe.prefill(
         params, head, cfg, ids, mask, last_pos, dtype=dtype)
-    return logits, counts, (latents,)
+    return logits, counts, (latents,), ()
 
 
-def _latent_attend(params, head, cfg, tokens, pools, table, start, nreal,
-                   logits_at, kv_scales, dtype):
+def _latent_attend(params, head, cfg, tokens, pools, states, table, start,
+                   nreal, logits_at, kv_scales, dtype):
     logits, counts, pool = latent_moe.paged_attend(
         params, head, cfg, tokens, pools[0], table, start, nreal,
         dtype=dtype)
-    return logits, counts, (pool,)
+    return logits, counts, (pool,), states
+
+
+def _hybrid_prefill(params, head, cfg, ids, mask, last_pos, dtype):
+    return hybrid_linear.prefill(params, head, cfg, ids, mask, last_pos,
+                                 dtype=dtype)
+
+
+def _hybrid_attend(params, head, cfg, tokens, pools, states, table, start,
+                   nreal, logits_at, kv_scales, dtype):
+    if tokens.shape[1] != 1:
+        # a window of several positions against a cache exists only after a
+        # prefix hit or in the speculative pair: both refused at construction
+        raise NotImplementedError(
+            "the hybrid_linear family decodes one position a row")
+    return hybrid_linear.paged_decode(params, head, cfg, tokens, pools,
+                                      states, table, start, dtype=dtype)
 
 
 FAMILIES = {
@@ -90,6 +119,17 @@ FAMILIES = {
         prefill=_latent_prefill, attend=_latent_attend,
         lazy_weights=True, int8=False, verify=False,
         handoff=False),
+    # no snapshot of the recurrent state exists at a page boundary, so
+    # nothing that resumes, rolls back or moves a stream mid-way is offered
+    "hybrid_linear": Family(
+        name="hybrid_linear", init_params=hybrid_linear.init_params,
+        init_head=hybrid_linear.init_head,
+        pool_widths=lambda cfg: (cfg.kv_width, cfg.kv_width),
+        prefill=_hybrid_prefill, attend=_hybrid_attend,
+        pool_layers=lambda cfg: cfg.num_gqa_layers,
+        state_shapes=hybrid_linear.state_shapes,
+        lazy_weights=True, int8=False, verify=False, handoff=False,
+        prefix=False),
 }
 
 
@@ -98,8 +138,9 @@ def of(cfg) -> Family:
 
 
 def token_bytes(cfg, kv_dtype) -> int:
-    """Bytes one cached position takes over every layer and pool."""
-    return int(cfg.num_layers * sum(of(cfg).pool_widths(cfg))
+    """Bytes one cached position takes over every PAGING layer and pool."""
+    family = of(cfg)
+    return int(family.pool_layers(cfg) * sum(family.pool_widths(cfg))
                * np.dtype(kv_dtype).itemsize)
 
 
@@ -109,3 +150,11 @@ def insert(pools: Tuple, news: Tuple, flat_pos, kv_scales: Optional[Tuple]
     scales = kv_scales or (None,) * len(pools)
     return tuple(decoder.insert_pool(p, n, flat_pos, s)
                  for p, n, s in zip(pools, news, scales))
+
+
+def insert_states(states: Tuple, news: Tuple, slot_ids) -> Tuple:
+    """A prefill's final states ``[rows, ...]`` into the rows ``slot_ids`` of
+    the per-slot arrays ``[slots, ...]`` (a filler row carries an id past
+    the end and is dropped)."""
+    return tuple(s.at[slot_ids].set(n.astype(s.dtype), mode="drop")
+                 for s, n in zip(states, news))

@@ -1,0 +1,501 @@
+"""A pre-norm decoder whose layers are of TWO kinds in a fixed period —
+softmax GQA without positions beside gated delta-rule linear attention —
+with sparse experts in every layer, served over paged K/V pools for the
+GQA layers and a PER-SLOT recurrent state for the linear ones.
+
+The third model family (the engine finds it through ``models/families.py``).
+The layer, from the published config of Solar-Open2-250B
+(``HybridLinearConfig``); ``benchmark/reference/solar_open2.py`` states the
+same equations again in plain float32, the linear layers by their
+SEQUENTIAL recurrence:
+
+- ``x += Mixer(rms(x))``; ``x += MoE(rms(x))``; final ``rms``; untied head.
+- **GQA layer** (layers 0, 4, 8, ...): ``q = a W_q`` (``num_heads`` heads),
+  ``k = a W_k``, ``v = a W_v`` (``num_kv_heads`` heads, each serving
+  ``num_heads / num_kv_heads`` query heads); NO position term of any kind;
+  causal softmax of ``q k^T / sqrt(d)`` in float32; ``o = (attn *
+  sigmoid(a W_g)) W_o``.
+- **linear layer** (the ``gqa_interval`` layers after each): ``[q~ | k~ |
+  v~] = a W_qkv``; a depthwise causal convolution of width ``conv_kernel``
+  over time on each channel, then silu; ``q = l2norm(q~) / sqrt(d_k)``, ``k =
+  l2norm(k~)``, ``v = v~``; log decay ``g = -exp(A_log) * softplus(a W_a1
+  W_a2 + dt_bias)`` a channel (``alpha = exp(g)`` in (0, 1)); step ``beta =
+  2 * sigmoid(a W_beta)`` a head; the state ``S`` (``d_k x d_v`` a head,
+  float32) moves by ``S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} +
+  beta_t k_t v_t^T`` and ``o_t = S_t^T q_t``; out ``= (rms_head(o_t) *
+  sigmoid(a W_g1 W_g2)) W_o``.
+- **experts**: ``models/latent_moe.py``'s layer as it stands (``route`` at
+  ``n_group`` 1 is a plain top-k; ``held_experts`` told which it holds).
+
+**What is kept between launches.**  A GQA layer caches K and V, ``kv_width``
+values a position each, in twin pools ``[n_gqa, P, page_sz, kv_width]`` that
+the programs never rebuild (``models/decoder.py``'s paged-cache contract).
+A linear layer keeps, for every SLOT, its state ``[slots, heads, d_k, d_v]``
+float32 and the last ``conv_kernel - 1`` inputs of its convolution
+``[slots, conv_kernel - 1, 3 x linear_width]``: one array a layer of each
+(``state_shapes``), donated like the pools.  A prompt's FINAL state — at
+the last REAL position of its padded row: padded positions are given ``beta
+= 0`` and ``g = 0``, which leaves the state as it is — is what the prefill
+hands over; a decode step reads and writes the rows of its row rung.
+
+**Two forms of one recurrence.**  A decode step applies it as written.  A
+prompt is cut into chunks of :data:`CHUNK`; inside a chunk, with ``G_r`` the
+running sum of ``g``, ``u_r = beta_r (v_r - S_{r-1}^T (alpha_r k_r))``
+solves the unit lower-triangular system ``(I + Diag(beta) tril(A, -1)) U =
+Diag(beta) (V - (exp(G) K) S_0)`` with ``A_ri = sum_d k_rd k_id exp(G_rd -
+G_id)``; ``o_r = S_0^T (exp(G_r) q_r) + sum_{i<=r} (sum_d q_rd k_id exp(G_rd
+- G_id)) u_i``; ``S_C = Diag(exp(G_C)) S_0 + sum_i (exp(G_C - G_i) k_i)
+u_i^T``; the state is carried over the chunks under ``lax.scan``.  Every
+exponent is a difference ``G_later - G_earlier <= 0``, so no decay, however
+strong, overflows (the factored form ``k_i / exp(G_i)`` does); the price is
+that ``A`` is an elementwise sum, not a matrix product.  Everything that
+touches the state is float32 at ``highest`` matmul precision.
+"""
+from __future__ import annotations
+
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from pdnlp_tpu.models.config import HybridLinearConfig
+from pdnlp_tpu.models.decoder import _layer_rows
+from pdnlp_tpu.models.latent_moe import (F32, _einsum, _embed, _logits, _mm,
+                                         _rms, _weight_dtype, moe_ffn)
+from pdnlp_tpu.ops.attention import NEG_INF
+
+Params = Dict[str, Any]
+HIGHEST = jax.lax.Precision.HIGHEST
+
+#: positions of one chunk of the chunkwise delta rule
+CHUNK = 64
+#: query rows of one softmax attention block of a prompt
+Q_BLOCK = 512
+#: under the square root of ``l2norm``
+L2_EPS = 1e-6
+
+
+# ------------------------------------------------------------------- weights
+
+def _ffn(H: int, width: int, lead: tuple = ()) -> Dict[str, tuple]:
+    return {"gate": lead + (H, width), "up": lead + (H, width),
+            "down": lead + (width, H)}
+
+
+def layer_shapes(cfg: HybridLinearConfig, l: int) -> Dict[str, Any]:
+    """Layer ``l``'s parameters as shapes.  Matrices are ``y = x @ w``; a
+    linear layer's ``W_q | W_k | W_v`` and its three convolutions are ONE
+    leaf each (``qkv``, ``conv``): a layout of the published columns."""
+    H, F = cfg.hidden_size, cfg.moe_intermediate_size
+    if cfg.is_gqa(l):
+        N, kv = cfg.num_heads * cfg.head_dim, cfg.kv_width
+        mixer = {"in_norm": (H,), "q": (H, N), "k": (H, kv), "v": (H, kv),
+                 "g": (H, N), "o": (N, H), "post_norm": (H,)}
+    else:
+        W, r = cfg.linear_width, cfg.low_rank
+        mixer = {"in_norm": (H,), "qkv": (H, 3 * W),
+                 "conv": (cfg.conv_kernel, 3 * W),
+                 "a_down": (H, r), "a_up": (r, W),
+                 "a_log": (cfg.linear_num_heads,), "dt_bias": (W,),
+                 "beta": (H, cfg.linear_num_heads),
+                 "g_down": (H, r), "g_up": (r, W),
+                 "o_norm": (cfg.linear_head_dim,), "o": (W, H),
+                 "post_norm": (H,)}
+    return {"mixer": mixer, "router": (H, cfg.n_routed_experts),
+            "shared": _ffn(H, F * cfg.n_shared_experts),
+            "experts": _ffn(H, F, (cfg.experts_held,))}
+
+
+def param_shapes(cfg: HybridLinearConfig) -> Dict[str, Any]:
+    """The parameter tree as shapes: every layer's leaves are its OWN (no
+    stack over layers — a program reads a layer's matrices where they lie,
+    and a stack sliced by layer is a copy of it)."""
+    return {"embed": (cfg.vocab_size, cfg.hidden_size),
+            "layers": [layer_shapes(cfg, l) for l in range(cfg.num_layers)],
+            "final_norm": (cfg.hidden_size,)}
+
+
+def _init_leaf(key, name: str, shape: tuple) -> jax.Array:
+    """Seeded values, float32: matrices normal / sqrt(fan-in), norm gains 1
+    + 0.1 normal, the convolution normal / sqrt(width); ``a_log`` the log
+    of uniform (1, 16) and ``dt_bias`` the inverse softplus of log-uniform
+    (0.001, 0.1), so that decays lie from near 0 to near 1."""
+    decay = name in ("a_log", "dt_bias")
+    draw = jax.random.uniform if decay else jax.random.normal
+    x = draw(key, shape, F32)
+    if name == "a_log":
+        return jnp.log(1.0 + 15.0 * x)
+    if name == "dt_bias":
+        dt = jnp.exp(np.log(1e-3) + x * (np.log(1e-1) - np.log(1e-3)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if name.endswith("norm"):
+        return 1.0 + 0.1 * x
+    if name == "embed":
+        return x
+    return x * shape[-2] ** -0.5
+
+
+def init_params(key: jax.Array, cfg: HybridLinearConfig) -> Params:
+    """Seeded weights in the family's STORED dtype, one leaf at a time (so
+    that nothing float32 the size of the model is ever alive)."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    wd = _weight_dtype(cfg)
+    out = [_init_leaf(jax.random.fold_in(key, i), str(path[-1].key),
+                      shape).astype(wd)
+           for i, (path, shape) in enumerate(leaves)]
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def init_head(key: jax.Array, cfg: HybridLinearConfig) -> Params:
+    """The untied output head ``[hidden, vocab]``."""
+    w = jax.random.normal(key, (cfg.hidden_size, cfg.vocab_size), F32)
+    return {"kernel": (w * cfg.hidden_size ** -0.5).astype(_weight_dtype(cfg))}
+
+
+def param_count(cfg: HybridLinearConfig) -> int:
+    leaves = jax.tree_util.tree_leaves(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    return int(sum(int(np.prod(s)) for s in leaves)
+               + cfg.hidden_size * cfg.vocab_size)
+
+
+def state_shapes(cfg: HybridLinearConfig, slots: int) -> Tuple:
+    """What a SLOT keeps besides its pages, as (shape, dtype): every linear
+    layer's recurrent state (float32), then every linear layer's
+    convolution tail (the compute dtype's values: ``None`` = the engine's)."""
+    N, d = cfg.linear_num_heads, cfg.linear_head_dim
+    n = cfg.num_linear_layers
+    return (((slots, N, d, d), jnp.float32),) * n \
+        + (((slots, cfg.conv_kernel - 1, 3 * cfg.linear_width), None),) * n
+
+
+# ---------------------------------------------------------------- delta rule
+
+def _heinsum(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """A float32 product that touches the state: ``highest`` precision."""
+    return jnp.einsum(spec, a, b, precision=HIGHEST,
+                      preferred_element_type=F32)
+
+
+def delta_step(q, k, v, g, beta, S):
+    """The recurrence as written, one position: ``q k g [B, N, dk]``, ``v
+    [B, N, dv]``, ``beta [B, N]``, ``S [B, N, dk, dv]``, all float32 ->
+    (``o [B, N, dv]``, ``S'``)."""
+    Sd = jnp.exp(g)[..., None] * S
+    u = beta[..., None] * (v - jnp.sum(k[..., None] * Sd, axis=-2))
+    S = Sd + k[..., None] * u[..., None, :]
+    return jnp.sum(q[..., None] * S, axis=-2), S
+
+
+def delta_chunked(q, k, v, g, beta, S, chunk: int = CHUNK):
+    """The same recurrence over ``T`` positions in chunks (module note): the
+    intra-chunk triangular system solved, the state carried over the chunks
+    under ``lax.scan``.  ``q k g [B, T, N, dk]``, ``v [B, T, N, dv]``, ``beta
+    [B, T, N]``, ``S [B, N, dk, dv]``, all float32; a position with
+    ``beta = 0`` and ``g = 0`` leaves the state as it is."""
+    B, T, N, dk = q.shape
+    C = min(chunk, T)
+    pad = -T % C
+    if pad:      # positions that leave the state alone; their rows are cut
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (
+            x.ndim - 2)) for x in (q, k, v, g, beta))
+    nC = (T + pad) // C
+
+    def cut(x):          # [B, T, N, ...] -> [nC, B, N, C, ...]
+        x = x.reshape((B, nC, C) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
+
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    eye = jnp.eye(C, dtype=F32)
+
+    def body(S, x):
+        q, k, v, g, b = x                 # [B, N, C, d]; b [B, N, C]
+        G = jnp.cumsum(g, axis=-2)
+        # exp(G_r - G_i) k_i for i <= r: every exponent is <= 0
+        diff = G[..., :, None, :] - G[..., None, :, :]
+        kd = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf)) \
+            * k[..., None, :, :]                               # [B,N,r,i,dk]
+        A = jnp.sum(k[..., :, None, :] * kd, axis=-1)          # [B,N,r,i]
+        Aqk = jnp.sum(q[..., :, None, :] * kd, axis=-1)
+        M = eye + b[..., :, None] * jnp.where(strict, A, 0.0)
+        eG = jnp.exp(G)
+        rhs = b[..., None] * (v - _heinsum("bnck,bnkv->bncv", k * eG, S))
+        U = jax.lax.linalg.triangular_solve(
+            M, rhs, left_side=True, lower=True, unit_diagonal=True)
+        o = _heinsum("bnck,bnkv->bncv", q * eG, S) \
+            + _heinsum("bnri,bniv->bnrv", Aqk, U)
+        GC = G[..., -1:, :]
+        S = jnp.exp(GC)[..., 0, :, None] * S \
+            + _heinsum("bnck,bncv->bnkv", k * jnp.exp(GC - G), U)
+        return S, o
+
+    S, o = jax.lax.scan(body, S, tuple(cut(x) for x in (q, k, v, g, beta)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)       # [B, nC, C, N, dv]
+    return o.reshape(B, nC * C, N, -1)[:, :T], S
+
+
+# ------------------------------------------------------------ a linear layer
+
+def _l2norm(x: jax.Array) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def _linear_inputs(a, mp: Params, cfg: HybridLinearConfig, conv_in, valid,
+                   dtype):
+    """Normed input ``a [B, T, H]`` and the convolution's window ``conv_in
+    [B, K - 1 + T, 3W]`` (the ``K - 1`` inputs before the first position,
+    then the ``T`` projected ones) -> ``q k v g [B, T, N, d]`` and ``beta
+    [B, T, N]`` float32, with ``beta = 0`` and ``g = 0`` where not
+    ``valid``."""
+    B, T = a.shape[:2]
+    N, d, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.conv_kernel
+    w = mp["conv"].astype(F32)
+    y = sum(conv_in[:, j:j + T].astype(F32) * w[j] for j in range(K))
+    q, k, v = (x.reshape(B, T, N, d)
+               for x in jnp.split(jax.nn.silu(y), 3, axis=-1))
+    q, k = _l2norm(q) * d ** -0.5, _l2norm(k)
+    z = _mm(_mm(a, mp["a_down"], dtype).astype(dtype), mp["a_up"], dtype)
+    g = -jnp.exp(mp["a_log"].astype(F32))[:, None] * jax.nn.softplus(
+        z + mp["dt_bias"].astype(F32)).reshape(B, T, N, d)
+    beta = 2.0 * jax.nn.sigmoid(_mm(a, mp["beta"], dtype))
+    keep = valid[..., None]
+    return (q, k, v, jnp.where(keep[..., None], g, 0.0),
+            jnp.where(keep, beta, 0.0))
+
+
+def _linear_out(o, a, mp: Params, cfg: HybridLinearConfig, dtype):
+    """``(rms_head(o) * sigmoid(a W_g1 W_g2)) W_o``: ``o [B, T, N, dv]``
+    float32 -> ``[B, T, H]`` float32."""
+    B, T = o.shape[:2]
+    gate = jax.nn.sigmoid(_mm(_mm(a, mp["g_down"], dtype).astype(dtype),
+                              mp["g_up"], dtype))
+    o = _rms(o, mp["o_norm"], cfg.rms_norm_eps).reshape(B, T, -1) * gate
+    return _mm(o.astype(dtype), mp["o"], dtype)
+
+
+def linear_prompt(a, mp: Params, cfg: HybridLinearConfig, valid, nreal,
+                  dtype):
+    """A linear layer's mixer over whole prompts from an EMPTY state ->
+    (``[B, T, H]`` float32, the final state ``[B, N, dk, dv]`` float32, the
+    convolution tail ``[B, K - 1, 3W]``: the last ``K - 1`` inputs before
+    position ``nreal``, zeros before position 0)."""
+    B = a.shape[0]
+    N, d, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.conv_kernel
+    qkv = _mm(a, mp["qkv"], dtype).astype(dtype)
+    conv_in = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+    q, k, v, g, beta = _linear_inputs(a, mp, cfg, conv_in, valid, dtype)
+    o, S = delta_chunked(q, k, v, g, beta, jnp.zeros((B, N, d, d), F32))
+    # conv_in[p] is the input of position p - (K - 1)
+    at = nreal.astype(jnp.int32)[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
+    tail = jnp.take_along_axis(conv_in, at[:, :, None], axis=1)
+    return _linear_out(o, a, mp, cfg, dtype), S, tail
+
+
+def linear_token(a, mp: Params, cfg: HybridLinearConfig, S, tail, live,
+                 dtype):
+    """The same mixer for ONE new position a row (``a [B, 1, H]``) from the
+    row's state ``S`` and convolution ``tail`` -> (``[B, 1, H]``, ``S'``,
+    ``tail'``); a row that is not ``live`` keeps both as they are."""
+    qkv = _mm(a, mp["qkv"], dtype).astype(tail.dtype)
+    conv_in = jnp.concatenate([tail, qkv], axis=1)              # [B, K, 3W]
+    q, k, v, g, beta = _linear_inputs(a, mp, cfg, conv_in, live[:, None],
+                                      dtype)
+    o, S2 = delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S)
+    S2 = jnp.where(live[:, None, None, None], S2, S)
+    tail2 = jnp.where(live[:, None, None], conv_in[:, 1:], tail)
+    return _linear_out(o[:, None], a, mp, cfg, dtype), S2, tail2
+
+
+# --------------------------------------------------------------- a GQA layer
+
+def _gqa_project(a, mp: Params, cfg: HybridLinearConfig, dtype):
+    """-> ``q [B, T, Nkv, rep, d]``, ``k v [B, T, kv_width]`` in ``dtype``."""
+    B, T = a.shape[:2]
+    q = _mm(a, mp["q"], dtype).astype(dtype).reshape(
+        B, T, cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads,
+        cfg.head_dim)
+    return (q, _mm(a, mp["k"], dtype).astype(dtype),
+            _mm(a, mp["v"], dtype).astype(dtype))
+
+
+def gqa_attend(q, k, v, qpos, cfg: HybridLinearConfig, dtype) -> jax.Array:
+    """Causal softmax without positions: queries ``q [B, T, Nkv, rep, d]``
+    at ``qpos [B, T]`` over keys and values ``[B, S, kv_width]`` (key j AT
+    position j), each KV head serving its ``rep`` query heads -> ``[B, T,
+    heads * d]`` in ``dtype``."""
+    B, T = q.shape[:2]
+    S, d = k.shape[1], cfg.head_dim
+    k = k.reshape(B, S, cfg.num_kv_heads, d)
+    v = v.reshape(B, S, cfg.num_kv_heads, d)
+    kpos = jnp.arange(S, dtype=jnp.int32)
+
+    def block(qb, qp):
+        s = _einsum("btgrd,bsgd->bgrts", qb, k) * d ** -0.5
+        vis = kpos[None, None, None, None, :] <= qp[:, None, None, :, None]
+        p = jax.nn.softmax(jnp.where(vis, s, NEG_INF), axis=-1).astype(dtype)
+        return _einsum("bgrts,bsgd->btgrd", p, v).astype(dtype)
+
+    if T <= Q_BLOCK or T % Q_BLOCK:
+        o = block(q, qpos)
+    else:
+        # blocks of one shape, ONE AFTER ANOTHER (latent_moe.attend_expanded:
+        # unrolled, every block's float32 scores are alive at once)
+        def cut(x):
+            return jnp.moveaxis(
+                x.reshape((B, T // Q_BLOCK, Q_BLOCK) + x.shape[2:]), 1, 0)
+
+        o = jax.lax.map(lambda x: block(x[0], x[1]), (cut(q), cut(qpos)))
+        o = jnp.moveaxis(o, 0, 1)
+    return o.reshape(B, T, cfg.num_heads * d)
+
+
+def gqa_attend_folded(q, k, v, qpos, cfg: HybridLinearConfig,
+                       dtype) -> jax.Array:
+    """:func:`gqa_attend` for ONE query a row (the decode step) over the
+    gathered pages AS THEY LIE, ``k v [B, S, kv_width]`` with the KV heads
+    folded in the minor axis: query head ``(g, r)`` becomes a row that holds
+    ``q[g, r]`` in KV head ``g``'s columns and zeros elsewhere, so both
+    products are ONE dot a row against ``[S, kv_width]`` and the head's own
+    block is cut from the small result.  Splitting ``[.., 1024]`` into ``[..,
+    8, 128]`` is a relayout of every gathered page (read from the first
+    traced chip run: four copies of 805 MB, 9.8 of the step's 42.5 ms);
+    ``num_kv_heads`` times the multiply-adds on a unit that has nothing
+    else to do.  -> ``[B, 1, heads * d]`` in ``dtype``."""
+    B = q.shape[0]
+    S, W = k.shape[1:]
+    G, d = cfg.num_kv_heads, cfg.head_dim
+    R = cfg.num_heads // G
+    own = jnp.eye(G, dtype=dtype)[None, :, None, :, None]  # [1, G, 1, G', 1]
+    qf = (q[:, 0, :, :, None, :] * own).reshape(B, G * R, W)
+    s = _einsum("bqc,bsc->bqs", qf, k) * d ** -0.5
+    vis = jnp.arange(S, dtype=jnp.int32)[None, None, :] <= qpos[:, :, None]
+    p = jax.nn.softmax(jnp.where(vis, s, NEG_INF), axis=-1).astype(dtype)
+    of = _einsum("bqs,bsc->bqc", p, v).reshape(B, G, R, G, d)
+    o = jnp.sum(of * own.astype(F32), axis=3)                 # [B, G, R, d]
+    return o.astype(dtype).reshape(B, 1, G * R * d)
+
+
+def _gqa_out(o, a, mp: Params, dtype):
+    """``(attn * sigmoid(a W_g)) W_o`` -> float32."""
+    gate = jax.nn.sigmoid(_mm(a, mp["g"], dtype))
+    return _mm((o.astype(F32) * gate).astype(dtype), mp["o"], dtype)
+
+
+# -------------------------------------------------------------------- layers
+
+def _moe(x, lp: Params, cfg: HybridLinearConfig, valid, dtype):
+    """``x += MoE(rms(x))`` -> (x', counts ``[experts_held]``)."""
+    B, T, H = x.shape
+    f = _rms(x, lp["mixer"]["post_norm"], cfg.rms_norm_eps)
+    # the layer's own experts as a stack of one: held_experts indexes
+    # (layer, expert) into the leaves where they lie
+    experts = {n: w[None] for n, w in lp["experts"].items()}
+    y, counts = moe_ffn(f.reshape(B * T, H), lp, experts, 0, cfg,
+                        valid.reshape(B * T), dtype)
+    return x + y.reshape(B, T, H).astype(dtype), counts
+
+
+# ----------------------------------------------------------------- programs
+
+def prefill(params: Params, head: Params, cfg: HybridLinearConfig,
+            input_ids: jax.Array,       # [B, S] int32 (left-aligned)
+            attention_mask: jax.Array,  # [B, S] {0,1}
+            last_pos: jax.Array,        # [B] index of the last real token
+            *, dtype=jnp.bfloat16):
+    """A cold prompt from position 0 -> (next-token logits ``[B, vocab]``
+    float32, counts ``[experts_held]``, the GQA layers' keys and values
+    ``2 x [n_gqa, B, S, kv_width]`` for ``decoder.insert_pool``, the linear
+    layers' final states then their convolution tails, a layer each)."""
+    B, S = input_ids.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    valid = attention_mask.astype(bool)
+    nreal = last_pos.astype(jnp.int32) + 1
+    x = _embed(params, input_ids, dtype)
+    counts = jnp.zeros((cfg.experts_held,), jnp.int32)
+    ks, vs, states, tails = [], [], [], []
+    for l, lp in enumerate(params["layers"]):
+        mp = lp["mixer"]
+        a = _rms(x, mp["in_norm"], cfg.rms_norm_eps)
+        if cfg.is_gqa(l):
+            q, k, v = _gqa_project(a, mp, cfg, dtype)
+            y = _gqa_out(gqa_attend(q, k, v, positions, cfg, dtype), a, mp,
+                         dtype)
+            ks.append(k)
+            vs.append(v)
+        else:
+            y, S_l, tail = linear_prompt(a, mp, cfg, valid, nreal, dtype)
+            states.append(S_l)
+            tails.append(tail)
+        x, n = _moe(x + y.astype(dtype), lp, cfg, valid, dtype)
+        counts = counts + n
+    h_last = jnp.take_along_axis(
+        x, last_pos.astype(jnp.int32)[:, None, None], axis=1)
+    return (_logits(params, head, cfg, h_last, dtype)[:, 0], counts,
+            (jnp.stack(ks), jnp.stack(vs)), tuple(states) + tuple(tails))
+
+
+def paged_decode(params: Params, head: Params, cfg: HybridLinearConfig,
+                 tokens: jax.Array,      # [B, 1] int32
+                 pools: Tuple,           # K and V: [n_gqa, P, page_sz, W]
+                 states: Tuple,          # state_shapes, rows >= B
+                 page_table: jax.Array,  # [B, <= MP] int32 (sentinel P)
+                 start: jax.Array,       # [B] abs position of the token
+                 *, dtype=jnp.bfloat16):
+    """The decode step: row b's token sits at ``start[b]``; a GQA layer
+    writes its key and value through the table in place and attends to
+    positions ``<= start[b]`` of the pages the table names; a linear layer
+    moves rows ``[0, B)`` of its state and convolution tail by one position.
+    A dead row (sentinel table) writes nothing, keeps its state and takes no
+    part in the expert layer.  -> (logits ``[B, vocab]``, counts, the pools,
+    the states)."""
+    pk, pv = pools
+    Lp, P, ps, W = pk.shape
+    B = tokens.shape[0]
+    MP = page_table.shape[1]
+    extent = MP * ps
+    n_lin = cfg.num_linear_layers
+    start = start.astype(jnp.int32)
+    positions = start[:, None]
+    phys = jnp.take_along_axis(
+        page_table, jnp.clip(positions // ps, 0, MP - 1), axis=1)
+    real = (positions < extent) & (phys < P)                       # [B, 1]
+    live = real[:, 0]
+    wrows = _layer_rows(jnp.where(real, phys * ps + positions % ps, P * ps),
+                        Lp, P * ps).reshape(Lp, B)
+    rpages = _layer_rows(page_table, Lp, P)                    # [Lp, B, MP]
+
+    def cached(pool, new, j):
+        flat = pool.reshape(Lp * P * ps, W).at[wrows[j]].set(
+            new.reshape(B, W).astype(pool.dtype), mode="drop")
+        got = jnp.take(flat.reshape(Lp * P, ps, W), rpages[j], axis=0,
+                       mode="clip").reshape(B, extent, W).astype(dtype)
+        return flat.reshape(pool.shape), got
+
+    x = _embed(params, tokens, dtype)
+    counts = jnp.zeros((cfg.experts_held,), jnp.int32)
+    states = list(states)
+    for l, lp in enumerate(params["layers"]):
+        mp = lp["mixer"]
+        a = _rms(x, mp["in_norm"], cfg.rms_norm_eps)
+        j = l // cfg.period
+        if cfg.is_gqa(l):
+            q, k, v = _gqa_project(a, mp, cfg, dtype)
+            pk, ck = cached(pk, k, j)
+            pv, cv = cached(pv, v, j)
+            y = _gqa_out(gqa_attend_folded(q, ck, cv, positions, cfg, dtype),
+                         a, mp, dtype)
+        else:
+            i = l - j - 1                       # which linear layer this is
+            S, tail = states[i], states[n_lin + i]
+            y, S2, tail2 = linear_token(a, mp, cfg, S[:B], tail[:B], live,
+                                        dtype)
+            states[i] = S.at[:B].set(S2)
+            states[n_lin + i] = tail.at[:B].set(tail2)
+        x, n = _moe(x + y.astype(dtype), lp, cfg, real, dtype)
+        counts = counts + n
+    return (_logits(params, head, cfg, x, dtype)[:, 0], counts, (pk, pv),
+            tuple(states))
+

@@ -133,22 +133,27 @@ class KVBudget:
 
     # ------------------------------------------------------------- doors
     def cap_pages(self, requested: int, page_bytes: int,
-                  min_pages: int = 1) -> int:
+                  min_pages: int = 1, reserved: int = 0) -> int:
         """Construction door (``serve.kvpage``): how many
         fixed-size KV pages the declared budget covers (= ``requested``
         when unbudgeted).  ``min_pages`` is the floor the engine needs to
         hold ONE maximum-length stream — a budget that cannot cover it
-        refuses loudly here instead of deadlocking every claim.  The
+        refuses loudly here instead of deadlocking every claim.
+        ``reserved``: bytes the engine allocates whatever the traffic (the
+        slots' recurrent state), paid from the budget before any page.  The
         page ALLOCATION ledger itself lives in
         :class:`pdnlp_tpu.serve.kvpage.PageAllocator`; this budget only
         sizes the pool."""
         if self.budget_bytes is None:
             return int(requested)
-        fit = self.budget_bytes // max(1, int(page_bytes))
+        fit = max(0, self.budget_bytes - int(reserved)) \
+            // max(1, int(page_bytes))
         if fit < int(min_pages):
+            state = (f" beside {reserved / 2**20:.1f} MB of per-slot state"
+                     if reserved else "")
             raise KVBudgetExceeded(
                 f"kv_hbm_mb={self.budget_bytes / 2**20:.1f} covers only "
-                f"{fit} KV pages ({page_bytes / 2**20:.2f} MB/page) but "
+                f"{fit} KV pages ({page_bytes / 2**20:.2f} MB/page){state} but "
                 f"one maximum-length stream needs {min_pages} — raise "
                 "--kv_hbm_mb or shrink --decode_max_len/--kv_page_sz")
         return min(int(requested), int(fit))

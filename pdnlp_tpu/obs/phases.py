@@ -573,6 +573,11 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     ``decode_fetch_bytes_per_step`` = the ``bytes`` of the ``decode.fetch``
     leaves over their count: what a decode launch hands the host (the
     chosen ids, 4 bytes a slot, plus a family's expert counts).
+    ``state_bytes_per_step`` (a family with recurrent layers; from
+    ``decode.dispatch``'s ``state_bytes``): the per-slot state a decode step
+    reads and writes, and ``state_bytes_share`` = its share of what the step
+    moves of per-STREAM memory (it and the cached positions the step's
+    attention covers, ``kv_positions_read x cache_bytes_per_token``).
     Empty when the stream holds no leaf."""
     per: Dict[object, Dict[str, List[float]]] = {}
     kv: Dict[object, Dict[str, List[int]]] = {}    # rep -> call -> [read, live]
@@ -580,6 +585,7 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     fetched: Dict[object, List[int]] = {}   # rep -> [decode fetches, bytes]
     rungs: Dict[object, Dict[int, int]] = {}   # rep -> rows launched -> steps
     token_bytes: Dict[object, int] = {}
+    state: Dict[object, List[int]] = {}     # rep -> [decode steps, bytes]
     edges: Dict[object, List[float]] = {}
     compiling: Dict[object, bool] = {}   # tid -> inside a compiling call
     for r in records:
@@ -603,6 +609,10 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             acc[1] += int(attrs.get("kv_positions_live", 0))
         if "cache_bytes_per_token" in attrs:
             token_bytes[rep] = int(attrs["cache_bytes_per_token"])
+        if name == "decode.dispatch" and "state_bytes" in attrs:
+            acc = state.setdefault(rep, [0, 0])
+            acc[0] += 1
+            acc[1] += int(attrs["state_bytes"])
         if name == "decode.dispatch" and "rows" in attrs:
             acc, rows = rungs.setdefault(rep, {}), int(attrs["rows"])
             acc[rows] = acc.get(rows, 0) + 1
@@ -645,6 +655,14 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
         if rep in fetched:
             amplification["decode_fetch_bytes_per_step"] = round(
                 fetched[rep][1] / fetched[rep][0], 1)
+        if rep in state:
+            n, total = state[rep]
+            amplification["state_bytes_per_step"] = round(total / n, 1)
+            read = kv.get(rep, {}).get("decode.dispatch", (0, 0))[0] \
+                * token_bytes.get(rep, 0)
+            if total + read:
+                amplification["state_bytes_share"] = round(
+                    total / (total + read), 4)
         out[str(rep)] = {
             "steps": steps, "wall_sec": round(wall, 6),
             "host_exposed_share": round((wall - waited) / wall, 4)
@@ -690,6 +708,13 @@ def format_decode_table(by_replica: Dict) -> str:
             lines.append("  decode steps by rows launched: " + ", ".join(
                 f"{share:.1%} at {rows}"
                 for rows, share in b["decode_row_rungs"].items()))
+        if "state_bytes_per_step" in b:
+            lines.append(
+                f"  recurrent state a decode step reads and writes: "
+                f"{b['state_bytes_per_step'] / 2**20:.1f} MB"
+                + (f" = {b['state_bytes_share']:.1%} of its per-stream "
+                   "bytes (beside the cached positions its attention covers)"
+                   if "state_bytes_share" in b else ""))
         if "expert_load" in b:
             e = b["expert_load"]
             lines.append(

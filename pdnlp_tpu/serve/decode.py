@@ -268,6 +268,16 @@ class PagedDecodeEngine(InferenceEngine):
     (:attr:`row_rungs`) chosen by the highest attached slot: fixed-shape
     programs, one per pair, all traced in :meth:`warmup_decode`.
 
+    A family with recurrent layers (``models.hybrid_linear``) pages only the
+    layers that cache positions (the pools' ``L`` is the family's
+    ``pool_layers``) and keeps, beside them, per-SLOT state arrays
+    ``[slots, ...]`` (:attr:`_states`; ``state_bytes`` a slot, whatever the
+    stream's length): a prefill hands each prompt's FINAL state to its slot
+    through ``_pinsert_fn``, the decode step reads and writes the rows of
+    its row rung, both donated like the pools.  What cannot follow such a
+    state is refused at construction: prefix sharing, the speculative pair,
+    the handoff, int8.
+
     Prefix sharing rides the :class:`~pdnlp_tpu.serve.kvpage.PrefixIndex`:
     a repeated prompt maps the indexed pages at refcount+1 and skips its
     prefill entirely (**full hit** — the stored first token is emitted
@@ -306,7 +316,8 @@ class PagedDecodeEngine(InferenceEngine):
                  max_len: Optional[int] = None,
                  buckets: Sequence[int] = DEFAULT_BUCKETS,
                  prefill_rows: Optional[int] = None,
-                 page_sz: Optional[int] = None, prefix_share: bool = True,
+                 page_sz: Optional[int] = None,
+                 prefix_share: Optional[bool] = None,
                  index_entries: int = 4096):
         super().__init__(args, tokenizer, mesh=mesh, metrics=metrics,
                          tracer=tracer)
@@ -332,7 +343,14 @@ class PagedDecodeEngine(InferenceEngine):
         family = self.family
         if self.kv_int8 and not family.int8:
             family.refuse("an int8 cache (--kv_dtype int8)",
-                          "its latent cache is stored in bf16")
+                          "its cache is stored in bf16")
+        # ``None``: share where the family can.  A family with a recurrent
+        # state cannot: a hit would need the state AT the shared boundary,
+        # and no snapshot of it exists
+        if prefix_share and not family.prefix:
+            family.refuse("prefix sharing (prefix_share=True)",
+                          "every prompt is prefilled whole")
+        prefix_share = family.prefix if prefix_share is None else prefix_share
         self.kv_dtype = (jnp.int8 if self.kv_int8
                          else {"fp32": jnp.float32,
                                "bf16": jnp.bfloat16}.get(kv_req, self.dtype))
@@ -350,8 +368,20 @@ class PagedDecodeEngine(InferenceEngine):
         self.budget = KVBudget(getattr(args, "kv_hbm_mb", 0))
         requested = int(slots or getattr(args, "decode_slots", 8))
         # bytes a cached position takes: ONE place computes it, from the
-        # family's pools, for budgets, refusals and snapshots alike
+        # family's pools over the layers that page, for budgets, refusals
+        # and snapshots alike
         self.token_bytes = families.token_bytes(cfg, self.kv_dtype)
+        m = self.rows_multiple
+        self.slots = max(m, (requested // m) * m)
+        #: what a SLOT keeps besides its pages (a recurrent family), as
+        #: (shape, dtype), and its bytes a slot: a stream costs its pages AND
+        #: this, whatever its length
+        self._state_specs = tuple(
+            (shape, jnp.dtype(dt or self.dtype))
+            for shape, dt in family.state_shapes(cfg, self.slots))
+        self.state_bytes = sum(
+            int(np.prod(shape[1:])) * dt.itemsize
+            for shape, dt in self._state_specs)
         ps = max(1, min(int(page_sz or getattr(args, "kv_page_sz", 0)
                             or 16), self.max_len))
         self.page_sz = ps
@@ -364,16 +394,17 @@ class PagedDecodeEngine(InferenceEngine):
              for i in range(1, self.DECODE_RUNGS + 1)})
         self.page_bytes = self.token_bytes * ps
         req_pages = requested * self.pages_per_stream
+        # the slots' states are allocated whole, so a budget pays for them
+        # before it pays for a page
         self.n_pages = self.budget.cap_pages(
-            req_pages, self.page_bytes, min_pages=self.pages_per_stream)
+            req_pages, self.page_bytes, min_pages=self.pages_per_stream,
+            reserved=self.slots * self.state_bytes)
         if self.n_pages < req_pages:
             print(f"[serve.decode] kv_hbm_mb caps KV pages "
                   f"{req_pages} -> {self.n_pages} "
                   f"({self.page_bytes / 2**20:.2f} MB/page, "
                   f"{self.pages_per_stream}/stream worst case)",
                   file=sys.stderr)
-        m = self.rows_multiple
-        self.slots = max(m, (requested // m) * m)
         # the decode step's row extents: one warmed program per (row rung,
         # page rung), chosen per step by the highest attached slot
         small = self.pad_rows(self.ROW_RUNG)
@@ -408,44 +439,51 @@ class PagedDecodeEngine(InferenceEngine):
         self._alloc_cache()
 
         # --- programs.  Every one takes the cache as ONE tuple of pools
-        # (twin K and V pools, or the one latent pool: the family's),
-        # donated, and the int8 scale tables — none for a float cache — as
-        # trailing arguments; what a family counts per launch rides back
-        # as ``aux``
+        # (twin K and V pools, or the one latent pool: the family's) and,
+        # AFTER the arguments it always had, ONE tuple of per-slot states
+        # (empty — no operand — for a family without a recurrent layer),
+        # both donated, then the int8 scale tables — none for a float cache;
+        # what a family counts per launch rides back as ``aux``
         metrics_ref = self.metrics
         dtype = self.dtype
 
         def _prefill_fn(params, head, ids, mask, last_pos):
             metrics_ref.retraces.inc()  # body runs only while tracing
-            # -> (logits, chosen ids, aux, the new rows of every pool)
-            logits, aux, news = family.prefill(params, head, cfg, ids, mask,
-                                               last_pos, dtype)
-            return logits, greedy_ids(logits), aux, news
+            # -> (logits, chosen ids, aux, the new rows of every pool, each
+            #     prompt's final state)
+            logits, aux, news, fin = family.prefill(
+                params, head, cfg, ids, mask, last_pos, dtype)
+            return logits, greedy_ids(logits), aux, news, fin
 
-        def _pinsert_fn(pools, news, flat_pos, *scales):
-            metrics_ref.retraces.inc()
-            return families.insert(pools, news, flat_pos, scales or None)
-
-        def _pdecode_fn(params, head, pools, tokens, table, pos, *scales):
-            metrics_ref.retraces.inc()
-            logits, aux, pools = family.attend(
-                params, head, cfg, tokens, pools, table, pos, None, "last",
-                scales or None, dtype)
-            return logits, greedy_ids(logits), aux, pools
-
-        def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
-                       *scales):
-            metrics_ref.retraces.inc()
-            logits, aux, pools = family.attend(
-                params, head, cfg, tokens, pools, table, start, nreal,
-                "last", scales or None, dtype)
-            return logits, greedy_ids(logits), aux, pools
-
-        def _pverify_fn(params, head, pools, tokens, table, start, nreal,
+        def _pinsert_fn(pools, news, flat_pos, states, fin, slot_ids,
                         *scales):
             metrics_ref.retraces.inc()
-            return family.attend(params, head, cfg, tokens, pools, table,
-                                 start, nreal, "all", scales or None, dtype)
+            # ``slot_ids`` is None (no operand) where there is no state
+            return (families.insert(pools, news, flat_pos, scales or None),
+                    families.insert_states(states, fin, slot_ids))
+
+        def _pdecode_fn(params, head, pools, tokens, table, pos, states,
+                        *scales):
+            metrics_ref.retraces.inc()
+            logits, aux, pools, states = family.attend(
+                params, head, cfg, tokens, pools, states, table, pos, None,
+                "last", scales or None, dtype)
+            return logits, greedy_ids(logits), aux, pools, states
+
+        def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
+                       states, *scales):
+            metrics_ref.retraces.inc()
+            logits, aux, pools, states = family.attend(
+                params, head, cfg, tokens, pools, states, table, start,
+                nreal, "last", scales or None, dtype)
+            return logits, greedy_ids(logits), aux, pools, states
+
+        def _pverify_fn(params, head, pools, tokens, table, start, nreal,
+                        states, *scales):
+            metrics_ref.retraces.inc()
+            return family.attend(params, head, cfg, tokens, pools, states,
+                                 table, start, nreal, "all", scales or None,
+                                 dtype)
 
         def _pcow_fn(pools, src, dst):
             metrics_ref.retraces.inc()
@@ -461,10 +499,10 @@ class PagedDecodeEngine(InferenceEngine):
                          for p, x in zip(pools, payloads))
 
         self._jit_prefill = jax.jit(_prefill_fn)
-        self._jit_pinsert = jax.jit(_pinsert_fn, donate_argnums=(0,))
-        self._jit_pdecode = jax.jit(_pdecode_fn, donate_argnums=(2,))
-        self._jit_pchunk = jax.jit(_pchunk_fn, donate_argnums=(2,))
-        self._jit_pverify = jax.jit(_pverify_fn, donate_argnums=(2,))
+        self._jit_pinsert = jax.jit(_pinsert_fn, donate_argnums=(0, 3))
+        self._jit_pdecode = jax.jit(_pdecode_fn, donate_argnums=(2, 6))
+        self._jit_pchunk = jax.jit(_pchunk_fn, donate_argnums=(2, 7))
+        self._jit_pverify = jax.jit(_pverify_fn, donate_argnums=(2, 7))
         self._jit_pcow = jax.jit(_pcow_fn, donate_argnums=(0,))
         # export reads the pool (no donation — the sender keeps serving
         # from it); import donates like every other cache writer
@@ -507,12 +545,18 @@ class PagedDecodeEngine(InferenceEngine):
             # array would alias the pools, and a donating program would
             # then donate the same buffer twice
             return jax.device_put(jnp.zeros(
-                (cfg.num_layers, self.n_pages, self.page_sz, width),
-                self.kv_dtype))
+                (self.family.pool_layers(cfg), self.n_pages, self.page_sz,
+                 width), self.kv_dtype))
 
-        #: the cache: one array per pool of the family (``models.families``),
-        #: every one donated to each program
+        #: the cache: one array per pool of the family (``models.families``)
+        #: over the layers that page, every one donated to each program
         self._pools = tuple(alloc(w) for w in self.family.pool_widths(cfg))
+        #: what the slots keep besides pages (a recurrent family; else
+        #: empty), ``[slots, ...]`` each and donated like the pools.  A
+        #: slot's rows are written WHOLE by the prefill that seats a stream,
+        #: so a detached slot's leftovers never reach the next stream
+        self._states = tuple(jax.device_put(jnp.zeros(shape, dt))
+                             for shape, dt in self._state_specs)
         self.allocator = PageAllocator(self.n_pages, self.page_sz,
                                        self.page_bytes)
         self.prefix = PrefixIndex(self.allocator, self.page_sz,
@@ -553,9 +597,11 @@ class PagedDecodeEngine(InferenceEngine):
         if total > self.max_len:
             need = pages_needed(total, self.page_sz)
             if self.budget.budget_bytes is not None:
+                state = (f" beside {self.state_bytes / 2**20:.2f} MB of "
+                         "recurrent state" if self.state_bytes else "")
                 raise KVBudgetExceeded(
                     f"stream needs {need} KV pages ({total} positions, "
-                    f"{need * self.page_bytes / 2**20:.2f} MB) but a "
+                    f"{need * self.page_bytes / 2**20:.2f} MB{state}) but a "
                     f"stream's page table holds {self.pages_per_stream} "
                     f"pages ({self.max_len} positions) under --kv_hbm_mb")
             raise ValueError(
@@ -841,7 +887,9 @@ class PagedDecodeEngine(InferenceEngine):
         return claim
 
     def detach_slot(self, slot: int) -> None:
-        """Release ``slot``'s page reservation."""
+        """Release ``slot``'s page reservation.  Its rows of the per-slot
+        states stay as they lie: nothing reads a detached slot's, and the
+        prefill that seats the next stream writes them whole."""
         if not (0 <= slot < self.slots):
             return
         st = self._slot_state[slot]
@@ -1092,11 +1140,17 @@ class PagedDecodeEngine(InferenceEngine):
             last = np.zeros((rows,), np.int32)
             flat = np.full((rows, bucket // unit),
                            self.n_pages * ps // unit, np.int32)
+            # the slot each row's final state goes to (a recurrent family;
+            # filler rows carry the sentinel ``slots`` and are dropped)
+            seat = (np.full((rows,), self.slots, np.int32)
+                    if self._states else None)
             for i, (x, s) in enumerate(zip(id_lists, slot_ids)):
                 ids[i, :len(x)] = x
                 mask[i, :len(x)] = 1
                 last[i] = len(x) - 1
                 if 0 <= s < self.slots and self._slot_state[s] is not None:
+                    if seat is not None:
+                        seat[i] = s
                     row = self._table[s]
                     if unit == ps:
                         n_pg = pages_needed(len(x), ps)
@@ -1112,11 +1166,15 @@ class PagedDecodeEngine(InferenceEngine):
                        streams=int(n), prefill=True, paged=True,
                        tokens=int(mask.sum()), dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, chosen, aux, news = self._jit_prefill(
+                if self._states:
+                    # the final states the launch writes into its slots
+                    sp.set(state_bytes=int(n) * self.state_bytes)
+            logits, chosen, aux, news, fin = self._jit_prefill(
                 self.params, self.head, sharded["ids"], sharded["mask"],
                 last)
-            self._pools = self._jit_pinsert(self._pools, news, flat,
-                                            *self._scale_args())
+            self._pools, self._states = self._jit_pinsert(
+                self._pools, news, flat, self._states, fin, seat,
+                *self._scale_args())
         return self._fetch_chosen(logits, chosen, "prefill", aux, rows=n)
 
     def prefill_chunk(self, suffixes: Sequence[Sequence[int]],
@@ -1158,9 +1216,10 @@ class PagedDecodeEngine(InferenceEngine):
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label,
                        **self._telemetry_attrs(request_ids))
-            logits, chosen, aux, self._pools = self._jit_pchunk(
-                self.params, self.head, self._pools, tokens, table, start,
-                nreal, *self._scale_args())
+            logits, chosen, aux, self._pools, self._states = \
+                self._jit_pchunk(
+                    self.params, self.head, self._pools, tokens, table,
+                    start, nreal, self._states, *self._scale_args())
         return self._fetch_chosen(logits, chosen, "chunk", aux, rows=n)
 
     def decode_batch(self, tokens: np.ndarray, pos: np.ndarray,
@@ -1208,9 +1267,13 @@ class PagedDecodeEngine(InferenceEngine):
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
-            logits, chosen, aux, self._pools = self._jit_pdecode(
-                self.params, self.head, self._pools, tok,
-                table[:, :rung], p, *self._scale_args())
+                if self._states:
+                    # the launched rows' states, read and written
+                    sp.set(state_bytes=2 * r * self.state_bytes)
+            logits, chosen, aux, self._pools, self._states = \
+                self._jit_pdecode(
+                    self.params, self.head, self._pools, tok,
+                    table[:, :rung], p, self._states, *self._scale_args())
         return self._fetch_chosen(logits, chosen, "decode", aux)
 
     def verify_ids(self, window: np.ndarray, pos: np.ndarray,
@@ -1241,9 +1304,10 @@ class PagedDecodeEngine(InferenceEngine):
                        pages_live=self.allocator.used_pages,
                        dtype=self.dtype_label, kv=self._kv_label(),
                        **self._telemetry_attrs(request_ids))
-            logits, aux, self._pools = self._jit_pverify(
+            logits, aux, self._pools, self._states = self._jit_pverify(
                 self.params, self.head, self._pools, tok,
-                jnp.asarray(self._table), start, nr, *self._scale_args())
+                jnp.asarray(self._table), start, nr, self._states,
+                *self._scale_args())
         return self._fetch(logits, "verify.device_wait", "verify.fetch",
                            aux)
 
@@ -1269,8 +1333,10 @@ class PagedDecodeEngine(InferenceEngine):
             # OOB slot id: filler tables/flat sentinels — no live page
             # is touched
             self.prefill_ids([[self.tokenizer.cls_id] * b], [self.slots])
-            self.prefill_chunk([[self.tokenizer.cls_id] * b],
-                               [self.slots], [0])
+            if self.family.prefix:
+                # the suffix chunk exists only after a prefix hit
+                self.prefill_chunk([[self.tokenizer.cls_id] * b],
+                                   [self.slots], [0])
         self._flush_cow(force=True)
         tok = np.zeros((self.slots,), np.int32)
         for rung in self.decode_rungs:
@@ -1292,6 +1358,7 @@ class PagedDecodeEngine(InferenceEngine):
             "kv_dtype": self._kv_label(),
             "cache_bytes": self.n_pages * self.page_bytes,
             "kv_pool_bytes": int(sum(p.nbytes for p in self._pools)),
+            "state_pool_bytes": int(sum(x.nbytes for x in self._states)),
             "weights_bytes": int(sum(
                 x.nbytes for x in jax.tree_util.tree_leaves(
                     (self.params, self.head)))),

@@ -122,7 +122,9 @@ class PageAllocator:
     threads read.  ``reclaimer`` (installed by the engine) is called with
     the shortfall when :meth:`alloc` comes up short — the prefix index's
     LRU eviction hook — and the allocation retries once before raising
-    :class:`KVPagesExhausted`."""
+    :class:`KVPagesExhausted`.  ``page_bytes`` is what the engine says a
+    page costs: the family's pools over the layers that PAGE (a recurrent
+    layer's per-slot state is no page, and no part of this ledger)."""
 
     def __init__(self, n_pages: int, page_sz: int, page_bytes: int = 0):
         self.n_pages = int(n_pages)

@@ -85,6 +85,13 @@ never an OOM); ``--replicas N`` decodes behind a
 :class:`~pdnlp_tpu.serve.decode.DecodeRouter` whose kill-recovery
 re-prefills orphan streams on survivors with no duplicated or lost
 tokens.  ``--max_new_tokens`` bounds each stream's generation.
+``--model`` picks the family (``models.families``; no other flag does):
+the BERT causal LM (``bert-*``: twin K/V pools), the latent-attention,
+sparse-expert decoder (``ax-k1-*``: one latent pool), the hybrid
+(``solar-open2-*``: gated delta-rule linear attention with a per-slot
+recurrent state beside paged GQA layers, sparse experts; it shares no
+prefix and refuses ``--kv_dtype int8``, ``--serve_dtype int8``,
+``--speculate`` and ``--disagg`` at construction, as the latent one does).
 
 ``--speculate id=ckpt[:dtype]`` (or a bare checkpoint path) adds
 **speculative decoding** to ``--decode``: a cheap drafter engine rides
