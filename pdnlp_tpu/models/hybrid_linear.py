@@ -39,20 +39,37 @@ the last REAL position of its padded row: padded positions are given ``beta
 hands over; a decode step reads and writes the rows of its row rung.
 
 **Two forms of one recurrence.**  A decode step applies it as written.  A
-prompt is cut into chunks of :data:`CHUNK`; inside a chunk, with ``G_r`` the
-running sum of ``g``, ``u_r = beta_r (v_r - S_{r-1}^T (alpha_r k_r))``
-solves the unit lower-triangular system ``(I + Diag(beta) tril(A, -1)) U =
-Diag(beta) (V - (exp(G) K) S_0)`` with ``A_ri = sum_d k_rd k_id exp(G_rd -
-G_id)``; ``o_r = S_0^T (exp(G_r) q_r) + sum_{i<=r} (sum_d q_rd k_id exp(G_rd
-- G_id)) u_i``; ``S_C = Diag(exp(G_C)) S_0 + sum_i (exp(G_C - G_i) k_i)
-u_i^T``; the state is carried over the chunks under ``lax.scan``.  Every
-exponent is a difference ``G_later - G_earlier <= 0``, so no decay, however
-strong, overflows (the factored form ``k_i / exp(G_i)`` does); the price is
-that ``A`` is an elementwise sum, not a matrix product.  Everything that
-touches the state is float32 at ``highest`` matmul precision.
+prompt is cut into chunks of :data:`CHUNK`.  Inside a chunk, with ``G_r``
+the running sum of ``g`` and ``M = I + Diag(beta) tril(A, -1)``, ``A_ri =
+sum_d k_rd k_id exp(G_rd - G_id)``, the rows ``U`` that the state gains
+solve ``M U = Diag(beta) (V - (exp(G) K) S_0)``, which is linear in the
+chunk's first state ``S_0``: ``U = u - w S_0`` with ``u = M^-1 (beta V)``
+and ``w = M^-1 (beta exp(G) K)``; then ``o = (exp(G) Q) S_0 + Aqk U``
+(``Aqk_ri = sum_d q_rd k_id exp(G_rd - G_id)``, ``i <= r``) and ``S_C =
+Diag(exp(G_C)) S_0 + (exp(G_C - G) K)^T U``.  **What does not read the
+state is not in the sequential loop**: ``G``, ``A``, ``Aqk``, ``u``, ``w``
+and the decayed ``q`` and ``k`` are computed for a GROUP of chunks at once
+(:data:`GROUP_BYTES`: a long prompt's are never all alive), and the loop
+that is left carries ``S`` through three products a chunk — ``[w; exp(G) Q]
+S_0`` stacked, ``Aqk U``, ``(exp(G_C - G) K)^T U`` — and one multiply.
+**The pairwise sums are matrix products where a reference point allows
+it**: a chunk's positions are cut into row blocks of :data:`ROW`; for a
+key ``i`` of an EARLIER block than row ``r``, with ``ref`` the first
+position of ``r``'s block, ``exp(G_r - G_i) = exp(G_r - G_ref) exp(G_ref -
+G_i)``, both factors at most 1, so ``A_ri = (k_r exp(G_r - G_ref)) . (k_i
+exp(G_ref - G_i))``; only a key of the row's OWN block is taken element by
+element (``[.., ROW, ROW, d]``).  ``M`` is inverted the same way: the own
+blocks by doubling (``_unit_lower_inverse``), the blocks before them by
+products, a row block after another.  Every exponent is a
+difference ``G_later - G_earlier <= 0``, so no decay, however strong,
+overflows (the factored form ``k_i / exp(G_i)`` does, and a test evaluates
+every ``exp``'s operand).  Everything that touches the state is float32 at
+``highest`` matmul precision.
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Dict, Tuple
 
 import jax
@@ -70,6 +87,14 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 #: positions of one chunk of the chunkwise delta rule
 CHUNK = 64
+#: positions of one row block of a chunk: pairwise decays inside a block are
+#: taken element by element, across blocks through the later block's first
+#: position
+ROW = 16
+#: what one GROUP of chunks may hold of its widest intermediate (the own-block
+#: products): the terms that do not read the state are computed a group at a
+#: time, so a long prompt's are never all alive at once
+GROUP_BYTES = 256 * 2 ** 20
 #: query rows of one softmax attention block of a prompt
 Q_BLOCK = 512
 #: under the square root of ``l2norm``
@@ -189,52 +214,144 @@ def delta_step(q, k, v, g, beta, S):
     return jnp.sum(q[..., None] * S, axis=-2), S
 
 
+def _unit_lower_inverse(L: jax.Array) -> jax.Array:
+    """``(I + L)^-1`` for strictly lower-triangular ``L [..., R, R]``, ``R``
+    a power of two, by doubling: with the diagonal blocks of one size
+    inverted (``a`` above ``d``), the block under them is ``-d L_21 a`` —
+    every matrix and every pair of blocks at once, ``log2 R`` steps.  Exact
+    sums of float32 products; the power series ``sum (-L)^n`` cancels
+    catastrophically at ``beta`` near 2."""
+    lead, R = L.shape[:-2], L.shape[-1]
+
+    def mm(x, y):
+        return jnp.sum(x[..., :, :, None] * y[..., None, :, :], axis=-2)
+
+    inv = jnp.ones(lead + (R, 1, 1), L.dtype)         # the 1 x 1 blocks'
+    h = 1
+    while h < R:
+        n = R // (2 * h)                               # pairs of blocks
+        pair = np.eye(n, dtype=bool)[:, None, :, None]
+        below = jnp.sum(jnp.where(pair, L.reshape(lead + (n, 2 * h, n, 2 * h)),
+                                  0.0), axis=-2)[..., h:, :h]   # [.., n, h, h]
+        ad = inv.reshape(lead + (n, 2, h, h))
+        a, d = ad[..., 0, :, :], ad[..., 1, :, :]
+        inv = jnp.block([[a, jnp.zeros_like(a)], [-mm(mm(d, below), a), d]])
+        h *= 2
+    return inv[..., 0, :, :]
+
+
+def _chunk_terms(q, k, v, g, b, R: int):
+    """All of a chunk's recurrence that does NOT read the state (module
+    note), for every chunk handed in at once: ``q k g [..., C, dk]``, ``v
+    [..., C, dv]``, ``b [..., C]`` -> ``w`` and ``exp(G) q`` stacked ``[...,
+    2C, dk]``, ``u [..., C, dv]``, ``Aqk [..., C, C]``, ``exp(G_C - G) k
+    [..., C, dk]``, ``exp(G_C) [..., dk]``.  Every exponent is a difference
+    ``G_later - G_earlier``."""
+    lead, (C, dk) = q.shape[:-2], q.shape[-2:]
+    nb = C // R
+
+    def rows(x):                     # [..., C, d] -> [..., nb, R, d]
+        return x.reshape(lead + (nb, R) + x.shape[-1:])
+
+    G = jnp.cumsum(g, axis=-2)
+    Gb, qb, kb = rows(G), rows(q), rows(k)
+    # a key of an EARLIER row block: through the block's first position
+    ref = Gb[..., :1, :]                                      # [.., nb, 1, dk]
+    earlier = np.arange(C) < R * np.arange(nb)[:, None]             # [nb, C]
+    kk = k[..., None, :, :] * jnp.exp(jnp.where(
+        earlier[..., None], ref - G[..., None, :, :], -jnp.inf))
+    fall = jnp.exp(Gb - ref)
+    A = _heinsum("...brd,...bid->...bri", kb * fall, kk)      # [.., nb, R, C]
+    Aqk = _heinsum("...brd,...bid->...bri", qb * fall, kk)
+    # a key of the row's OWN block, i <= r: element by element
+    own = np.tril(np.ones((R, R), bool))
+    kd = kb[..., None, :, :] * jnp.exp(jnp.where(
+        own[..., None], Gb[..., :, None, :] - Gb[..., None, :, :], -jnp.inf))
+    A_own = jnp.sum(kb[..., :, None, :] * kd, axis=-1)        # [.., nb, R, R]
+    Aqk_own = jnp.sum(qb[..., :, None, :] * kd, axis=-1)
+    at_own = np.eye(nb, dtype=bool)[:, None, :, None]         # [nb, 1, nb, 1]
+    Aqk = jnp.where(at_own, Aqk_own[..., None, :],
+                    Aqk.reshape(lead + (nb, R, nb, R))).reshape(lead + (C, C))
+    # (I + Diag(b) tril(A, -1)) [u | w] = Diag(b) [v | exp(G) k], a row
+    # block after another: the own block inverted, the earlier ones' rows
+    # of the solution taken off first
+    eG = jnp.exp(G)
+    bb = rows(b[..., None])
+    inv = _unit_lower_inverse(bb * jnp.where(np.tril(own, -1), A_own, 0.0))
+    rhs = rows(b[..., None] * jnp.concatenate([v, k * eG], axis=-1))
+    A = bb * A
+    sol = []
+    for j in range(nb):
+        x = rhs[..., j, :, :]
+        if j:
+            x = x - _heinsum("...ri,...iv->...rv", A[..., j, :, :j * R],
+                             jnp.concatenate(sol, axis=-2))
+        sol.append(_heinsum("...ri,...iv->...rv", inv[..., j, :, :], x))
+    uw = jnp.concatenate(sol, axis=-2)                        # [.., C, dv+dk]
+    dv = v.shape[-1]
+    GC = G[..., -1:, :]
+    return (jnp.concatenate([uw[..., dv:], q * eG], axis=-2), uw[..., :dv],
+            Aqk, k * jnp.exp(GC - G), jnp.exp(GC)[..., 0, :])
+
+
+@functools.partial(jax.jit, static_argnames=("C", "R", "per"))
+def _scan_groups(q, k, v, g, beta, S, C: int, R: int, per: int):
+    """:func:`delta_chunked` at chunks of ``C`` positions in row blocks of
+    ``R``, ``per`` chunks a group, over whole groups.  Jitted, so that the
+    layers of a trunk — one shape, some hundred operations each — are traced
+    and lowered ONCE a program: a program's set-up goes with what is
+    lowered."""
+    B, T, N, _ = q.shape                       # T: whole groups
+    nG = T // (per * C)
+
+    def cut(x):    # [B, T, N, ...] -> [nG, B, per, C, N, ...]: no relayout
+        return jnp.moveaxis(x.reshape((B, nG, per, C) + x.shape[2:]), 1, 0)
+
+    def step(S, x):
+        wq, u, Aqk, kend, eGC = x
+        wqS = _heinsum("bnck,bnkv->bncv", wq, S)
+        U = u - wqS[..., :C, :]
+        o = wqS[..., C:, :] + _heinsum("bnri,bniv->bnrv", Aqk, U)
+        S = eGC[..., None] * S + _heinsum("bnck,bncv->bnkv", kend, U)
+        return S, o
+
+    def group(S, x):
+        # heads before positions HERE, a group at a time, where the
+        # relayout rides on the first elementwise pass over each operand
+        x = (jnp.moveaxis(jnp.moveaxis(y, 1, 0), 3, 2) for y in x)
+        S, o = jax.lax.scan(step, S, _chunk_terms(*x, R))
+        return S, jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)   # [B, per, C, N, dv]
+
+    S, o = jax.lax.scan(group, S, tuple(cut(x) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, N, -1), S
+
+
 def delta_chunked(q, k, v, g, beta, S, chunk: int = CHUNK):
-    """The same recurrence over ``T`` positions in chunks (module note): the
-    intra-chunk triangular system solved, the state carried over the chunks
-    under ``lax.scan``.  ``q k g [B, T, N, dk]``, ``v [B, T, N, dv]``, ``beta
-    [B, T, N]``, ``S [B, N, dk, dv]``, all float32; a position with
-    ``beta = 0`` and ``g = 0`` leaves the state as it is."""
+    """The same recurrence over ``T`` positions in chunks (module note):
+    what does not read the state computed a GROUP of chunks at a time, the
+    state carried over a group's chunks, and over the groups, under
+    ``lax.scan``.  ``q k g [B, T, N, dk]``, ``v [B, T, N, dv]``, ``beta [B,
+    T, N]``, ``S [B, N, dk, dv]``, all float32; a position with ``beta = 0``
+    and ``g = 0`` leaves the state as it is."""
     B, T, N, dk = q.shape
-    C = min(chunk, T)
-    pad = -T % C
+    C = min(chunk, -(-T // ROW) * ROW)
+    R = math.gcd(ROW, C)
+    nC = -(-T // C)
+    # chunks a group: the widest thing a chunk's terms hold is the own-block
+    # products ``[B, N, C, R, dk]``.  A count that DIVIDES the chunks where
+    # one over half the budget does (padding a long prompt to whole groups
+    # is a copy of every operand), else groups of one size, as few as the
+    # budget allows, the last one padded
+    most = min(nC, max(1, GROUP_BYTES // (B * N * C * R * dk * 4)))
+    per = max(p for p in range(1, most + 1) if nC % p == 0)
+    if 2 * per < most:
+        per = -(-nC // -(-nC // most))
+    pad = -T % (per * C)
     if pad:      # positions that leave the state alone; their rows are cut
         q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (
             x.ndim - 2)) for x in (q, k, v, g, beta))
-    nC = (T + pad) // C
-
-    def cut(x):          # [B, T, N, ...] -> [nC, B, N, C, ...]
-        x = x.reshape((B, nC, C) + x.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
-
-    lower = jnp.tril(jnp.ones((C, C), bool))
-    strict = jnp.tril(jnp.ones((C, C), bool), -1)
-    eye = jnp.eye(C, dtype=F32)
-
-    def body(S, x):
-        q, k, v, g, b = x                 # [B, N, C, d]; b [B, N, C]
-        G = jnp.cumsum(g, axis=-2)
-        # exp(G_r - G_i) k_i for i <= r: every exponent is <= 0
-        diff = G[..., :, None, :] - G[..., None, :, :]
-        kd = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf)) \
-            * k[..., None, :, :]                               # [B,N,r,i,dk]
-        A = jnp.sum(k[..., :, None, :] * kd, axis=-1)          # [B,N,r,i]
-        Aqk = jnp.sum(q[..., :, None, :] * kd, axis=-1)
-        M = eye + b[..., :, None] * jnp.where(strict, A, 0.0)
-        eG = jnp.exp(G)
-        rhs = b[..., None] * (v - _heinsum("bnck,bnkv->bncv", k * eG, S))
-        U = jax.lax.linalg.triangular_solve(
-            M, rhs, left_side=True, lower=True, unit_diagonal=True)
-        o = _heinsum("bnck,bnkv->bncv", q * eG, S) \
-            + _heinsum("bnri,bniv->bnrv", Aqk, U)
-        GC = G[..., -1:, :]
-        S = jnp.exp(GC)[..., 0, :, None] * S \
-            + _heinsum("bnck,bncv->bnkv", k * jnp.exp(GC - G), U)
-        return S, o
-
-    S, o = jax.lax.scan(body, S, tuple(cut(x) for x in (q, k, v, g, beta)))
-    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)       # [B, nC, C, N, dv]
-    return o.reshape(B, nC * C, N, -1)[:, :T], S
+    o, S = _scan_groups(q, k, v, g, beta, S, C, R, per)
+    return o[:, :T], S
 
 
 # ------------------------------------------------------------ a linear layer

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jex_core
 
 import solar_open2_reference as ref
 from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
@@ -251,24 +252,67 @@ def _delta_inputs(key, B, T, N, d, decay):
     return q, k, v, g, beta, jax.random.normal(ks[5], (B, N, d, d))
 
 
-@pytest.mark.parametrize("decay", [(1e-5, 1e-3), (1.0, 30.0), (1e-4, 30.0)],
-                         ids=["near_one", "near_zero", "both"])
-@pytest.mark.parametrize("T", [150, 64])
-def test_chunkwise_scan_equals_the_sequential_recurrence(decay, T):
-    """``-g`` from 1e-5 (alpha = 0.99999) to 30 (alpha = 1e-13: the factored
-    form ``k / exp(G)`` would overflow inside a chunk), beta up to 2, rows
-    padded past their real length with ``beta = 0``, ``g = 0``: outputs and
-    the state at the last REAL position agree."""
-    B, N, d = 2, 3, 16
-    q, k, v, g, beta, S0 = _delta_inputs(jax.random.key(T), B, T, N, d, decay)
-    assert float(beta.max()) > 1.9 and float(beta.min()) < 0.1
+DECAYS = {"near_one": (1e-5, 1e-3), "near_zero": (1.0, 30.0),
+          "both": (1e-4, 30.0)}
+# T, heads, head width, chunks a group (None: the module's byte budget), and
+# whether the triangular system is made as badly conditioned as it gets
+SHAPES = {
+    "T150": (150, 3, 16, None, False),
+    "T64": (64, 3, 16, None, False),
+    # 11 chunks, the last one padded, in 3 groups of 4: a ragged last group
+    "T650_in_groups_of_4_chunks": (650, 3, 16, 4, False),
+    # the published head width: the row-block factors are 128 wide
+    "T150_d128": (150, 2, 128, None, False),
+    "T150_d128_repeated_keys_beta_2": (150, 2, 128, None, True),
+}
+
+
+def _chunked_case(decay, shape, monkeypatch):
+    """-> (the inputs of one case, its rows' real lengths)."""
+    T, N, d, per, hard = SHAPES[shape]
+    B = 2
+    q, k, v, g, beta, S0 = _delta_inputs(jax.random.key(T), B, T, N, d,
+                                         DECAYS[decay])
+    if hard:
+        # every key the same and beta near 2 (``kda_allow_neg_eigval``):
+        # ``I + Diag(beta) tril(A, -1)`` at its worst conditioned, with a
+        # decay of ``-g`` = 30 ON the row blocks' edges and inside them
+        k = jnp.broadcast_to(k[:, :1], k.shape)
+        beta = jnp.full_like(beta, 1.999)
+        at = np.isin(np.arange(T) % hl.ROW, (0, 7, hl.ROW - 1))
+        g = jnp.where(at[None, :, None, None], -30.0, g)
+    else:
+        assert float(beta.max()) > 1.9 and float(beta.min()) < 0.1
+    if per:
+        monkeypatch.setattr(hl, "GROUP_BYTES", per * (
+            B * N * hl.CHUNK * hl.ROW * d * 4))
     nreal = np.array([T, T * 2 // 3])
     valid = jnp.arange(T)[None] < nreal[:, None]
     g = jnp.where(valid[..., None, None], g, 0.0)
     beta = jnp.where(valid[..., None], beta, 0.0)
+    assert float(jnp.abs(S0).max()) > 1.0       # not from an empty state
+    return (q, k, v, g, beta, S0), nreal
+
+
+@pytest.mark.parametrize("decay", list(DECAYS))
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_chunkwise_scan_equals_the_sequential_recurrence(decay, shape,
+                                                         monkeypatch):
+    """``-g`` from 1e-5 (alpha = 0.99999) to 30 (alpha = 1e-13: the factored
+    form ``k / exp(G)`` would overflow inside a chunk), beta up to 2, a
+    non-zero first state, rows padded past their real length with ``beta =
+    0``, ``g = 0``: outputs and the state at the last REAL position agree —
+    over one group of chunks and over several, at head widths 16 and 128."""
+    (q, k, v, g, beta, S0), nreal = _chunked_case(decay, shape, monkeypatch)
+    if SHAPES[shape][3]:
+        loops = _loops(jax.make_jaxpr(hl.delta_chunked)(
+            q, k, v, g, beta, S0).jaxpr)
+        assert [(depth, e.params["length"]) for e, depth, _ in loops] == [
+            (0, 3), (1, 4)]                          # 3 groups of 4 chunks
     o1, S1 = delta_sequential(q, k, v, g, beta, S0)
     o2, S2 = jax.jit(hl.delta_chunked)(q, k, v, g, beta, S0)
     assert np.isfinite(np.asarray(o2)).all()
+    assert np.isfinite(np.asarray(S2)).all()
     np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), atol=STATE_TOL)
     np.testing.assert_allclose(np.asarray(S2), np.asarray(S1), atol=STATE_TOL)
     # row 1's final state IS the state after its last real position
@@ -277,6 +321,137 @@ def test_chunkwise_scan_equals_the_sequential_recurrence(decay, T):
                                    g[1:, :n], beta[1:, :n], S0[1:])
     np.testing.assert_allclose(np.asarray(S2[1:]), np.asarray(S_cut),
                                atol=STATE_TOL)
+
+
+# ------------------------ (b') the FORM of the chunkwise scan, as a jaxpr
+
+def _inner(eqn):
+    """The jaxprs an equation carries (a scan's body, a jitted helper's)."""
+    found = (getattr(v, "jaxpr", v) for v in eqn.params.values())
+    return [j for j in found if hasattr(j, "eqns")]
+
+
+def _loops(jaxpr, depth=0):
+    """Every ``scan`` of a jaxpr as (equation, depth, body), helpers looked
+    through."""
+    for e in jaxpr.eqns:
+        for sub in _inner(e):
+            if e.primitive.name == "scan":
+                yield e, depth, sub
+            yield from _loops(sub, depth + (e.primitive.name == "scan"))
+
+
+def _equations(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in _inner(e):
+            yield from _equations(sub)
+
+
+def _evaluate(jaxpr, consts, args, seen):
+    """The jaxpr run one equation at a time (a scan one step at a time),
+    ``seen(primitive name, operand values)`` called before each."""
+    env = dict(zip(jaxpr.constvars, consts))
+    env.update(zip(jaxpr.invars, args))
+
+    def read(v):
+        return env[v] if isinstance(v, jex_core.Var) else v.val
+
+    for e in jaxpr.eqns:
+        vals = [read(v) for v in e.invars]
+        seen(e.primitive.name, vals)
+        if e.primitive.name == "scan":
+            body, p = e.params["jaxpr"], e.params
+            nc, nk = p["num_consts"], p["num_carry"]
+            carry, ys = vals[nc:nc + nk], []
+            steps = range(p["length"])
+            for i in (reversed(steps) if p["reverse"] else steps):
+                out = _evaluate(body.jaxpr, body.consts, vals[:nc] + carry + [
+                    x[i] for x in vals[nc + nk:]], seen)
+                carry, y = out[:nk], out[nk:]
+                ys.append(y)
+            ys = ys[::-1] if p["reverse"] else ys
+            out = carry + [jnp.stack(y) for y in zip(*ys)]
+        elif e.primitive.name == "jit":
+            out = _evaluate(e.params["jaxpr"].jaxpr, e.params["jaxpr"].consts,
+                            vals, seen)
+        else:
+            assert not _inner(e), e.primitive.name     # nothing is skipped
+            out = e.primitive.bind(*vals, **e.params)
+            out = out if e.primitive.multiple_results else [out]
+        env.update(zip(e.outvars, out))
+    return [read(v) for v in jaxpr.outvars]
+
+
+@pytest.fixture(scope="module")
+def chunked_jaxpr():
+    """``delta_chunked`` at ``C = 64`` over 3 groups of 2 chunks, heads of
+    the published width."""
+    B, T, N, d = 1, 6 * hl.CHUNK, 2, 128
+    args = _delta_inputs(jax.random.key(0), B, T, N, d, DECAYS["near_zero"])
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hl, "GROUP_BYTES",
+                      2 * B * N * hl.CHUNK * hl.ROW * d * 4)
+        closed = jax.make_jaxpr(hl.delta_chunked)(*args)
+    return closed, args, (B, N, hl.CHUNK, d)
+
+
+def test_what_does_not_read_the_state_is_outside_the_innermost_loop(
+        chunked_jaxpr):
+    """The sequential loop carries the state through products and one
+    multiply, nothing else: no solve, no running sum, no ``exp``, and
+    nothing of ``C x C x d`` elements a head (the pairwise decays) — what
+    keeps the next edit from sliding work back into the loop."""
+    closed, _, (B, N, C, d) = chunked_jaxpr
+    loops = list(_loops(closed.jaxpr))
+    # 3 groups of chunks, a group's 2 chunks: nothing deeper
+    assert [(depth, e.params["length"]) for e, depth, _ in loops] == [
+        (0, 3), (1, 2)]
+    body = loops[-1][2]
+    names = [e.primitive.name for e in _equations(body)]
+    assert not {"triangular_solve", "cumsum", "reduce_window_sum", "exp",
+                "scan", "while", "custom_linear_solve"} & set(names), names
+    assert names.count("dot_general") <= 4 and names.count("mul") == 1
+    widest = max(int(np.prod(v.aval.shape)) for e in _equations(body)
+                 for v in e.outvars)
+    assert widest < B * N * C * C * d
+    # ... and the solve is nowhere the compiler's: row blocks, by products
+    everything = [e.primitive.name for e in _equations(closed.jaxpr)]
+    assert "triangular_solve" not in everything
+    assert "exp" in everything and "cumsum" in everything
+
+
+def test_every_product_of_the_chunkwise_scan_is_float32_at_highest(
+        chunked_jaxpr):
+    """The configuration's ``precision``: "the delta rule's products at
+    highest precision", float32 in, float32 out."""
+    closed, _, _ = chunked_jaxpr
+    dots = [e for e in _equations(closed.jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert len(dots) >= 4
+    for e in dots:
+        assert e.params["precision"] == (hl.HIGHEST, hl.HIGHEST), e
+        assert e.params["preferred_element_type"] == jnp.float32, e
+        assert all(v.aval.dtype == jnp.float32 for v in e.invars), e
+
+
+def test_no_exponent_of_the_chunkwise_scan_is_positive(chunked_jaxpr):
+    """Every ``exp`` is of a difference ``G_later - G_earlier``: on decays
+    down to ``alpha`` = 1e-13 a position no operand of any ``exp`` rises
+    above 0 (the factored form ``k_i / exp(G_i)`` would reach e^1900)."""
+    closed, args, _ = chunked_jaxpr
+    tops = []
+
+    def seen(name, vals):
+        if name == "exp":
+            tops.append(float(jnp.max(vals[0])))
+
+    out = _evaluate(closed.jaxpr, closed.consts, list(args), seen)
+    assert len(tops) >= 5 and max(tops) <= 0.0, tops
+    # the walk above IS the function: the same outputs
+    o, S = hl.delta_chunked(*args)
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(o), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out[1]), np.asarray(S), atol=1e-6)
 
 
 @pytest.mark.parametrize("nreal", [1, 2, 3, 9, 16])
