@@ -3,7 +3,7 @@
 
     python chip_smoke.py            # one chip: device, train, train-packed,
                                     # serve, decode, decode-latent,
-                                    # decode-hybrid
+                                    # decode-latent-mhc, decode-hybrid
     python chip_smoke.py --chips 4  # four chips: device, mesh-train (dp and
                                     # zero against one device), replicas
 
@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import gc
 import io
 import json
@@ -655,23 +656,30 @@ def phase_decode(ctx) -> dict:
                             "stream_owners", "index_entries")}}
 
 
-def phase_decode_latent(ctx) -> dict:
+#: the latent family's presets a phase builds: (on the chip, in a rehearsal)
+LATENT = ("ax-k1-ep16-share-l2", "ax-k1-share-tiny")
+LATENT_MHC = ("xing4-29b-ep1-stage-l2", "xing4-stage-tiny")
+
+
+def phase_decode_latent(ctx, presets=LATENT, tag="latent") -> dict:
     """The second model family (latent attention over a one-pool page cache,
     sparse experts told which they hold) through the same ``serve_tpu.py
     --decode``: the published widths at one dense + one expert layer, seeded
-    weights (the family has no trainer), a repeated prompt."""
+    weights (the family has no trainer), a repeated prompt.  ``LATENT_MHC``:
+    the same family with a four-stream residual mixed by hyper-connections
+    and a bias-corrected router, its experts held whole."""
     import serve_tpu
 
-    model = "ax-k1-share-tiny" if ctx.rehearse else "ax-k1-ep16-share-l2"
+    model = presets[1] if ctx.rehearse else presets[0]
     a, b = request_lines(ctx, 2, 8, 24)
     prompts = [a, b, a]
     max_new = 8
-    metrics = os.path.join(ctx.out, "decode_latent_metrics.json")
+    metrics = os.path.join(ctx.out, f"decode_{tag}_metrics.json")
     argv = ["--model", model, "--dtype", "bfloat16", "--max_seq_len", "128",
             "--seed", str(ctx.seed), "--data_path", ctx.corpus,
             "--vocab_path", ctx.vocab, "--decode", "--decode_slots", "2",
             # a directory of its own: no checkpoint of another family in it
-            "--output_dir", os.path.join(ctx.out, "latent"), "--buckets", "32", "--max_new_tokens",
+            "--output_dir", os.path.join(ctx.out, tag), "--buckets", "32", "--max_new_tokens",
             str(max_new), "--metrics_path", metrics]
     with captured(serve_tpu, "build_decode_pool") as pools:
         out = run_cli(serve_tpu.main, argv, "\n".join(prompts) + "\n")
@@ -688,11 +696,16 @@ def phase_decode_latent(ctx) -> dict:
         rep = json.load(f)["replicas"]["0"]
     kv = rep["kv"]
     check(kv["prefix"]["hits_full"] == 1, f"prefix index: {kv['prefix']}")
+    # the residual streams are carried, never cached: 2 bytes a value
+    cfg = engine.cfg
+    check(kv["stream_bytes_a_token"] == cfg.hc_mult * cfg.hidden_size * 2,
+          f"streams: {kv['stream_bytes_a_token']} bytes a token")
     leak = engine.leak_check()
     check(leak["ok"] and leak["leaked_pages"] == 0, f"leak check: {leak}")
     return {"model": model, "prompts": len(prompts), "repeats": 1,
             "tokens_streamed": sum(r[1] == "tok" for r in rows),
             "cache_bytes_per_token": engine.token_bytes,
+            "stream_bytes_a_token": kv["stream_bytes_a_token"],
             "kv_pool_bytes": kv["kv_pool_bytes"],
             "weights_bytes": kv["weights_bytes"],
             "compile_cache": rep["engine"]["compile_cache"]}
@@ -961,6 +974,8 @@ def main(argv=None) -> int:
         run_phase(ctx, "serve", phase_serve)
         run_phase(ctx, "decode", phase_decode)
         run_phase(ctx, "decode-latent", phase_decode_latent)
+        run_phase(ctx, "decode-latent-mhc", functools.partial(
+            phase_decode_latent, presets=LATENT_MHC, tag="latent_mhc"))
         run_phase(ctx, "decode-hybrid", phase_decode_hybrid)
     if ctx.rehearse:
         print("chip_smoke: rehearsal walked every phase; this is not a chip "

@@ -10,7 +10,7 @@ plus small variants used by tests and the multichip dryrun.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,13 +65,28 @@ class BertConfig:
 class LatentMoEConfig:
     """A pre-norm decoder with latent attention (MLA) and sparse experts,
     served only (``models/latent_moe.py``).  Field names are the published
-    ``config.json``'s where it has one; the defaults are A.X-K1's widths
-    (https://huggingface.co/skt/A.X-K1/blob/main/config.json).
+    ``config.json``'s where it has one.  Two published models are run
+    through it: A.X-K1 (https://huggingface.co/skt/A.X-K1/blob/main/config.json),
+    whose widths the DEFAULTS are — so a preset of any other model states
+    EVERY width, or it would inherit A.X-K1's — and Xing4.0-29B-A4B
+    (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json).
 
     ``experts_held`` / ``expert_first`` say which of the ``n_routed_experts``
     THIS process holds (expert parallelism's share): the router keeps its
     full width, its groups and its experts per token; only the held
-    experts' part of a layer's result is computed."""
+    experts' part of a layer's result is computed.  ``experts_held ==
+    n_routed_experts`` holds a layer's experts whole.
+
+    ``hc_mult`` > 1 turns the residual into that many STREAMS mixed by
+    manifold-constrained hyper-connections (``models/hyper_connections.py``:
+    every sub-layer reads the streams through a learned, input-dependent
+    row, writes through another and carries them through a doubly-stochastic
+    matrix made by ``hc_sinkhorn_iters`` Sinkhorn steps whose denominators
+    carry ``hc_eps``, from logits clipped to ``hc_res_clamp``); 1 is the
+    plain residual ``x + F(x)``, with no mixing leaf and no mixing
+    operation.  ``selection_bias``: the router CHOOSES experts by ``score +
+    bias`` (a learned leaf a layer) and GATES by the score alone
+    (``topk_method: "noaux_tc"``); False: no such leaf, chosen by score."""
     vocab_size: int = 163_840
     hidden_size: int = 7168
     num_layers: int = 61          # leading dense layers + expert layers
@@ -101,6 +116,11 @@ class LatentMoEConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
     max_position: int = 131_072
+    hc_mult: int = 1                    # residual streams (1: x + F(x))
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    selection_bias: bool = False        # choose by score + bias, gate by score
     weight_dtype: str = "bfloat16"      # how the weights are STORED
 
     family = "latent_moe"
@@ -202,6 +222,25 @@ class HybridLinearConfig:
         return dataclasses.replace(self, **kw)
 
 
+def _xing4(**kw) -> LatentMoEConfig:
+    """Xing4.0-29B-A4B's published ``config.json``, EVERY field stated (a
+    default left standing would be A.X-K1's); ``kw`` cuts it."""
+    fields = dict(
+        vocab_size=131_072, hidden_size=3584, num_layers=40, first_k_dense=2,
+        num_heads=32, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=9216,
+        moe_intermediate_size=1024, n_routed_experts=64, experts_held=64,
+        expert_first=0, num_experts_per_tok=4, n_shared_experts=1, n_group=1,
+        topk_group=1, routed_scaling_factor=2.0, rms_norm_eps=1e-6,
+        rope_theta=10_000.0, rope_factor=64.0, rope_original_max=4096,
+        rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, max_position=262_144, hc_mult=4,
+        hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0),
+        selection_bias=True, weight_dtype="bfloat16")
+    fields.update(kw)
+    return LatentMoEConfig(**fields)
+
+
 _REGISTRY = {
     # chinese-bert-wwm-ext shape (BERT-base, ~102M params at vocab 21128)
     "bert-base": BertConfig(),
@@ -241,6 +280,23 @@ _REGISTRY = {
         qk_rope_head_dim=8, v_head_dim=16, intermediate_size=256,
         moe_intermediate_size=64, n_routed_experts=8, experts_held=4,
         num_experts_per_tok=3, n_group=4, topk_group=2, max_position=4096),
+    # one pipeline STAGE of Xing4.0-29B-A4B (ep_size 1: each layer whole on
+    # one chip, all 64 experts held): every width as published, the dense
+    # layer (the two leading ones count once) and 5 of the 38 expert layers,
+    # the whole vocabulary; a four-stream residual (hc_mult 4) and a
+    # bias-corrected router
+    "xing4-29b-ep1-stage": _xing4(num_layers=6, first_k_dense=1),
+    # the same stage cut to the dense layer and ONE expert layer: what
+    # chip_smoke.py builds
+    "xing4-29b-ep1-stage-l2": _xing4(num_layers=2, first_k_dense=1),
+    # the same family at a size the CPU tests run: 1 dense + 2 expert
+    # layers, 8 experts all held of which 3 a token are taken, 4 streams
+    "xing4-stage-tiny": _xing4(
+        vocab_size=1000, hidden_size=128, num_layers=3, first_k_dense=1,
+        num_heads=4, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=256,
+        moe_intermediate_size=64, n_routed_experts=8, experts_held=8,
+        num_experts_per_tok=3, max_position=4096),
     # one chip's share of Solar-Open2-250B when 16 chips share each layer
     # (experts 16 ways: 20 held; the vocabulary 8 ways comes from the
     # tokenizer): every width as published, two whole periods of the 12

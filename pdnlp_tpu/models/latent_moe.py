@@ -17,6 +17,14 @@ same equations again in plain float32:
   scores in float32, group-limited choice, ``num_experts_per_tok`` a token,
   normalised and scaled) plus a shared expert.
 
+With ``cfg.hc_mult`` > 1 (Xing4.0-29B-A4B) the residual ``h`` is that many
+STREAMS ``[n, B, T, H]``: the embedding is copied into them, every sub-layer
+(attention, feed-forward) reads, writes and carries them through
+``models/hyper_connections.py``'s mixing, the final norm reads their sum.
+The streams are never cached: nothing below the layer knows of them.  With
+``cfg.selection_bias`` the router chooses by ``score + bias`` and gates by
+the score.  ``benchmark/reference/xing4.py`` states both in plain float32.
+
 **What is cached** is one vector a token a layer, ``[c_kv | k_rope]``
 (``cfg.latent_width`` values, padded to ``cfg.cache_width``: whole lane
 tiles), in pages ``[L, P, page_sz, width]`` that the
@@ -54,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pdnlp_tpu.models import hyper_connections
 from pdnlp_tpu.models.config import LatentMoEConfig
 from pdnlp_tpu.models.decoder import _layer_rows
 from pdnlp_tpu.ops.attention import NEG_INF
@@ -97,13 +106,22 @@ def param_shapes(cfg: LatentMoEConfig) -> Dict[str, Any]:
 
     K, M = cfg.first_k_dense, cfg.num_moe_layers
     Fs = F * cfg.n_shared_experts
-    return {
+    shapes = {
         "embed": (cfg.vocab_size, H),
         "dense": {"attn": attn((K,)), "ffn": ffn((K,), I)},
         "moe": {"attn": attn((M,)), "router": (M, H, E),
                 "experts": ffn((M, Eh), F), "shared": ffn((M,), Fs)},
         "final_norm": (H,),
     }
+    if cfg.selection_bias:
+        shapes["moe"]["router_bias"] = (M, E)
+    if cfg.hc_mult > 1:
+        # one mixing a sub-layer: before attention, before the feed-forward
+        for part, lead in (("dense", (K,)), ("moe", (M,))):
+            shapes[part]["hc"] = {
+                sub: hyper_connections.param_shapes(cfg.hc_mult, H, lead)
+                for sub in ("attn", "ffn")}
+    return shapes
 
 
 def _is_norm(path) -> bool:
@@ -112,17 +130,25 @@ def _is_norm(path) -> bool:
 
 def init_params(key: jax.Array, cfg: LatentMoEConfig) -> Params:
     """Seeded weights in the family's STORED dtype: matrices normal /
-    sqrt(fan-in), norm gains 1 + 0.1 normal.  One leaf at a time, so that
-    nothing float32 the size of the model is ever alive."""
+    sqrt(fan-in), norm gains 1 + 0.1 normal, the selection bias 0.1 normal,
+    the mixing leaves ``hyper_connections.init_leaf``'s.  One leaf at a
+    time, so that nothing float32 the size of the model is ever alive."""
     shapes = param_shapes(cfg)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     wd = _weight_dtype(cfg)
     out = []
     for i, (path, shape) in enumerate(leaves):
-        x = jax.random.normal(jax.random.fold_in(key, i), shape, F32)
+        k, name = jax.random.fold_in(key, i), path[-1].key
+        if any(p.key == "hc" for p in path):
+            out.append(hyper_connections.init_leaf(
+                k, name, shape, cfg.hc_mult).astype(wd))
+            continue
+        x = jax.random.normal(k, shape, F32)
         if _is_norm(path):
             x = 1.0 + 0.1 * x
+        elif name == "router_bias":
+            x = 0.1 * x
         elif len(shape) >= 2 and path[0].key != "embed":
             x = x * (shape[-2] ** -0.5)
         out.append(x.astype(wd))
@@ -324,20 +350,28 @@ def attend_absorbed(q_nope, q_rope, latent, ap: Params,
 
 # -------------------------------------------------------------- expert layer
 
-def route(f: jax.Array, router: jax.Array, cfg: LatentMoEConfig, dtype):
+def route(f: jax.Array, router: jax.Array, cfg, dtype,
+          bias: Optional[jax.Array] = None):
     """``f [T, H]`` -> (expert ids ``[T, k]``, gates ``[T, k]`` float32,
     scores ``[T, E]`` float32): sigmoid scores over ALL experts; a group's
     score is the sum of its two largest; the ``topk_group`` best groups
     stay; the ``k`` largest scores inside them are taken, normalised to sum
-    1 and scaled."""
+    1 and scaled.  With a selection ``bias [E]`` (``noaux_tc``) groups and
+    experts are CHOSEN by ``score + bias`` and the chosen are GATED by their
+    scores alone."""
     T, E, G = f.shape[0], cfg.n_routed_experts, cfg.n_group
     s = jax.nn.sigmoid(_mm(f, router, dtype))
-    grp = jax.lax.top_k(s.reshape(T, G, E // G), 2)[0].sum(-1)       # [T, G]
+    sel = s if bias is None else s + bias.astype(F32)
+    grp = jax.lax.top_k(sel.reshape(T, G, E // G), 2)[0].sum(-1)     # [T, G]
     _, keep = jax.lax.top_k(grp, cfg.topk_group)
     in_kept = jnp.zeros((T, G), bool).at[
         jnp.arange(T)[:, None], keep].set(True)
-    masked = jnp.where(jnp.repeat(in_kept, E // G, axis=1), s, 0.0)
+    # a biased score can be negative: a dropped group's lie below any
+    masked = jnp.where(jnp.repeat(in_kept, E // G, axis=1), sel,
+                       0.0 if bias is None else -jnp.inf)
     top, idx = jax.lax.top_k(masked, cfg.num_experts_per_tok)
+    if bias is not None:
+        top = jnp.take_along_axis(s, idx, axis=-1)
     gates = top / (top.sum(-1, keepdims=True) + 1e-20) \
         * cfg.routed_scaling_factor
     return idx, gates, s
@@ -395,7 +429,8 @@ def held_experts(f: jax.Array, idx: jax.Array, gates: jax.Array,
         return out.at[rows].add(y * g[:, None], mode="drop",
                                 unique_indices=True)
 
-    out = jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((T, H), F32))
+    with jax.named_scope("experts.loop"):
+        out = jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((T, H), F32))
     return out, counts
 
 
@@ -403,7 +438,7 @@ def moe_ffn(f: jax.Array, lp: Params, experts: Params, m,
             cfg: LatentMoEConfig, valid: jax.Array, dtype):
     """Expert layer ``m`` on ``f [T, H]``: this process's experts' part plus
     the shared expert -> (``[T, H]`` float32, counts ``[experts_held]``)."""
-    idx, gates, _ = route(f, lp["router"], cfg, dtype)
+    idx, gates, _ = route(f, lp["router"], cfg, dtype, lp.get("router_bias"))
     routed, counts = held_experts(f, idx, gates, valid, experts, m, cfg,
                                   dtype)
     return routed + _gated(f, lp["shared"], dtype), counts
@@ -411,20 +446,41 @@ def moe_ffn(f: jax.Array, lp: Params, experts: Params, m,
 
 # -------------------------------------------------------------------- layers
 
+def _read(x, hp: Optional[Params], cfg: LatentMoEConfig):
+    """What a sub-layer reads of the residual, and how its output goes back
+    (:func:`_write`): ``x [B, T, H]`` itself and a plain add — or, of the
+    streams ``x [n, B, T, H]`` with the sub-layer's mixing leaves ``hp``,
+    ``H_pre @ X`` and the pair (``H_res``, ``H_post``)."""
+    if hp is None:
+        return x, None
+    pre, post, res = hyper_connections.coefficients(x, hp, cfg)
+    return hyper_connections.read(x, pre), (res, post)
+
+
+def _write(x, y, mix, dtype):
+    """The residual after a sub-layer's output ``y [B, T, H]`` float32."""
+    if mix is None:
+        return x + y.astype(dtype)
+    return hyper_connections.write(x, y, *mix)
+
+
 def _layer(x, lp: Params, cfg: LatentMoEConfig, l, positions, valid, attend,
            carry, dtype, experts: Optional[Params] = None):
-    """Layer ``l`` on ``x [B, T, H]``.  ``attend(l, carry, q_nope, q_rope,
+    """Layer ``l`` on the residual ``x [B, T, H]`` (``[n, B, T, H]`` streams
+    where ``lp`` holds mixing leaves).  ``attend(l, carry, q_nope, q_rope,
     latent, ap) -> ([B, T, N * dv], carry')`` puts the latent where it has
     to go (the pool, in place; or the collected prompt latents) and attends.
     ``experts``: every expert layer's experts (``None``: a dense layer).
     -> (x', carry', counts or None)."""
-    B, T, H = x.shape
-    ap = lp["attn"]
-    a = _rms(x, ap["in_norm"], cfg.rms_norm_eps)
+    ap, hc = lp["attn"], lp.get("hc", {})
+    u, mix = _read(x, hc.get("attn"), cfg)
+    B, T, H = u.shape
+    a = _rms(u, ap["in_norm"], cfg.rms_norm_eps)
     q_nope, q_rope, latent = _project(a, ap, cfg, positions, dtype)
     o, carry = attend(l, carry, q_nope, q_rope, latent, ap)
-    x = x + _mm(o, ap["o"], dtype).astype(dtype)
-    f = _rms(x, ap["post_norm"], cfg.rms_norm_eps)
+    x = _write(x, _mm(o, ap["o"], dtype), mix, dtype)
+    u, mix = _read(x, hc.get("ffn"), cfg)
+    f = _rms(u, ap["post_norm"], cfg.rms_norm_eps)
     if experts is None:
         y, counts = _gated(f, lp["ffn"], dtype), None
     else:
@@ -432,7 +488,7 @@ def _layer(x, lp: Params, cfg: LatentMoEConfig, l, positions, valid, attend,
                             l - cfg.first_k_dense, cfg, valid.reshape(B * T),
                             dtype)
         y = y.reshape(B, T, H)
-    return x + y.astype(dtype), carry, counts
+    return _write(x, y, mix, dtype), carry, counts
 
 
 def _run_layers(params: Params, cfg: LatentMoEConfig, x, positions, valid,
@@ -474,6 +530,25 @@ def _embed(params: Params, ids: jax.Array, dtype) -> jax.Array:
     return jnp.take(params["embed"], ids, axis=0).astype(dtype)
 
 
+def _streams(x: jax.Array, cfg: LatentMoEConfig) -> jax.Array:
+    """The embedding as the layers' residual: itself, or copied into the
+    ``hc_mult`` streams ``[n, B, T, H]``."""
+    if cfg.hc_mult == 1:
+        return x
+    return jnp.broadcast_to(x[None], (cfg.hc_mult,) + x.shape)
+
+
+def _read_out(x: jax.Array, at: jax.Array, cfg: LatentMoEConfig, dtype
+              ) -> jax.Array:
+    """Position ``at[b]`` of every row of the residual -> ``[B, 1, H]``: what
+    the final norm reads (of streams, their SUM)."""
+    at = at.astype(jnp.int32)[:, None, None]
+    if cfg.hc_mult == 1:
+        return jnp.take_along_axis(x, at, axis=1)
+    x = jnp.take_along_axis(x, at[None], axis=2)
+    return jnp.sum(x.astype(F32), axis=0).astype(dtype)
+
+
 # ----------------------------------------------------------------- programs
 
 def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
@@ -488,7 +563,7 @@ def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
     B, S = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     valid = attention_mask.astype(bool)
-    x = _embed(params, input_ids, dtype)
+    x = _streams(_embed(params, input_ids, dtype), cfg)
     collected = jnp.zeros((cfg.num_layers, B, S, cfg.cache_width), dtype)
     pad = ((0, 0), (0, 0), (0, cfg.cache_width - cfg.latent_width))
 
@@ -500,8 +575,7 @@ def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
 
     x, collected, counts = _run_layers(params, cfg, x, positions, valid,
                                        attend, collected, dtype)
-    h_last = jnp.take_along_axis(
-        x, last_pos.astype(jnp.int32)[:, None, None], axis=1)
+    h_last = _read_out(x, last_pos, cfg, dtype)
     return _logits(params, head, cfg, h_last, dtype)[:, 0], counts, collected
 
 
@@ -552,10 +626,10 @@ def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
                                 dtype)
         return o, flat.reshape(pool.shape)
 
-    x = _embed(params, tokens, dtype)
+    x = _streams(_embed(params, tokens, dtype), cfg)
     x, pool, counts = _run_layers(params, cfg, x, positions, real, attend,
                                   pool, dtype)
     last = (jnp.zeros((B,), jnp.int32) if nreal is None
             else jnp.clip(nreal.astype(jnp.int32) - 1, 0, T - 1))
-    x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+    x = _read_out(x, last, cfg, dtype)
     return _logits(params, head, cfg, x, dtype)[:, 0], counts, pool

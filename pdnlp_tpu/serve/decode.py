@@ -1357,6 +1357,11 @@ class PagedDecodeEngine(InferenceEngine):
             "max_len": int(self.max_len),
             "kv_dtype": self._kv_label(),
             "cache_bytes": self.n_pages * self.page_bytes,
+            # the residual a token carries between layers (a family's
+            # ``hc_mult`` streams of it): never cached, beside token_bytes
+            "stream_bytes_a_token": int(
+                getattr(self.cfg, "hc_mult", 1) * self.cfg.hidden_size
+                * np.dtype(self.dtype).itemsize),
             "kv_pool_bytes": int(sum(p.nbytes for p in self._pools)),
             "state_pool_bytes": int(sum(x.nbytes for x in self._states)),
             "weights_bytes": int(sum(

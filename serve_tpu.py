@@ -87,7 +87,8 @@ re-prefills orphan streams on survivors with no duplicated or lost
 tokens.  ``--max_new_tokens`` bounds each stream's generation.
 ``--model`` picks the family (``models.families``; no other flag does):
 the BERT causal LM (``bert-*``: twin K/V pools), the latent-attention,
-sparse-expert decoder (``ax-k1-*``: one latent pool), the hybrid
+sparse-expert decoder (``ax-k1-*``: one latent pool; ``xing4-*``: the same
+with a four-stream residual mixed by hyper-connections), the hybrid
 (``solar-open2-*``: gated delta-rule linear attention with a per-slot
 recurrent state beside paged GQA layers, sparse experts; it shares no
 prefix and refuses ``--kv_dtype int8``, ``--serve_dtype int8``,

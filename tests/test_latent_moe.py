@@ -64,6 +64,10 @@ def sizes_of(cfg) -> dict:
         rms_norm_eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
         num_hidden_layers=cfg.num_layers,
         first_k_dense_replace=cfg.first_k_dense, vocab_size=cfg.vocab_size,
+        # the mixing's keys (read by ``xing4_reference`` alone)
+        hc_mult=cfg.hc_mult, hc_sinkhorn_iters=cfg.hc_sinkhorn_iters,
+        hc_eps=cfg.hc_eps, mhc_h_res_clamp_min=cfg.hc_res_clamp[0],
+        mhc_h_res_clamp_max=cfg.hc_res_clamp[1],
         rope_scaling=dict(
             type="yarn", factor=cfg.rope_factor,
             original_max_position_embeddings=cfg.rope_original_max,
@@ -71,7 +75,7 @@ def sizes_of(cfg) -> dict:
             mscale=cfg.rope_mscale, mscale_all_dim=cfg.rope_mscale_all_dim))
 
 
-def program_weights(seed, sizes, held=None, banned=()):
+def program_weights(seed, sizes, held=None, banned=(), ref=ref):
     """The reference's seeded weights laid into the program's trees."""
     key = ref.seed_key(seed)
     top = ref.top_weights(key, sizes, banned)
@@ -87,14 +91,14 @@ def program_weights(seed, sizes, held=None, banned=()):
     return params, {"kernel": top["head"]}
 
 
-def make_engine(tok, **kw):
+def make_engine(tok, ref=ref, **kw):
     base = dict(model=MODEL, decode_slots=4, decode_max_len=128,
                 max_seq_len=128, max_new_tokens=8, dtype="float32")
     base.update(kw)
     eng = PagedDecodeEngine(Args(**base), tokenizer=tok, mesh=None,
                             buckets=BUCKETS, page_sz=PAGE)
     sizes = sizes_of(eng.cfg)
-    params, head = program_weights(SEED, sizes)
+    params, head = program_weights(SEED, sizes, ref=ref)
     like = jax.tree_util.tree_map(lambda x: (x.shape, x.dtype),
                                   (eng.params, eng.head))
     assert like == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype),
@@ -105,11 +109,15 @@ def make_engine(tok, **kw):
 
 @pytest.fixture(scope="module")
 def served(tok):
+    return serve_three(tok)
+
+
+def serve_three(tok, ref=ref, **kw):
     """One engine, three streams one after another, every logits row the
     engine handed its batcher recorded: a cold prompt, a prompt sharing its
     first two pages (the chunk path), and the cold prompt again (a full
     prefix hit whose trailing partial page is copied on write)."""
-    eng, sizes = make_engine(tok, trace=True)
+    eng, sizes = make_engine(tok, ref=ref, trace=True, **kw)
     eng.warmup_decode()
     rows = []
     for name in ("prefill_ids", "prefill_chunk", "decode_batch"):
@@ -145,7 +153,8 @@ def served(tok):
     return out
 
 
-def check_against_reference(sizes, prompt, emitted, slot, rows, first_from):
+def check_against_reference(sizes, prompt, emitted, slot, rows, first_from,
+                            ref=ref):
     """Every logits row the stream was served against the reference's full
     forward of prompt + emitted tokens; -> positions compared."""
     seq = prompt + emitted
@@ -369,10 +378,11 @@ def test_engine_seam_is_bitwise_for_the_bert_family(tok):
     np.testing.assert_array_equal(np.asarray(eng._cache_v), np.asarray(pv))
 
 
+@pytest.mark.parametrize("model", [MODEL, "xing4-stage-tiny"])
 @pytest.mark.parametrize("what", ["kv_int8", "weights_int8",
                                   "speculative_pair", "handoff"])
-def test_refusals_are_loud_and_at_construction(tok, what):
-    base = dict(model=MODEL, decode_slots=4, decode_max_len=64,
+def test_refusals_are_loud_and_at_construction(tok, what, model):
+    base = dict(model=model, decode_slots=4, decode_max_len=64,
                 max_seq_len=64)
     if what == "kv_int8":
         with pytest.raises(ValueError, match="int8 cache"):
