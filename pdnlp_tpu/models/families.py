@@ -14,8 +14,9 @@ the GQA layers alone for the hybrid (``models/hybrid_linear.py``) — and a
 TUPLE of per-slot state arrays ``[slots, ...]`` (``state_shapes``: the
 hybrid's recurrent states and convolution tails; empty for the other two,
 whose programs an empty tuple adds no operand to).  It returns ``aux``
-beside the logits: what a launch counted (assignments to each held expert),
-or ``None``.
+beside the logits: what a launch counted (``latent_moe.no_load``: assignments
+to each held expert, and the rows the experts' products computed), or
+``None``.
 """
 from __future__ import annotations
 
@@ -77,17 +78,17 @@ def _bert_attend(params, head, cfg, tokens, pools, states, table, start,
 
 
 def _latent_prefill(params, head, cfg, ids, mask, last_pos, dtype):
-    logits, counts, latents = latent_moe.prefill(
+    logits, load, latents = latent_moe.prefill(
         params, head, cfg, ids, mask, last_pos, dtype=dtype)
-    return logits, counts, (latents,), ()
+    return logits, load, (latents,), ()
 
 
 def _latent_attend(params, head, cfg, tokens, pools, states, table, start,
                    nreal, logits_at, kv_scales, dtype):
-    logits, counts, pool = latent_moe.paged_attend(
+    logits, load, pool = latent_moe.paged_attend(
         params, head, cfg, tokens, pools[0], table, start, nreal,
         dtype=dtype)
-    return logits, counts, (pool,), states
+    return logits, load, (pool,), states
 
 
 def _hybrid_prefill(params, head, cfg, ids, mask, last_pos, dtype):

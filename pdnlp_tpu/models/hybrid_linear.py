@@ -79,7 +79,8 @@ import numpy as np
 from pdnlp_tpu.models.config import HybridLinearConfig
 from pdnlp_tpu.models.decoder import _layer_rows
 from pdnlp_tpu.models.latent_moe import (F32, _einsum, _embed, _logits, _mm,
-                                         _rms, _weight_dtype, moe_ffn)
+                                         _rms, _weight_dtype, add_load,
+                                         moe_ffn, no_load)
 from pdnlp_tpu.ops.attention import NEG_INF
 
 Params = Dict[str, Any]
@@ -504,15 +505,15 @@ def _gqa_out(o, a, mp: Params, dtype):
 # -------------------------------------------------------------------- layers
 
 def _moe(x, lp: Params, cfg: HybridLinearConfig, valid, dtype):
-    """``x += MoE(rms(x))`` -> (x', counts ``[experts_held]``)."""
+    """``x += MoE(rms(x))`` -> (x', the layer's load: ``moe_ffn``'s)."""
     B, T, H = x.shape
     f = _rms(x, lp["mixer"]["post_norm"], cfg.rms_norm_eps)
     # the layer's own experts as a stack of one: held_experts indexes
     # (layer, expert) into the leaves where they lie
     experts = {n: w[None] for n, w in lp["experts"].items()}
-    y, counts = moe_ffn(f.reshape(B * T, H), lp, experts, 0, cfg,
-                        valid.reshape(B * T), dtype)
-    return x + y.reshape(B, T, H).astype(dtype), counts
+    y, load = moe_ffn(f.reshape(B * T, H), lp, experts, 0, cfg,
+                      valid.reshape(B * T), dtype)
+    return x + y.reshape(B, T, H).astype(dtype), load
 
 
 # ----------------------------------------------------------------- programs
@@ -523,7 +524,7 @@ def prefill(params: Params, head: Params, cfg: HybridLinearConfig,
             last_pos: jax.Array,        # [B] index of the last real token
             *, dtype=jnp.bfloat16):
     """A cold prompt from position 0 -> (next-token logits ``[B, vocab]``
-    float32, counts ``[experts_held]``, the GQA layers' keys and values
+    float32, the load (``latent_moe.no_load``), the GQA layers' keys and values
     ``2 x [n_gqa, B, S, kv_width]`` for ``decoder.insert_pool``, the linear
     layers' final states then their convolution tails, a layer each)."""
     B, S = input_ids.shape
@@ -531,7 +532,7 @@ def prefill(params: Params, head: Params, cfg: HybridLinearConfig,
     valid = attention_mask.astype(bool)
     nreal = last_pos.astype(jnp.int32) + 1
     x = _embed(params, input_ids, dtype)
-    counts = jnp.zeros((cfg.experts_held,), jnp.int32)
+    load = no_load(cfg)
     ks, vs, states, tails = [], [], [], []
     for l, lp in enumerate(params["layers"]):
         mp = lp["mixer"]
@@ -547,10 +548,10 @@ def prefill(params: Params, head: Params, cfg: HybridLinearConfig,
             states.append(S_l)
             tails.append(tail)
         x, n = _moe(x + y.astype(dtype), lp, cfg, valid, dtype)
-        counts = counts + n
+        load = add_load(load, n)
     h_last = jnp.take_along_axis(
         x, last_pos.astype(jnp.int32)[:, None, None], axis=1)
-    return (_logits(params, head, cfg, h_last, dtype)[:, 0], counts,
+    return (_logits(params, head, cfg, h_last, dtype)[:, 0], load,
             (jnp.stack(ks), jnp.stack(vs)), tuple(states) + tuple(tails))
 
 
@@ -566,7 +567,7 @@ def paged_decode(params: Params, head: Params, cfg: HybridLinearConfig,
     positions ``<= start[b]`` of the pages the table names; a linear layer
     moves rows ``[0, B)`` of its state and convolution tail by one position.
     A dead row (sentinel table) writes nothing, keeps its state and takes no
-    part in the expert layer.  -> (logits ``[B, vocab]``, counts, the pools,
+    part in the expert layer.  -> (logits ``[B, vocab]``, the load, the pools,
     the states)."""
     pk, pv = pools
     Lp, P, ps, W = pk.shape
@@ -592,7 +593,7 @@ def paged_decode(params: Params, head: Params, cfg: HybridLinearConfig,
         return flat.reshape(pool.shape), got
 
     x = _embed(params, tokens, dtype)
-    counts = jnp.zeros((cfg.experts_held,), jnp.int32)
+    load = no_load(cfg)
     states = list(states)
     for l, lp in enumerate(params["layers"]):
         mp = lp["mixer"]
@@ -612,7 +613,7 @@ def paged_decode(params: Params, head: Params, cfg: HybridLinearConfig,
             states[i] = S.at[:B].set(S2)
             states[n_lin + i] = tail.at[:B].set(tail2)
         x, n = _moe(x + y.astype(dtype), lp, cfg, real, dtype)
-        counts = counts + n
-    return (_logits(params, head, cfg, x, dtype)[:, 0], counts, (pk, pv),
+        load = add_load(load, n)
+    return (_logits(params, head, cfg, x, dtype)[:, 0], load, (pk, pv),
             tuple(states))
 

@@ -47,14 +47,17 @@ read it:
 its groups and its experts per token; the layer computes ``sum over held e
 of g_e Expert_e(f)`` for the tokens routed to them, plus the shared expert.
 No token is dropped and no capacity is sized: assignments are sorted by
-expert and run as row blocks, as many as there are (a loop whose trip count
-is data), so an expert's weights are read only if a token chose it.  What
+expert, laid out contiguous and run as row tiles, as many as there are (a
+loop whose trip count is data), so an expert's weights are read only if a
+token chose it.  What
 the absent experts would add is left out — on one chip the layer runs
-without its exchange.  Every program also returns the count of assignments
-to each held expert, summed over the layers.
+without its exchange.  Every program also returns its expert LOAD, summed
+over the layers: the count of assignments to each held expert and the rows
+the experts' products computed for them (whole tiles).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -65,6 +68,7 @@ import numpy as np
 from pdnlp_tpu.models import hyper_connections
 from pdnlp_tpu.models.config import LatentMoEConfig
 from pdnlp_tpu.models.decoder import _layer_rows
+from pdnlp_tpu.ops import grouped
 from pdnlp_tpu.ops.attention import NEG_INF
 
 Params = Dict[str, Any]
@@ -72,8 +76,8 @@ F32 = jnp.float32
 
 #: query rows of one attention block on the expanded path
 Q_BLOCK = 512
-#: rows of one expert block (sorted assignments of ONE expert)
-EXPERT_BLOCK = 256
+#: the most rows of one tile of the experts' grouped products
+EXPERT_BLOCK = 128
 
 
 # ------------------------------------------------------------------- weights
@@ -377,71 +381,146 @@ def route(f: jax.Array, router: jax.Array, cfg, dtype,
     return idx, gates, s
 
 
+def expert_tile(T: int, k: int, cfg: LatentMoEConfig) -> int:
+    """Rows of one tile of the experts' grouped products, from the load the
+    shapes state: eight times one expert's expected run ``T * k /
+    n_routed_experts`` as a power of two between 16 — a bfloat16 sublane
+    tile — and :data:`EXPERT_BLOCK`: 16-64 rows in a decode step,
+    :data:`EXPERT_BLOCK` in a prompt (measured on the chip at the
+    four-stream cell's sizes, PERF.md PR 39: a decode step's products 9.07 /
+    8.22 / 7.82 ms at 16 / 32 / 64 rows, a prompt's 19.3 / 20.0 ms at 128 /
+    256 — a tile is a step of the kernel's grid, and what a step computes
+    hides behind the weights it waits for)."""
+    tile = 16
+    while tile < min(8 * T * k / cfg.n_routed_experts, EXPERT_BLOCK):
+        tile *= 2
+    return tile
+
+
+def expert_window(T: int, k: int, cfg: LatentMoEConfig) -> int:
+    """Sorted assignments laid out at a time, in whole tiles: twice what
+    this process's share of the experts expects of the ``T * k`` (all of
+    them where it holds every expert).  More than that — every token
+    choosing held experts — takes further passes, never a drop."""
+    tile = expert_tile(T, k, cfg)
+    rows = min(T * k, 2 * T * k * cfg.experts_held // cfg.n_routed_experts)
+    return max(-(-rows // tile), 1) * tile
+
+
+def _window_runs(first, counts, lo, rows: int):
+    """The held experts' runs ``[first, first + counts)`` of the sorted
+    order, each cut to the window ``[lo, lo + rows)`` -> its rows there."""
+    return (jnp.clip(first + counts - lo, 0, rows)
+            - jnp.clip(first - lo, 0, rows))
+
+
+def expert_rows(counts: jax.Array, T: int, k: int, cfg: LatentMoEConfig
+                ) -> jax.Array:
+    """Rows the grouped products of :func:`held_experts` compute for
+    ``counts [experts_held]`` assignments: the row tiles that hold any of
+    them, window by window (a tile that two runs share is computed once for
+    each)."""
+    tile, rows = expert_tile(T, k, cfg), expert_window(T, k, cfg)
+    first = jnp.cumsum(counts) - counts
+    lo = jnp.arange(0, T * k, rows, dtype=jnp.int32)[:, None]
+    return tile * jnp.sum(grouped.tiles_held(
+        _window_runs(first[None, :], counts[None, :], lo, rows), tile))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "dtype"))
 def held_experts(f: jax.Array, idx: jax.Array, gates: jax.Array,
                  valid: jax.Array, experts: Params, m, cfg: LatentMoEConfig,
                  dtype):
     """``sum over held e of g_e Expert_e(f)`` -> (``[T, H]`` float32, counts
     ``[experts_held]`` int32).  Dropless: the ``T * k`` assignments are
-    sorted by expert; each held expert's run is cut into blocks of
-    :data:`EXPERT_BLOCK` rows and a loop runs the blocks that exist (its trip
-    count is data), each one gather of its rows, the expert's gated
-    feed-forward, one scatter-add.  Assignments to absent experts and of
-    rows that are not ``valid`` (padding, dead slots) sort past the end.
+    sorted by expert and their rows of ``f`` LAID OUT in that order by one
+    gather (a window of :func:`expert_window` rows at a time: one pass
+    unless more tokens chose held experts than twice the share expects);
+    the experts' gated feed-forward runs over the layout as two grouped
+    products (``ops/grouped.py``: group sizes are data, row tiles of
+    :func:`expert_tile`, an expert's matrices read once and only if a token
+    chose it); a gather by the inverse permutation brings the results back
+    to token order, a slot of the ``k`` at a time, where a token's gated
+    parts are summed in float32.  Assignments to absent experts and of rows
+    that are not ``valid`` (padding, dead slots) sort past the end, are
+    never computed and add exactly 0.
     ``experts``: EVERY expert layer's ``gate`` / ``up`` ``[M, Eh, H, F]`` and
-    ``down`` ``[M, Eh, F, H]``, of which this is layer ``m``: a block reads
-    its expert's matrices where they lie (a layer's slab cut out first — as
-    a scan over the layers would — is a copy of all twelve, read from a
-    described-v5e compile: three temporaries of 352 MB a layer)."""
+    ``down`` ``[M, Eh, F, H]``, of which this is layer ``m``: the products
+    read an expert's matrices where they lie, the stack seen as ``M * Eh``
+    groups (a layer's slab cut out first — as a scan over the layers would —
+    is a copy of all twelve, read from a described-v5e compile: three
+    temporaries of 352 MB a layer).  Jitted, so that a trunk that calls it a
+    layer at a time lowers it once a program."""
     T, H = f.shape
     k, Eh = idx.shape[1], cfg.experts_held
-    blk = min(EXPERT_BLOCK, T)
+    tile, rows = expert_tile(T, k, cfg), expert_window(T, k, cfg)
     local = idx - cfg.expert_first
-    local = jnp.where((local >= 0) & (local < Eh) & valid[:, None], local, Eh)
-    flat_e = local.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    tok_sorted = jnp.concatenate(
-        [(order // k).astype(jnp.int32), jnp.full((blk,), T, jnp.int32)])
-    gate_sorted = jnp.concatenate(
-        [gates.reshape(-1)[order], jnp.zeros((blk,), F32)])
+    held = (local >= 0) & (local < Eh) & valid[:, None]
+    flat_e = jnp.where(held, local, Eh).reshape(-1)
     counts = jnp.sum(flat_e[:, None] == jnp.arange(Eh)[None, :], axis=0,
                      dtype=jnp.int32)                                # [Eh]
     first = jnp.cumsum(counts) - counts            # an expert's first row
-    blocks = -(-counts // blk)                     # ... and its blocks
-    block_end = jnp.cumsum(blocks)
-    n_blocks = block_end[-1]
+    order = jnp.argsort(flat_e, stable=True)
+    # assignment (t, j) lies at row at[t, j] of the sorted order
+    at = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
+    # slack behind the T * k rows: a window's slice is never clamped back
+    tok_sorted = jnp.concatenate(
+        [(order // k).astype(jnp.int32), jnp.zeros((rows,), jnp.int32)])
+    stack = {n: w.reshape((-1,) + w.shape[2:]) for n, w in experts.items()}
 
-    def body(b, out):
-        e = jnp.sum(b >= block_end).astype(jnp.int32)       # block b's expert
-        j = b - (block_end[e] - blocks[e])                  # ... its j-th
-        row0 = first[e] + j * blk
-        live = (jnp.arange(blk) + j * blk) < counts[e]
-        # a dead row of the block: an index of its own past the end, which
-        # the gather fills with zeros and the scatter drops
-        rows = jnp.where(live, jax.lax.dynamic_slice(tok_sorted, (row0,),
-                                                     (blk,)),
-                         T + jnp.arange(blk))
-        g = jnp.where(live, jax.lax.dynamic_slice(gate_sorted, (row0,),
-                                                  (blk,)), 0.0)
-        x = jnp.take(f, rows, axis=0, mode="fill", fill_value=0)
-        y = _gated(x, {n: jax.lax.dynamic_slice(
-            w, (m, e, 0, 0), (1, 1) + w.shape[2:])[0, 0]
-            for n, w in experts.items()}, dtype)
-        return out.at[rows].add(y * g[:, None], mode="drop",
-                                unique_indices=True)
+    def window(p, out):
+        lo = p * rows
+        pairs, n_pairs = grouped.plan(
+            _window_runs(first, counts, lo, rows), tile, rows // tile)
+        with jax.named_scope("experts.lay"):
+            x = jnp.take(f, jax.lax.dynamic_slice(tok_sorted, (lo,), (rows,)),
+                         axis=0).astype(dtype)
+        with jax.named_scope("experts.loop"):
+            a = grouped.grouped(
+                x, (stack["gate"], stack["up"]), pairs, n_pairs, m * Eh,
+                tile=tile, combine=lambda g, u: jax.nn.silu(g) * u,
+                out_dtype=dtype)
+            y = grouped.grouped(a, (stack["down"],), pairs, n_pairs, m * Eh,
+                                tile=tile)
+        with jax.named_scope("experts.unsort"):
+            # rows past the last run's were never written: selected, not
+            # multiplied, away
+            here = held & (at >= lo) & (at < lo + rows)
+            back = jnp.clip(at - lo, 0, rows - 1)
+            for j in range(k):
+                out = out + jnp.where(
+                    here[:, j, None],
+                    jnp.take(y, back[:, j], axis=0) * gates[:, j, None], 0.0)
+            return out
 
-    with jax.named_scope("experts.loop"):
-        out = jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((T, H), F32))
+    out = jax.lax.fori_loop(0, -(-jnp.sum(counts) // rows), window,
+                            jnp.zeros((T, H), F32))
     return out, counts
 
 
 def moe_ffn(f: jax.Array, lp: Params, experts: Params, m,
             cfg: LatentMoEConfig, valid: jax.Array, dtype):
     """Expert layer ``m`` on ``f [T, H]``: this process's experts' part plus
-    the shared expert -> (``[T, H]`` float32, counts ``[experts_held]``)."""
+    the shared expert -> (``[T, H]`` float32, the layer's load: counts
+    ``[experts_held]`` and the rows computed for them, :func:`no_load`)."""
     idx, gates, _ = route(f, lp["router"], cfg, dtype, lp.get("router_bias"))
     routed, counts = held_experts(f, idx, gates, valid, experts, m, cfg,
                                   dtype)
-    return routed + _gated(f, lp["shared"], dtype), counts
+    rows = expert_rows(counts, f.shape[0], idx.shape[1], cfg)
+    return routed + _gated(f, lp["shared"], dtype), (counts, rows)
+
+
+def no_load(cfg: LatentMoEConfig):
+    """The zero of what a program counts of its expert layers: assignments
+    to each held expert ``[experts_held]`` and the rows the experts'
+    products computed (a scalar), both int32, summed over the layers."""
+    return (jnp.zeros((cfg.experts_held,), jnp.int32),
+            jnp.zeros((), jnp.int32))
+
+
+def add_load(a, b):
+    """The sum of two loads (:func:`no_load`'s pairs)."""
+    return a[0] + b[0], a[1] + b[1]
 
 
 # -------------------------------------------------------------------- layers
@@ -471,7 +550,7 @@ def _layer(x, lp: Params, cfg: LatentMoEConfig, l, positions, valid, attend,
     latent, ap) -> ([B, T, N * dv], carry')`` puts the latent where it has
     to go (the pool, in place; or the collected prompt latents) and attends.
     ``experts``: every expert layer's experts (``None``: a dense layer).
-    -> (x', carry', counts or None)."""
+    -> (x', carry', the layer's load or None)."""
     ap, hc = lp["attn"], lp.get("hc", {})
     u, mix = _read(x, hc.get("attn"), cfg)
     B, T, H = u.shape
@@ -482,13 +561,13 @@ def _layer(x, lp: Params, cfg: LatentMoEConfig, l, positions, valid, attend,
     u, mix = _read(x, hc.get("ffn"), cfg)
     f = _rms(u, ap["post_norm"], cfg.rms_norm_eps)
     if experts is None:
-        y, counts = _gated(f, lp["ffn"], dtype), None
+        y, load = _gated(f, lp["ffn"], dtype), None
     else:
-        y, counts = moe_ffn(f.reshape(B * T, H), lp, experts,
-                            l - cfg.first_k_dense, cfg, valid.reshape(B * T),
-                            dtype)
+        y, load = moe_ffn(f.reshape(B * T, H), lp, experts,
+                          l - cfg.first_k_dense, cfg, valid.reshape(B * T),
+                          dtype)
         y = y.reshape(B, T, H)
-    return _write(x, y, mix, dtype), carry, counts
+    return _write(x, y, mix, dtype), carry, load
 
 
 def _run_layers(params: Params, cfg: LatentMoEConfig, x, positions, valid,
@@ -503,21 +582,20 @@ def _run_layers(params: Params, cfg: LatentMoEConfig, x, positions, valid,
                              dtype)
 
     def step(c, scanned):
-        x, carry, counts = c
+        x, carry, load = c
         lp, l = scanned
         x, carry, n = _layer(x, lp, cfg, l, positions, valid, attend, carry,
                              dtype, experts)
-        return (x, carry, counts + n), None
+        return (x, carry, add_load(load, n)), None
 
     # the experts stay OUT of the scanned inputs: a block indexes (layer,
     # expert) into the whole stack (held_experts)
     moe = dict(params["moe"])
     experts = moe.pop("experts")
     li = jnp.arange(K, cfg.num_layers, dtype=jnp.int32)
-    (x, carry, counts), _ = jax.lax.scan(
-        step, (x, carry, jnp.zeros((cfg.experts_held,), jnp.int32)),
-        (moe, li))
-    return x, carry, counts
+    (x, carry, load), _ = jax.lax.scan(step, (x, carry, no_load(cfg)),
+                                       (moe, li))
+    return x, carry, load
 
 
 def _logits(params: Params, head: Params, cfg: LatentMoEConfig, x, dtype):
@@ -557,7 +635,7 @@ def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
             last_pos: jax.Array,        # [B] index of the last real token
             *, dtype=jnp.bfloat16):
     """A cold prompt: the expanded path from position 0 -> (next-token
-    logits ``[B, vocab]`` float32, counts ``[experts_held]``, the latents
+    logits ``[B, vocab]`` float32, the load (:func:`no_load`), the latents
     ``[L, B, S, cache_width]`` for
     :func:`~pdnlp_tpu.models.decoder.insert_pool`)."""
     B, S = input_ids.shape
@@ -573,10 +651,10 @@ def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
         return o, jax.lax.dynamic_update_index_in_dim(
             collected, jnp.pad(latent, pad), l, axis=0)
 
-    x, collected, counts = _run_layers(params, cfg, x, positions, valid,
-                                       attend, collected, dtype)
+    x, collected, load = _run_layers(params, cfg, x, positions, valid,
+                                     attend, collected, dtype)
     h_last = _read_out(x, last_pos, cfg, dtype)
-    return _logits(params, head, cfg, h_last, dtype)[:, 0], counts, collected
+    return _logits(params, head, cfg, h_last, dtype)[:, 0], load, collected
 
 
 def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
@@ -593,7 +671,7 @@ def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
     The contract is ``decoder.paged_attend_layers``'s, for one pool: padded
     window slots, dead rows (sentinel tables) and positions past the table
     write nothing and take no part in the expert layer.  -> (last real
-    token's logits ``[B, vocab]``, counts, the pool).  ``absorb``: ``None``
+    token's logits ``[B, vocab]``, the load, the pool).  ``absorb``: ``None``
     = by the window (T == 1); the tests ask for either path."""
     L, P, ps, W = pool.shape
     B, T = tokens.shape
@@ -627,9 +705,9 @@ def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
         return o, flat.reshape(pool.shape)
 
     x = _streams(_embed(params, tokens, dtype), cfg)
-    x, pool, counts = _run_layers(params, cfg, x, positions, real, attend,
-                                  pool, dtype)
+    x, pool, load = _run_layers(params, cfg, x, positions, real, attend,
+                                pool, dtype)
     last = (jnp.zeros((B,), jnp.int32) if nreal is None
             else jnp.clip(nreal.astype(jnp.int32) - 1, 0, T - 1))
     x = _read_out(x, last, cfg, dtype)
-    return _logits(params, head, cfg, x, dtype)[:, 0], counts, pool
+    return _logits(params, head, cfg, x, dtype)[:, 0], load, pool

@@ -566,10 +566,12 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     extent (``rows`` of ``decode.dispatch``: the engine's row rung, chosen
     by the highest attached slot), keyed by the extent.
     ``expert_load`` (a family with sparse experts; from ``decode.fetch``'s
-    ``expert_assignments`` / ``expert_tokens_max`` / ``experts_idle``):
-    assignments to the held experts a decode step, the busiest held expert's
-    tokens a step, idle held experts a step, and ``cache_bytes_per_token``
-    as ``decode.dispatch`` states it.
+    ``expert_assignments`` / ``expert_rows_computed`` / ``expert_tokens_max``
+    / ``experts_idle``): assignments to the held experts a decode step, the
+    busiest held expert's tokens a step, idle held experts a step,
+    ``expert_fill`` = assignments over the rows the experts' products
+    computed for them (whole tiles: how far the row tile follows the load),
+    and ``cache_bytes_per_token`` as ``decode.dispatch`` states it.
     ``decode_fetch_bytes_per_step`` = the ``bytes`` of the ``decode.fetch``
     leaves over their count: what a decode launch hands the host (the
     chosen ids, 4 bytes a slot, plus a family's expert counts).
@@ -581,7 +583,8 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     Empty when the stream holds no leaf."""
     per: Dict[object, Dict[str, List[float]]] = {}
     kv: Dict[object, Dict[str, List[int]]] = {}    # rep -> call -> [read, live]
-    experts: Dict[object, List[int]] = {}   # rep -> [launches, sum, max, idle]
+    # rep -> [launches, assignments, busiest, idle, rows computed]
+    experts: Dict[object, List[int]] = {}
     fetched: Dict[object, List[int]] = {}   # rep -> [decode fetches, bytes]
     rungs: Dict[object, Dict[int, int]] = {}   # rep -> rows launched -> steps
     token_bytes: Dict[object, int] = {}
@@ -621,11 +624,12 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             acc[0] += 1
             acc[1] += int(attrs["bytes"])
         if name == "decode.fetch" and "expert_assignments" in attrs:
-            acc = experts.setdefault(rep, [0, 0, 0, 0])
+            acc = experts.setdefault(rep, [0, 0, 0, 0, 0])
             acc[0] += 1
             acc[1] += int(attrs["expert_assignments"])
             acc[2] += int(attrs.get("expert_tokens_max", 0))
             acc[3] += int(attrs.get("experts_idle", 0))
+            acc[4] += int(attrs.get("expert_rows_computed", 0))
         e = edges.setdefault(rep, [t0, t0 + dur])
         e[0], e[1] = min(e[0], t0), max(e[1], t0 + dur)
     out: Dict[str, Dict] = {}
@@ -641,11 +645,14 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             if live:
                 amplification[key] = round(read / live, 4)
         if rep in experts:
-            n, total, most, idle = experts[rep]
+            n, total, most, idle, rows = experts[rep]
             amplification["expert_load"] = {
                 "assignments_per_step": round(total / n, 3),
                 "busiest_expert_tokens_per_step": round(most / n, 3),
                 "idle_experts_per_step": round(idle / n, 3)}
+            if rows:
+                amplification["expert_load"]["expert_fill"] = round(
+                    total / rows, 4)
         if rep in rungs:
             amplification["decode_row_rungs"] = {
                 str(rows): round(n / steps, 4)
@@ -722,7 +729,9 @@ def format_decode_table(by_replica: Dict) -> str:
                 f"{e['assignments_per_step']:.1f} assignments to held "
                 f"experts (all layers), the busiest expert's "
                 f"{e['busiest_expert_tokens_per_step']:.1f}, "
-                f"{e['idle_experts_per_step']:.2f} held experts idle")
+                f"{e['idle_experts_per_step']:.2f} held experts idle"
+                + (f"; {e['expert_fill']:.1%} of the rows their products "
+                   "computed" if "expert_fill" in e else ""))
         if "cache_bytes_per_token" in b:
             lines.append(f"  cache bytes per token: "
                          f"{b['cache_bytes_per_token']}")

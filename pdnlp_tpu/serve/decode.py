@@ -717,8 +717,9 @@ class PagedDecodeEngine(InferenceEngine):
         ``<p>.fetch``, the ``device_get`` to numpy, with the ``bytes`` it
         moved.  Untraced, the fetch is the one barrier it always was.
         ``aux`` (a family with experts: this launch's assignments to each
-        held expert, summed over the layers) is fetched with it and lands
-        on the fetch leaf."""
+        held expert and the rows the experts' products computed for them,
+        both summed over the layers) is fetched with it and lands on the
+        fetch leaf."""
         tr = self.tracer
         sp = tr.leaf(wait_leaf, self.span_attrs)
         if sp:
@@ -729,12 +730,13 @@ class PagedDecodeEngine(InferenceEngine):
             if sp:
                 nbytes = int(out.nbytes)
                 if aux is not None:
-                    load = np.asarray(jax.device_get(aux))
-                    nbytes += int(load.nbytes)
+                    load, rows = (np.asarray(a) for a in jax.device_get(aux))
+                    nbytes += int(load.nbytes + rows.nbytes)
                     load = load.astype(np.int64)
                     self.expert_load = load if self.expert_load is None \
                         else self.expert_load + load
                     sp.set(expert_assignments=int(load.sum()),
+                           expert_rows_computed=int(rows),
                            expert_tokens_max=int(load.max()),
                            experts_idle=int((load == 0).sum()))
                 sp.set(bytes=nbytes)

@@ -214,3 +214,62 @@ def test_paged_programs_leave_the_pool_where_it_lies(program, one_chip):
     entry = compiled.as_text().split("ENTRY", 1)[1]
     pools = re.findall(r"bf16\[2,16384,16,768\]\{([0-9,]+):", entry)
     assert pools and set(pools) == {"3,2,1,0"}
+
+
+# ------------------------------------- the four-stream family's decode step
+
+def test_the_four_stream_decode_step_keeps_its_temporaries(one_chip,
+                                                           monkeypatch):
+    """``xing4-29b-ep1-stage``'s ``_pdecode_fn`` at the cell's top rung (128
+    rows, 256 pages a row, the pool donated): the experts' grouped products
+    (``ops/grouped.py``) compile as Mosaic kernels — two, inside the scan
+    over the layers: gate with up, then down —, read the experts' stack
+    where it lies (no layer's slab is cut out: a
+    copy of 1.4 GB a layer), and the program's temporaries stay what the
+    parent's were (0.757 GiB in this compile, the gathered latents; PERF.md,
+    PR 39): ``sat_hbm_peak_pct`` reads 96 % and the runtime's limit is
+    near."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from pdnlp_tpu.models import families
+    from pdnlp_tpu.ops import grouped
+    from pdnlp_tpu.serve.decode import greedy_ids
+
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    cfg = get_config("xing4-29b-ep1-stage")
+    family = families.of(cfg)
+    key, bf, i32 = jax.random.key(0), jnp.bfloat16, jnp.int32
+    rows, pages, ps = 128, 4096 // 16, 16
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, head = jax.tree_util.tree_map(
+        lambda x: S(x.shape, x.dtype), jax.eval_shape(
+            lambda: (family.init_params(key, cfg),
+                     family.init_head(key, cfg))))
+    pool = S((cfg.num_layers, rows * pages, ps, cfg.cache_width), bf)
+
+    def _pdecode_fn(params, head, pools, tokens, table, pos):
+        logits, aux, pools, _ = family.attend(
+            params, head, cfg, tokens, pools, (), table, pos, None, "last",
+            None, bf)
+        return greedy_ids(logits), aux, pools
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(_pdecode_fn, donate_argnums=(2,)).lower(
+            params, head, (pool,), S((rows, 1), i32), S((rows, pages), i32),
+            S((rows,), i32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.78 * 2 ** 30, m.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # every held expert's matrices enter the kernels as the stack's own
+    # buffer: no bf16[64, 3584, 1024] (one layer's slab) is ever written
+    assert not re.search(r"bf16\[64,(3584,1024|1024,3584)\]", text)

@@ -285,7 +285,9 @@ def test_the_one_stream_presets_programs_take_and_give_what_they_did(tok,
                                                                      program):
     """``ax-k1-share-tiny``: no mixing leaf, no bias leaf; ``_prefill_fn``
     and ``_pdecode_fn`` take the operands and give the outputs they took and
-    gave, and nothing in them carries a mixing's name."""
+    gave (since PR 39 with the rows the experts' products computed, a scalar
+    beside the held experts' counts), and nothing in them carries a mixing's
+    name."""
     eng = PagedDecodeEngine(Args(model="ax-k1-share-tiny", decode_slots=4,
                                  decode_max_len=64, max_seq_len=64,
                                  dtype="float32"),
@@ -304,13 +306,13 @@ def test_the_one_stream_presets_programs_take_and_give_what_they_did(tok,
         args = (i32(rows, 16), i32(rows, 16), i32(rows))
         jaxpr = jax.make_jaxpr(eng._jit_prefill)(eng.params, eng.head, *args)
         outs = [((rows, V), jnp.float32), ((rows,), jnp.int32),
-                ((Eh,), jnp.int32),
+                ((Eh,), jnp.int32), ((), jnp.int32),
                 ((cfg.num_layers, rows, 16, cfg.cache_width), jnp.float32)]
     else:
         args = ((pool,), i32(4, 1), i32(4, 2), i32(4), ())
         jaxpr = jax.make_jaxpr(eng._jit_pdecode)(eng.params, eng.head, *args)
         outs = [((4, V), jnp.float32), ((4,), jnp.int32), ((Eh,), jnp.int32),
-                (pool.shape, pool.dtype)]
+                ((), jnp.int32), (pool.shape, pool.dtype)]
     want_in = [(w.shape, w.dtype) for w in weights] + [
         (a.shape, a.dtype) for a in jax.tree_util.tree_leaves(args)]
     assert [(a.shape, a.dtype) for a in jaxpr.in_avals] == want_in

@@ -220,9 +220,20 @@ def test_the_leaves_carry_expert_load_and_cache_bytes(served):
         assert {"expert_assignments", "expert_tokens_max",
                 "experts_idle"} <= set(a)
     # what a served launch hands the host: the chosen id of each of the 4
-    # slots and the held experts' counts, never the [slots, vocab] logits
+    # slots, the held experts' counts and the rows computed for them, never
+    # the [slots, vocab] logits
     held = served["sizes"]["n_routed_experts"]
-    assert {a["bytes"] for a in fetch} == {4 * 4 + 4 * held}
+    assert {a["bytes"] for a in fetch} == {4 * 4 + 4 * held + 4}
+    # the experts' products run whole tiles: never fewer rows than
+    # assignments, and a launch that assigned nothing computed nothing
+    launches = fetch + [r["attrs"] for r in recs
+                        if r["name"] == "prefill.fetch"]
+    assert any(r["name"] == "prefill.fetch" for r in recs)
+    for a in launches:
+        assert a["expert_rows_computed"] >= a["expert_assignments"]
+        assert (a["expert_rows_computed"] == 0) == (
+            a["expert_assignments"] == 0)
+        assert a["expert_rows_computed"] % 16 == 0
     tb = served["kv"]["pages"]["page_bytes"] // PAGE
     assert all(a["cache_bytes_per_token"] == tb for a in steps)
     assert all(a["kv_positions_read"] >= a["kv_positions_live"] > 0
@@ -236,9 +247,14 @@ def test_the_leaves_carry_expert_load_and_cache_bytes(served):
     assert served["kv"]["weights_bytes"] > 0
     table = decode_host_phases(recs)["0"]
     assert table["expert_load"]["assignments_per_step"] >= 0
+    assert 0 < table["expert_load"]["expert_fill"] <= 1
+    assert table["expert_load"]["expert_fill"] == pytest.approx(
+        sum(a["expert_assignments"] for a in fetch)
+        / sum(a["expert_rows_computed"] for a in fetch), abs=1e-4)
     assert table["cache_bytes_per_token"] == tb
     text = format_decode_table({"0": table})
     assert "expert load per decode step" in text
+    assert "of the rows their products computed" in text
     assert "KV read amplification" in text
 
 
@@ -306,10 +322,11 @@ def test_shares_add_up_to_the_uncut_layer(model):
 
 def test_no_assignment_dropped_when_one_expert_gets_every_token(model):
     cfg, sizes, _, _ = model
-    T = 2 * lm.EXPERT_BLOCK + 37          # three blocks of ONE expert
+    k = cfg.num_experts_per_tok
+    T = 2 * lm.EXPERT_BLOCK + 37          # three tiles of ONE expert
+    assert lm.expert_tile(T, k, cfg) == lm.EXPERT_BLOCK
     w = ref.layer_weights(ref.seed_key(SEED), sizes, 1)
     f = jax.random.normal(jax.random.key(4), (T, cfg.hidden_size))
-    k = cfg.num_experts_per_tok
     idx = jnp.tile(jnp.asarray([[2] + [cfg.experts_held + i
                                       for i in range(k - 1)]]), (T, 1))
     gates = jnp.full((T, k), 0.5)
@@ -328,6 +345,268 @@ def test_no_assignment_dropped_when_one_expert_gets_every_token(model):
                                   stacked(w["experts"]), 0, cfg, jnp.float32)
     assert counts.tolist() == [0, 0, 5, 0]
     assert not np.asarray(out[5:]).any()
+
+
+def per_expert_sum(f, idx, gates, valid, experts, cfg):
+    """The float32 reference's sum, an expert at a time over every row:
+    ``sum over held e of [e chosen and the row valid] g_e Expert_e(f)``."""
+    total = jnp.zeros(f.shape, jnp.float32)
+    for e in range(cfg.experts_held):
+        one = jax.tree_util.tree_map(lambda x: x[e].astype(jnp.float32),
+                                     experts)
+        g = jnp.sum(jnp.where((idx == cfg.expert_first + e)
+                              & valid[:, None], gates, 0.0), axis=1)
+        total = total + g[:, None] * ref._gated(f, one, "f32")
+    return total
+
+
+def rows_by_hand(counts, T, k, cfg):
+    """The row tiles that hold any assignment, window by window, counted in
+    a loop: a tile two runs share counts once for each."""
+    tile, rows = lm.expert_tile(T, k, cfg), lm.expert_window(T, k, cfg)
+    first, n = np.cumsum(counts) - counts, 0
+    for lo in range(0, int(np.sum(counts)), rows):
+        at = 0                                # the run's first row in here
+        for a, c in zip(first, counts):
+            part = max(min(a + c, lo + rows) - max(a, lo), 0)
+            if part:
+                n += (at + part - 1) // tile - at // tile + 1
+            at += part
+    return n * tile
+
+
+def wide_router(cfg, experts=64):
+    """The tiny share under a router wide enough that a decode-sized call
+    runs tiles smaller than itself (a tile follows ``T * k / experts``)."""
+    return cfg.replace(n_routed_experts=experts)
+
+
+@pytest.mark.parametrize("T,tile,runs", [
+    (16, 16, (0, 16, 1, 16)),       # idle, a tile = every row, one row
+    (128, 64, (0, 64, 65, 128)),    # idle, a tile, a tile + 1, every row
+])
+def test_a_decode_sized_call_at_the_edges_of_a_tile(model, T, tile, runs):
+    cfg, sizes, _, _ = model
+    cfg = wide_router(cfg)
+    k, Eh = cfg.num_experts_per_tok, cfg.experts_held
+    assert (k, Eh) == (3, 4) and lm.expert_tile(T, k, cfg) == tile
+    w = ref.layer_weights(ref.seed_key(SEED), sizes, 1)
+    f = jax.random.normal(jax.random.key(6), (T, cfg.hidden_size))
+    # expert 3 takes every row; experts 1 and 2 the first rows of their
+    # runs' lengths; what is left of a token's k goes to absent experts
+    idx = np.empty((T, k), np.int32)
+    idx[:, 0] = 3
+    idx[:, 1] = np.where(np.arange(T) < runs[1], 1, Eh + 1)
+    idx[:, 2] = np.where(np.arange(T) < runs[2], 2, Eh + 2)
+    gates = jax.random.uniform(jax.random.key(7), (T, k), minval=0.2)
+    valid = jnp.ones((T,), bool)
+    out, counts = lm.held_experts(f, jnp.asarray(idx), gates, valid,
+                                  stacked(w["experts"]), 0, cfg,
+                                  jnp.float32)
+    assert counts.tolist() == list(runs)
+    want = per_expert_sum(f, jnp.asarray(idx), gates, valid, w["experts"],
+                          cfg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-3)
+    assert int(lm.expert_rows(counts, T, k, cfg)) == rows_by_hand(
+        runs, T, k, cfg) >= sum(runs)
+
+
+@pytest.mark.parametrize("T", [16, 128, 300])
+def test_absent_experts_and_rows_not_valid_add_exactly_zero(model, T):
+    cfg, sizes, _, _ = model
+    cfg = wide_router(cfg, 16)
+    k, Eh = cfg.num_experts_per_tok, cfg.experts_held
+    w = ref.layer_weights(ref.seed_key(SEED), sizes, 1)
+    rng = np.random.default_rng(T)
+    f = jax.random.normal(jax.random.key(8), (T, cfg.hidden_size))
+    idx = np.stack([rng.permutation(16)[:k] for _ in range(T)]
+                   ).astype(np.int32)
+    idx[1] = [Eh, Eh + 1, Eh + 2]            # a row that chose none held
+    gates = jax.random.uniform(jax.random.key(9), (T, k), minval=0.2)
+    valid = np.ones((T,), bool)
+    valid[[0, T // 2, T - 1]] = False        # padding, a dead slot
+    out, counts = lm.held_experts(f, jnp.asarray(idx), gates,
+                                  jnp.asarray(valid), stacked(w["experts"]),
+                                  0, cfg, jnp.float32)
+    mine = (idx < Eh) & valid[:, None]
+    assert counts.tolist() == [int((mine & (idx == e)).sum())
+                               for e in range(Eh)]
+    silent = ~mine.any(axis=1)
+    assert silent[[0, 1, T // 2, T - 1]].all()
+    assert not np.asarray(out)[silent].any()
+    want = per_expert_sum(f, jnp.asarray(idx), gates, jnp.asarray(valid),
+                          w["experts"], cfg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-3)
+
+
+def test_more_rows_than_a_window_take_further_passes_not_a_drop(model):
+    """A share's buffers hold twice what it expects of the ``T * k``
+    assignments (in whole tiles); every token choosing its one held expert
+    is more."""
+    cfg, sizes, _, _ = model
+    cfg = wide_router(cfg, 16).replace(experts_held=1, expert_first=2)
+    k, T = cfg.num_experts_per_tok, 700
+    tile, rows = lm.expert_tile(T, k, cfg), lm.expert_window(T, k, cfg)
+    assert tile < rows < T and rows % tile == 0
+    w = ref.layer_weights(ref.seed_key(SEED), sizes, 1)
+    one = jax.tree_util.tree_map(lambda x: x[2:3], w["experts"])
+    f = jax.random.normal(jax.random.key(10), (T, cfg.hidden_size))
+    idx = jnp.tile(jnp.asarray([[2, 0, 1]]), (T, 1))
+    gates = jax.random.uniform(jax.random.key(11), (T, k), minval=0.2)
+    out, counts = lm.held_experts(f, idx, gates, jnp.ones((T,), bool),
+                                  stacked(one), 0, cfg, jnp.float32)
+    assert counts.tolist() == [T]
+    want = gates[:, :1] * ref._gated(
+        f, jax.tree_util.tree_map(lambda x: x[0].astype(jnp.float32), one),
+        "f32")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-3)
+    # two windows: the run is cut at the first one's end
+    assert int(lm.expert_rows(counts, T, k, cfg)) == rows_by_hand(
+        [T], T, k, cfg) == rows + -(-(T - rows) // tile) * tile
+
+
+@pytest.mark.parametrize("sizes", [
+    (0, 0, 0, 0),            # nothing to do: no pair, nothing written
+    (5, 0, 11, 16),          # an empty group, a tile shared by two groups
+    (16, 16, 0, 0),          # groups that end on the tiles' edges
+    (0, 0, 0, 45),           # one group over three tiles, the last partial
+    (1, 1, 1, 1),            # four groups in one tile
+])
+def test_grouped_products_follow_their_plan(sizes):
+    """``ops/grouped.py`` alone: every group's rows times its own matrix of
+    a stack that holds other groups before it (``base``), rows past the last
+    group's left unwritten."""
+    from pdnlp_tpu.ops import grouped
+
+    tile, n_tiles, K, N, base = 16, 3, 32, 256, 2
+    rng = np.random.default_rng(sum(sizes))
+    x = jnp.asarray(rng.normal(size=(tile * n_tiles, K)), jnp.float32)
+    ws = [jnp.asarray(rng.normal(size=(base + 4, K, N)), jnp.bfloat16)
+          for _ in range(2)]
+    pairs, n_pairs = grouped.plan(jnp.asarray(sizes, jnp.int32), tile,
+                                  n_tiles)
+    end = np.cumsum(sizes)
+    start = end - np.asarray(sizes)
+    held = [(e - 1) // tile - s // tile + 1 if n else 0
+            for s, e, n in zip(start, end, sizes)]
+    assert int(n_pairs) == sum(held)
+    assert grouped.tiles_held(jnp.asarray(sizes), tile).tolist() == held
+    g, t = (np.asarray(a)[:int(n_pairs)] for a in pairs[:2])
+    assert g.tolist() == [i for i, h in enumerate(held) for _ in range(h)]
+    assert t.tolist() == [s // tile + j for s, h in zip(start, held)
+                          for j in range(h)]
+    got = grouped.grouped(x, ws, pairs, n_pairs, base, tile=tile,
+                          combine=lambda a, b: a * b)
+    for i, (s, e) in enumerate(zip(start, end)):
+        want = (x[s:e] @ ws[0][base + i].astype(jnp.float32)) \
+            * (x[s:e] @ ws[1][base + i].astype(jnp.float32))
+        np.testing.assert_allclose(np.asarray(got[s:e]), np.asarray(want),
+                                   rtol=2e-5, atol=2e-4)
+
+
+def _primitives(jaxpr, out=None, depth=0):
+    """(depth of enclosing loops, primitive, operand shapes) of every
+    equation, sub-jaxprs walked."""
+    out = [] if out is None else out
+    for e in jaxpr.eqns:
+        out.append((depth, e.primitive.name,
+                    [getattr(v.aval, "shape", None) for v in e.invars]))
+        inner = depth + (e.primitive.name == "while")
+        for v in e.params.values():
+            for x in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(x, "jaxpr", x)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, out, inner)
+    return out
+
+
+def test_one_gather_in_one_pass_out_and_grouped_products_between(model):
+    cfg, sizes, _, _ = model
+    cfg = wide_router(cfg)
+    T, k, H = 128, cfg.num_experts_per_tok, cfg.hidden_size
+    F = cfg.moe_intermediate_size
+    w = ref.layer_weights(ref.seed_key(SEED), sizes, 1)
+    jaxpr = jax.make_jaxpr(lambda f, idx, gates, valid: lm.held_experts(
+        f, idx, gates, valid, stacked(w["experts"]), 0, cfg, jnp.float32))(
+        jnp.zeros((T, H)), jnp.zeros((T, k), jnp.int32), jnp.zeros((T, k)),
+        jnp.ones((T,), bool))
+    prims = _primitives(jaxpr.jaxpr)
+    rows = lm.expert_window(T, k, cfg)
+    # rows of activations are indexed in the window's body alone (one loop
+    # deep): f laid out in sorted order by ONE gather, the results brought
+    # back to their tokens a slot of the k at a time — whatever else is
+    # indexed is a vector of ints
+    wide = [(d, n, s[0]) for d, n, s in prims
+            if (n == "gather" or n.startswith("scatter"))
+            and len(s[0]) == 2 and s[0][1] in (H, F)]
+    assert sorted(wide) == sorted([(1, "gather", (T, H))]
+                                  + [(1, "gather", (rows, H))] * k)
+    # between them two grouped products over the layout — gate and up in
+    # one, then down — their plan (group sizes: data) a scalar operand, and
+    # no loop over tiles or experts around them
+    calls = [s for d, n, s in prims if n == "pallas_call" and d == 1]
+    assert len(calls) == len([n for _, n, _ in prims
+                              if n == "pallas_call"]) == 2
+    assert [(rows, H) in s for s in calls] == [True, False]
+    assert [(rows, F) in s for s in calls] == [False, True]
+    assert not [n for d, n, _ in prims if d >= 2 and n == "while"]
+
+
+#: (configuration, rows x experts a token, the tile PERF.md states): the
+#: three expert-layer cells' decode steps (both row rungs) and prompts
+TILES = [
+    ("xing4-29b-ep1-stage", 128, 64), ("xing4-29b-ep1-stage", 16, 16),
+    ("xing4-29b-ep1-stage", 1024, 128), ("xing4-29b-ep1-stage", 3072, 128),
+    ("ax-k1-ep16-share", 128, 64), ("ax-k1-ep16-share", 16, 16),
+    ("ax-k1-ep16-share", 1024, 128), ("ax-k1-ep16-share", 3072, 128),
+    ("solar-open2-ep16-share", 64, 16),
+    ("solar-open2-ep16-share", 3072, 128),
+    ("solar-open2-ep16-share", 5632, 128),
+]
+
+
+@pytest.mark.parametrize("name,T,tile", TILES)
+def test_the_tile_follows_the_load_the_shapes_state(name, T, tile):
+    cfg = get_config(name)
+    k = cfg.num_experts_per_tok
+    assert lm.expert_tile(T, k, cfg) == tile
+    # a share's window: twice its expected rows in whole tiles; all T * k
+    # where every expert is held
+    rows = lm.expert_window(T, k, cfg)
+    assert rows % tile == 0
+    if cfg.experts_held == cfg.n_routed_experts:
+        assert rows == T * k
+    else:
+        twice = 2 * T * k * cfg.experts_held // cfg.n_routed_experts
+        assert max(twice, 1) <= rows < twice + tile
+
+
+def test_expert_fill_of_a_known_table():
+    """``decode_host_phases``' ``expert_load``: assignments over the rows
+    the experts' products computed, over the decode steps' fetch leaves."""
+    from pdnlp_tpu.obs.phases import decode_host_phases
+
+    def leaf(name, t, **attrs):
+        return {"name": name, "t0": t, "dur": 0.001, "tid": 1,
+                "attrs": dict(attrs, replica=0, round=int(t * 100))}
+
+    recs = []
+    for i, (n, rows) in enumerate([(2540, 7840), (2560, 7840), (0, 0)]):
+        t = 0.05 * i
+        recs += [leaf("decode.dispatch", t, phase="decode", rows=128),
+                 leaf("decode.fetch", t + 0.02, bytes=772,
+                      expert_assignments=n, expert_rows_computed=rows,
+                      expert_tokens_max=25, experts_idle=75)]
+    # a prompt's leaf is not a decode step's
+    recs.append(leaf("prefill.fetch", 0.2, bytes=4, expert_assignments=60000,
+                     expert_rows_computed=111616))
+    load = decode_host_phases(recs)["0"]["expert_load"]
+    assert load["assignments_per_step"] == 1700.0
+    assert load["expert_fill"] == round(5100 / 15680, 4)
+    # leaves of a program from before the counter: no fill, no error
+    old = [dict(r, attrs={k: v for k, v in r["attrs"].items()
+                          if k != "expert_rows_computed"}) for r in recs]
+    assert "expert_fill" not in decode_host_phases(old)["0"]["expert_load"]
 
 
 def test_engine_seam_is_bitwise_for_the_bert_family(tok):
