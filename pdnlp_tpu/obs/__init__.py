@@ -17,7 +17,13 @@ offline half (``summarize`` / ``diff`` / ``export`` / ``merge`` /
 Off by default: entrypoints enable tracing with ``--trace`` (spans land
 under ``<output_dir>/trace/trace_proc<i>.jsonl``), the live exporter with
 ``--metrics_port``; what the instrumentation costs when it is on is in
-PERF.md (sections 6-7).
+PERF.md (sections 6-7).  A LEAF span (``Tracer.leaf``: the decode worker's
+host phases) has three answers: it records under ``--trace`` or while a
+JAX profiler session is on; else, inside a worker's open round, it tallies
+its seconds into that round's row of the tracer's ring of rounds
+(``Tracer.rounds()``, ``phases.round_account``: on with no flag at all);
+else it is the shared no-op.  ``trace.BUILDS`` counts the executables JAX
+builds, where JAX builds them.
 """
 from pdnlp_tpu.obs.exporter import MetricsExporter, prometheus_text
 from pdnlp_tpu.obs.memory import MemorySampler, device_memory_stats, \

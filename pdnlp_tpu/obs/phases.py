@@ -43,6 +43,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Dict, List, Optional, Sequence
 
+from pdnlp_tpu.obs.trace import ROUND_LEAVES, ROUND_RECORD
+
 PHASES = ("data_wait", "h2d_put", "step_dispatch", "device_block",
           "eval", "ckpt_save", "ckpt_wait", "log")
 
@@ -752,3 +754,166 @@ def format_decode_table(by_replica: Dict) -> str:
                          f"{s['mean_ms']:>10.3f} {s['p95_ms']:>10.3f}")
     return "\n".join(lines)
 
+
+# ------------------------------------------------- the worker's round account
+
+#: how many of the longest rounds the account prints whole
+LONGEST_ROUNDS = 16
+#: the seconds of rounds a worker's ``snapshot()`` accounts for: an
+#: interval an exporter's scrape or a flight-recorder tick can compare
+#: with the last, and a bound on what a snapshot costs the serving process
+#: (PERF.md section 6, PR 40: by the window's rounds, not by the ring)
+ACCOUNT_SECONDS = 30.0
+_WAITS = tuple(k for k in ROUND_LEAVES if k.endswith(".wait_fetch"))
+
+
+def round_rows(records: Sequence[Dict]) -> List[Dict]:
+    """The rows of the ring of rounds that ``Tracer.flush`` wrote beside
+    the spans (records named ``round``: the row is their attrs, the
+    record's own — clock-aligned — ``t0`` its start)."""
+    return [dict(r.get("attrs") or {}, t0=float(r.get("t0", 0.0)))
+            for r in records
+            if r.get("name") == ROUND_RECORD
+            and "wall" in (r.get("attrs") or {})]
+
+
+def _round_parts(row: Dict) -> Dict[str, float]:
+    """One row's split, in seconds: the leaves it holds under their names
+    (a call's ``wait_fetch`` = its wait for the device and the fetch,
+    one barrier untraced), then ``other`` — they add up to ``wall``."""
+    parts = {k: row[k] for k in ROUND_LEAVES if row.get(k)}
+    parts["other"] = row.get("other", 0.0)
+    return parts
+
+
+def _ms(seconds: float) -> float:
+    return round(1e3 * seconds, 4)
+
+
+def round_account(rows: Sequence[Dict]) -> Dict:
+    """Where the decode worker's rounds went, from rows of the ring of
+    rounds (``Tracer.rounds(t0, t1)`` cuts them to a window;
+    ``round_rows`` of a flushed file).  Per round KIND (``decode`` = one
+    decode step and nothing else; ``prefill`` / ``chunk`` / ``verify`` =
+    it also held such a call): ``count``, ``wall_ms`` p50 / p90 / p99 /
+    max / mean, the ``mean_ms`` and ``p50_ms`` over the kind's rounds of
+    every part (the leaves by ``decode_host_phases``' names, a call's two
+    waits as its ``wait_fetch``; ``other``), ``builds`` / ``build_ms``
+    (the executables JAX built inside them).  ``cpu``: the worker's own
+    CPU time, from the rows that carry a reading of its thread's clock
+    (``cpu`` over ``cpu_span``; the clock is read every tenth of a second,
+    not every round) — the ``share`` of the read spans it was on its CPU,
+    that share of a mean round as ``ms_per_round``, and
+    ``host_off_cpu_ms_per_round`` = (``wall`` - every wait) a round less
+    it: the time the worker wanted to run and did not (less the little
+    CPU a wait itself burns).  ``longest``: the ``LONGEST_ROUNDS`` longest
+    rounds whole, each with its split, its builds and, where it carries
+    one, its reading of the CPU clock.  Milliseconds throughout;
+    JSON-ready."""
+    by_kind: Dict[str, List[Dict]] = {}
+    for r in rows:
+        by_kind.setdefault(r.get("kind", "decode"), []).append(r)
+    kinds = {}
+    for kind, rs in sorted(by_kind.items(), key=lambda kv: -len(kv[1])):
+        walls = sorted(r["wall"] for r in rs)
+        split = [_round_parts(r) for r in rs]
+        names = sorted({k for p in split for k in p})
+        kinds[kind] = {
+            "count": len(rs),
+            "wall_ms": {"p50": _ms(_percentile(walls, 50)),
+                        "p90": _ms(_percentile(walls, 90)),
+                        "p99": _ms(_percentile(walls, 99)),
+                        "max": _ms(walls[-1]),
+                        "mean": _ms(sum(walls) / len(walls))},
+            "parts": {
+                name: {"mean_ms": _ms(sum(vals) / len(vals)),
+                       "p50_ms": _ms(_percentile(vals, 50))}
+                for name in names
+                for vals in [sorted(p.get(name, 0.0) for p in split)]},
+            "builds": sum(int(r.get("builds", 0)) for r in rs),
+            "build_ms": round(1e3 * sum(r.get("build_s", 0.0)
+                                        for r in rs), 3),
+        }
+    wall = sum(r["wall"] for r in rows)
+    span = sum(r.get("cpu_span", 0.0) for r in rows)
+    cpu = {"span_sec": round(span, 6)}
+    if span > 0.0:
+        share = sum(r.get("cpu", 0.0) for r in rows) / span
+        waited = sum(r.get(k, 0.0) for r in rows for k in _WAITS)
+        on_cpu = share * wall / len(rows)
+        cpu.update(share=round(share, 4), ms_per_round=_ms(on_cpu),
+                   host_off_cpu_ms_per_round=_ms(
+                       (wall - waited) / len(rows) - on_cpu))
+    longest = sorted(rows, key=lambda r: -r["wall"])[:LONGEST_ROUNDS]
+    return {
+        "rounds": len(rows),
+        "wall_sec": round(wall, 6),
+        "builds": sum(k["builds"] for k in kinds.values()),
+        "cpu": cpu,
+        "kinds": kinds,
+        "longest": [{
+            "t0": round(r["t0"], 6), "replica": r.get("replica", 0),
+            "round": r.get("round", 0), "kind": r.get("kind", "decode"),
+            "live": r.get("live", 0), "seated": r.get("seated", 0),
+            "wall_ms": _ms(r["wall"]),
+            "parts_ms": {k: _ms(v) for k, v in _round_parts(r).items()},
+            "cpu_ms": _ms(r.get("cpu", 0.0)),
+            "cpu_span_ms": _ms(r.get("cpu_span", 0.0)),
+            "builds": int(r.get("builds", 0)),
+            "build_ms": round(1e3 * r.get("build_s", 0.0), 3),
+        } for r in longest],
+    }
+
+
+def recent_round_account(tracer, replica: int) -> Dict:
+    """``round_account`` of one worker's rounds that began in the last
+    ``ACCOUNT_SECONDS`` (``window_sec``): what its ``snapshot()`` carries."""
+    account = round_account(tracer.rounds(
+        t0=tracer.clock() - ACCOUNT_SECONDS, replica=replica))
+    account["window_sec"] = ACCOUNT_SECONDS
+    return account
+
+
+def format_round_table(by_replica: Dict) -> str:
+    """``round_account`` per replica as text (``trace_tpu.py summarize``,
+    under the host-phase table)."""
+    lines = []
+    for rep, acc in by_replica.items():
+        lines.append(
+            f"decode worker rounds, replica {rep}: {acc['rounds']} rounds in "
+            f"{acc['wall_sec']:.3f}s; {acc['builds']} executables built "
+            "inside them")
+        cpu = acc["cpu"]
+        if "share" in cpu:
+            lines.append(
+                f"  the worker on its CPU {cpu['share']:.1%} of "
+                f"{cpu['span_sec']:.3f}s read = {cpu['ms_per_round']:.3f} ms "
+                f"a round; host_off_cpu "
+                f"{cpu['host_off_cpu_ms_per_round']:.3f} ms a round")
+        for kind, k in acc["kinds"].items():
+            w = k["wall_ms"]
+            lines.append(
+                f"  {kind} rounds: {k['count']}; wall ms p50 {w['p50']:.3f} "
+                f"p90 {w['p90']:.3f} p99 {w['p99']:.3f} max {w['max']:.3f}"
+                + (f"; {k['builds']} builds, {k['build_ms']:.1f} ms"
+                   if k["builds"] else ""))
+            header = f"    {'part':<22} {'mean_ms':>10} {'p50_ms':>10}"
+            lines += [header, "    " + "-" * (len(header) - 4)]
+            for name, s in sorted(k["parts"].items(),
+                                  key=lambda kv: -kv[1]["mean_ms"]):
+                lines.append(f"    {name:<22} {s['mean_ms']:>10.3f} "
+                             f"{s['p50_ms']:>10.3f}")
+        lines.append(f"  the {len(acc['longest'])} longest rounds:")
+        for r in acc["longest"]:
+            parts = ", ".join(
+                f"{k} {v:.3f}" for k, v in sorted(
+                    r["parts_ms"].items(), key=lambda kv: -kv[1]) if v)
+            lines.append(
+                f"    round {r['round']} ({r['kind']}, {r['live']} live, "
+                f"{r['seated']} seated) {r['wall_ms']:.3f} ms: {parts}"
+                + (f"; on its CPU {r['cpu_ms']:.1f} of the "
+                   f"{r['cpu_span_ms']:.1f} ms that end with it"
+                   if r["cpu_span_ms"] else "")
+                + (f"; {r['builds']} builds, {r['build_ms']:.1f} ms"
+                   if r["builds"] else ""))
+    return "\n".join(lines)

@@ -33,12 +33,30 @@ The tracer records host-side spans into a ring buffer:
 - **leaf spans, on two clocks**: ``leaf(name)`` is a span that is ALSO a
   ``jax.profiler.TraceAnnotation`` — the same interval lies in this ring
   buffer and on the ``/host:CPU`` plane of the profiler's trace, beside
-  the device's operations.  Leaves record whenever the tracer is enabled
-  OR a JAX profiler session is on (:meth:`Tracer.follow_profiler`): a
-  harness that starts ``jax.profiler.start_trace`` itself and can pass
-  no ``--trace`` still gets the worker's host phases.  A session switches
-  on leaves only — ``span``/``mark``/``record`` (the per-token hop
-  stream, the memory samples) stay behind ``enabled``.
+  the device's operations.  ``leaf`` has THREE answers: it **records**
+  whenever the tracer is enabled OR a JAX profiler session is on
+  (:meth:`Tracer.follow_profiler`): a harness that starts
+  ``jax.profiler.start_trace`` itself and can pass no ``--trace`` still
+  gets the worker's host phases; else it **tallies** where the calling
+  thread has a round open (below); else it is the shared **no-op**.  A
+  session switches on leaves only — ``span``/``mark``/``record`` (the
+  per-token hop stream, the memory samples) stay behind ``enabled``.
+
+- **a ring of rounds, profiler or not**: a decode worker brackets each
+  round of its loop with :meth:`Tracer.open_round` /
+  :meth:`Tracer.close_round`.  While a round is open on a thread, every
+  leaf of that thread adds its seconds to the round's cell of its name:
+  a recording leaf as it exits, and where nothing records a FALSY tally
+  span, one per thread and reused (two clock reads; no attribute, no
+  annotation, nothing in the ring of spans).  ``close_round`` writes ONE
+  fixed-width row (``ROUND_COLUMNS``) into a preallocated array of
+  ``ROUND_CAPACITY`` rows: the round's wall time, each call's dispatch /
+  wait and fetch / emit, ``admit``, ``other`` = the wall less every
+  leaf, the executables JAX built inside it (:class:`Builds`), and — on
+  the rows that end ``CPU_EVERY_S`` after the last reading, never a
+  system call a round — the worker thread's own CPU seconds since that
+  reading.  Read back with :meth:`Tracer.rounds`; summed by
+  ``phases.round_account``.
 
 Listeners (``add_listener``) receive each finished span record — this is
 how :class:`~pdnlp_tpu.obs.phases.StepBreakdown` and, through it, the
@@ -47,6 +65,7 @@ without a second set of timing calls in the loop.
 """
 from __future__ import annotations
 
+import array
 import collections
 import json
 import os
@@ -59,14 +78,15 @@ class Span:
     """One open span: ``with tracer.span("step_dispatch") as sp: ...``."""
 
     __slots__ = ("_tracer", "name", "attrs", "t0", "_tid", "_depth",
-                 "_note")
+                 "_note", "_round")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict,
-                 note=None):
+                 note=None, rnd: Optional["_Round"] = None):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self._note = note  # a leaf's open jax.profiler.TraceAnnotation
+        self._round = rnd  # a leaf's open round: its seconds land there too
 
     def set(self, **attrs) -> "Span":
         """Attach attributes after entry (e.g. bytes counted inside)."""
@@ -92,6 +112,10 @@ class Span:
     def __exit__(self, *exc) -> None:
         tr = self._tracer
         t1 = tr.clock()
+        if self._round is not None:
+            col = _LEAF_COLUMN.get(self.name)
+            if col is not None:
+                self._round.cells[col] += t1 - self.t0
         if self._note is not None:
             self._note.__exit__(*exc)
         _, stack = tr._thread_state()
@@ -126,6 +150,144 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+# ------------------------------------------------------- the ring of rounds
+
+#: rows the ring of rounds holds (a 30 s window of a decode worker is 600
+#: to 2 800 rounds); the oldest are written over
+ROUND_CAPACITY = 16_384
+#: the engine calls a round may hold, and what a round is called after:
+#: the first of these it dispatched (``decode`` = one decode step and
+#: nothing else; a ``cow`` does not hide a prefill), ``host`` if none
+ROUND_KINDS = ("prefill", "chunk", "verify", "cow", "decode", "host")
+#: a call's parts.  ``wait_fetch`` is the call's wait for the device with
+#: the fetch of what it answers: untraced ONE barrier (``device_get``
+#: whole, timed by the tally span of ``<p>.fetch``), and where the leaves
+#: record the sum of the two they are then (``<p>.device_wait`` and
+#: ``<p>.fetch``: the ring of spans holds them apart)
+ROUND_PARTS = ("dispatch", "wait_fetch", "emit")
+#: the worker thread's CPU clock is read at the end of the first round
+#: that ends this long after the last reading, never once a round: on the
+#: chip's host a read (``time.thread_time``) is a system call of 5 us back
+#: to back and about 25 us between other work, and the clock ticks in
+#: 10 ms steps (PERF.md section 6, PR 40) — a reading over less says little
+CPU_EVERY_S = 0.1
+#: one row: seconds unless named otherwise.  ``t0`` on the tracer's clock;
+#: ``kind`` an index into ``ROUND_KINDS`` (a name in :meth:`Tracer.rounds`);
+#: ``live`` rows and streams ``seated`` as the round's seating left them;
+#: ``other`` = ``wall`` less every leaf (the worker's wait for its lock,
+#: the staging of a step's tokens, ``notify_all``: what no leaf names);
+#: ``builds`` / ``build_s`` the executables JAX built on this thread
+#: inside the round; ``cpu`` the worker thread's own CPU time over the
+#: ``cpu_span`` seconds that END with this round (this round and the
+#: rounds before it back to the last reading, or to the worker's last idle
+#: wait), both 0 on a row that took no reading — a round of ``CPU_EVERY_S``
+#: or longer always takes one
+ROUND_COLUMNS = (
+    "t0", "wall", "replica", "round", "kind", "live", "seated", "admit",
+    *(f"{call}.{part}" for call in ("decode", "prefill", "chunk", "verify")
+      for part in ROUND_PARTS),
+    "cow.dispatch", "other", "cpu", "cpu_span", "builds", "build_s")
+_COLUMN = {name: i for i, name in enumerate(ROUND_COLUMNS)}
+_T0, _WALL, _REPLICA, _NUMBER, _KIND, _LIVE, _SEATED = range(7)
+_FIRST_LEAF, _END_LEAF = _COLUMN["admit"], _COLUMN["other"]
+#: the cells that are leaves' sums: with ``other`` they add up to ``wall``
+ROUND_LEAVES = ROUND_COLUMNS[_FIRST_LEAF:_END_LEAF]
+_CPU, _CPU_SPAN = _COLUMN["cpu"], _COLUMN["cpu_span"]
+_BUILDS, _BUILD_S = _COLUMN["builds"], _COLUMN["build_s"]
+#: leaf name -> its cell, recording or tallying alike: a call's two wait
+#: leaves (``<p>.device_wait``, ``<p>.fetch``) share its ``wait_fetch``
+_LEAF_COLUMN = {name: _COLUMN[name] for name in ROUND_LEAVES
+                if not name.endswith(".wait_fetch")}
+_LEAF_COLUMN.update(
+    (name[:-len("wait_fetch")] + leaf, _COLUMN[name])
+    for name in ROUND_LEAVES if name.endswith(".wait_fetch")
+    for leaf in ("device_wait", "fetch"))
+#: ``ROUND_KINDS``' calls' dispatch cells, in that order
+_KIND_COLUMNS = tuple(_COLUMN[call + ".dispatch"]
+                      for call in ROUND_KINDS[:-1])
+#: a row's name as a record of a flushed file (``Tracer.flush``)
+ROUND_RECORD = "round"
+_INT_COLUMNS = ("replica", "round", "live", "seated", "builds")
+_EMPTY_ROW = array.array("d", bytes(8 * len(ROUND_COLUMNS)))
+
+#: the round open on the calling thread, whichever tracer opened it: the
+#: build listener (one a process) has no tracer to ask
+_thread = threading.local()
+
+
+class _Round(_NullSpan):
+    """One thread's open round: the row being summed, and the thread's one
+    tally span.  The span is a no-op span that times itself — FALSY, so
+    every ``if sp:`` of a site stays false — and reused: ``Tracer.leaf``
+    points it at a cell and hands it out; a site that drops it unentered
+    (``_fetch``'s wait leaf) leaves nothing behind."""
+
+    __slots__ = ("tracer", "cells", "t0", "cpu0", "cpu_t0", "_col", "_t")
+
+    def __init__(self, tracer: "Tracer"):
+        self.tracer = tracer
+        self.cells = array.array("d", _EMPTY_ROW)
+        self.cpu0 = None    # the thread's CPU clock at its last reading ...
+        self.cpu_t0 = 0.0   # ... and the tracer's clock then
+
+    def tally(self, name: str) -> "_Round":
+        self._col = _LEAF_COLUMN.get(name, -1)
+        return self
+
+    def __enter__(self) -> "_Round":
+        self._t = self.tracer.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = self.tracer.clock() - self._t
+        if self._col >= 0:     # a leaf of no column stays in ``other``
+            self.cells[self._col] += dt
+
+
+class Builds:
+    """Executables BUILT, counted where JAX builds them: a listener on
+    ``backend_compile_duration``, which JAX 0.9 announces around
+    ``compile_or_get_cached`` (``jax/_src/dispatch.py``) — an executable
+    compiled OR loaded from the persistent cache, with or without a
+    retrace.  A count of traces (``ServeMetrics.retraces``: the times a
+    jitted body's Python ran) cannot see one built from a cached jaxpr.
+    One instance a process (:data:`BUILDS`); a build also lands on the
+    round open on its thread (the row's ``builds`` / ``build_s``)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._listening = False
+        self.executables_built = 0
+        self.backend_compile_s = 0.0
+
+    def listen(self) -> "Builds":
+        """Register with ``jax.monitoring``, once."""
+        with self._lock:
+            if not self._listening:
+                import jax.monitoring
+
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_duration)
+                self._listening = True
+        return self
+
+    def _on_duration(self, event: str, seconds: float, **kwargs) -> None:
+        if event != self.EVENT:
+            return
+        with self._lock:
+            self.executables_built += 1
+            self.backend_compile_s += seconds
+        rnd = getattr(_thread, "round", None)
+        if rnd is not None:
+            rnd.cells[_BUILDS] += 1
+            rnd.cells[_BUILD_S] += seconds
+
+
+#: the process's build counters (``Tracer.follow_profiler`` starts them)
+BUILDS = Builds()
 
 #: a leaf's name in the JAX profiler's trace is this + the span's name.
 #: The benchmark's trace reducer keeps only host annotations that start
@@ -168,6 +330,8 @@ class Tracer:
         self._listeners: List[Callable[[Dict], None]] = []
         self._session_on = _no_session  # follow_profiler(): JAX's own test
         self._annotation = None         # ... and jax.profiler.TraceAnnotation
+        self._rounds = None             # open_round(): the ring of rounds
+        self._rounds_written = 0
 
     # --------------------------------------------------------------- spans
     def span(self, name: str, **attrs):
@@ -187,7 +351,8 @@ class Tracer:
         (and ``tests/test_decode_spans.py`` pins that it flips with a real
         session), never a tracer that silently records nothing.
         Idempotent; the import is paid once, at engine construction, not
-        inside a traced window."""
+        inside a traced window.  The process's build counters
+        (:data:`BUILDS`) start listening with it."""
         if self._annotation is None:
             import jax.profiler
 
@@ -199,6 +364,7 @@ class Tracer:
                     "and Tracer.follow_profiler must be taught where")
             self._session_on = note.is_enabled
             self._annotation = note
+            BUILDS.listen()
         return self
 
     @property
@@ -213,12 +379,19 @@ class Tracer:
         never nest in one another.  ``base``: an EXISTING dict of attrs
         every leaf of the caller shares (copied only when recording);
         anything else is ``set`` on the open span under ``if sp:`` — a
-        site that is off builds nothing and gets the shared no-op span."""
+        site that is off builds nothing.  Three answers: a recording
+        ``Span`` (which also feeds the round open on this thread, if one
+        is); else, inside an open round, the thread's falsy tally span;
+        else the shared no-op span."""
+        rnd = getattr(_thread, "round", None)
+        if rnd is not None and rnd.tracer is not self:
+            rnd = None
         if not self.enabled and not self._session_on():
-            return _NULL_SPAN
+            return _NULL_SPAN if rnd is None else rnd.tally(name)
         note = self._annotation
         return Span(self, name, dict(base) if base else {},
-                    None if note is None else note(ANNOTATION_PREFIX + name))
+                    None if note is None else note(ANNOTATION_PREFIX + name),
+                    rnd)
 
     def leaf_at(self, name: str, t0: float, t1: float, attrs: Dict,
                 owed: bool = False) -> None:
@@ -230,6 +403,103 @@ class Tracer:
         if owed or self.enabled or self._session_on():
             tid, _ = self._thread_state()
             self._record(name, t0, t1, tid, 0, attrs)
+
+    # -------------------------------------------------------------- rounds
+    def open_round(self, replica: int, number: int) -> None:
+        """Open a round of the calling worker thread (module docstring):
+        until :meth:`close_round` or :meth:`drop_round`, this thread's
+        leaves sum into it."""
+        rnd = getattr(_thread, "cached", None)
+        if rnd is None or rnd.tracer is not self:
+            rnd = _thread.cached = _Round(self)
+            with self._lock:
+                if self._rounds is None:
+                    self._rounds = array.array(
+                        "d", bytes(8 * ROUND_CAPACITY * len(ROUND_COLUMNS)))
+        cells = rnd.cells
+        cells[:] = _EMPTY_ROW
+        cells[_REPLICA], cells[_NUMBER] = replica, number
+        _thread.round = rnd
+        if rnd.cpu0 is None:    # the thread's first round, or after idle
+            rnd.cpu0, rnd.cpu_t0 = time.thread_time(), self.clock()
+        rnd.t0 = cells[_T0] = self.clock()
+
+    def close_round(self, live: int = 0, seated: int = 0) -> None:
+        """End the open round and write its row.  No round open: no-op."""
+        rnd = getattr(_thread, "round", None)
+        if rnd is None:
+            return
+        _thread.round = None
+        cells = rnd.cells
+        t1 = self.clock()
+        wall = cells[_WALL] = t1 - rnd.t0
+        cells[_END_LEAF] = wall - sum(cells[_FIRST_LEAF:_END_LEAF])
+        if t1 - rnd.cpu_t0 >= CPU_EVERY_S:      # ``ROUND_COLUMNS``: ``cpu``
+            cpu = time.thread_time()
+            cells[_CPU], cells[_CPU_SPAN] = cpu - rnd.cpu0, t1 - rnd.cpu_t0
+            rnd.cpu0, rnd.cpu_t0 = cpu, t1
+        kind = len(_KIND_COLUMNS)     # "host": no engine call
+        for k, col in enumerate(_KIND_COLUMNS):
+            if cells[col] > 0.0:
+                kind = k
+                break
+        cells[_KIND], cells[_LIVE], cells[_SEATED] = kind, live, seated
+        width = len(cells)
+        with self._lock:
+            at = (self._rounds_written % ROUND_CAPACITY) * width
+            self._rounds[at:at + width] = cells
+            self._rounds_written += 1
+
+    def drop_round(self) -> None:
+        """End the open round WITHOUT a row: an idle wait is not a round,
+        and a worker that dies or stops leaves none half summed."""
+        rnd = getattr(_thread, "round", None)
+        if rnd is not None:
+            rnd.cpu0 = None     # the wait's own CPU time is no round's
+            _thread.round = None
+
+    def rounds(self, t0: Optional[float] = None, t1: Optional[float] = None,
+               replica: Optional[int] = None) -> List[Dict]:
+        """The ring of rounds, oldest first, as JSON-ready dicts keyed by
+        ``ROUND_COLUMNS`` (``kind`` by name); ``t0`` / ``t1`` (tracer
+        clock) keep the rounds that BEGAN in ``[t0, t1)``, ``replica``
+        one worker's.  With a ``t0`` only the ring's tail is copied and
+        looked at: a reader of the last seconds (a worker's ``snapshot``)
+        costs by those seconds' rounds, not by the ring."""
+        width = len(ROUND_COLUMNS)
+        with self._lock:
+            ring, n = self._rounds, self._rounds_written
+            first = max(0, n - ROUND_CAPACITY)      # by count written
+            if t0 is not None:
+                # rows are written as rounds END, so their ends count up
+                # (two workers' to a few us): skip, by bisection, those
+                # that ended before ``t0`` — they began before it too
+                hi = n
+                while first < hi:
+                    mid = (first + hi) // 2
+                    at = (mid % ROUND_CAPACITY) * width
+                    if ring[at + _T0] + ring[at + _WALL] < t0:
+                        first = mid + 1
+                    else:
+                        hi = mid
+            if first == n:
+                return []
+            at, end = (first % ROUND_CAPACITY) * width, \
+                ((n - 1) % ROUND_CAPACITY + 1) * width
+            flat = ring[at:end] if at < end else ring[at:] + ring[:end]
+        out = []
+        for i in range(0, len(flat), width):
+            began = flat[i]
+            if (t0 is not None and began < t0) \
+                    or (t1 is not None and began >= t1) \
+                    or (replica is not None and flat[i + _REPLICA] != replica):
+                continue
+            row = dict(zip(ROUND_COLUMNS, flat[i:i + width]))
+            for key in _INT_COLUMNS:
+                row[key] = int(row[key])
+            row["kind"] = ROUND_KINDS[int(row["kind"])]
+            out.append(row)
+        return out
 
     def block(self, value, name: str = "device_block", **attrs):
         """``jax.block_until_ready(value)`` inside its own span — the
@@ -354,6 +624,11 @@ class Tracer:
         from pdnlp_tpu.obs.merge import CLOCK_SYNC
 
         records = self.records()
+        # the ring of rounds rides along, a record a row, so the offline
+        # reader (``trace_tpu.py summarize``) prints the same account
+        records += [{"name": ROUND_RECORD, "t0": row["t0"],
+                     "dur": row["wall"], "tid": 0, "depth": 0, "attrs": row}
+                    for row in self.rounds()]
         records.append({"name": CLOCK_SYNC, "t0": self.clock(), "dur": 0.0,
                         "tid": 0, "depth": 0,
                         "attrs": {"wall": time.time()}})

@@ -118,6 +118,7 @@ import numpy as np
 from pdnlp_tpu.models import decoder, families
 from pdnlp_tpu.obs.decision import mint_decision_id, record_decision
 from pdnlp_tpu.obs.memory import KVBudget, KVBudgetExceeded
+from pdnlp_tpu.obs.phases import recent_round_account
 from pdnlp_tpu.obs.request import mint_request_id, record_hop
 from pdnlp_tpu.serve.batcher import (
     DEFAULT_BUCKETS, DeadlineExceeded, QueueFullError, pick_bucket,
@@ -715,7 +716,10 @@ class PagedDecodeEngine(InferenceEngine):
         launch: the chosen ids; the logits of a verify window), nothing
         else, so the device's time is never smeared into the host's — then
         ``<p>.fetch``, the ``device_get`` to numpy, with the ``bytes`` it
-        moved.  Untraced, the fetch is the one barrier it always was.
+        moved.  Untraced, the fetch is the one barrier it always was: the
+        wait leaf comes back falsy and is never entered, and inside a
+        worker's round the fetch leaf's tally span times ``device_get``
+        whole — the round's ``wait_fetch``, never its ``fetch``.
         ``aux`` (a family with experts: this launch's assignments to each
         held expert and the rows the experts' products computed for them,
         both summed over the layers) is fetched with it and lands on the
@@ -1749,7 +1753,10 @@ class DecodeBatcher:
         span (``Tracer.leaf``) stamped with the round's counter, so a
         trace shows where a round's host time went; the engine's calls
         bring their own (``<p>.dispatch`` / ``.device_wait`` /
-        ``.fetch``), this class the ``admit`` and ``<p>.emit`` ones."""
+        ``.fetch``), this class the ``admit`` and ``<p>.emit`` ones.
+        Profiler or not, the round is also a ROW of the tracer's ring of
+        rounds (``Tracer.open_round``): the same leaves summed, under the
+        same ``replica`` and ``round``; an idle wait is no round."""
         tr = self.tracer
         rnd = 0
         try:
@@ -1766,6 +1773,7 @@ class DecodeBatcher:
                 attrs["round"] = rnd
                 if dr is not None:
                     dr.span_attrs["round"] = rnd
+                tr.open_round(self.replica, rnd)
                 with self._lock:
                     if self._poison is not None:
                         raise self._poison
@@ -1786,6 +1794,8 @@ class DecodeBatcher:
                     if not claims and live == 0:
                         if self._stop:
                             return
+                        tr.drop_round()
+                        rnd -= 1    # the rows' rounds count up by one
                         self._wake.notify_all()  # unblock stop(drain)
                         self._wake.wait(timeout=0.05)
                         continue
@@ -1805,8 +1815,11 @@ class DecodeBatcher:
                         self._decode_step()
                 with self._lock:
                     self._wake.notify_all()
+                tr.close_round(live, len(claims) + len(imports))
         except BaseException as e:  # noqa: BLE001 — a dead engine must
             self._die(e)           # never strand callers or streams
+        finally:
+            tr.drop_round()     # stopped or dead inside one: no half row
 
     def _seat_locked(self, dr, claims: List[tuple],
                      imports: List[tuple]) -> None:
@@ -2409,6 +2422,9 @@ class DecodeBatcher:
             "replica": self.rmetrics.snapshot(),
             "kv": self.engine.kv_snapshot(),
             "engine": self.engine.metrics.snapshot(),
+            # where this worker's last seconds of rounds went, profiler
+            # or not
+            "rounds": recent_round_account(self.tracer, self.replica),
         }
         if self.drafter is not None or self._spec_rounds:
             out["speculation"] = self.spec_snapshot()
@@ -2620,9 +2636,18 @@ class PrefillWorker:
 
     # ------------------------------------------------------------- worker
     def _run(self) -> None:
+        """The prefill pool's round: claim one group → prefill → stage and
+        dispatch.  A row of the tracer's ring of rounds like the
+        interleaved batcher's (``Tracer.open_round``), with the engine
+        calls' leaves; its seating, exports and dispatches have none and
+        read as the row's ``other``."""
+        tr = self.tracer
+        rnd = 0
         try:
             while True:
                 claims: List[tuple] = []
+                rnd += 1
+                tr.open_round(self.replica, rnd)
                 with self._lock:
                     if self._poison is not None:
                         raise self._poison
@@ -2666,14 +2691,19 @@ class PrefillWorker:
                     if not claims:
                         if self._stop:
                             return
+                        tr.drop_round()     # an idle wait is no round
+                        rnd -= 1
                         self._wake.notify_all()  # unblock stop(drain)
                         self._wake.wait(timeout=0.05)
                         continue
                 self._prefill(claims)  # dispatches per staged stream
                 with self._lock:
                     self._wake.notify_all()
+                tr.close_round(live, len(claims))
         except BaseException as e:  # noqa: BLE001 — a dead engine must
             self._die(e)           # never strand callers or streams
+        finally:
+            tr.drop_round()     # stopped or dead inside one: no half row
 
     def _expire_waiting_locked(self) -> None:
         now = time.monotonic()
@@ -2875,6 +2905,7 @@ class PrefillWorker:
             "replica": self.rmetrics.snapshot(),
             "kv": self.engine.kv_snapshot(),
             "engine": self.engine.metrics.snapshot(),
+            "rounds": recent_round_account(self.tracer, self.replica),
         }
 
 

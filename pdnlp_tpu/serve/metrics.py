@@ -26,6 +26,10 @@ What each instrument answers:
   the first call at a ``(bucket, rows)`` shape, a hit is every later one;
 - ``retraces`` — times the jitted forward actually re-traced; after warmup
   this must stay FLAT (the acceptance bar for the serve smoke);
+- ``executables_built`` / ``backend_compile_s`` — executables the PROCESS
+  built, counted where JAX builds them (``obs.trace.BUILDS``: compiled or
+  loaded from the persistent cache, with or without a retrace), and the
+  seconds that took; plain numbers, the same on every engine of a process;
 - ``requests_total`` / ``rejected_total`` / ``deadline_expired_total`` —
   admission accounting (rejects = backpressure, expiries = shed load).
 
@@ -41,6 +45,7 @@ import json
 import os
 from typing import Dict
 
+from pdnlp_tpu.obs.trace import BUILDS
 from pdnlp_tpu.utils.metrics import Counter, Gauge, Histogram
 
 
@@ -61,6 +66,14 @@ class ServeMetrics:
         self.deadline_expired_total = Counter()
         self.batches_total = Counter()
 
+    @property
+    def executables_built(self) -> int:
+        return BUILDS.executables_built
+
+    @property
+    def backend_compile_s(self) -> float:
+        return BUILDS.backend_compile_s
+
     def snapshot(self) -> Dict:
         """JSON-ready state of every instrument (plain floats/ints only)."""
         return {
@@ -79,6 +92,8 @@ class ServeMetrics:
                 "hits": self.cache_hits.value,
                 "misses": self.cache_misses.value,
                 "retraces": self.retraces.value,
+                "executables_built": self.executables_built,
+                "backend_compile_s": round(self.backend_compile_s, 6),
             },
         }
 

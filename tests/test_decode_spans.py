@@ -7,10 +7,13 @@ CPU, under a REAL ``jax.profiler.start_trace``.
 Pinned here: the leaves cover the worker's round and never nest; one
 ``request`` record per finished stream, joinable to its dispatch leaf by
 ``rid``; what a session does NOT switch on (the per-token hop stream, the
-memory sample); that off is off (the shared no-op span, by identity); that
+memory sample); that off is off (every span falsy, the ring of spans empty;
+the shared no-op span, by identity, on a thread with no round open); that
 a submitter never waits on the worker's device wait; the engine-call phase
-tables derived from the leaves; and ``trace_tpu.py summarize``'s
-host-phase table on a recorded file."""
+tables derived from the leaves; ``trace_tpu.py summarize``'s host-phase
+table on a recorded file; and the worker's own account of every round,
+profiler or not (``Tracer.open_round`` / ``rounds``, ``round_account``,
+the build counters)."""
 import glob
 import json
 import os
@@ -26,9 +29,13 @@ from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
 from pdnlp_tpu.obs import trace as obs_trace
 from pdnlp_tpu.obs.phases import (
     CALL_LEAVES, StepBreakdown, decode_host_phases, format_decode_table,
+    format_round_table, recent_round_account, round_account, round_rows,
     worker_leaf,
 )
-from pdnlp_tpu.obs.trace import _NULL_SPAN, ANNOTATION_PREFIX, Tracer
+from pdnlp_tpu.obs.trace import (
+    _NULL_SPAN, ANNOTATION_PREFIX, BUILDS, ROUND_COLUMNS, ROUND_KINDS,
+    ROUND_LEAVES, Tracer,
+)
 from pdnlp_tpu.serve import DecodeBatcher, PagedDecodeEngine
 from pdnlp_tpu.serve import decode as decode_mod
 from pdnlp_tpu.utils.config import Args
@@ -90,8 +97,10 @@ def traced(engine, tmp_path_factory):
     tr.clear()
     out = str(tmp_path_factory.mktemp("profile"))
     real = jax.block_until_ready
+    waits = []
 
     def a_device_step(x):
+        waits.append(1)
         # bert-tiny on the CPU answers in under a millisecond, and a round
         # is then so short that the tracer's own 15-20 us between two
         # leaves are 5 % of it; a device whose programs take 8 ms (the
@@ -102,6 +111,7 @@ def traced(engine, tmp_path_factory):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(decode_mod.jax, "block_until_ready", a_device_step)
         jax.profiler.start_trace(out)
+        began = tr.now()
         try:
             streams = serve(engine)
         finally:
@@ -109,7 +119,8 @@ def traced(engine, tmp_path_factory):
     records = tr.records()
     tr.clear()
     xplane = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
-    return {"records": records, "streams": streams, "xplane": xplane[-1]}
+    return {"records": records, "streams": streams, "xplane": xplane[-1],
+            "rounds": tr.rounds(began), "waits": len(waits)}
 
 
 def worker_leaves(records):
@@ -352,10 +363,13 @@ def test_engine_call_phases_are_derived_from_their_leaves(traced):
 
 @pytest.mark.parametrize("switch", ["off", "args_trace"])
 def test_off_is_off_and_args_trace_keeps_its_streams(engine, switch):
-    """No session.  ``off``: nothing is recorded and every span site got
-    the shared no-op span, by identity.  ``args_trace``: the leaves record
-    (no annotation is needed), and so do the hop stream and the request
-    records — what ``--trace`` always meant."""
+    """No session.  ``off``: nothing is recorded, no site built an
+    attribute — every handed span is falsy and the ring of spans is empty
+    (inside the worker's round the span is its tally span; a thread with no
+    round open still gets the shared no-op span, by identity).
+    ``args_trace``: the leaves record (no annotation is needed), and so do
+    the hop stream and the request records — what ``--trace`` always
+    meant."""
     on = switch == "args_trace"
     tr = Tracer(enabled=on).follow_profiler()
     handed = []
@@ -369,9 +383,11 @@ def test_off_is_off_and_args_trace_keeps_its_streams(engine, switch):
     assert len(handed) > 40
     names = [r["name"] for r in tr.records()]
     if switch == "off":
-        assert names == [] and all(sp is _NULL_SPAN for sp in handed)
+        assert names == [] and not any(handed)
+        assert all(sp is not _NULL_SPAN for sp in handed)   # all in a round
+        assert leaf("decode.dispatch", {"replica": 0}) is _NULL_SPAN
         return
-    assert all(sp is not _NULL_SPAN for sp in handed)
+    assert all(handed) and all(sp is not _NULL_SPAN for sp in handed)
     assert names.count("request") == len(streams)
     assert set(LEAF_NAMES) <= set(names)
     hops = [r["attrs"]["hop"] for r in tr.records() if r["name"] == "hop"]
@@ -518,3 +534,403 @@ def test_summarize_prints_the_decode_workers_host_phase_table():
     assert worker["host_exposed_ms_per_step"] == pytest.approx(
         1e3 * worker["wall_sec"] / 15 - waited, abs=1e-2)
     assert format_decode_table({"0": worker}).splitlines()[0] == head
+
+
+# ------------------------------------- the worker's own account of a round
+
+LEAF_CELLS = ROUND_LEAVES
+
+
+@pytest.fixture(scope="module")
+def untraced(engine):
+    """The batch served with NO session and no ``--trace``, on a tracer of
+    its own: the spans its sites were handed, the barriers ``_fetch`` made,
+    the ring of spans and the ring of rounds."""
+    tr = Tracer(enabled=False).follow_profiler()
+    handed, waits = [], []
+    leaf = tr.leaf
+    tr.leaf = lambda *a, **k: handed.append(leaf(*a, **k)) or handed[-1]
+    real = jax.block_until_ready
+    old = engine.tracer
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(decode_mod.jax, "block_until_ready",
+                   lambda x: waits.append(1) or real(x))
+        try:
+            streams = serve(engine, tracer=tr, salt=7)
+        finally:
+            engine.tracer = old
+    return {"tracer": tr, "handed": handed, "waits": len(waits),
+            "streams": streams, "rounds": tr.rounds(),
+            "records": tr.records()}
+
+
+def test_an_untraced_batcher_leaves_a_row_a_round(untraced):
+    rows = untraced["rounds"]
+    steps = sum(len(s.emitted) for s in untraced["streams"])
+    assert len(rows) >= steps // 4      # four slots: a step advances <= 4
+    numbers = [r["round"] for r in rows]
+    assert numbers == list(range(numbers[0], numbers[0] + len(rows)))
+    starts = [r["t0"] for r in rows]
+    assert starts == sorted(starts)
+    for r in rows:
+        assert tuple(r) == ROUND_COLUMNS and r["replica"] == 0
+        assert r["other"] >= 0.0 and r["wall"] > 0.0
+        parts = sum(r[c] for c in LEAF_CELLS) + r["other"]
+        assert parts == pytest.approx(r["wall"], abs=1e-6)
+        assert r["cpu_span"] >= r["cpu"] >= 0.0
+        assert 1 <= r["live"] <= 4 and 0 <= r["seated"] <= 4
+    decoded = [r for r in rows if r["decode.dispatch"] > 0]
+    assert decoded and all(r["decode.wait_fetch"] > 0 for r in decoded)
+    assert sum(r["seated"] for r in rows) == len(untraced["streams"])
+
+
+def test_untraced_the_ring_of_spans_stays_empty_and_nothing_is_built(
+        untraced):
+    assert untraced["records"] == []
+    assert len(untraced["handed"]) > 40 and not any(untraced["handed"])
+    # one reused tally span a worker thread, never a fresh object a leaf
+    assert len({id(sp) for sp in untraced["handed"]}) == 1
+    # and off the worker's thread, with no round open: the shared no-op
+    assert untraced["tracer"].leaf("decode.dispatch") is _NULL_SPAN
+
+
+@pytest.mark.parametrize("session", ["untraced", "traced"])
+def test_fetch_waits_apart_only_where_the_leaves_record(
+        request, session):
+    """``_fetch`` untraced is ONE runtime barrier a call (``device_get``):
+    ``jax.block_until_ready`` is not called; under a session it is, once
+    an engine call."""
+    run = request.getfixturevalue(session)
+    if session == "untraced":
+        assert run["waits"] == 0
+        return
+    waited = [r for r in run["records"] if r["name"].endswith(".device_wait")]
+    assert run["waits"] == len(waited) > 0
+
+
+def test_under_a_session_the_rows_are_the_leaves_sums(traced):
+    """The recording leaves feed the same cells: a row's dispatch /
+    ``wait_fetch`` (= device_wait + fetch) / emit / admit are the sums of
+    its round's leaves."""
+    rows = traced["rounds"]
+    assert rows
+    sums = {}
+    for rec in traced["records"]:
+        if worker_leaf(rec["name"]):
+            call, _, part = rec["name"].rpartition(".")
+            cell = f"{call}.wait_fetch" \
+                if part in ("device_wait", "fetch") else rec["name"]
+            key = (rec["attrs"]["round"], cell)
+            sums[key] = sums.get(key, 0.0) + rec["dur"]
+    by_round = {r["round"]: r for r in rows}
+    assert {k[0] for k in sums} <= set(by_round)
+    for (rnd, name), total in sums.items():
+        assert by_round[rnd][name] == pytest.approx(total, abs=1e-6), name
+    for r in rows:
+        for c in LEAF_CELLS:
+            if r[c]:
+                assert (r["round"], c) in sums, c
+        assert sum(r[c] for c in LEAF_CELLS) + r["other"] \
+            == pytest.approx(r["wall"], abs=1e-6)
+    # the same account, traced and untraced, side by side
+    acc = round_account(rows)
+    assert acc["kinds"]["decode"]["parts"]["decode.wait_fetch"]["mean_ms"] \
+        >= 8.0      # the fixture's device step sleeps 8 ms
+
+
+def test_a_served_batchs_rounds_are_named_after_what_they_held(untraced):
+    rows = untraced["rounds"]
+    for r in rows:
+        held = [c for c in ROUND_KINDS[:-1] if r[c + ".dispatch"] > 0]
+        assert r["kind"] == (held[0] if held else "host")
+    # cold prefills, a partial hit, plain steps; and a full hit's COW,
+    # which names a round only where no prompt call shares it
+    assert {"prefill", "chunk", "decode"} <= {r["kind"] for r in rows}
+    cows = [r for r in rows if r["cow.dispatch"] > 0]
+    assert cows and all(r["kind"] in ("prefill", "chunk", "cow")
+                        for r in cows)
+
+
+@pytest.mark.parametrize("calls,kind", [
+    (("cow", "prefill", "decode"), "prefill"),   # a cow does not hide it
+    (("cow", "chunk", "decode"), "chunk"),
+    (("prefill", "chunk", "decode"), "prefill"),
+    (("decode", "verify"), "verify"),
+    (("cow", "decode"), "cow"),
+    (("decode",), "decode"),
+    ((), "host"),
+])
+def test_a_rounds_kind_is_the_first_call_it_held(calls, kind):
+    tr = Tracer(enabled=False)
+    tr.open_round(3, 11)
+    for call in calls:
+        with tr.leaf(call + ".dispatch"):
+            time.sleep(0.0002)
+    with tr.leaf("not.a.leaf.of.the.round"):    # stays in ``other``
+        time.sleep(0.0002)
+    tr.close_round(live=2, seated=1)
+    (row,) = tr.rounds()
+    assert (row["kind"], row["replica"], row["round"]) == (kind, 3, 11)
+    assert (row["live"], row["seated"]) == (2, 1)
+    assert row["other"] >= 0.0002
+    assert tr.leaf("decode.dispatch") is _NULL_SPAN     # closed: off again
+
+
+def test_a_dropped_round_writes_no_row_and_tracers_keep_apart():
+    a, b = Tracer(enabled=False), Tracer(enabled=False)
+    a.open_round(0, 1)
+    assert b.leaf("admit") is _NULL_SPAN    # another tracer's round
+    sp = a.leaf("decode.device_wait")       # handed out and dropped
+    assert not sp and sp is a.leaf("decode.fetch")
+    a.drop_round()
+    a.close_round()                         # nothing open: no-op
+    assert a.rounds() == [] and b.rounds() == []
+    assert a.leaf("admit") is _NULL_SPAN
+
+
+def test_the_threads_cpu_clock_is_read_by_the_tenth_of_a_second(monkeypatch):
+    """On the chip's host a read of the thread's CPU clock is a system call
+    of 25 us between other work and ticks in 10 ms steps (PERF.md section
+    6, PR 40): no leaf reads it, and a round reads it only where
+    ``CPU_EVERY_S`` have passed since the last reading — the row then
+    carries the CPU seconds of the span that ends with it; an idle wait's
+    CPU time is no round's."""
+    reads, now = [], [100.0]
+    clock = time.thread_time
+    monkeypatch.setattr(obs_trace.time, "thread_time",
+                        lambda: reads.append(1) or clock())
+    tr = Tracer(enabled=False, clock=lambda: now[0])
+
+    def a_round(i, seconds):
+        tr.open_round(0, i)
+        with tr.leaf("decode.dispatch"):
+            pass
+        with tr.leaf("decode.fetch"):
+            sum(range(20000))           # the worker's own CPU, in a wait
+            now[0] += seconds
+        tr.close_round()
+
+    for i in range(1, 11):
+        a_round(i, 0.03)                # ten rounds of 30 ms
+    assert len(reads) == 1 + 2          # the first round's start, 120, 240 ms
+    a_round(11, 0.25)                   # a long round always reads
+    assert len(reads) == 4
+    tr.open_round(0, 12)
+    tr.drop_round()                     # idle: the next round reads anew
+    a_round(12, 0.03)
+    assert len(reads) == 5
+    rows = tr.rounds()
+    assert [r["round"] for r in rows if r["cpu_span"]] == [4, 8, 11]
+    spans = [r["cpu_span"] for r in rows if r["cpu_span"]]
+    assert spans == pytest.approx([0.12, 0.12, 0.06 + 0.25])
+    assert all(r["cpu"] == 0.0 for r in rows if not r["cpu_span"])
+    assert sum(r["cpu"] for r in rows) > 0.0
+    # the spans that were read lie end to end: no round between is left out
+    assert sum(spans) == pytest.approx(sum(r["wall"] for r in rows[:11]))
+
+
+def test_a_build_inside_a_round_is_counted_where_jax_builds(engine):
+    """``retraces`` counts a jitted body's Python runs; ``BUILDS`` listens
+    to JAX's own ``backend_compile_duration``: a build lands on the
+    process's counters, on ``engine.metrics`` and on the round open on
+    its thread; a call of a warmed shape builds nothing."""
+    import numpy as np
+
+    tr = Tracer(enabled=False).follow_profiler()
+    m = engine.metrics
+    fn = jax.jit(lambda x: x * 3 + 40)
+    x = np.arange(7, dtype=np.float32)
+    tok = np.zeros((engine.slots,), np.int32)
+    pos = np.full((engine.slots,), 15, np.int32)
+    engine._decode_rows(tok, pos, live=0)       # a warmed shape, all dead
+    built, secs, traces = (m.executables_built, m.backend_compile_s,
+                           m.retraces.value)
+    tr.open_round(0, 1)
+    jax.block_until_ready(fn(x))
+    assert m.executables_built == built + 1 == BUILDS.executables_built
+    assert m.backend_compile_s > secs
+    jax.block_until_ready(fn(x))                # the same shape again
+    engine._decode_rows(tok, pos, live=0)       # the engine's, warmed
+    tr.close_round(live=0)
+    assert m.executables_built == built + 1 and m.retraces.value == traces
+    (row,) = tr.rounds()
+    assert row["builds"] == 1 and row["build_s"] > 0.0
+    assert 0.0 < row["build_s"] <= row["wall"]
+    snap = m.snapshot()["compile_cache"]
+    assert snap["executables_built"] == built + 1
+    assert snap["backend_compile_s"] > 0
+    # outside a round the process still counts; no row takes it
+    jax.block_until_ready(jax.jit(lambda x: x * 5 + 41)(x))
+    assert m.executables_built == built + 2 and len(tr.rounds()) == 1
+
+
+def test_the_ring_of_rounds_wraps_without_growing(monkeypatch):
+    monkeypatch.setattr(obs_trace, "ROUND_CAPACITY", 8)
+    tr = Tracer(enabled=False)
+    for i in range(1, 6):
+        tr.open_round(0, i)
+        tr.close_round()
+    assert [r["round"] for r in tr.rounds()] == [1, 2, 3, 4, 5]
+    size = len(tr._rounds)
+    assert size == 8 * len(ROUND_COLUMNS)
+    for i in range(6, 21):
+        tr.open_round(0, i)
+        with tr.leaf("decode.dispatch"):
+            pass
+        tr.close_round()
+    rows = tr.rounds()
+    assert [r["round"] for r in rows] == list(range(13, 21))
+    assert len(tr._rounds) == size
+    mid = rows[3]["t0"]
+    assert [r["round"] for r in tr.rounds(mid)] == [16, 17, 18, 19, 20]
+    assert [r["round"] for r in tr.rounds(None, mid)] == [13, 14, 15]
+
+
+def _row(i, wall, kind="decode", **cells):
+    row = dict.fromkeys(ROUND_COLUMNS, 0.0)
+    row.update(t0=float(i), wall=wall, replica=0, round=i, kind=kind,
+               live=4, seated=0, builds=0)
+    row.update(cells)
+    return row
+
+
+def test_round_account_by_hand():
+    """Percentiles, the worker's CPU share and ``host_off_cpu``, and the
+    longest rounds of made-up rows: 20 plain rounds of 10..29 ms and two
+    that held a prefill."""
+    rows = [_row(i, (10 + i) * 1e-3, **{
+        "decode.dispatch": 1e-3, "decode.wait_fetch": 6e-3,
+        "decode.emit": 1e-3, "other": (2 + i) * 1e-3}) for i in range(20)]
+    # the thread's clock was read at the ends of rounds 9, 19 and 20: the
+    # spans are those rounds' walls summed (145, 245 and 150 ms)
+    rows[9].update(cpu=29e-3, cpu_span=0.145)
+    rows[19].update(cpu=49e-3, cpu_span=0.245)
+    rows.append(_row(20, 0.150, "prefill", **{
+        "prefill.dispatch": 4e-3, "prefill.wait_fetch": 91e-3,
+        "prefill.emit": 2e-3, "decode.dispatch": 1e-3,
+        "decode.wait_fetch": 6e-3, "decode.emit": 1e-3, "admit": 3e-3,
+        "other": 42e-3, "cpu": 30e-3, "cpu_span": 0.150, "builds": 2,
+        "build_s": 0.040}))
+    rows.append(_row(21, 0.050, "prefill", **{
+        "prefill.dispatch": 4e-3, "prefill.wait_fetch": 30e-3,
+        "decode.dispatch": 1e-3, "decode.wait_fetch": 6e-3,
+        "other": 9e-3}))
+    acc = round_account(rows)
+    assert acc["rounds"] == 22 and acc["builds"] == 2
+    assert acc["wall_sec"] == 0.59
+    assert list(acc["kinds"]) == ["decode", "prefill"]   # by count
+    d = acc["kinds"]["decode"]
+    assert d["count"] == 20 and d["builds"] == 0
+    assert d["wall_ms"] == {"p50": 19.5, "p90": 27.1, "p99": 28.81,
+                            "max": 29.0, "mean": 19.5}
+    assert d["parts"]["decode.wait_fetch"] == {"mean_ms": 6.0, "p50_ms": 6.0}
+    assert d["parts"]["other"] == {"mean_ms": 11.5, "p50_ms": 11.5}
+    assert set(d["parts"]) == {"decode.dispatch", "decode.wait_fetch",
+                               "decode.emit", "other"}
+    p = acc["kinds"]["prefill"]
+    assert p["count"] == 2 and p["builds"] == 2 and p["build_ms"] == 40.0
+    assert p["parts"]["prefill.wait_fetch"] == {"mean_ms": 60.5,
+                                                "p50_ms": 60.5}
+    # on its CPU 108 of the 540 ms that were read = a fifth; of a mean
+    # round of 590 / 22 ms that is 5.3636; the waits are 22 x 6 + 121 ms,
+    # so host_off_cpu = (590 - 253) / 22 - 5.3636 = 9.9545 ms a round
+    assert acc["cpu"] == {"span_sec": 0.54, "share": 0.2,
+                          "ms_per_round": 5.3636,
+                          "host_off_cpu_ms_per_round": 9.9545}
+    longest = acc["longest"]
+    assert len(longest) == 16
+    assert [r["round"] for r in longest] == [20, 21] + list(range(19, 5, -1))
+    top = longest[0]
+    assert (top["kind"], top["wall_ms"], top["builds"], top["build_ms"]) \
+        == ("prefill", 150.0, 2, 40.0)
+    assert (top["cpu_ms"], top["cpu_span_ms"]) == (30.0, 150.0)
+    assert (longest[1]["cpu_ms"], longest[1]["cpu_span_ms"]) == (0.0, 0.0)
+    assert top["parts_ms"]["other"] == 42.0
+    assert sum(top["parts_ms"].values()) == pytest.approx(150.0)
+    assert "prefill.wait_fetch" in top["parts_ms"] \
+        and "chunk.dispatch" not in top["parts_ms"]
+    # rows that carry no reading of the CPU clock: no share is made up
+    assert round_account(rows[:9])["cpu"] == {"span_sec": 0.0}
+    assert round_account([]) == {
+        "rounds": 0, "wall_sec": 0, "builds": 0, "cpu": {"span_sec": 0},
+        "kinds": {}, "longest": []}
+    json.dumps(acc)
+    text = format_round_table({"0": acc})
+    assert "decode worker rounds, replica 0: 22 rounds" in text
+    assert "on its CPU 20.0% of 0.540s read = 5.364 ms a round; " \
+        "host_off_cpu 9.95" in text
+    assert "round 20 (prefill, 4 live, 0 seated) 150.000 ms" in text
+    assert "on its CPU 30.0 of the 150.0 ms that end with it" in text
+    assert "2 builds, 40.0 ms" in text
+
+
+def test_a_cut_by_time_reads_the_rings_tail(monkeypatch):
+    """``rounds(t0)`` finds its first row by bisection over the rounds'
+    ends — wrapped ring or not — and hands out the rows a walk over the
+    whole ring would; a worker's ``snapshot()`` accounts for its own last
+    ``ACCOUNT_SECONDS`` alone."""
+    monkeypatch.setattr(obs_trace, "ROUND_CAPACITY", 64)
+    now = [50.0]
+    tr = Tracer(enabled=False, clock=lambda: now[0])
+    for i in range(1, 151):             # replicas 0 and 1 in turn, 0.5 s each
+        tr.open_round(i % 2, i)
+        now[0] += 0.5
+        tr.close_round()
+    rows = tr.rounds()
+    assert [r["round"] for r in rows] == list(range(87, 151))
+    for cut in (0.0, rows[0]["t0"], rows[0]["t0"] + 0.1, rows[30]["t0"],
+                rows[-1]["t0"], rows[-1]["t0"] + 0.1, now[0] + 9.0):
+        assert tr.rounds(cut) == [r for r in rows if r["t0"] >= cut]
+        assert tr.rounds(cut, replica=1) == [
+            r for r in rows if r["t0"] >= cut and r["replica"] == 1]
+    acc = recent_round_account(tr, 1)
+    assert acc["window_sec"] == 30.0
+    assert acc["rounds"] == 30          # 60 rounds began in 30 s: half its
+    assert {r["replica"] for r in acc["longest"]} == {1}
+    now[0] += 29.4                      # only round 150 is left
+    assert recent_round_account(tr, 0)["rounds"] == 1
+
+
+def test_summarize_prints_the_round_table_from_a_flushed_file(
+        engine, tmp_path, monkeypatch):
+    """``--trace``: ``Tracer.flush`` writes the ring of rounds beside the
+    spans, and ``trace_tpu.py summarize`` prints the account an operator
+    reads in ``snapshot()["rounds"]`` under the host-phase table."""
+    # bert-tiny on the CPU serves the batch in under a tenth of a second
+    monkeypatch.setattr(obs_trace, "CPU_EVERY_S", 0.0)
+    tr = Tracer(str(tmp_path), enabled=True, process_index=0)
+    tr.follow_profiler()
+    old = engine.tracer
+    try:
+        serve(engine, tracer=tr, salt=11)
+    finally:
+        engine.tracer = old
+    path = tr.flush()
+    with open(path, encoding="utf-8") as f:
+        recs = [json.loads(ln) for ln in f if ln.strip()]
+    rows = round_rows(recs)
+    assert len(rows) == len(tr.rounds()) > 5
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "trace_tpu.py"), "summarize",
+         path], capture_output=True, text=True, check=True, cwd=ROOT)
+    lines = out.stdout.splitlines()
+    host = next(i for i, ln in enumerate(lines)
+                if ln.startswith("decode worker, replica 0"))
+    head = next(i for i, ln in enumerate(lines)
+                if ln.startswith("decode worker rounds, replica 0"))
+    assert head > host
+    assert lines[head].startswith(
+        f"decode worker rounds, replica 0: {len(rows)} rounds")
+    assert any("host_off_cpu" in ln for ln in lines)
+    assert any("longest rounds" in ln for ln in lines)
+    as_json = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "trace_tpu.py"), "summarize",
+         path, "--json"], capture_output=True, text=True, check=True,
+        cwd=ROOT)
+    assert json.loads(as_json.stdout)["decode_rounds"]["0"] \
+        == round_account(rows)
+    # a file without rounds (the recorded one predates them) prints none
+    old_file = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "trace_tpu.py"), "summarize",
+         RECORDED], capture_output=True, text=True, check=True, cwd=ROOT)
+    assert "decode worker rounds" not in old_file.stdout

@@ -392,6 +392,44 @@ def test_exporter_sick_source_does_not_blind_the_rest():
     assert "pdnlp_good_v 3" in prometheus_text(snaps)
 
 
+def test_decode_snapshot_carries_the_round_account_and_it_scrapes():
+    """``DecodeBatcher.snapshot()["rounds"]`` — the worker's own account of
+    its rounds, kept with no profiler and no ``--trace`` — is JSON-ready and
+    flattens into gauges through the exporter that is there."""
+    from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
+    from pdnlp_tpu.serve import DecodeBatcher, PagedDecodeEngine
+    from pdnlp_tpu.utils.config import Args
+
+    tok = WordPieceTokenizer(build_vocab(["天地人你我"], size=64))
+    args = Args(model="bert-tiny", decode_slots=2, decode_max_len=32,
+                max_new_tokens=4, kv_page_sz=16)
+    eng = PagedDecodeEngine(args, tokenizer=tok, mesh=None, buckets=(16,),
+                            prefill_rows=2, tracer=Tracer(enabled=False))
+    with DecodeBatcher(eng, replica=3) as b:
+        b.eos_id = -1
+        for s in [b.submit_ids([5, 6, 7 + i], max_new_tokens=4)
+                  for i in range(3)]:
+            s.result(timeout=120)
+    snap = b.snapshot()     # stopped: the last round's row is written
+    assert eng.tracer.records() == []          # nothing was traced
+    acc = json.loads(json.dumps(snap))["rounds"]
+    assert acc["rounds"] == len(eng.tracer.rounds()) >= 4
+    assert acc["builds"] >= 1                  # unwarmed: it compiled
+    assert set(acc["kinds"]) >= {"prefill", "decode"}
+    assert acc["longest"][0]["replica"] == 3
+    assert snap["engine"]["compile_cache"]["executables_built"] >= 1
+    text = prometheus_text({"decode": snap})
+    assert f'pdnlp_decode_rounds_rounds {acc["rounds"]}' in text
+    assert "pdnlp_decode_rounds_kinds_decode_wall_ms_p50 " in text
+    assert "pdnlp_decode_rounds_kinds_decode_parts_other_mean_ms " in text
+    # the worker's CPU clock is read every tenth of a second: the compiles
+    # of this unwarmed engine alone take longer
+    assert "pdnlp_decode_rounds_cpu_host_off_cpu_ms_per_round " in text
+    assert acc["window_sec"] == 30.0            # the last 30 s of rounds
+    assert 'pdnlp_decode_rounds_longest_wall_ms{longest="0"} ' in text
+    assert "pdnlp_decode_engine_compile_cache_executables_built " in text
+
+
 # ------------------------------------------------------------ HBM accounting
 
 class _FakeDevice:

@@ -10,7 +10,10 @@ Subcommands:
   --decode --trace true`` file additionally prints the decode worker's
   host-phase table — per decode step, each leaf span's share (``admit``,
   ``<call>.dispatch`` / ``.device_wait`` / ``.fetch`` / ``.emit``) and the
-  host-exposed share of a round;
+  host-exposed share of a round — and under it the worker's round account
+  (``obs.phases.round_account``: per kind of round its wall percentiles
+  and the mean / p50 of every part, ``other``, ``cpu``, ``host_off_cpu``,
+  the executables built inside, and the sixteen longest rounds whole);
 - ``diff <base> <candidate>`` — per-phase mean deltas between two traces;
   exits **1** when any phase's mean grew beyond ``--threshold`` (default
   0.20 = 20%) — the CI guard: run a traced smoke on main and on a PR, diff
@@ -58,7 +61,8 @@ from pdnlp_tpu.obs.export import (
 )
 from pdnlp_tpu.obs.merge import merge_traces
 from pdnlp_tpu.obs.phases import (
-    StepBreakdown, decode_host_phases, format_decode_table, format_table,
+    StepBreakdown, decode_host_phases, format_decode_table,
+    format_round_table, format_table, round_account, round_rows,
 )
 from pdnlp_tpu.obs.regress import diff_breakdowns
 from pdnlp_tpu.obs.request import chain_issues, format_chain, hop_chain
@@ -86,14 +90,22 @@ def cmd_summarize(ns) -> int:
     records = load_records(ns.trace)
     summary = StepBreakdown.from_records(records).summary()
     worker = decode_host_phases(records)
+    rows = round_rows(records)
+    rounds = {str(rep): round_account([r for r in rows
+                                       if r.get("replica", 0) == rep])
+              for rep in sorted({r.get("replica", 0) for r in rows})}
     if ns.json:
         if worker:
             summary["decode_worker"] = worker
+        if rounds:
+            summary["decode_rounds"] = rounds
         print(json.dumps(summary, indent=2))
     else:
         print(format_table(summary))
         if worker:
             print(format_decode_table(worker))
+        if rounds:
+            print(format_round_table(rounds))
     return 0
 
 
