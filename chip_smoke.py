@@ -2,10 +2,12 @@
 """The standing proof that the trainer and the server start on a TPU chip.
 
     python chip_smoke.py            # one chip: device, train, train-packed,
-                                    # serve, decode, decode-latent,
+                                    # serve, decode-kernel, decode,
+                                    # decode-latent,
                                     # decode-latent-mhc, decode-hybrid
     python chip_smoke.py --chips 4  # four chips: device, mesh-train (dp and
-                                    # zero against one device), replicas
+                                    # zero against one device), replicas,
+                                    # decode-mesh
 
 One process, no children that need the chip.  Every phase drives the entry
 point a user would call (``train.run.build_parallel_trainer`` — the path
@@ -656,6 +658,81 @@ def phase_decode(ctx) -> dict:
                             "stream_owners", "index_entries")}}
 
 
+#: the paged kernel against float32 (outputs are O(1); the kernel rounds its
+#: probabilities and its output to bf16, 2^-9 each) — set from the dtype
+PAGED_ATOL = 2e-2
+
+
+def phase_decode_kernel(ctx) -> dict:
+    """``ops/paged.py`` against the gathered form it replaces
+    (``jnp.take`` of the rung + ``decoder._attend_folded``) and against the
+    same mathematics in float32, at the causal cells' sizes: both row rungs,
+    the page rungs' ends, lengths at a page's and a block's edges, dead rows,
+    a sentinel tail.  Interpret mode forgives what Mosaic does not (an out-of-bounds
+    copy, a wait that no copy answers), so this is the check that counts."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pdnlp_tpu.models import decoder
+    from pdnlp_tpu.ops import paged
+    from pdnlp_tpu.ops.attention import NEG_INF
+
+    N, D, ps = (2, 64, 16) if ctx.rehearse else (12, 64, 16)
+    H, L = N * D, 2
+    cases = [(8, 2)] if ctx.rehearse else [(192, 8), (192, 32), (16, 8),
+                                           (16, 32)]
+    rng = np.random.default_rng(ctx.seed)
+    worst = {"kernel_vs_f32": 0.0, "gather_vs_f32": 0.0,
+             "kernel_vs_gather": 0.0}
+    for B, MP in cases:
+        P = B * MP + 8                       # a layer's pages
+        pools = [jnp.asarray(rng.standard_normal((L * P, ps, H)),
+                             jnp.bfloat16) for _ in range(2)]
+        q = jnp.asarray(rng.standard_normal((B, 1, N, D)), jnp.bfloat16)
+        extent = MP * ps
+        lengths = rng.integers(1, extent + 1, B).astype(np.int32)
+        lengths[:8] = [1, ps, ps + 1, extent, 0, extent - 1, 0,
+                       min(paged.BLOCK + 1, extent)]
+        # the second layer's pages, shuffled; past a row's own the sentinel
+        # (the next layer's first page, or the pool's end: never read)
+        table = rng.permutation(P)[:B * MP].reshape(B, MP).astype(np.int32)
+        table = np.where(np.arange(MP)[None] * ps < lengths[:, None],
+                         table, P) + P
+        ids, lens = jnp.asarray(table), jnp.asarray(lengths)
+
+        def gathered(q, pk, pv, dtype):
+            got = [jnp.take(p, ids, axis=0, mode="clip").reshape(
+                B, extent, H).astype(dtype) for p in (pk, pv)]
+            bias = jnp.where(jnp.arange(extent)[None, None, None]
+                             < lens[:, None, None, None], 0.0, NEG_INF)
+            return decoder._attend_folded(q.astype(dtype), *got, bias)
+
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(functools.partial(
+                gathered, dtype=jnp.float32))(q, *pools))
+        old = np.asarray(jax.jit(functools.partial(
+            gathered, dtype=jnp.bfloat16))(q, *pools), np.float32)
+        new = np.asarray(jax.jit(decoder._attend_paged)(
+            q, *pools, ids, lens), np.float32)
+        live = lengths > 0
+        check(not new[~live].any(), f"{B}x{MP}: a dead row's output is not "
+                                    "zeros")
+        for name, a, b in (("kernel_vs_f32", new, want),
+                           ("gather_vs_f32", old, want),
+                           ("kernel_vs_gather", new, old)):
+            worst[name] = max(worst[name],
+                              float(np.abs(a[live] - b[live]).max()))
+        check(np.isfinite(new).all(), f"{B}x{MP}: not finite")
+    check(worst["kernel_vs_f32"] <= PAGED_ATOL,
+          f"paged kernel against float32: {worst} > {PAGED_ATOL}")
+    check(worst["kernel_vs_f32"] <= worst["gather_vs_f32"] + 1e-3,
+          f"the kernel lost precision against the gathered form: {worst}")
+    return {"cases": [f"{B} rows x {MP} pages" for B, MP in cases],
+            "max_abs_diff": {k: round(v, 5) for k, v in worst.items()},
+            "atol": PAGED_ATOL}
+
+
 #: the latent family's presets a phase builds: (on the chip, in a rehearsal)
 LATENT = ("ax-k1-ep16-share-l2", "ax-k1-share-tiny")
 LATENT_MHC = ("xing4-29b-ep1-stage-l2", "xing4-stage-tiny")
@@ -921,6 +998,66 @@ def phase_replicas(ctx) -> dict:
             "retraces_post_warmup": retraces}
 
 
+def phase_decode_mesh(ctx) -> dict:
+    """``serve_tpu.py --decode`` as it starts on a host of several chips
+    with its default ``--replicas 1``: ONE engine over a mesh of all of
+    them, every program replicated.  Mosaic refuses a kernel in a ``jit``
+    over more than one device, at lowering — which no CPU test reaches — so
+    the BERT family's decode step must keep its gathered form there
+    (``decoder.attend_form``) and say so in its span; with a replica a chip
+    (``--replicas <chips>``) it takes the kernel again.  The two generate
+    from the same weights; their tokens are compared, not held equal (the
+    kernel's scores accumulate in float32, the gathered form's round to
+    bfloat16, and a near tie may fall the other way)."""
+    import jax
+    import serve_tpu
+
+    n = ctx.chips
+    prompts = request_lines(ctx, 4, 8, 24)
+    max_new = 16
+    out = {"prompts": len(prompts), "max_new_tokens": max_new}
+    gens = {}
+    from pdnlp_tpu.obs.trace import get_tracer
+
+    for replicas, form in ((1, "gather"), (n, "kernel")):
+        get_tracer().clear()    # one tracer a process: the leg before's spans
+        with captured(serve_tpu, "build_decode_pool") as pools:
+            text = run_cli(serve_tpu.main, base_argv(
+                ctx, "--decode", "--checkpoint", ctx.checkpoint,
+                "--decode_slots", "4", "--buckets", "32",
+                "--replicas", str(replicas), "--trace", "true",
+                "--max_new_tokens", str(max_new)),
+                "\n".join(prompts) + "\n")
+        rows = [l.split("\t") for l in text.splitlines() if l.strip()]
+        check(not [r for r in rows if r[1] == "ERROR"],
+              f"{replicas} replica(s): stream errors: {text}")
+        toks = {i: [r[2] for r in rows if r[1] == "tok" and int(r[0]) == i]
+                for i in range(len(prompts))}
+        check(all(len(t) == max_new for t in toks.values()),
+              f"{replicas} replica(s): tokens streamed "
+              f"{ {i: len(t) for i, t in toks.items()} }")
+        engine = pools[0].engine(0)
+        devs = {d.id for leaf in jax.tree_util.tree_leaves(engine.params)
+                for d in leaf.devices()}
+        check(len(devs) == n // replicas,
+              f"{replicas} replica(s): engine 0 spans {sorted(devs)}")
+        forms = [r["attrs"].get("attend") for r in engine.tracer.records()
+                 if r["name"] == "decode.dispatch"]
+        check(forms and set(forms) == {form},
+              f"{replicas} replica(s): decode steps took {set(forms)}, "
+              f"expected {form}")
+        gens[form] = toks
+        out[f"replicas_{replicas}"] = {
+            "devices_an_engine": len(devs), "attend": form,
+            "decode_steps": len(forms)}
+    same = [a == b for i in gens["kernel"]
+            for a, b in zip(gens["kernel"][i], gens["gather"][i])]
+    out["tokens_agree"] = round(sum(same) / len(same), 4)
+    check(out["tokens_agree"] >= 0.5,
+          f"the two forms disagree on most tokens: {out['tokens_agree']}")
+    return out
+
+
 # --------------------------------------------------------------------- main
 
 
@@ -968,10 +1105,12 @@ def main(argv=None) -> int:
     if ctx.chips == 4:
         run_phase(ctx, "mesh-train", phase_mesh_train)
         run_phase(ctx, "replicas", phase_replicas)
+        run_phase(ctx, "decode-mesh", phase_decode_mesh)
     else:
         run_phase(ctx, "train", phase_train)
         run_phase(ctx, "train-packed", phase_train_packed)
         run_phase(ctx, "serve", phase_serve)
+        run_phase(ctx, "decode-kernel", phase_decode_kernel)
         run_phase(ctx, "decode", phase_decode)
         run_phase(ctx, "decode-latent", phase_decode_latent)
         run_phase(ctx, "decode-latent-mhc", functools.partial(

@@ -60,8 +60,9 @@ import numpy as np
 
 from pdnlp_tpu.models import bert
 from pdnlp_tpu.models.config import BertConfig
+from pdnlp_tpu.ops import paged
 from pdnlp_tpu.ops.attention import (NEG_INF, dot_product_attention,
-                                     mask_bias)
+                                     mask_bias, pin_auto_for_mesh)
 
 Params = Dict[str, Any]
 
@@ -362,6 +363,44 @@ def _attend_folded(q: jax.Array,     # [B, T, N, D]
     return jnp.einsum("btnnd->btnd", out.reshape(B, T, N, N, D))
 
 
+def _attend_paged(q: jax.Array,         # [B, 1, N, D]
+                  pool_k: jax.Array,    # [L * P, page_sz, H]
+                  pool_v: jax.Array,
+                  page_ids: jax.Array,  # [B, MP] rows of the pools
+                  lengths: jax.Array    # [B] keys a row sees (0: dead)
+                  ) -> jax.Array:
+    """:func:`_attend_folded` at one query position over keys that are NOT
+    gathered: the same expanded query, its rows padded to the dtype's
+    sublane tile, handed to the kernel that walks each row's live pages
+    (``ops/paged.paged_decode``: scores accumulate in float32, softmax in
+    float32, ``probs @ V`` in the compute dtype).  A dead row's output is
+    zeros."""
+    B, _, N, D = q.shape
+    tile = 32 // q.dtype.itemsize
+    Np = -(-N // tile) * tile
+    eye = jnp.eye(Np, N, dtype=q.dtype)
+    qe = (q[:, 0, None] * eye[:, :, None]).reshape(B, Np, N * D)
+    out = paged.paged_decode(qe, pool_k, pool_v, page_ids, lengths,
+                             scale=D ** -0.5)
+    return jnp.einsum("btnnd->btnd", out[:, :N].reshape(B, 1, N, N, D))
+
+
+def attend_form(T: int, int8: bool, mesh=None) -> str:
+    """How :func:`paged_attend_layers` reads the cache at ``T`` query
+    positions a row — the ONE statement of the choice: the body branches on
+    it and the engine's ``decode.dispatch`` span reports it.  ``"kernel"``:
+    the decode step (T = 1) over a float pool walks each row's own live
+    pages (``ops/paged.py``); ``"gather"``: every launched row's whole page
+    rung is gathered — a window of several positions, an int8 pool (its
+    pages dequantize at read), and a step jitted over a ``mesh`` of more
+    than one device, where a Mosaic kernel cannot stand
+    (``ops.attention.pin_auto_for_mesh``, which says so once)."""
+    if T != 1 or int8:
+        return "gather"
+    return ("kernel" if pin_auto_for_mesh(
+        "auto", mesh, "paged decode attention") == "auto" else "gather")
+
+
 def paged_attend_layers(params: Params, head: Params, cfg: BertConfig,
                         tokens: jax.Array,      # [B, T] int32
                         pages_k: jax.Array,     # [L, P, page_sz, H]
@@ -372,7 +411,7 @@ def paged_attend_layers(params: Params, head: Params, cfg: BertConfig,
                         *, logits_at: str = "last",
                         kv_scales: Optional[Tuple[jax.Array,
                                                   jax.Array]] = None,
-                        dtype=jnp.float32, unroll=True
+                        dtype=jnp.float32, unroll=True, mesh=None
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The ONE body of every paged program: ``tokens[b, t]`` sits at
     absolute position ``start[b] + t``, writes its K/V through the page
@@ -391,7 +430,8 @@ def paged_attend_layers(params: Params, head: Params, cfg: BertConfig,
     ``kv_scales`` = (k_scale, v_scale) ``[L, N, D]`` switches the pool to
     int8: new rows quantize before the write and the gathered pages
     dequantize at read, so the current token's K/V round-trips through the
-    cache like everyone else's."""
+    cache like everyone else's.  ``mesh``: what the caller's ``jit`` runs
+    over, where that is more than one device (:func:`attend_form`)."""
     _check_dense_trunk(params["layers"])
     if logits_at not in ("last", "all"):
         raise ValueError(f"logits_at must be 'last' or 'all', "
@@ -448,13 +488,26 @@ def paged_attend_layers(params: Params, head: Params, cfg: BertConfig,
             got = dequantize_kv(got, scale.reshape(H), dtype)
         return got if fold else got.reshape(B, extent, N, D)
 
+    if attend_form(T, kv_scales is not None, mesh) == "kernel":
+        # no rung is gathered: a kernel walks each row's own live pages
+        # where they lie (``ops/paged.py``).  A live row sees ``pos + 1``
+        # keys, a dead one (it writes nothing: ``wrows``' test) none
+        lengths = jnp.where(real & (phys < P), positions + 1, 0)[:, 0]
+
+        def read(q, pk, pv, idx, *_):
+            return _attend_paged(q, pk.reshape(L * P, ps, H),
+                                 pv.reshape(L * P, ps, H), idx, lengths)
+    else:
+        def read(q, pk, pv, idx, ks_l, vs_l):
+            return attend(q, get(pk, idx, ks_l), get(pv, idx, vs_l), bias)
+
     def layer(carry, scanned):
         x, pk, pv = carry
         lp, rows, idx, ks_l, vs_l = scanned
         q, k_new, v_new = _qkv(x, lp, cfg, dtype)           # [B, T, N, D]
         pk = put(pk, rows, k_new, ks_l)
         pv = put(pv, rows, v_new, vs_l)
-        attn = attend(q, get(pk, idx, ks_l), get(pv, idx, vs_l), bias)
+        attn = read(q, pk, pv, idx, ks_l, vs_l)
         return (_finish_layer(x, lp, cfg, attn, dtype), pk, pv), None
 
     xs = (params["layers"], wrows, rpages) + (kv_scales or (None, None))

@@ -41,7 +41,8 @@ class Family:
     #:       prompt's final state: one [B, ...] per state array)
     prefill: Callable
     #: (params, head, cfg, tokens, pools, states, table, start, nreal,
-    #:  logits_at, kv_scales, dtype) -> (logits, aux, pools, states)
+    #:  logits_at, kv_scales, dtype, mesh: what the program is jitted over)
+    #:   -> (logits, aux, pools, states)
     attend: Callable
     #: cfg -> how many layers a cached position lives in (the pools' L)
     pool_layers: Callable = lambda cfg: cfg.num_layers
@@ -57,6 +58,10 @@ class Family:
     handoff: bool = True          # exporting / importing a stream's pages
     prefix: bool = True           # sharing a prompt's prefix pages (and the
                                   # suffix chunk that follows a hit)
+    #: (T, int8 pool?, mesh) -> how ``attend`` reads the cache: "kernel"
+    #: (each row's own live pages, ``decoder.attend_form``) or "gather"
+    #: (the page rung of every launched row)
+    attend_form: Callable = lambda T, int8, mesh: "gather"
 
     def refuse(self, what: str, use: str) -> None:
         raise ValueError(
@@ -70,10 +75,10 @@ def _bert_prefill(params, head, cfg, ids, mask, last_pos, dtype):
 
 
 def _bert_attend(params, head, cfg, tokens, pools, states, table, start,
-                 nreal, logits_at, kv_scales, dtype):
+                 nreal, logits_at, kv_scales, dtype, mesh=None):
     logits, pk, pv = decoder.paged_attend_layers(
         params, head, cfg, tokens, pools[0], pools[1], table, start, nreal,
-        logits_at=logits_at, kv_scales=kv_scales, dtype=dtype)
+        logits_at=logits_at, kv_scales=kv_scales, dtype=dtype, mesh=mesh)
     return logits, None, (pk, pv), states
 
 
@@ -84,7 +89,7 @@ def _latent_prefill(params, head, cfg, ids, mask, last_pos, dtype):
 
 
 def _latent_attend(params, head, cfg, tokens, pools, states, table, start,
-                   nreal, logits_at, kv_scales, dtype):
+                   nreal, logits_at, kv_scales, dtype, mesh=None):
     logits, load, pool = latent_moe.paged_attend(
         params, head, cfg, tokens, pools[0], table, start, nreal,
         dtype=dtype)
@@ -97,7 +102,7 @@ def _hybrid_prefill(params, head, cfg, ids, mask, last_pos, dtype):
 
 
 def _hybrid_attend(params, head, cfg, tokens, pools, states, table, start,
-                   nreal, logits_at, kv_scales, dtype):
+                   nreal, logits_at, kv_scales, dtype, mesh=None):
     if tokens.shape[1] != 1:
         # a window of several positions against a cache exists only after a
         # prefix hit or in the speculative pair: both refused at construction
@@ -112,7 +117,8 @@ FAMILIES = {
         name="bert", init_params=bert.init_params,
         init_head=decoder.init_lm_head,
         pool_widths=lambda cfg: (cfg.hidden_size, cfg.hidden_size),
-        prefill=_bert_prefill, attend=_bert_attend),
+        prefill=_bert_prefill, attend=_bert_attend,
+        attend_form=decoder.attend_form),
     "latent_moe": Family(
         name="latent_moe", init_params=latent_moe.init_params,
         init_head=latent_moe.init_head,

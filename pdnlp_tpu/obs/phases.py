@@ -567,6 +567,9 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     ``decode_row_rungs`` = the share of decode steps launched at each row
     extent (``rows`` of ``decode.dispatch``: the engine's row rung, chosen
     by the highest attached slot), keyed by the extent.
+    ``decode_attend`` = the share of decode steps whose attention walked
+    each row's live pages (``"kernel"``, ``ops/paged.py``) or gathered the
+    page rung (``"gather"``), from ``decode.dispatch``'s ``attend``.
     ``expert_load`` (a family with sparse experts; from ``decode.fetch``'s
     ``expert_assignments`` / ``expert_rows_computed`` / ``expert_tokens_max``
     / ``experts_idle``): assignments to the held experts a decode step, the
@@ -589,6 +592,7 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
     experts: Dict[object, List[int]] = {}
     fetched: Dict[object, List[int]] = {}   # rep -> [decode fetches, bytes]
     rungs: Dict[object, Dict[int, int]] = {}   # rep -> rows launched -> steps
+    forms: Dict[object, Dict[str, int]] = {}   # rep -> attend -> steps
     token_bytes: Dict[object, int] = {}
     state: Dict[object, List[int]] = {}     # rep -> [decode steps, bytes]
     edges: Dict[object, List[float]] = {}
@@ -621,6 +625,9 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
         if name == "decode.dispatch" and "rows" in attrs:
             acc, rows = rungs.setdefault(rep, {}), int(attrs["rows"])
             acc[rows] = acc.get(rows, 0) + 1
+        if name == "decode.dispatch" and "attend" in attrs:
+            acc = forms.setdefault(rep, {})
+            acc[attrs["attend"]] = acc.get(attrs["attend"], 0) + 1
         if name == "decode.fetch" and "bytes" in attrs:
             acc = fetched.setdefault(rep, [0, 0])
             acc[0] += 1
@@ -659,6 +666,10 @@ def decode_host_phases(records: Sequence[Dict]) -> Dict:
             amplification["decode_row_rungs"] = {
                 str(rows): round(n / steps, 4)
                 for rows, n in sorted(rungs[rep].items())}
+        if rep in forms:
+            amplification["decode_attend"] = {
+                form: round(n / steps, 4)
+                for form, n in sorted(forms[rep].items())}
         if rep in token_bytes:
             amplification["cache_bytes_per_token"] = token_bytes[rep]
         if rep in fetched:
@@ -717,6 +728,10 @@ def format_decode_table(by_replica: Dict) -> str:
             lines.append("  decode steps by rows launched: " + ", ".join(
                 f"{share:.1%} at {rows}"
                 for rows, share in b["decode_row_rungs"].items()))
+        if "decode_attend" in b:
+            lines.append("  decode steps by attention: " + ", ".join(
+                f"{share:.1%} {form}"
+                for form, share in b["decode_attend"].items()))
         if "state_bytes_per_step" in b:
             lines.append(
                 f"  recurrent state a decode step reads and writes: "

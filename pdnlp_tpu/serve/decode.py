@@ -299,7 +299,10 @@ class PagedDecodeEngine(InferenceEngine):
     Pages replicate on a mesh (no ``NamedSharding`` axis): the page ->
     stream mapping is dynamic, so there is no static batch axis to shard;
     several chips are served by replicas, one engine a chip
-    (:class:`DecodeRouter`)."""
+    (:class:`DecodeRouter`).  An engine handed a mesh of several devices
+    runs every program replicated over them, and a family's step that
+    would take a Mosaic kernel keeps its XLA form there
+    (``decoder.attend_form``)."""
 
     #: fixed copy-on-write batch rows — one compiled ``copy_pool``
     #: program per engine; unused rows ride the OOB sentinel
@@ -446,7 +449,7 @@ class PagedDecodeEngine(InferenceEngine):
         # both donated, then the int8 scale tables — none for a float cache;
         # what a family counts per launch rides back as ``aux``
         metrics_ref = self.metrics
-        dtype = self.dtype
+        dtype, mesh = self.dtype, self.mesh
 
         def _prefill_fn(params, head, ids, mask, last_pos):
             metrics_ref.retraces.inc()  # body runs only while tracing
@@ -468,7 +471,7 @@ class PagedDecodeEngine(InferenceEngine):
             metrics_ref.retraces.inc()
             logits, aux, pools, states = family.attend(
                 params, head, cfg, tokens, pools, states, table, pos, None,
-                "last", scales or None, dtype)
+                "last", scales or None, dtype, mesh)
             return logits, greedy_ids(logits), aux, pools, states
 
         def _pchunk_fn(params, head, pools, tokens, table, start, nreal,
@@ -476,7 +479,7 @@ class PagedDecodeEngine(InferenceEngine):
             metrics_ref.retraces.inc()
             logits, aux, pools, states = family.attend(
                 params, head, cfg, tokens, pools, states, table, start,
-                nreal, "last", scales or None, dtype)
+                nreal, "last", scales or None, dtype, mesh)
             return logits, greedy_ids(logits), aux, pools, states
 
         def _pverify_fn(params, head, pools, tokens, table, start, nreal,
@@ -484,7 +487,7 @@ class PagedDecodeEngine(InferenceEngine):
             metrics_ref.retraces.inc()
             return family.attend(params, head, cfg, tokens, pools, states,
                                  table, start, nreal, "all", scales or None,
-                                 dtype)
+                                 dtype, mesh)
 
         def _pcow_fn(pools, src, dst):
             metrics_ref.retraces.inc()
@@ -1265,10 +1268,17 @@ class PagedDecodeEngine(InferenceEngine):
             phase = self._seen(("decode", r, rung), "decode")
             if sp:
                 alive = table[:, 0] < self.n_pages
+                # what attention reads: the seated rows' own pages where
+                # the step walks them (the program's own choice, asked of
+                # where it is made), else the rung of every row
+                form = self.family.attend_form(1, self.kv_int8, self.mesh)
+                pages = (int((p[alive] // self.page_sz + 1).sum())
+                         if form == "kernel" else r * rung)
                 sp.set(phase=phase, rows=r, live=int(live),
                        decode=True, paged=True,
                        pages_live=self.allocator.used_pages,
-                       kv_positions_read=r * rung * self.page_sz,
+                       attend=form,
+                       kv_positions_read=pages * self.page_sz,
                        kv_positions_live=int((p[alive] + 1).sum()),
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label, kv=self._kv_label(),

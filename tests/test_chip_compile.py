@@ -216,6 +216,133 @@ def test_paged_programs_leave_the_pool_where_it_lies(program, one_chip):
     assert pools and set(pools) == {"3,2,1,0"}
 
 
+# ------------------------------------ the BERT family's decode step kernel
+
+@pytest.mark.parametrize("rows,pages", [(192, 8), (192, 32), (16, 8),
+                                        (16, 32)])
+def test_the_paged_decode_kernel_compiles_inside_the_decode_program(
+        rows, pages, one_chip, monkeypatch):
+    """``ops/paged.py`` alone, and the BERT family's whole ``_pdecode_fn`` at
+    bert-base's widths and depth — the causal cells' row rungs and the page
+    ladder's ends, the pools donated: Mosaic takes the kernel (the copies by
+    page, the loop whose bound is data), the program calls it once a layer,
+    and the pools enter it as they lie — aliased through, no ``copy`` of a
+    pool-shaped operand (one would be 2.25 GiB of the cell's pool)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from pdnlp_tpu.models import families
+    from pdnlp_tpu.ops import paged
+    from pdnlp_tpu.serve.decode import greedy_ids
+
+    monkeypatch.setattr(paged, "_interpret", lambda: False)
+    cfg = get_config("bert-base", num_labels=C, dropout=0.0,
+                     attn_dropout=0.0)
+    family = families.of(cfg)
+    key, bf, i32 = jax.random.key(0), jnp.bfloat16, jnp.int32
+    L, P, ps = cfg.num_layers, 4096, 16
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, head = jax.tree_util.tree_map(
+        lambda x: S(x.shape, x.dtype), jax.eval_shape(
+            lambda: (family.init_params(key, cfg),
+                     family.init_head(key, cfg))))
+    pool = S((L, P, ps, H), bf)
+
+    def _pdecode_fn(params, head, pools, tokens, table, pos):
+        logits, aux, pools, _ = family.attend(
+            params, head, cfg, tokens, pools, (), table, pos, None, "last",
+            None, bf)
+        return greedy_ids(logits), aux, pools
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        alone = paged.paged_decode.lower(
+            S((rows, 16, H), bf), S((L * P, ps, H), bf),
+            S((L * P, ps, H), bf), S((rows, pages), i32), S((rows,), i32),
+            scale=D ** -0.5).compile()
+        compiled = jax.jit(_pdecode_fn, donate_argnums=(2,)).lower(
+            params, head, (pool, pool), S((rows, 1), i32),
+            S((rows, pages), i32), S((rows,), i32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    assert alone.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == L
+    assert text.count("paged_decode/pallas_call") == L
+    m = compiled.memory_analysis()
+    pool_bytes = L * P * ps * H * 2
+    assert m.alias_size_in_bytes >= 2 * pool_bytes
+    assert m.temp_size_in_bytes < pool_bytes // 4
+    assert re.search(rf"bf16\[{L},{P},{ps},{H}\]", text)
+    assert not re.search(
+        rf"bf16\[({L},{P}|{L * P}),{ps},{H}\]\S* copy\(", text)
+
+
+def test_a_decode_step_over_four_chips_keeps_the_gathered_form(monkeypatch):
+    """An engine handed a mesh of several chips (``serve_tpu.py`` with fewer
+    replicas than chips) runs its programs replicated over them, and Mosaic
+    refuses a kernel in a ``jit`` over more than one device — at lowering,
+    which interpret mode never reaches.  The BERT family's ``_pdecode_fn``
+    with everything replicated over the described 2x2: told the mesh, as
+    the engine tells it, it compiles and holds no kernel; not told, it is
+    refused in Mosaic's own words."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from pdnlp_tpu.models import families
+    from pdnlp_tpu.ops import paged
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    monkeypatch.setattr(paged, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    everywhere = NamedSharding(mesh, PartitionSpec())
+    cfg = get_config("bert-base", num_labels=C, dropout=0.0,
+                     attn_dropout=0.0, num_layers=2)
+    family = families.of(cfg)
+    key, bf, i32 = jax.random.key(0), jnp.bfloat16, jnp.int32
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=everywhere)
+
+    params, head = jax.tree_util.tree_map(
+        lambda x: S(x.shape, x.dtype), jax.eval_shape(
+            lambda: (family.init_params(key, cfg),
+                     family.init_head(key, cfg))))
+    pool = S((cfg.num_layers, 1024, 16, H), bf)
+    shapes = (params, head, (pool, pool), S((16, 1), i32), S((16, 8), i32),
+              S((16,), i32))
+
+    def _pdecode_fn(told):
+        def fn(params, head, pools, tokens, table, pos):
+            logits, _, pools, _ = family.attend(
+                params, head, cfg, tokens, pools, (), table, pos, None,
+                "last", None, bf, told)
+            return logits, pools
+        return jax.jit(fn, donate_argnums=(2,))
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = _pdecode_fn(mesh).lower(*shapes).compile()
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            _pdecode_fn(None).lower(*shapes)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
 # ------------------------------------- the four-stream family's decode step
 
 def test_the_four_stream_decode_step_keeps_its_temporaries(one_chip,

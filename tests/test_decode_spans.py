@@ -438,18 +438,26 @@ def test_a_submitter_never_waits_on_the_workers_device_wait(
 def test_dispatch_leaves_count_what_attention_covers_and_what_is_live(
         traced, engine):
     """``decode.dispatch`` and ``chunk.dispatch`` carry the cached positions
-    the call's attention covers (rows x extent) beside the positions that
-    are live; ``summarize`` prints their ratio per decode step."""
+    the call's attention covers beside the positions that are live — a
+    decode step of this family walks the seated rows' own pages
+    (``attend="kernel"``): their live positions, each row's rounded up to a
+    page; a chunk covers rows x the whole extent —; ``summarize`` prints
+    their ratio per decode step and the share of steps in each form."""
     recs = traced["records"]
     dec = [r["attrs"] for r in recs if r["name"] == "decode.dispatch"]
     chk = [r["attrs"] for r in recs if r["name"] == "chunk.dispatch"]
     assert dec and chk
-    extents = {r * engine.page_sz * engine.slots for r in engine.decode_rungs}
+    ps = engine.page_sz
     for a in dec:
-        assert a["kv_positions_read"] in extents
+        assert a["attend"] == "kernel"
+        assert a["kv_positions_read"] % ps == 0
         assert 0 < a["kv_positions_live"] <= a["kv_positions_read"]
-    # 19-token prompts and 6 new tokens stay on the second rung of four
-    assert {a["kv_positions_read"] for a in dec} == {sorted(extents)[1]}
+        assert a["kv_positions_read"] - a["kv_positions_live"] \
+            <= engine.slots * (ps - 1)
+    # 19-token prompts and 6 new tokens: two pages a seated row, under the
+    # second rung of four that every launched row was read at before
+    assert {a["kv_positions_read"] for a in dec} <= {
+        2 * ps * n for n in range(1, engine.slots + 1)}
     for a in chk:
         assert a["kv_positions_read"] == engine.prefill_rows * engine.max_len
         assert 0 < a["kv_positions_live"] <= a["kv_positions_read"]
@@ -460,8 +468,54 @@ def test_dispatch_leaves_count_what_attention_covers_and_what_is_live(
         / sum(a["kv_positions_live"] for a in dec), abs=1e-3)
     assert 1.0 <= amp < engine.max_len
     assert worker["chunk_kv_read_amplification"] >= 1.0
+    assert worker["decode_attend"] == {"kernel": 1.0}
     text = format_decode_table({"0": worker})
     assert f"KV read amplification per decode step: {amp:.3f}" in text
+    assert "decode steps by attention: 100.0% kernel" in text
+
+
+@pytest.mark.parametrize("kind,attend", [("float", "kernel"),
+                                         ("int8", "gather"),
+                                         ("mesh", "gather")])
+def test_the_dispatch_leaf_says_which_form_of_attention_the_step_took(
+        kind, attend, monkeypatch):
+    """``attend`` is the counter of how often the kernel engages, and it is
+    the program's own word (``decoder.attend_form``, asked by the step and
+    by the span alike): a float pool of the BERT family reads the ALIVE
+    rows' pages x ``page_sz`` exactly; an int8 pool, and an engine whose
+    programs run over a mesh of several devices (Mosaic refuses a kernel
+    there), keep the gathered form — the kernel is never traced — and the
+    old product, rows x the page rung x ``page_sz``: what a family with an
+    attention core of its own states too (``*_kv_read_amplification`` read
+    what they read)."""
+    from pdnlp_tpu.ops import paged
+    from pdnlp_tpu.parallel import make_mesh
+
+    if attend == "gather":
+        def refuse(*a, **k):
+            raise AssertionError("the kernel was traced")
+        monkeypatch.setattr(paged, "paged_decode", refuse)
+    tok = WordPieceTokenizer(build_vocab(TEXTS, size=128))
+    args = Args(model="bert-tiny", decode_slots=4, decode_max_len=64,
+                max_new_tokens=8, kv_page_sz=16,
+                kv_dtype="int8" if kind == "int8" else "auto")
+    tr = Tracer(enabled=True)
+    eng = PagedDecodeEngine(
+        args, tokenizer=tok, buckets=(16,), prefill_rows=2, tracer=tr,
+        mesh=make_mesh(num_devices=2) if kind == "mesh" else None)
+    ps = [SHARED[:7], SHARED[:16] + [30, 31]]
+    for slot, p in zip((0, 2), ps):
+        eng.attach_stream(slot, decode_mod.DecodeStream(p, 8), share=False)
+    eng.prefill_ids(ps, [0, 2])
+    tr.clear()
+    pos = [7, 0, 18, 0]
+    eng.decode_batch([5, 0, 6, 0], pos, live=2)
+    a, = [r["attrs"] for r in tr.records() if r["name"] == "decode.dispatch"]
+    assert a["attend"] == attend and a["rows"] == 4
+    assert a["kv_positions_live"] == 8 + 19
+    rung = next(g for g in eng.decode_rungs if g * 16 > 18)
+    assert a["kv_positions_read"] == (
+        (1 + 2) * 16 if attend == "kernel" else 4 * rung * 16)
 
 
 def test_summarize_prints_the_share_of_decode_steps_at_each_row_rung(

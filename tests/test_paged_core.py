@@ -2,7 +2,8 @@
 and its three thin callers, against a float32 DENSE recompute — T = 1, T > 1
 with ragged ``nreal``, the head at every position — plus what the core must
 never do (dead rows, sentinel tables and filler write nothing), the int8
-path, greedy tokens equal to the dense recompute's over a stream that crosses
+path, the decode step's kernel (``ops/paged.py``) against the gathered form it
+replaces — and under NaN in every position it must not read —, greedy tokens equal to the dense recompute's over a stream that crosses
 every rung of the decode extent and every page boundary, and the structural
 guard: the donated pools are aliased to the outputs and no temporary is as
 large as a pool (the pool is never rebuilt)."""
@@ -13,6 +14,7 @@ import pytest
 
 from pdnlp_tpu.data.tokenizer import WordPieceTokenizer, build_vocab
 from pdnlp_tpu.models import bert, decoder, get_config
+from pdnlp_tpu.ops import paged
 from pdnlp_tpu.ops.attention import mask_bias
 from pdnlp_tpu.serve import DecodeBatcher, PagedDecodeEngine
 from pdnlp_tpu.utils.config import Args
@@ -288,6 +290,121 @@ def test_int8_pool_round_trips_through_the_cache_like_the_dense_model(model):
         assert np.abs(np.asarray(pk)[:, table[:, (n + t) // PS],
                                      (n + t) % PS]).sum() > 0
     assert worst < 2e-3, worst
+
+
+# ------------------------------- the decode step's kernel (ops/paged.py)
+
+K_PS, K_MP, K_N, K_D = 16, 20, 2, 8        # page size, table width, heads
+K_P = 24 * K_MP + 4                        # a layer's pages (layer 1 of 2)
+
+
+def kernel_case(rows, dtype, seed=0):
+    """Random pools of two layers, queries, a shuffled table into the second
+    layer, lengths at a page's edges, dead rows and sentinels."""
+    rng = np.random.default_rng(seed)
+    H, extent = K_N * K_D, K_PS * K_MP
+    pools = [jnp.asarray(rng.standard_normal((2 * K_P, K_PS, H)), dtype)
+             for _ in range(2)]
+    q = jnp.asarray(rng.standard_normal((rows, 1, K_N, K_D)), jnp.float32)
+    lengths = rng.integers(1, extent + 1, rows).astype(np.int32)
+    # a page's edges, a block's edges (``paged.BLOCK``: the extent holds
+    # one whole block and a part), dead rows
+    lengths[:8] = [1, K_PS, K_PS + 1, extent, 0, paged.BLOCK + 1, 0,
+                   paged.BLOCK]
+    table = rng.permutation(K_P)[:rows * K_MP].reshape(rows, K_MP)
+    # a sentinel tail after a live row's own pages, sentinel tables for the
+    # dead rows (flat ids: the sentinel of layer 1 is the pool's end)
+    table = np.where(np.arange(K_MP)[None] * K_PS < lengths[:, None],
+                     table, K_P).astype(np.int32) + K_P
+    return q, pools, table, lengths
+
+
+def gathered(q, pools, table, lengths):
+    """The form the kernel replaces: ``get`` + ``_attend_folded``."""
+    B, extent = table.shape[0], table.shape[1] * K_PS
+    k, v = (jnp.take(p, table, axis=0, mode="clip").reshape(B, extent, -1)
+            for p in pools)
+    bias = jnp.where(jnp.arange(extent)[None, None, None]
+                     < lengths[:, None, None, None], 0.0, decoder.NEG_INF)
+    return np.asarray(decoder._attend_folded(
+        q, k.astype(q.dtype), v.astype(q.dtype), bias))
+
+
+@pytest.mark.parametrize("rows,dtype", [
+    (16, jnp.float32), (24, jnp.float32), (16, jnp.bfloat16),
+    (24, jnp.bfloat16)])
+def test_the_kernel_equals_the_gathered_form(rows, dtype):
+    """Interpreted, on the same pools: lengths 1, a page, a page and one,
+    a block, a block and one, the whole extent (two blocks); dead rows and
+    sentinel tables give zeros; a sentinel tail in a live row's table is
+    never followed."""
+    assert paged.BLOCK < K_PS * K_MP < 2 * paged.BLOCK
+    q, pools, table, lengths = kernel_case(rows, dtype)
+    got = np.asarray(decoder._attend_paged(q, *pools, table, lengths))
+    want = gathered(q, pools, table, lengths)
+    live = lengths > 0
+    assert not got[~live].any()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+
+
+def test_the_kernel_reads_a_rows_live_pages_and_nothing_else():
+    """Every page no row owns, and every row's last page past ``pos``, holds
+    NaN: the output is still the gathered form's on the clean pools (which
+    itself cannot pass this: it multiplies what it masks)."""
+    q, pools, table, lengths = kernel_case(16, jnp.float32, seed=1)
+    want = gathered(q, pools, table, lengths)
+    owned = np.zeros((2 * K_P, K_PS), bool)
+    for row, n in zip(table, lengths):
+        p = np.arange(n)
+        owned[row[p // K_PS], p % K_PS] = True
+    poisoned = [jnp.where(owned[:, :, None], p, jnp.nan) for p in pools]
+    assert np.isnan(np.asarray(poisoned[0])).mean() > 0.5
+    got = np.asarray(decoder._attend_paged(q, *poisoned, table, lengths))
+    live = lengths > 0
+    assert not got[~live].any()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    assert np.isnan(gathered(q, poisoned, table, lengths)[live]).any()
+
+
+@pytest.mark.parametrize("program,kernel", [
+    ("decode", True), ("decode-int8", False), ("chunk", False),
+    ("verify", False), ("decode-on-a-mesh", False)])
+def test_only_the_float_decode_step_takes_the_kernel(model, program, kernel):
+    """Told apart by shape and dtype, not by a flag: T = 1 over a float pool
+    calls the kernel once a layer; the int8 pool, the chunk and the
+    verify window keep the gathered form (none) — and so does the step of a
+    caller that says its ``jit`` runs over several devices, where Mosaic
+    refuses a kernel (``decoder.attend_form`` is the one place that
+    decides, and what the engine's span reports)."""
+    from pdnlp_tpu.parallel import make_mesh
+
+    cfg, params, head = model
+    table, zero = tables(), np.zeros(ROWS, np.int32)
+    tok = sequences(cfg.vocab_size, [4] * ROWS)
+    mesh = make_mesh(num_devices=2) if program.endswith("mesh") else None
+    assert decoder.attend_form(
+        1 if program.startswith("decode") else 4, program == "decode-int8",
+        mesh) == ("kernel" if kernel else "gather")
+    if program == "decode-int8":
+        ks, vs = decoder.calibrate_kv_scales(params, cfg, seq_len=32)
+        jaxpr = jax.make_jaxpr(lambda pk, pv: decoder.paged_decode_step(
+            params, head, cfg, tok[:, :1], pk, pv, table, zero + 3,
+            kv_scales=(jnp.asarray(ks), jnp.asarray(vs))))(
+                *empty_pools(cfg, jnp.int8))
+    else:
+        step = {"decode": lambda pk, pv: decoder.paged_decode_step(
+                    params, head, cfg, tok[:, :1], pk, pv, table, zero + 3),
+                "decode-on-a-mesh": lambda pk, pv: decoder.paged_decode_step(
+                    params, head, cfg, tok[:, :1], pk, pv, table, zero + 3,
+                    mesh=mesh),
+                "chunk": lambda pk, pv: decoder.paged_chunk_step(
+                    params, head, cfg, tok, pk, pv, table, zero, zero + 4),
+                "verify": lambda pk, pv: decoder.paged_verify_step(
+                    params, head, cfg, tok, pk, pv, table, zero + 3,
+                    zero + 4)}[program]
+        jaxpr = jax.make_jaxpr(step)(*empty_pools(cfg))
+    # jitted, so the layers share ONE traced kernel: it is lowered once
+    assert str(jaxpr).count("pallas_call") == (1 if kernel else 0)
 
 
 # ------------------------------------------- the engines, rung by rung
