@@ -4,7 +4,8 @@
     python chip_smoke.py            # one chip: device, train, train-packed,
                                     # serve, decode-kernel, decode,
                                     # decode-latent,
-                                    # decode-latent-mhc, decode-hybrid
+                                    # decode-latent-mhc,
+                                    # decode-latent-dsa, decode-hybrid
     python chip_smoke.py --chips 4  # four chips: device, mesh-train (dp and
                                     # zero against one device), replicas,
                                     # decode-mesh
@@ -736,6 +737,7 @@ def phase_decode_kernel(ctx) -> dict:
 #: the latent family's presets a phase builds: (on the chip, in a rehearsal)
 LATENT = ("ax-k1-ep16-share-l2", "ax-k1-share-tiny")
 LATENT_MHC = ("xing4-29b-ep1-stage-l2", "xing4-stage-tiny")
+LATENT_DSA = ("glm-5.2-ep16-share-l2", "glm52-share-tiny")
 
 
 def phase_decode_latent(ctx, presets=LATENT, tag="latent") -> dict:
@@ -744,7 +746,10 @@ def phase_decode_latent(ctx, presets=LATENT, tag="latent") -> dict:
     --decode``: the published widths at one dense + one expert layer, seeded
     weights (the family has no trainer), a repeated prompt.  ``LATENT_MHC``:
     the same family with a four-stream residual mixed by hyper-connections
-    and a bias-corrected router, its experts held whole."""
+    and a bias-corrected router, its experts held whole.  ``LATENT_DSA``: the
+    same family under a learned sparse attention — an indexer in the dense
+    layer whose picks the expert layer reuses, the index keys a second pool
+    through the latents' page table."""
     import serve_tpu
 
     model = presets[1] if ctx.rehearse else presets[0]
@@ -767,7 +772,10 @@ def phase_decode_latent(ctx, presets=LATENT, tag="latent") -> dict:
     check(sorted(gens) == [0, 1, 2], f"missing generations: {sorted(gens)}")
     check(gens[2] == gens[0], f"the repeated prompt differs: {gens}")
     engine = pools[0].engine(0)
-    check(engine.family.name == "latent_moe" and len(engine._pools) == 1,
+    # latents alone, or latents and a learned sparse attention's index keys
+    n_pools = 2 if engine.cfg.index_n_heads else 1
+    check(engine.family.name == "latent_moe"
+          and len(engine._pools) == n_pools,
           f"family {engine.family.name}, {len(engine._pools)} pools")
     with open(metrics) as f:
         rep = json.load(f)["replicas"]["0"]
@@ -777,12 +785,16 @@ def phase_decode_latent(ctx, presets=LATENT, tag="latent") -> dict:
     cfg = engine.cfg
     check(kv["stream_bytes_a_token"] == cfg.hc_mult * cfg.hidden_size * 2,
           f"streams: {kv['stream_bytes_a_token']} bytes a token")
+    check(kv["index_bytes_a_token"]
+          == cfg.num_index_layers * cfg.index_cache_width * 2,
+          f"index keys: {kv['index_bytes_a_token']} bytes a token")
     leak = engine.leak_check()
     check(leak["ok"] and leak["leaked_pages"] == 0, f"leak check: {leak}")
     return {"model": model, "prompts": len(prompts), "repeats": 1,
             "tokens_streamed": sum(r[1] == "tok" for r in rows),
             "cache_bytes_per_token": engine.token_bytes,
             "stream_bytes_a_token": kv["stream_bytes_a_token"],
+            "index_bytes_a_token": kv["index_bytes_a_token"],
             "kv_pool_bytes": kv["kv_pool_bytes"],
             "weights_bytes": kv["weights_bytes"],
             "compile_cache": rep["engine"]["compile_cache"]}
@@ -1115,6 +1127,8 @@ def main(argv=None) -> int:
         run_phase(ctx, "decode-latent", phase_decode_latent)
         run_phase(ctx, "decode-latent-mhc", functools.partial(
             phase_decode_latent, presets=LATENT_MHC, tag="latent_mhc"))
+        run_phase(ctx, "decode-latent-dsa", functools.partial(
+            phase_decode_latent, presets=LATENT_DSA, tag="latent_dsa"))
         run_phase(ctx, "decode-hybrid", phase_decode_hybrid)
     if ctx.rehearse:
         print("chip_smoke: rehearsal walked every phase; this is not a chip "

@@ -65,11 +65,12 @@ class BertConfig:
 class LatentMoEConfig:
     """A pre-norm decoder with latent attention (MLA) and sparse experts,
     served only (``models/latent_moe.py``).  Field names are the published
-    ``config.json``'s where it has one.  Two published models are run
+    ``config.json``'s where it has one.  Three published models are run
     through it: A.X-K1 (https://huggingface.co/skt/A.X-K1/blob/main/config.json),
     whose widths the DEFAULTS are — so a preset of any other model states
-    EVERY width, or it would inherit A.X-K1's — and Xing4.0-29B-A4B
-    (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json).
+    EVERY width, or it would inherit A.X-K1's —, Xing4.0-29B-A4B
+    (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json)
+    and GLM-5.2 (https://huggingface.co/zai-org/GLM-5.2/blob/main/config.json).
 
     ``experts_held`` / ``expert_first`` say which of the ``n_routed_experts``
     THIS process holds (expert parallelism's share): the router keeps its
@@ -86,7 +87,30 @@ class LatentMoEConfig:
     plain residual ``x + F(x)``, with no mixing leaf and no mixing
     operation.  ``selection_bias``: the router CHOOSES experts by ``score +
     bias`` (a learned leaf a layer) and GATES by the score alone
-    (``topk_method: "noaux_tc"``); False: no such leaf, chosen by score."""
+    (``topk_method: "noaux_tc"``); False: no such leaf, chosen by score.
+
+    ``index_n_heads`` > 0 makes the attention a LEARNED SPARSE one: a layer
+    whose entry of ``indexer_types`` (one a layer HELD, the first ``"full"``)
+    is ``"full"`` holds a lightning indexer — ``index_n_heads`` heads of
+    ``index_head_dim``, the first ``qk_rope_head_dim`` values of each
+    rotated — that scores every visible position and picks the
+    ``index_topk`` best; a ``"shared"`` layer holds no indexer and uses the
+    last ``"full"`` layer's picks; the softmax runs over the picked positions
+    alone.  The ``"full"`` layers' index keys are a SECOND pool of the page
+    cache (``index_cache_width`` wide over ``num_index_layers`` layers,
+    through the latents' page table).  0, the default: no indexer leaf, no
+    second pool, every cached position attended — today's programs, not a
+    degenerate selection.
+
+    Rotary positions: ``rope_factor`` 1 is the plain rotary table (``theta
+    ** (-2i/d)``, scale ``(d_nope + d_rope) ** -0.5``); above 1, yarn.  The
+    program rotates the pairs ``(x[i], x[i + d/2])``.  A model published
+    with INTERLEAVED pairs ``(x[2i], x[2i+1])`` is given its rotary columns
+    de-interleaved (of ``q_b_rope``, of ``kv_a``'s rotary part and of the
+    indexer's two projections with the index key's norm): a permutation of
+    columns that both sides of every dot share, so every score is the same
+    (``benchmark/reference/glm52.py`` ``program_layout``; a test holds the
+    two equal)."""
     vocab_size: int = 163_840
     hidden_size: int = 7168
     num_layers: int = 61          # leading dense layers + expert layers
@@ -121,9 +145,44 @@ class LatentMoEConfig:
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
     selection_bias: bool = False        # choose by score + bias, gate by score
+    index_n_heads: int = 0              # the indexer's heads (0: no indexer)
+    index_head_dim: int = 128
+    index_topk: int = 2048              # positions a query attends to
+    indexer_types: Tuple[str, ...] = ()  # "full" | "shared", a layer held
     weight_dtype: str = "bfloat16"      # how the weights are STORED
 
     family = "latent_moe"
+
+    def __post_init__(self):
+        if not self.index_n_heads:
+            return
+        kinds = tuple(self.indexer_types)
+        if (len(kinds) != self.num_layers or kinds[:1] != ("full",)
+                or set(kinds) - {"full", "shared"}):
+            raise ValueError(
+                f"indexer_types {kinds} is not one of 'full' | 'shared' for "
+                f"each of the {self.num_layers} layers held, the first "
+                "'full' (a 'shared' layer uses the picks of the last 'full' "
+                "one before it)")
+        if self.hc_mult > 1 or self.qk_rope_head_dim > self.index_head_dim:
+            raise ValueError(
+                "the indexer reads a one-stream residual and rotates the "
+                "first qk_rope_head_dim values of an index head")
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        """The layers that hold an indexer and cache index keys."""
+        return tuple(l for l, kind in enumerate(self.indexer_types)
+                     if kind == "full") if self.index_n_heads else ()
+
+    @property
+    def num_index_layers(self) -> int:
+        return len(self.full_layers)
+
+    @property
+    def index_cache_width(self) -> int:
+        """One cached index key as its pool holds it: whole lane tiles."""
+        return -(-self.index_head_dim // 128) * 128
 
     @property
     def latent_width(self) -> int:
@@ -236,9 +295,39 @@ def _xing4(**kw) -> LatentMoEConfig:
         rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
         rope_mscale_all_dim=1.0, max_position=262_144, hc_mult=4,
         hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0),
-        selection_bias=True, weight_dtype="bfloat16")
+        selection_bias=True, index_n_heads=0, index_head_dim=128,
+        index_topk=2048, indexer_types=(), weight_dtype="bfloat16")
     fields.update(kw)
     return LatentMoEConfig(**fields)
+
+
+def _glm52(**kw) -> LatentMoEConfig:
+    """GLM-5.2's published ``config.json``, EVERY field stated (a default
+    left standing would be A.X-K1's); ``kw`` cuts it.  ``indexer_types`` is
+    the published list (``full`` for layers 0-2 and every fourth from 6)."""
+    fields = dict(
+        vocab_size=154_880, hidden_size=6144, num_layers=78, first_k_dense=3,
+        num_heads=64, q_lora_rank=2048, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        intermediate_size=12_288, moe_intermediate_size=2048,
+        n_routed_experts=256, experts_held=256, expert_first=0,
+        num_experts_per_tok=8, n_shared_experts=1, n_group=1, topk_group=1,
+        routed_scaling_factor=2.5, rms_norm_eps=1e-5, rope_theta=8_000_000.0,
+        rope_factor=1.0, rope_original_max=1_048_576, rope_beta_fast=32.0,
+        rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        max_position=1_048_576, hc_mult=1, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_res_clamp=(-30.0, 30.0), selection_bias=True, index_n_heads=32,
+        index_head_dim=128, index_topk=2048,
+        indexer_types=tuple("full" if l < 3 or (l - 2) % 4 == 0 else "shared"
+                            for l in range(78)),
+        weight_dtype="bfloat16")
+    fields.update(kw)
+    return LatentMoEConfig(**fields)
+
+
+#: published layers 2-8 of GLM-5.2: the last dense layer and six expert layers
+_GLM52_HELD = ("full", "shared", "shared", "shared", "full", "shared",
+               "shared")
 
 
 _REGISTRY = {
@@ -297,6 +386,30 @@ _REGISTRY = {
         qk_rope_head_dim=8, v_head_dim=16, intermediate_size=256,
         moe_intermediate_size=64, n_routed_experts=8, experts_held=8,
         num_experts_per_tok=3, max_position=4096),
+    # one chip's share of GLM-5.2 when 16 chips share each layer (experts 16
+    # ways: 16 held; the vocabulary 8 ways: 19 360 rows): every width as
+    # published, published layers 2-8 — the last dense layer (its indexer
+    # scores), expert layers 3-5 (they use its picks), 6 (scores), 7 and 8
+    "glm-5.2-ep16-share": _glm52(
+        num_layers=7, first_k_dense=1, experts_held=16, vocab_size=19_360,
+        indexer_types=_GLM52_HELD),
+    # the same share cut to the dense layer and ONE expert layer (which uses
+    # the dense layer's picks): what chip_smoke.py builds
+    "glm-5.2-ep16-share-l2": _glm52(
+        num_layers=2, first_k_dense=1, experts_held=16, vocab_size=19_360,
+        indexer_types=_GLM52_HELD[:2]),
+    # the same family at a size the CPU tests run: 1 dense + 4 expert layers
+    # (full, shared, shared, full, shared), 4 heads of 24 + 8 / 32, an
+    # indexer of 4 heads of 16 (8 rotated) that picks 16 positions, 8
+    # experts of which 3 a token are taken and 4 held
+    "glm52-share-tiny": _glm52(
+        vocab_size=1000, hidden_size=128, num_layers=5, first_k_dense=1,
+        num_heads=4, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=32, intermediate_size=256,
+        moe_intermediate_size=64, n_routed_experts=8, experts_held=4,
+        num_experts_per_tok=3, max_position=4096, rope_original_max=4096,
+        index_n_heads=4, index_head_dim=16, index_topk=16,
+        indexer_types=("full", "shared", "shared", "full", "shared")),
     # one chip's share of Solar-Open2-250B when 16 chips share each layer
     # (experts 16 ways: 20 held; the vocabulary 8 ways comes from the
     # tokenizer): every width as published, two whole periods of the 12

@@ -6,17 +6,25 @@ how its weights come into being, and the step functions its jitted programs
 call.  ``of(cfg)`` is looked up once, at construction; no call site asks
 which family it serves.
 
-A step function takes and returns the cache as a TUPLE of pools ``[L, P,
-page_sz, width]``, each over the layers that PAGE (``pool_layers``) — twin K
-and V pools for the BERT causal LM (``models/decoder.py``), one latent pool
-for the latent-attention decoder (``models/latent_moe.py``), twin pools over
-the GQA layers alone for the hybrid (``models/hybrid_linear.py``) — and a
+A step function takes and returns the cache as a TUPLE of pools.  A POOL is
+one KIND of cached value: an array ``[layers, P, page_sz, width]`` with its
+OWN width (``pool_widths``) and its OWN count of layers (``pool_layers``: a
+count a pool, or one for all), over the pages of ONE table, ONE allocator
+and ONE prefix index — page ``p`` names the same positions in every pool, so
+a shared prefix shares all of them.  Twin K and V pools for the BERT causal
+LM (``models/decoder.py``); one latent pool for the latent-attention decoder
+(``models/latent_moe.py``) and, where it has an indexer, a second, narrower
+pool of index keys over the layers that score alone; twin pools over the GQA
+layers alone for the hybrid (``models/hybrid_linear.py``).  ``pool_shapes``
+and ``token_bytes`` are the one place that reads the two.  Beside the pools
+a step function takes and returns a
 TUPLE of per-slot state arrays ``[slots, ...]`` (``state_shapes``: the
 hybrid's recurrent states and convolution tails; empty for the other two,
 whose programs an empty tuple adds no operand to).  It returns ``aux``
 beside the logits: what a launch counted (``latent_moe.no_load``: assignments
 to each held expert, and the rows the experts' products computed), or
-``None``.
+``None``; a latent configuration with an indexer adds the positions its
+real queries saw and picked (``latent_moe.no_picks``).
 """
 from __future__ import annotations
 
@@ -44,8 +52,12 @@ class Family:
     #:  logits_at, kv_scales, dtype, mesh: what the program is jitted over)
     #:   -> (logits, aux, pools, states)
     attend: Callable
-    #: cfg -> how many layers a cached position lives in (the pools' L)
+    #: cfg -> how many layers a cached position lives in: one count a pool,
+    #: or ONE for every pool
     pool_layers: Callable = lambda cfg: cfg.num_layers
+    #: (cfg, extent) -> positions of a row's ``extent`` a decode step that
+    #: gathers reads a layer
+    read_extent: Callable = lambda cfg, extent: extent
     #: (cfg, slots) -> ((shape, dtype or None = the compute dtype), ...) of
     #: what a SLOT keeps besides its pages
     state_shapes: Callable = lambda cfg, slots: ()
@@ -83,17 +95,27 @@ def _bert_attend(params, head, cfg, tokens, pools, states, table, start,
 
 
 def _latent_prefill(params, head, cfg, ids, mask, last_pos, dtype):
-    logits, load, latents = latent_moe.prefill(
+    logits, load, news = latent_moe.prefill(
         params, head, cfg, ids, mask, last_pos, dtype=dtype)
-    return logits, load, (latents,), ()
+    return logits, load, news if cfg.index_n_heads else (news,), ()
 
 
 def _latent_attend(params, head, cfg, tokens, pools, states, table, start,
                    nreal, logits_at, kv_scales, dtype, mesh=None):
+    # the latents alone, or the pair with the index keys
     logits, load, pool = latent_moe.paged_attend(
-        params, head, cfg, tokens, pools[0], table, start, nreal,
-        dtype=dtype)
-    return logits, load, (pool,), states
+        params, head, cfg, tokens, pools if cfg.index_n_heads else pools[0],
+        table, start, nreal, dtype=dtype)
+    return logits, load, pool if cfg.index_n_heads else (pool,), states
+
+
+def _latent_pools(cfg):
+    """(layers, width) of the latents' pool and, with an indexer, of the
+    index keys' (the layers that score alone)."""
+    pools = ((cfg.num_layers, cfg.cache_width),)
+    if cfg.index_n_heads:
+        pools += ((cfg.num_index_layers, cfg.index_cache_width),)
+    return pools
 
 
 def _hybrid_prefill(params, head, cfg, ids, mask, last_pos, dtype):
@@ -122,8 +144,11 @@ FAMILIES = {
     "latent_moe": Family(
         name="latent_moe", init_params=latent_moe.init_params,
         init_head=latent_moe.init_head,
-        pool_widths=lambda cfg: (cfg.cache_width,),
+        pool_widths=lambda cfg: tuple(w for _, w in _latent_pools(cfg)),
         prefill=_latent_prefill, attend=_latent_attend,
+        pool_layers=lambda cfg: tuple(n for n, _ in _latent_pools(cfg)),
+        read_extent=lambda cfg, extent: (
+            min(cfg.index_topk, extent) if cfg.index_n_heads else extent),
         lazy_weights=True, int8=False, verify=False,
         handoff=False),
     # no snapshot of the recurrent state exists at a page boundary, so
@@ -144,10 +169,18 @@ def of(cfg) -> Family:
     return FAMILIES[cfg.family]
 
 
-def token_bytes(cfg, kv_dtype) -> int:
-    """Bytes one cached position takes over every PAGING layer and pool."""
+def pool_shapes(cfg) -> Tuple[Tuple[int, int], ...]:
+    """(layers, width) of every pool of ``cfg``'s family."""
     family = of(cfg)
-    return int(family.pool_layers(cfg) * sum(family.pool_widths(cfg))
+    widths, layers = family.pool_widths(cfg), family.pool_layers(cfg)
+    if isinstance(layers, int):
+        layers = (layers,) * len(widths)
+    return tuple(zip(layers, widths))
+
+
+def token_bytes(cfg, kv_dtype) -> int:
+    """Bytes one cached position takes over every pool's layers."""
+    return int(sum(n * w for n, w in pool_shapes(cfg))
                * np.dtype(kv_dtype).itemsize)
 
 

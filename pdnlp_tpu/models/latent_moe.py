@@ -25,6 +25,21 @@ The streams are never cached: nothing below the layer knows of them.  With
 ``cfg.selection_bias`` the router chooses by ``score + bias`` and gates by
 the score.  ``benchmark/reference/xing4.py`` states both in plain float32.
 
+With ``cfg.index_n_heads`` (GLM-5.2) the attention is a LEARNED SPARSE one
+(:func:`index_project`, :func:`index_scores`, :func:`select_mask`,
+:func:`select_picks`).  A layer whose ``cfg.indexer_types`` entry is
+``"full"`` holds a lightning indexer: index queries ``qI = c_q W_iq`` (heads
+of ``index_head_dim``), ONE index key ``kI = layer_norm(a W_ik)`` a token,
+head weights ``w = a W_iw``, the first ``qk_rope_head_dim`` values of every
+``qI`` head and of ``kI`` rotated; ``I[t, s] = sum_h w_t[h] relu(qI_t[h] .
+kI_s)`` in float32 over the visible ``s <= t``; the ``index_topk`` largest
+are PICKED (a tie to the lower position; every visible position while there
+are no more than that).  A ``"shared"`` layer holds no indexer and uses the
+picks of the last ``"full"`` layer before it: they travel beside the
+residual.  The softmax runs over the picked positions alone.  The ``"full"``
+layers' index keys are a SECOND pool, through the same page table
+(``benchmark/reference/glm52.py`` states all of it in plain float32).
+
 **What is cached** is one vector a token a layer, ``[c_kv | k_rope]``
 (``cfg.latent_width`` values, padded to ``cfg.cache_width``: whole lane
 tiles), in pages ``[L, P, page_sz, width]`` that the
@@ -41,6 +56,14 @@ read it:
   runs over the latent pages as they lie — heads are rows of one dot
   against ``[positions, width]``, the form ``decoder._attend_folded`` found
   for the twin pools.
+
+Under an indexer the picks take two forms.  A window of SEVERAL queries (a
+prompt, the chunk after a prefix hit) keeps them as a MASK ``[B, T, S]`` over
+the expanded path's dense scores.  The decode step keeps them as POSITIONS
+``[B, index_topk]`` with a flag of which are real: it reads the ``"full"``
+layers' index keys of the row's pages, and in every layer gathers
+``index_topk`` latents a row by position through the table — never the
+row's whole extent — for the absorbed path.
 
 **The share.**  The expert layer is TOLD which experts it holds
 (``cfg.expert_first``, ``cfg.experts_held``).  The router keeps its width,
@@ -78,6 +101,15 @@ F32 = jnp.float32
 Q_BLOCK = 512
 #: the most rows of one tile of the experts' grouped products
 EXPERT_BLOCK = 128
+#: the most tokens one pass of an expert layer takes (:func:`moe_in_parts`)
+MOE_TOKENS = 4096
+#: query rows of one block of index scores ([rows, index heads, keys] float32)
+INDEX_BLOCK = 128
+#: float32 bytes of one attention block's scores under a mask of picks: the
+#: block's rows follow it (:func:`_mask_block`)
+MASK_SCORE_BYTES = 2 ** 28
+#: key extents a prompt's masked attention is cut into (:func:`_attend_masked`)
+MASK_GROUPS = 4
 
 
 # ------------------------------------------------------------------- weights
@@ -119,6 +151,12 @@ def param_shapes(cfg: LatentMoEConfig) -> Dict[str, Any]:
     }
     if cfg.selection_bias:
         shapes["moe"]["router_bias"] = (M, E)
+    if cfg.index_n_heads:
+        # one set a "full" layer, dense or expert: stacked by their order
+        Nf, Hi, di = cfg.num_index_layers, cfg.index_n_heads, cfg.index_head_dim
+        shapes["indexer"] = {
+            "iq": (Nf, qr, Hi * di), "ik": (Nf, H, di), "ik_norm": (Nf, di),
+            "ik_bias": (Nf, di), "iw": (Nf, H, Hi)}
     if cfg.hc_mult > 1:
         # one mixing a sub-layer: before attention, before the feed-forward
         for part, lead in (("dense", (K,)), ("moe", (M,))):
@@ -134,8 +172,9 @@ def _is_norm(path) -> bool:
 
 def init_params(key: jax.Array, cfg: LatentMoEConfig) -> Params:
     """Seeded weights in the family's STORED dtype: matrices normal /
-    sqrt(fan-in), norm gains 1 + 0.1 normal, the selection bias 0.1 normal,
-    the mixing leaves ``hyper_connections.init_leaf``'s.  One leaf at a
+    sqrt(fan-in), norm gains 1 + 0.1 normal, the selection bias and the
+    index key's norm bias 0.1 normal, the mixing leaves
+    ``hyper_connections.init_leaf``'s.  One leaf at a
     time, so that nothing float32 the size of the model is ever alive."""
     shapes = param_shapes(cfg)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
@@ -151,7 +190,7 @@ def init_params(key: jax.Array, cfg: LatentMoEConfig) -> Params:
         x = jax.random.normal(k, shape, F32)
         if _is_norm(path):
             x = 1.0 + 0.1 * x
-        elif name == "router_bias":
+        elif name in ("router_bias", "ik_bias"):
             x = 0.1 * x
         elif len(shape) >= 2 and path[0].key != "embed":
             x = x * (shape[-2] ** -0.5)
@@ -210,6 +249,8 @@ def yarn_inv_freq(cfg: LatentMoEConfig) -> np.ndarray:
     d, base = cfg.qk_rope_head_dim, cfg.rope_theta
     i = np.arange(0, d, 2, dtype=np.float64) / d
     extra, inter = 1.0 / base ** i, 1.0 / (cfg.rope_factor * base ** i)
+    if cfg.rope_factor <= 1:
+        return extra.astype(np.float32)         # the plain table
 
     def correction(turns):
         return d * math.log(cfg.rope_original_max / (turns * 2 * math.pi)) \
@@ -252,7 +293,8 @@ def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 def _project(a: jax.Array, ap: Params, cfg: LatentMoEConfig,
              positions: jax.Array, dtype):
     """Normed input ``a [B, T, H]`` -> (q_nope ``[B, T, N, dn]``, q_rope
-    ``[B, T, N, dr]``, latent ``[B, T, kr + dr]`` = ``[c_kv | k_rope]``)."""
+    ``[B, T, N, dr]``, latent ``[B, T, kr + dr]`` = ``[c_kv | k_rope]``, the
+    normed query latent ``c_q [B, T, qr]`` an indexer reads)."""
     B, T = a.shape[:2]
     N, kr = cfg.num_heads, cfg.kv_lora_rank
     cos, sin = _rope_tables(cfg, positions)                    # [B, T, dr/2]
@@ -266,24 +308,30 @@ def _project(a: jax.Array, ap: Params, cfg: LatentMoEConfig,
     kv = _mm(a, ap["kv_a"], dtype).astype(dtype)
     c_kv = _rms(kv[..., :kr], ap["kv_norm"], cfg.rms_norm_eps)
     k_rope = _rope(kv[..., kr:], cos, sin)
-    return q_nope, q_rope, jnp.concatenate([c_kv, k_rope], axis=-1)
+    return q_nope, q_rope, jnp.concatenate([c_kv, k_rope], axis=-1), cq
 
 
 def _softmax_rows(scores: jax.Array, qpos: jax.Array, kpos: jax.Array,
-                  dtype) -> jax.Array:
+                  dtype, mask: Optional[jax.Array] = None) -> jax.Array:
     """``scores [B, N, T, S]`` float32 -> probabilities in ``dtype``; key j
-    is visible to query t iff ``kpos[j] <= qpos[b, t]``."""
-    vis = kpos[None, None, None, :] <= qpos[:, None, :, None]
+    is visible to query t iff ``kpos[j] <= qpos[b, t]`` — or, under an
+    indexer's picks, iff ``mask[b, t, j]``."""
+    vis = (kpos[None, None, None, :] <= qpos[:, None, :, None]
+           if mask is None else mask[:, None])
     return jax.nn.softmax(jnp.where(vis, scores, NEG_INF), axis=-1).astype(dtype)
 
 
+@jax.named_scope("mla.attend")
 def attend_expanded(q_nope, q_rope, latent, ap: Params,
                     cfg: LatentMoEConfig, qpos: jax.Array, dtype,
-                    causal_cut: bool = False) -> jax.Array:
+                    causal_cut: bool = False,
+                    mask: Optional[jax.Array] = None) -> jax.Array:
     """K and V expanded from ``latent [B, S, width]`` (key j at position j),
     queries at ``qpos [B, T]`` attended in blocks of :data:`Q_BLOCK`.
     ``causal_cut``: query t IS position t (a prompt from position 0), so a
-    block needs only the keys up to its own end.  -> ``[B, T, N * dv]``."""
+    block needs only the keys up to its own end.  ``mask [B, T, S]``: an
+    indexer's picks — key j takes part in query t's softmax iff ``mask[b, t,
+    j]`` (:func:`_attend_masked`).  -> ``[B, T, N * dv]``."""
     B, T, N = q_nope.shape[:3]
     S, kr = latent.shape[1], cfg.kv_lora_rank
     c_kv, k_rope = latent[..., :kr], latent[..., kr:cfg.latent_width]
@@ -293,14 +341,16 @@ def attend_expanded(q_nope, q_rope, latent, ap: Params,
         B, S, N, cfg.v_head_dim)
     scale = softmax_scale(cfg)
 
-    def block(qn, qr, qp, s1):
+    def block(qn, qr, qp, s1, m=None):
         scores = (_einsum("btnd,bsnd->bnts", qn, k_nope[:, :s1])
                   + _einsum("btnr,bsr->bnts", qr, k_rope[:, :s1]))
         probs = _softmax_rows(scores * scale, qp,
-                              jnp.arange(s1, dtype=jnp.int32), dtype)
+                              jnp.arange(s1, dtype=jnp.int32), dtype, m)
         return _einsum("bnts,bsnd->btnd", probs, v[:, :s1]).astype(dtype)
 
-    if T <= Q_BLOCK:
+    if mask is not None:
+        o = _attend_masked(block, q_nope, q_rope, qpos, mask, causal_cut)
+    elif T <= Q_BLOCK:
         o = block(q_nope, q_rope, qpos, min(S, T) if causal_cut else S)
     elif causal_cut or T % Q_BLOCK:
         # a prompt from position 0: each block has its own key extent
@@ -324,9 +374,51 @@ def attend_expanded(q_nope, q_rope, latent, ap: Params,
     return o.reshape(B, T, N * cfg.v_head_dim)
 
 
+def _mask_block(N: int, S: int) -> int:
+    """Query rows of one attention block under a mask of picks: the largest
+    power of two up to :data:`Q_BLOCK` whose ``[N, rows, S]`` float32 scores
+    stay within :data:`MASK_SCORE_BYTES` (64 heads over 7 680 keys: 128)."""
+    rows = Q_BLOCK
+    while rows > 16 and N * rows * S * 4 > MASK_SCORE_BYTES:
+        rows //= 2
+    return rows
+
+
+def _attend_masked(block, q_nope, q_rope, qpos, mask, causal_cut: bool):
+    """:func:`attend_expanded`'s blocks under ``mask [B, T, S]``, ONE AFTER
+    ANOTHER (``lax.map``: never two blocks' scores alive).  ``causal_cut``:
+    the blocks go in up to :data:`MASK_GROUPS` runs, each with the key extent
+    its last block needs — five eighths of the whole square's products at
+    four runs, where a block of its own extent each (a half) would unroll
+    sixty blocks a layer.  -> ``[B, T, N, dv]``."""
+    B, T, N = q_nope.shape[:3]
+    S = mask.shape[-1]
+    rows = _mask_block(N, S)
+    if T <= rows or T % rows:
+        s1 = min(S, T) if causal_cut else S
+        return block(q_nope, q_rope, qpos, s1, mask[..., :s1])
+    n = T // rows
+    per = -(-n // (min(MASK_GROUPS, n) if causal_cut else 1))
+    outs = []
+    for g0 in range(0, n, per):
+        g1 = min(n, g0 + per)
+        s1 = min(S, g1 * rows) if causal_cut else S
+
+        def cut(x, t0=g0 * rows, t1=g1 * rows, g=g1 - g0):
+            return jnp.moveaxis(
+                x[:, t0:t1].reshape((B, g, rows) + x.shape[2:]), 1, 0)
+
+        o = jax.lax.map(lambda a, s1=s1: block(a[0], a[1], a[2], s1, a[3]),
+                        (cut(q_nope), cut(q_rope), cut(qpos),
+                         cut(mask[..., :s1])))
+        outs.append(jnp.moveaxis(o, 0, 1).reshape((B, -1) + o.shape[3:]))
+    return jnp.concatenate(outs, axis=1)
+
+
+@jax.named_scope("mla.attend")
 def attend_absorbed(q_nope, q_rope, latent, ap: Params,
-                    cfg: LatentMoEConfig, qpos: jax.Array, dtype
-                    ) -> jax.Array:
+                    cfg: LatentMoEConfig, qpos: jax.Array, dtype,
+                    mask: Optional[jax.Array] = None) -> jax.Array:
     """The same attention with ``W_kvb`` absorbed: ``q' = q_nope W_kvb[k]``
     (per head, ``kr`` wide), scores ``q' . c_kv + q_rope . k_rope`` against
     the latents as they lie (heads are rows of ONE dot), ``o = (P c_kv)
@@ -334,7 +426,8 @@ def attend_absorbed(q_nope, q_rope, latent, ap: Params,
     latents WHOLE (``P @ latent``, its ``c_kv`` columns cut from the small
     result): cutting the columns out of the gathered pages first is a copy
     of them, and cutting them out of the pool a copy of the pool (read from
-    a described-v5e compile: 3.2 GB)."""
+    a described-v5e compile: 3.2 GB).  ``mask [B, T, S]``: ``latent`` holds a
+    row's PICKED positions (any order) and ``mask`` says which are real."""
     B, T, N = q_nope.shape[:3]
     S, kr = latent.shape[1], cfg.kv_lora_rank
     wk = ap["kv_b_k"].astype(dtype).reshape(kr, N, cfg.qk_nope_head_dim)
@@ -346,10 +439,139 @@ def attend_absorbed(q_nope, q_rope, latent, ap: Params,
     scores = _einsum("bqc,bsc->bqs", qf, latent) * softmax_scale(cfg)
     probs = _softmax_rows(
         jnp.swapaxes(scores.reshape(B, T, N, S), 1, 2), qpos,
-        jnp.arange(S, dtype=jnp.int32), dtype)                 # [B, N, T, S]
+        jnp.arange(S, dtype=jnp.int32), dtype, mask)           # [B, N, T, S]
     o_lat = _einsum("bnts,bsc->btnc", probs, latent)[..., :kr].astype(dtype)
     o = _einsum("btnc,cnd->btnd", o_lat, wv).astype(dtype)
     return o.reshape(B, T, N * cfg.v_head_dim)
+
+
+# ------------------------------------------------------------------- indexer
+
+def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float
+                ) -> jax.Array:
+    """LayerNorm with weight and bias, statistics and result in float32."""
+    xf = x.astype(F32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
+    return (xf - mu) * jax.lax.rsqrt(var + eps) * w.astype(F32) \
+        + b.astype(F32)
+
+
+def index_project(a: jax.Array, cq: jax.Array, ip: Params,
+                  cfg: LatentMoEConfig, positions: jax.Array, dtype):
+    """A ``"full"`` layer's indexer on the layer's normed input ``a [B, T,
+    H]`` and normed query latent ``cq [B, T, qr]`` -> (index queries ``[B,
+    T, Hi, di]`` and the ONE index key a token ``[B, T, di]``, both in
+    ``dtype`` with their first ``qk_rope_head_dim`` values rotated at
+    ``positions``; head weights ``[B, T, Hi]`` float32)."""
+    B, T = a.shape[:2]
+    Hi, di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    cos, sin = _rope_tables(cfg, positions)
+
+    def rotated(x, cos, sin):
+        return jnp.concatenate([_rope(x[..., :dr], cos, sin), x[..., dr:]],
+                               axis=-1)
+
+    with jax.named_scope("dsa.index"):
+        q = _mm(cq, ip["iq"], dtype).astype(dtype).reshape(B, T, Hi, di)
+        k = _layer_norm(_mm(a, ip["ik"], dtype), ip["ik_norm"],
+                        ip["ik_bias"], cfg.rms_norm_eps).astype(dtype)
+        return (rotated(q, cos[:, :, None], sin[:, :, None]),
+                rotated(k, cos, sin), _mm(a, ip["iw"], dtype))
+
+
+def index_scores(qI: jax.Array, w: jax.Array, kI: jax.Array) -> jax.Array:
+    """``I[b, t, s] = sum_h w[b, t, h] relu(qI[b, t, h] . kI[b, s])`` float32
+    (``kI [B, S, >= di]``: a pool's rows may be held wider than a key)."""
+    B, T, Hi, di = qI.shape
+    dots = _einsum("bqd,bsd->bqs", qI.reshape(B, T * Hi, di), kI[..., :di])
+    return jnp.sum(jax.nn.relu(dots).reshape(B, T, Hi, -1) * w[..., None],
+                   axis=2)
+
+
+def _seen(scores: jax.Array, visible: jax.Array) -> jax.Array:
+    """Float32 scores as a pick compares them: what is not visible below
+    everything, and -0 made +0 (equal as scores, apart as bits and to the
+    sort beneath ``lax.top_k``)."""
+    return jnp.where(visible, jnp.where(scores == 0, 0.0, scores),
+                     -jnp.inf).astype(F32)
+
+
+def select_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
+    """The ``k`` largest visible ``scores [..., S]`` of every row as a MASK
+    (all the visible where there are no more than ``k``); a tie goes to the
+    lower position.  Exact, and without a sort: the ``k``-th largest value
+    is found a bit at a time over the floats' order-preserving integer keys
+    (32 counts over the row), then the lowest tied positions fill what is
+    left.  On the chip a prompt's ``[T, T]`` pick costs 45 passes over its
+    scores where ``lax.top_k`` of thousands sorts them (PERF.md section 6,
+    PR 43)."""
+    if k >= scores.shape[-1]:
+        return visible
+    u = jax.lax.bitcast_convert_type(_seen(scores, visible), jnp.uint32)
+    key = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+    def bit(i, thr):
+        cand = thr | jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        n = jnp.sum(key >= cand, axis=-1, keepdims=True, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
+    above, tied = key > thr, key == thr
+    need = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    first = jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= need
+    return (above | (tied & first)) & visible
+
+
+def select_picks(scores: jax.Array, visible: jax.Array, k: int):
+    """The same pick as POSITIONS, for one query a row: ``scores [B, S]`` ->
+    (positions ``[B, min(k, S)]`` int32, which of them are real ``[B, min(k,
+    S)]``).  ``lax.top_k`` puts the lower index first among equals."""
+    vals, pos = jax.lax.top_k(_seen(scores, visible),
+                              min(k, scores.shape[-1]))
+    return pos.astype(jnp.int32), vals > -jnp.inf
+
+
+def index_mask(qI, w, kI, qpos: jax.Array, cfg: LatentMoEConfig) -> jax.Array:
+    """The picks of a window of queries at ``qpos [B, T]`` over the keys
+    ``kI [B, S, >= di]`` (key j at position j, visible iff ``j <= qpos``) as
+    a mask ``[B, T, S]``; queries in blocks of :data:`INDEX_BLOCK`, one after
+    another, so that ``[rows, heads, S]`` float32 is the most alive."""
+    B, T = qpos.shape
+    kpos = jnp.arange(kI.shape[1], dtype=jnp.int32)
+
+    def block(q, wt, qp):
+        with jax.named_scope("dsa.index"):
+            scores = index_scores(q, wt, kI)
+        with jax.named_scope("dsa.select"):
+            return select_mask(scores, kpos[None, None, :] <= qp[..., None],
+                               cfg.index_topk)
+
+    if T <= INDEX_BLOCK or T % INDEX_BLOCK:
+        return block(qI, w, qpos)
+
+    def cut(x):
+        return jnp.moveaxis(x.reshape(
+            (B, T // INDEX_BLOCK, INDEX_BLOCK) + x.shape[2:]), 1, 0)
+
+    m = jax.lax.map(lambda a: block(*a), (cut(qI), cut(w), cut(qpos)))
+    return jnp.moveaxis(m, 0, 1).reshape(B, T, -1)
+
+
+def no_picks():
+    """The zero of what a program counts of its selection: positions visible
+    to its real queries and positions picked for them, both int32 scalars
+    (of the FIRST ``"full"`` layer: every one picks as many)."""
+    return jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)
+
+
+def _count_picks(picked: jax.Array, positions: jax.Array, real: jax.Array):
+    """``picked [B, T, ...]`` flags, queries at ``positions [B, T]`` of which
+    ``real`` count -> (visible, picked) as :func:`no_picks`."""
+    n = jnp.sum(picked.reshape(real.shape + (-1,)), axis=-1, dtype=jnp.int32)
+    return (jnp.sum(jnp.where(real, positions + 1, 0), dtype=jnp.int32),
+            jnp.sum(jnp.where(real, n, 0), dtype=jnp.int32))
 
 
 # -------------------------------------------------------------- expert layer
@@ -510,6 +732,25 @@ def moe_ffn(f: jax.Array, lp: Params, experts: Params, m,
     return routed + _gated(f, lp["shared"], dtype), (counts, rows)
 
 
+def moe_in_parts(f: jax.Array, lp: Params, experts: Params, m,
+                 cfg: LatentMoEConfig, valid: jax.Array, dtype):
+    """:func:`moe_ffn` over ``f [T, H]`` in equal parts of at most
+    :data:`MOE_TOKENS` tokens, one after another, their loads summed: a
+    prompt of 8 192 tokens in one pass keeps 2.07 GiB of temporaries alive
+    (the ``k`` gathers of the un-sort, float32 ``[T, H]`` each; read from a
+    described-v5e compile, PERF.md section 6, PR 43), which do not fit
+    beside 12.6 GiB of weights and pools.  A held expert's matrices are
+    then read once a part.  No prompt of the other configurations is longer
+    than one part."""
+    n = -(-f.shape[0] // MOE_TOKENS)
+    if n == 1 or f.shape[0] % n:
+        return moe_ffn(f, lp, experts, m, cfg, valid, dtype)
+    ys, (counts, rows) = jax.lax.map(
+        lambda a: moe_ffn(a[0], lp, experts, m, cfg, a[1], dtype),
+        (f.reshape(n, -1, f.shape[1]), valid.reshape(n, -1)))
+    return ys.reshape(f.shape), (counts.sum(0), rows.sum())
+
+
 def no_load(cfg: LatentMoEConfig):
     """The zero of what a program counts of its expert layers: assignments
     to each held expert ``[experts_held]`` and the rows the experts'
@@ -544,28 +785,34 @@ def _write(x, y, mix, dtype):
 
 
 def _layer(x, lp: Params, cfg: LatentMoEConfig, l, positions, valid, attend,
-           carry, dtype, experts: Optional[Params] = None):
+           carry, dtype, experts: Optional[Params] = None,
+           indexer: Optional[Params] = None):
     """Layer ``l`` on the residual ``x [B, T, H]`` (``[n, B, T, H]`` streams
     where ``lp`` holds mixing leaves).  ``attend(l, carry, q_nope, q_rope,
     latent, ap) -> ([B, T, N * dv], carry')`` puts the latent where it has
     to go (the pool, in place; or the collected prompt latents) and attends.
     ``experts``: every expert layer's experts (``None``: a dense layer).
+    ``indexer``: a ``"full"`` layer's indexer leaves — ``attend`` is then
+    also given :func:`index_project`'s three, scores and picks anew; without
+    them it attends under the picks its carry holds.
     -> (x', carry', the layer's load or None)."""
     ap, hc = lp["attn"], lp.get("hc", {})
     u, mix = _read(x, hc.get("attn"), cfg)
     B, T, H = u.shape
     a = _rms(u, ap["in_norm"], cfg.rms_norm_eps)
-    q_nope, q_rope, latent = _project(a, ap, cfg, positions, dtype)
-    o, carry = attend(l, carry, q_nope, q_rope, latent, ap)
+    q_nope, q_rope, latent, cq = _project(a, ap, cfg, positions, dtype)
+    index = () if indexer is None else (
+        index_project(a, cq, indexer, cfg, positions, dtype),)
+    o, carry = attend(l, carry, q_nope, q_rope, latent, ap, *index)
     x = _write(x, _mm(o, ap["o"], dtype), mix, dtype)
     u, mix = _read(x, hc.get("ffn"), cfg)
     f = _rms(u, ap["post_norm"], cfg.rms_norm_eps)
     if experts is None:
         y, load = _gated(f, lp["ffn"], dtype), None
     else:
-        y, load = moe_ffn(f.reshape(B * T, H), lp, experts,
-                          l - cfg.first_k_dense, cfg, valid.reshape(B * T),
-                          dtype)
+        y, load = moe_in_parts(f.reshape(B * T, H), lp, experts,
+                               l - cfg.first_k_dense, cfg,
+                               valid.reshape(B * T), dtype)
         y = y.reshape(B, T, H)
     return _write(x, y, mix, dtype), carry, load
 
@@ -574,28 +821,54 @@ def _run_layers(params: Params, cfg: LatentMoEConfig, x, positions, valid,
                 attend, carry, dtype):
     """The leading dense layer(s), then the expert layers under ONE scan
     whose carry holds ``attend``'s state (the pool is carried whole, never
-    sliced by layer)."""
+    sliced by layer).  With an indexer the expert layers go in RUNS: a
+    ``"full"`` layer stands alone (its leaves exist for it only, its place
+    in the index pool is a number), the ``"shared"`` layers after it are one
+    scan that carries its picks."""
     K = cfg.first_k_dense
+    full = cfg.full_layers
+
+    def indexer(l):
+        return None if l not in full else jax.tree_util.tree_map(
+            lambda w: w[full.index(l)], params["indexer"])
+
     for l in range(K):
         lp = jax.tree_util.tree_map(lambda w: w[l], params["dense"])
         x, carry, _ = _layer(x, lp, cfg, l, positions, valid, attend, carry,
-                             dtype)
+                             dtype, indexer=indexer(l))
 
-    def step(c, scanned):
+    def step(c, scanned, indexer=None):
         x, carry, load = c
         lp, l = scanned
         x, carry, n = _layer(x, lp, cfg, l, positions, valid, attend, carry,
-                             dtype, experts)
+                             dtype, experts, indexer)
         return (x, carry, add_load(load, n)), None
 
     # the experts stay OUT of the scanned inputs: a block indexes (layer,
     # expert) into the whole stack (held_experts)
     moe = dict(params["moe"])
     experts = moe.pop("experts")
-    li = jnp.arange(K, cfg.num_layers, dtype=jnp.int32)
-    (x, carry, load), _ = jax.lax.scan(step, (x, carry, no_load(cfg)),
-                                       (moe, li))
-    return x, carry, load
+    if not cfg.index_n_heads:
+        li = jnp.arange(K, cfg.num_layers, dtype=jnp.int32)
+        (x, carry, load), _ = jax.lax.scan(step, (x, carry, no_load(cfg)),
+                                           (moe, li))
+        return x, carry, load
+
+    def of(l):
+        # a layer's leaves where they lie in the stack (a run's layers cut
+        # out to be scanned would be a copy of them)
+        return jax.tree_util.tree_map(lambda w: w[l - K], moe)
+
+    c, l = (x, carry, no_load(cfg)), K
+    while l < cfg.num_layers:
+        if l in full:
+            (c, _), l = step(c, (of(l), l), indexer(l)), l + 1
+            continue
+        end = min([f for f in full if f > l], default=cfg.num_layers)
+        c, _ = jax.lax.scan(lambda c, m: step(c, (of(m), m)), c,
+                            jnp.arange(l, end, dtype=jnp.int32))
+        l = end
+    return c
 
 
 def _logits(params: Params, head: Params, cfg: LatentMoEConfig, x, dtype):
@@ -637,7 +910,11 @@ def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
     """A cold prompt: the expanded path from position 0 -> (next-token
     logits ``[B, vocab]`` float32, the load (:func:`no_load`), the latents
     ``[L, B, S, cache_width]`` for
-    :func:`~pdnlp_tpu.models.decoder.insert_pool`)."""
+    :func:`~pdnlp_tpu.models.decoder.insert_pool`).  With an indexer every
+    query attends under its picks (a mask over the dense scores), the load
+    is followed by :func:`no_picks`'s two counts, and the rows are a PAIR:
+    the latents and the ``"full"`` layers' index keys ``[Lf, B, S,
+    index_cache_width]``."""
     B, S = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     valid = attention_mask.astype(bool)
@@ -651,15 +928,40 @@ def prefill(params: Params, head: Params, cfg: LatentMoEConfig,
         return o, jax.lax.dynamic_update_index_in_dim(
             collected, jnp.pad(latent, pad), l, axis=0)
 
-    x, collected, load = _run_layers(params, cfg, x, positions, valid,
-                                     attend, collected, dtype)
+    def attend_picked(l, carry, q_nope, q_rope, latent, ap, index=None):
+        collected, keys, mask, counts = carry
+        if index is not None:
+            qI, kI, w = index
+            at = cfg.full_layers.index(l)
+            mask = index_mask(qI, w, kI, positions, cfg)
+            keys = keys.at[at].set(jnp.pad(kI, (
+                (0, 0), (0, 0), (0, keys.shape[-1] - kI.shape[-1]))))
+            if at == 0:
+                counts = _count_picks(mask, positions, valid)
+        o = attend_expanded(q_nope, q_rope, latent, ap, cfg, positions,
+                            dtype, causal_cut=True, mask=mask)
+        collected = jax.lax.dynamic_update_index_in_dim(
+            collected, jnp.pad(latent, pad), l, axis=0)
+        return o, (collected, keys, mask, counts)
+
+    if not cfg.index_n_heads:
+        x, collected, load = _run_layers(params, cfg, x, positions, valid,
+                                         attend, collected, dtype)
+        news = collected
+    else:
+        keys = jnp.zeros((cfg.num_index_layers, B, S, cfg.index_cache_width),
+                         dtype)
+        x, (collected, keys, _, counts), load = _run_layers(
+            params, cfg, x, positions, valid, attend_picked,
+            (collected, keys, jnp.zeros((B, S, S), bool), no_picks()), dtype)
+        load, news = load + counts, (collected, keys)
     h_last = _read_out(x, last_pos, cfg, dtype)
-    return _logits(params, head, cfg, h_last, dtype)[:, 0], load, collected
+    return _logits(params, head, cfg, h_last, dtype)[:, 0], load, news
 
 
 def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
                  tokens: jax.Array,      # [B, T] int32
-                 pool: jax.Array,        # [L, P, page_sz, width]
+                 pool,                   # [L, P, page_sz, width]
                  page_table: jax.Array,  # [B, <= MP] int32 (sentinel P)
                  start: jax.Array,       # [B] abs position of tokens[:, 0]
                  nreal: Optional[jax.Array] = None,   # [B] real lengths
@@ -672,7 +974,18 @@ def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
     window slots, dead rows (sentinel tables) and positions past the table
     write nothing and take no part in the expert layer.  -> (last real
     token's logits ``[B, vocab]``, the load, the pool).  ``absorb``: ``None``
-    = by the window (T == 1); the tests ask for either path."""
+    = by the window (T == 1); the tests ask for either path.
+
+    With an indexer ``pool`` is the PAIR (latents, index keys ``[Lf, P,
+    page_sz, index_cache_width]``) over ONE table, returned as a pair, and
+    the load is followed by :func:`no_picks`'s counts.  A ``"full"`` layer
+    writes its index key in place, reads the index keys of the table's
+    pages and picks.  One query a row (the decode step): the picks are
+    POSITIONS, and every layer gathers those ``index_topk`` latents a row by
+    position through the table — never the row's extent.  A window of
+    several: the picks are a mask over the extent's dense scores."""
+    if cfg.index_n_heads:
+        pool, ipool = pool
     L, P, ps, W = pool.shape
     B, T = tokens.shape
     MP = page_table.shape[1]
@@ -685,17 +998,29 @@ def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
     phys = jnp.take_along_axis(
         page_table, jnp.clip(positions // ps, 0, MP - 1), axis=1)
     real &= phys < P
-    wrows = _layer_rows(jnp.where(real, phys * ps + positions % ps, P * ps),
-                        L, P * ps).reshape(L, B * T)
+    wflat = jnp.where(real, phys * ps + positions % ps, P * ps)
+    wrows = _layer_rows(wflat, L, P * ps).reshape(L, B * T)
     rpages = _layer_rows(page_table, L, P)                     # [L, B, MP]
     absorb = (T == 1) if absorb is None else absorb
 
+    def put(pool, rows, l, new):
+        """``new [B, T, <= width]`` into layer ``l``'s rows ``rows[l]`` of a
+        pool's flat view -> the view."""
+        width = pool.shape[-1]
+        new = jnp.pad(new, ((0, 0), (0, 0), (0, width - new.shape[-1])))
+        return pool.reshape(-1, width).at[rows[l]].set(
+            new.reshape(B * T, width).astype(pool.dtype), mode="drop")
+
+    def pages_of(flat, pages, l):
+        """The whole extent of every row in layer ``l``, page by page
+        through the table."""
+        return jnp.take(flat.reshape(-1, ps, flat.shape[-1]), pages[l],
+                        axis=0, mode="clip").reshape(B, extent, -1).astype(
+                            dtype)
+
     def attend(l, pool, q_nope, q_rope, latent, ap):
-        new = jnp.pad(latent, ((0, 0), (0, 0), (0, W - cfg.latent_width)))
-        flat = pool.reshape(L * P * ps, W).at[wrows[l]].set(
-            new.reshape(B * T, W).astype(pool.dtype), mode="drop")
-        got = jnp.take(flat.reshape(L * P, ps, W), rpages[l], axis=0,
-                       mode="clip").reshape(B, extent, W).astype(dtype)
+        flat = put(pool, wrows, l, latent)
+        got = pages_of(flat, rpages, l)
         if not absorb:
             o = attend_expanded(q_nope, q_rope, got, ap, cfg, positions,
                                 dtype)
@@ -704,9 +1029,56 @@ def paged_attend(params: Params, head: Params, cfg: LatentMoEConfig,
                                 dtype)
         return o, flat.reshape(pool.shape)
 
+    def attend_picked(l, carry, q_nope, q_rope, latent, ap, index=None):
+        pool, ipool, picks, counts = carry
+        flat = put(pool, wrows, l, latent)
+        if index is not None:
+            qI, kI, w = index
+            at = cfg.full_layers.index(l)
+            with jax.named_scope("dsa.index"):
+                iflat = put(ipool, iwrows, at, kI)
+                keys = pages_of(iflat, ipages, at)
+            ipool = iflat.reshape(ipool.shape)
+            if T > 1:
+                picks = index_mask(qI, w, keys, positions, cfg)
+            else:
+                with jax.named_scope("dsa.index"):
+                    scores = index_scores(qI, w, keys)[:, 0]
+                with jax.named_scope("dsa.select"):
+                    picks = select_picks(scores, kpos[None, :] <= positions,
+                                         cfg.index_topk)
+            if at == 0:
+                counts = _count_picks(picks if T > 1 else picks[1][:, None],
+                                      positions, real)
+        if T > 1:
+            got, mask = pages_of(flat, rpages, l), picks
+        else:
+            at_pos, mask = picks[0], picks[1][:, None]
+            with jax.named_scope("dsa.gather"):
+                page = jnp.take_along_axis(page_table, at_pos // ps, axis=1)
+                rows = l * (P * ps) + jnp.where(
+                    page < P, page * ps + at_pos % ps, 0)
+                got = jnp.take(flat, rows, axis=0, mode="clip").astype(dtype)
+        o = (attend_absorbed if absorb else attend_expanded)(
+            q_nope, q_rope, got, ap, cfg, positions, dtype, mask=mask)
+        return o, (flat.reshape(pool.shape), ipool, picks, counts)
+
     x = _streams(_embed(params, tokens, dtype), cfg)
-    x, pool, load = _run_layers(params, cfg, x, positions, real, attend,
-                                pool, dtype)
+    if not cfg.index_n_heads:
+        x, pool, load = _run_layers(params, cfg, x, positions, real, attend,
+                                    pool, dtype)
+    else:
+        Lf = ipool.shape[0]
+        iwrows = _layer_rows(wflat, Lf, P * ps).reshape(Lf, B * T)
+        ipages = _layer_rows(page_table, Lf, P)
+        kpos = jnp.arange(extent, dtype=jnp.int32)
+        k = min(cfg.index_topk, extent)
+        none = (jnp.zeros((B, T, extent), bool) if T > 1 else
+                (jnp.zeros((B, k), jnp.int32), jnp.zeros((B, k), bool)))
+        x, (pool, ipool, _, counts), load = _run_layers(
+            params, cfg, x, positions, real, attend_picked,
+            (pool, ipool, none, no_picks()), dtype)
+        load, pool = load + counts, (pool, ipool)
     last = (jnp.zeros((B,), jnp.int32) if nreal is None
             else jnp.clip(nreal.astype(jnp.int32) - 1, 0, T - 1))
     x = _read_out(x, last, cfg, dtype)

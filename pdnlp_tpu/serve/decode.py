@@ -362,6 +362,10 @@ class PagedDecodeEngine(InferenceEngine):
         #: assignments to each held expert, summed over the launches whose
         #: fetch leaf recorded (a family with experts; else stays None)
         self.expert_load: Optional[np.ndarray] = None
+        #: by program (``decode`` / ``prefill`` / ``chunk``): positions
+        #: visible to its real queries and positions picked for them, summed
+        #: like ``expert_load`` (a learned sparse attention; else empty)
+        self.positions_seen: Dict[str, np.ndarray] = {}
 
         # --- capacity.  Pages, not slots, are the budgeted unit: the
         # declared HBM budget caps the page POOL (loud refusal at
@@ -542,19 +546,19 @@ class PagedDecodeEngine(InferenceEngine):
         construction and post-chaos :meth:`reset_cache`, never hot."""
         cfg = self.cfg
 
-        def alloc(width):
+        def alloc(layers, width):
             # one position's values of one pool are ONE minor axis: [page_sz,
             # width] is what the chip tiles (models.decoder, paged-cache
             # note).  SEPARATE buffers: device_put of one shared zeros
             # array would alias the pools, and a donating program would
             # then donate the same buffer twice
             return jax.device_put(jnp.zeros(
-                (self.family.pool_layers(cfg), self.n_pages, self.page_sz,
-                 width), self.kv_dtype))
+                (layers, self.n_pages, self.page_sz, width), self.kv_dtype))
 
-        #: the cache: one array per pool of the family (``models.families``)
-        #: over the layers that page, every one donated to each program
-        self._pools = tuple(alloc(w) for w in self.family.pool_widths(cfg))
+        #: the cache: one array per pool of the family (``models.families``),
+        #: each over ITS layers and width, every one donated to each program
+        self._pools = tuple(alloc(n, w)
+                            for n, w in families.pool_shapes(cfg))
         #: what the slots keep besides pages (a recurrent family; else
         #: empty), ``[slots, ...]`` each and donated like the pools.  A
         #: slot's rows are written WHOLE by the prefill that seats a stream,
@@ -725,8 +729,9 @@ class PagedDecodeEngine(InferenceEngine):
         whole — the round's ``wait_fetch``, never its ``fetch``.
         ``aux`` (a family with experts: this launch's assignments to each
         held expert and the rows the experts' products computed for them,
-        both summed over the layers) is fetched with it and lands on the
-        fetch leaf."""
+        both summed over the layers; under a learned sparse attention also
+        the positions its real queries saw and picked) is fetched with it
+        and lands on the fetch leaf."""
         tr = self.tracer
         sp = tr.leaf(wait_leaf, self.span_attrs)
         if sp:
@@ -737,7 +742,8 @@ class PagedDecodeEngine(InferenceEngine):
             if sp:
                 nbytes = int(out.nbytes)
                 if aux is not None:
-                    load, rows = (np.asarray(a) for a in jax.device_get(aux))
+                    load, rows, *picks = (np.asarray(a)
+                                          for a in jax.device_get(aux))
                     nbytes += int(load.nbytes + rows.nbytes)
                     load = load.astype(np.int64)
                     self.expert_load = load if self.expert_load is None \
@@ -746,6 +752,14 @@ class PagedDecodeEngine(InferenceEngine):
                            expert_rows_computed=int(rows),
                            expert_tokens_max=int(load.max()),
                            experts_idle=int((load == 0).sum()))
+                    if picks:
+                        # a learned sparse attention's two counts
+                        nbytes += sum(int(p.nbytes) for p in picks)
+                        seen = self.positions_seen.setdefault(
+                            fetch_leaf.split(".")[0], np.zeros(2, np.int64))
+                        seen += np.asarray(picks, np.int64)
+                        sp.set(positions_visible=int(picks[0]),
+                               positions_picked=int(picks[1]))
                 sp.set(bytes=nbytes)
         return out
 
@@ -1272,13 +1286,16 @@ class PagedDecodeEngine(InferenceEngine):
                 # the step walks them (the program's own choice, asked of
                 # where it is made), else the rung of every row
                 form = self.family.attend_form(1, self.kv_int8, self.mesh)
-                pages = (int((p[alive] // self.page_sz + 1).sum())
-                         if form == "kernel" else r * rung)
+                # ... or what a learned sparse attention picks of it
+                read = (int((p[alive] // self.page_sz + 1).sum())
+                        * self.page_sz if form == "kernel" else
+                        r * self.family.read_extent(self.cfg,
+                                                    rung * self.page_sz))
                 sp.set(phase=phase, rows=r, live=int(live),
                        decode=True, paged=True,
                        pages_live=self.allocator.used_pages,
                        attend=form,
-                       kv_positions_read=pages * self.page_sz,
+                       kv_positions_read=read,
                        kv_positions_live=int((p[alive] + 1).sum()),
                        cache_bytes_per_token=self.token_bytes,
                        dtype=self.dtype_label, kv=self._kv_label(),
@@ -1378,6 +1395,13 @@ class PagedDecodeEngine(InferenceEngine):
             "stream_bytes_a_token": int(
                 getattr(self.cfg, "hc_mult", 1) * self.cfg.hidden_size
                 * np.dtype(self.dtype).itemsize),
+            # of token_bytes, a learned sparse attention's index keys
+            "index_bytes_a_token": int(
+                getattr(self.cfg, "num_index_layers", 0)
+                * getattr(self.cfg, "index_cache_width", 0)
+                * np.dtype(self.kv_dtype).itemsize),
+            "positions_seen": {k: [int(x) for x in v]
+                               for k, v in self.positions_seen.items()},
             "kv_pool_bytes": int(sum(p.nbytes for p in self._pools)),
             "state_pool_bytes": int(sum(x.nbytes for x in self._states)),
             "weights_bytes": int(sum(

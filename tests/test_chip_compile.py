@@ -400,3 +400,65 @@ def test_the_four_stream_decode_step_keeps_its_temporaries(one_chip,
     # every held expert's matrices enter the kernels as the stack's own
     # buffer: no bf16[64, 3584, 1024] (one layer's slab) is ever written
     assert not re.search(r"bf16\[64,(3584,1024|1024,3584)\]", text)
+
+
+# ------------------------------- the learned sparse attention's decode step
+
+def test_the_sparse_decode_step_gathers_its_picks_and_never_the_extent(
+        one_chip, monkeypatch):
+    """``glm-5.2-ep16-share``'s ``_pdecode_fn`` at the cell's top rung (32
+    rows, 512 pages a row, latents and index keys in two pools of 7 and 2
+    layers, both donated): the index keys of a row's pages are read whole
+    (``[32, 8192, 128]``), the latents are GATHERED at the 2 048 picked
+    positions a row (``[32, 2048, 640]``) and a row's whole extent of them
+    (``[32, 8192, 640]``: 336 MB a layer) is never built; the program's
+    temporaries stay under 0.7 GiB beside 12.55 GiB of weights and pools
+    (0.593 in this compile; PERF.md section 6, PR 43)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from pdnlp_tpu.models import families
+    from pdnlp_tpu.ops import grouped
+    from pdnlp_tpu.serve.decode import greedy_ids
+
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    cfg = get_config("glm-5.2-ep16-share")
+    family = families.of(cfg)
+    key, bf, i32 = jax.random.key(0), jnp.bfloat16, jnp.int32
+    rows, pages, ps = 32, 8192 // 16, 16
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, head = jax.tree_util.tree_map(
+        lambda x: S(x.shape, x.dtype), jax.eval_shape(
+            lambda: (family.init_params(key, cfg),
+                     family.init_head(key, cfg))))
+    shapes = families.pool_shapes(cfg)
+    assert shapes == ((7, 640), (2, 128))
+    pools = tuple(S((n, rows * pages, ps, w), bf) for n, w in shapes)
+
+    def _pdecode_fn(params, head, pools, tokens, table, pos):
+        logits, aux, pools, _ = family.attend(
+            params, head, cfg, tokens, pools, (), table, pos, None, "last",
+            None, bf)
+        return greedy_ids(logits), aux, pools
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(_pdecode_fn, donate_argnums=(2,)).lower(
+            params, head, pools, S((rows, 1), i32), S((rows, pages), i32),
+            S((rows,), i32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.7 * 2 ** 30, m.temp_size_in_bytes
+    # both pools stay where they lie
+    assert m.alias_size_in_bytes >= sum(
+        n * rows * pages * ps * w * 2 for n, w in shapes)
+    text = compiled.as_text()
+    assert re.search(r"bf16\[32,2048,640\]", text)
+    assert re.search(r"bf16\[32,8192,128\]", text)
+    assert not re.search(r"bf16\[32,8192,640\]", text)

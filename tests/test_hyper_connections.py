@@ -392,6 +392,9 @@ def test_the_big_preset_is_the_configuration_files_numbers_one_by_one():
         "hc_res_clamp": (cfg["mhc_h_res_clamp_min"],
                          cfg["mhc_h_res_clamp_max"]),
         "selection_bias": cfg["topk_method"] == "noaux_tc",
+        # no learned sparse attention: no indexer, and its sizes unread
+        "index_n_heads": 0, "index_head_dim": 128, "index_topk": 2048,
+        "indexer_types": (),
         "weight_dtype": "bfloat16",
     }
     assert set(want) == {f.name for f in dataclasses.fields(pre)}

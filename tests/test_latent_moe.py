@@ -64,6 +64,10 @@ def sizes_of(cfg) -> dict:
         rms_norm_eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
         num_hidden_layers=cfg.num_layers,
         first_k_dense_replace=cfg.first_k_dense, vocab_size=cfg.vocab_size,
+        # the indexer's keys (read by ``glm52_reference`` alone)
+        index_n_heads=cfg.index_n_heads, index_head_dim=cfg.index_head_dim,
+        index_topk=cfg.index_topk, indexer_types=list(cfg.indexer_types),
+        rope_parameters=dict(rope_theta=cfg.rope_theta, rope_type="default"),
         # the mixing's keys (read by ``xing4_reference`` alone)
         hc_mult=cfg.hc_mult, hc_sinkhorn_iters=cfg.hc_sinkhorn_iters,
         hc_eps=cfg.hc_eps, mhc_h_res_clamp_min=cfg.hc_res_clamp[0],
@@ -82,12 +86,19 @@ def program_weights(seed, sizes, held=None, banned=(), ref=ref):
     K = sizes["first_k_dense_replace"]
     ws = [ref.layer_weights(key, sizes, l, held)
           for l in range(sizes["num_hidden_layers"])]
+    if hasattr(ref, "program_layout"):
+        # a reference whose rotary pairs are interleaved: its weights as the
+        # program takes them; its indexers are a stack of their own
+        ws = [ref.program_layout(w, sizes) for w in ws]
+    indexers = [w.pop("indexer") for w in ws if "indexer" in w]
 
     def stack(xs):
         return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *xs)
 
     params = {"embed": top["embed"], "final_norm": top["final_norm"],
               "dense": stack(ws[:K]), "moe": stack(ws[K:])}
+    if indexers:
+        params["indexer"] = stack(indexers)
     return params, {"kernel": top["head"]}
 
 
@@ -112,11 +123,12 @@ def served(tok):
     return serve_three(tok)
 
 
-def serve_three(tok, ref=ref, **kw):
+def serve_three(tok, ref=ref, extra=(), **kw):
     """One engine, three streams one after another, every logits row the
     engine handed its batcher recorded: a cold prompt, a prompt sharing its
     first two pages (the chunk path), and the cold prompt again (a full
-    prefix hit whose trailing partial page is copied on write)."""
+    prefix hit whose trailing partial page is copied on write).  ``extra``:
+    (label, prompt length) of further cold streams served after them."""
     eng, sizes = make_engine(tok, ref=ref, trace=True, **kw)
     eng.warmup_decode()
     rows = []
@@ -134,8 +146,9 @@ def serve_three(tok, ref=ref, **kw):
     cold = rng.integers(5, V, 41).tolist()
     shared = cold[:32] + rng.integers(5, V, 13).tolist()
     out = {}
-    for label, prompt in (("cold", cold), ("prefix_hit", shared),
-                          ("cow", cold)):
+    more = [(label, rng.integers(5, V, n).tolist()) for label, n in extra]
+    for label, prompt in [("cold", cold), ("prefix_hit", shared),
+                          ("cow", cold)] + more:
         del rows[:]
         b = DecodeBatcher(eng, replica=0)
         b.eos_id = -1
@@ -147,6 +160,7 @@ def serve_three(tok, ref=ref, **kw):
     out["prefix"] = eng.prefix.snapshot()
     out["records"] = eng.tracer.records()
     out["kv"], out["load"] = eng.kv_snapshot(), eng.expert_load
+    out["picks"] = eng.positions_seen
     out["cow"] = out["cow"] + (eng.allocator.snapshot(),)
     out["sizes"] = sizes
     out["leak"] = eng.leak_check()
@@ -657,7 +671,8 @@ def test_engine_seam_is_bitwise_for_the_bert_family(tok):
     np.testing.assert_array_equal(np.asarray(eng._cache_v), np.asarray(pv))
 
 
-@pytest.mark.parametrize("model", [MODEL, "xing4-stage-tiny"])
+@pytest.mark.parametrize("model", [MODEL, "xing4-stage-tiny",
+                                   "glm52-share-tiny"])
 @pytest.mark.parametrize("what", ["kv_int8", "weights_int8",
                                   "speculative_pair", "handoff"])
 def test_refusals_are_loud_and_at_construction(tok, what, model):
